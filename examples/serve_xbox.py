@@ -27,10 +27,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddlebox_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
 
 def role_server(args) -> None:
     """Serving fleet on the store root (jax never imports here)."""

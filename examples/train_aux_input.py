@@ -19,10 +19,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddlebox_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
 
 def write_files(out_dir: str, n_lines: int, n_items: int, num_slots: int,
                 vocab: int, seed: int):

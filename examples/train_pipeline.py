@@ -19,10 +19,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddlebox_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
 
 def main() -> None:
     ap = argparse.ArgumentParser()

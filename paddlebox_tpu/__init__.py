@@ -21,10 +21,5 @@ Layer map (TPU-native analog of reference SURVEY.md §1):
 
 from paddlebox_tpu.version import __version__
 
+# the package import stays jax-free (serving replicas, host tools)
 from paddlebox_tpu.config import flags  # noqa: F401
-# jax compat shims apply when jax itself is imported — NOT eagerly here:
-# the package import stays jax-free (serving replicas, host tools), while
-# every jax-using flow still sees the patched spellings before first use
-from paddlebox_tpu.utils.compat_hook import install_deferred as _icd
-
-_icd()

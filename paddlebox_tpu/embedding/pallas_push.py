@@ -9,14 +9,17 @@ mf creation drawn from the on-core PRNG — in VMEM tiles on the VPU.
 
 Semantics match `apply_push` (embedding/optimizers.py) for the adagrad
 layout with no expand block; `push_sparse_dedup` routes here when the
-`use_pallas_push` flag is on (XLA path otherwise — measured on v5e the
-two are at parity for small widths; the kernel exists for the wide-embedx
-configs where XLA's fusion of the 20+ column updates splinters).
+`use_pallas_push` flag is on (XLA path otherwise; the kernel exists for
+the wide-embedx configs where XLA's fusion of the 20+ column updates might
+splinter — which of the two is faster on the chip is not measured).
+Both kernels here compile through Mosaic on the v5e and match their XLA
+oracles there (chip_smoke.py's kernel leg).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +31,21 @@ from paddlebox_tpu.embedding import accessor as acc
 from paddlebox_tpu.embedding.accessor import PushLayout, ValueLayout
 
 _TILE = 256
+
+
+def pallas_interpret() -> bool:
+    """The ONE rule both kernels dispatch by: compiled by Mosaic on ``tpu``,
+    interpreted on ``cpu`` (the test platform, where interpret mode is the
+    only way the kernel body runs at all), an error anywhere else — a
+    backend nobody recognised must not quietly run a python-rate kernel."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        "pallas push kernels are compiled on 'tpu' and interpreted on "
+        f"'cpu' only; default backend is {backend!r}")
 
 
 def _adagrad(w, g2sum, scaled, lr, conf):
@@ -118,13 +136,12 @@ def _blocked_write_kernel(bidx_ref, slab_ref, tiles_ref, rmap_ref, out_ref):
     (push_blocked_write) therefore orders every sentinel slot BEFORE the
     real write of the block it clamps onto — a revisit-before-update is an
     identity write of the block's original bits, which is pipeline-safe."""
-    rm = rmap_ref[0]
-    out_ref[:] = jnp.where((rm >= 0)[:, None], tiles_ref[0], slab_ref[:])
+    out_ref[:] = jnp.where(rmap_ref[0] >= 0, tiles_ref[0], slab_ref[:])
 
 
 def pallas_blocked_write(slab: jnp.ndarray, tiles: jnp.ndarray,
                          row_map: jnp.ndarray, blk_idx: jnp.ndarray,
-                         interpret: bool = False) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """Blocked slab placement (round 11, `push_blocked_pallas`): the grid
     runs over the NB touched blocks with the block ids SCALAR-PREFETCHED —
     each step's in/out BlockSpec index maps through blk_idx[i], so the
@@ -143,6 +160,7 @@ def pallas_blocked_write(slab: jnp.ndarray, tiles: jnp.ndarray,
              the caller; their row_map is all -1 so the write is a no-op
              — and the caller must schedule them BEFORE the real write of
              the clamped block, see _blocked_write_kernel)
+    interpret: None = pallas_interpret()'s backend rule
     """
     NB, B, W = tiles.shape
     C = slab.shape[0]
@@ -155,7 +173,11 @@ def pallas_blocked_write(slab: jnp.ndarray, tiles: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((B, W), lambda i, b: (b[i], 0)),
             pl.BlockSpec((1, B, W), lambda i, b: (i, 0, 0)),
-            pl.BlockSpec((1, B), lambda i, b: (i, 0)),
+            # row_map rides as [NB, B, 1]: the TPU lowering wants a block's
+            # last two dims divisible by (8, 128) or equal to the array's,
+            # which a (1, B) slice of [NB, B] is not; the (B, 1) column
+            # lane-broadcasts against the (B, W) tiles in the kernel
+            pl.BlockSpec((1, B, 1), lambda i, b: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((B, W), lambda i, b: (b[i], 0)),
     )
@@ -166,19 +188,20 @@ def pallas_blocked_write(slab: jnp.ndarray, tiles: jnp.ndarray,
         # operand 0 is the scalar-prefetch vector; the slab (operand 1)
         # aliases the output so untouched blocks keep their bits
         input_output_aliases={1: 0},
-        interpret=interpret,
-    )(blk_idx, slab, tiles, row_map)
+        interpret=pallas_interpret() if interpret is None else interpret,
+    )(blk_idx, slab, tiles, row_map.reshape(NB, B, 1))
 
 
 def pallas_apply_push(values: jnp.ndarray, grads: jnp.ndarray, seed,
                       layout: ValueLayout,
                       conf: SparseOptimizerConfig,
-                      interpret: bool = False,
+                      interpret: Optional[bool] = None,
                       row_ids=None) -> jnp.ndarray:
     """Drop-in for apply_push (adagrad, no expand block). values padded to
     a _TILE multiple by the caller-invisible grid; seed: int32 scalar;
     row_ids: [n] slab ids keying the creation randoms (positional arange
-    fallback when the caller has none)."""
+    fallback when the caller has none); interpret: None = pallas_interpret()'s
+    backend rule."""
     if layout.optimizer != "adagrad" or layout.expand_dim:
         raise ValueError("pallas push kernel supports the adagrad layout "
                          "without expand block")
@@ -209,6 +232,6 @@ def pallas_apply_push(values: jnp.ndarray, grads: jnp.ndarray, seed,
         kernel,
         out_shape=jax.ShapeDtypeStruct((n_pad, width), values.dtype),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(seed_arr, values, grads, row_ids)
     return out[:n]

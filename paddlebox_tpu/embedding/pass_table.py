@@ -199,9 +199,8 @@ def dedup_uids_sorted(ids: np.ndarray, pad_base: int) -> np.ndarray:
     dedup over the K occurrences, then an LSD radix sort of the n_u
     UNIQUES only (byte passes skip when constant), so heavy key
     recurrence pays one byte store per occurrence + O(n_u) sort instead
-    of np.unique's comparison sort of the whole occurrence vector
-    (measured best-of-7 1.1x at dup 2 up to 4.5x at dup 64, BASELINE.md
-    round 11). The kernel DECLINES low-duplication shapes and any id
+    of np.unique's comparison sort of the whole occurrence vector.
+    The kernel DECLINES low-duplication shapes and any id
     outside [0, pad_base) — both return -1 and this wrapper keeps the
     numpy tier, which also remains the oracle the sortedness contract
     test pins both against (tests/test_wire_modes.py).
@@ -213,7 +212,7 @@ def dedup_uids_sorted(ids: np.ndarray, pad_base: int) -> np.ndarray:
     the one far outlier, which the kernel tracks out-of-band. Engaging
     requires 2*span <= K, which guarantees mean duplication
     K/n_unique >= 2 (n_unique <= span) — production bucket
-    concatenations now take the native tier (BASELINE.md round 13)."""
+    concatenations now take the native tier."""
     ids = np.ascontiguousarray(np.asarray(ids), np.int32)
     K = ids.shape[0]
     if K and ids.min() < 0:

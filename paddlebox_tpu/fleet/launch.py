@@ -6,6 +6,13 @@ set, hosts the rendezvous KV store in the launcher process, forwards the
 script's stdout/stderr, and propagates the first non-zero exit code.
 
     python -m paddlebox_tpu.fleet.launch --nproc 2 train.py --epochs 3
+
+One host = one process = one mesh over its local chips: a chip belongs to
+one process at a time, and nothing here divides a host's chips between
+children. `--nproc N` with N > 1 on ONE host is therefore the CPU-backend
+tier (JAX_PLATFORMS=cpu: the multi-process tests and examples, each child
+with its own virtual devices); on a chip host run one process (N = 1 per
+host) and let its mesh span the local chips (device_mesh_1d()).
 """
 
 from __future__ import annotations

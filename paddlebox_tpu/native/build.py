@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -11,7 +12,9 @@ from typing import Optional
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD = os.path.join(_DIR, "_build")
 _SOURCES = ["slot_parser.cc", "host_store.cc", "route.cc"]
-_LIB_NAME = "libpbtpu_native.so"
+# portable codegen (no -march=native: the .so may outlive the host that
+# compiled it)
+_CXX = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
 
 # RLock: get_lib is reachable from __del__ paths (destroy_route_index via
 # store/table finalizers) — a GC-triggered finalizer on the thread that is
@@ -21,28 +24,31 @@ _lib: Optional[ctypes.CDLL] = None
 _failed = False
 
 
-def _needs_build(so_path: str) -> bool:
-    if not os.path.exists(so_path):
-        return True
-    so_m = os.path.getmtime(so_path)
-    return any(os.path.getmtime(os.path.join(_DIR, s)) > so_m
-               for s in _SOURCES)
+def _lib_path() -> str:
+    """``_build/libpbtpu_native.<hash>.so`` where the hash covers the bytes
+    of every source and the compile command: a library left behind by other
+    sources (``_build/`` is git-ignored and travels with copied trees, where
+    mtimes say nothing) has another name and can never be loaded."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for src in _SOURCES:
+        with open(os.path.join(_DIR, src), "rb") as fh:
+            h.update(fh.read())
+    return os.path.join(_BUILD,
+                        "libpbtpu_native.%s.so" % h.hexdigest()[:16])
 
 
 def _build() -> str:
-    os.makedirs(_BUILD, exist_ok=True)
-    so_path = os.path.join(_BUILD, _LIB_NAME)
-    if _needs_build(so_path):
+    so_path = _lib_path()
+    if not os.path.exists(so_path):
+        os.makedirs(_BUILD, exist_ok=True)
         srcs = [os.path.join(_DIR, s) for s in _SOURCES]
-        # portable codegen (no -march=native: the .so may outlive the host
-        # that compiled it); per-process tmp name so concurrent first-import
-        # builds can't clobber each other's output before os.replace
+        # per-process tmp name so concurrent first-import builds can't
+        # clobber each other's output before os.replace
         tmp = f"{so_path}.{os.getpid()}.tmp"
-        cmd = ["g++", "-O3", "-shared", "-fPIC",
-               "-std=c++17", "-o", tmp, *srcs]
         # bounded: a wedged toolchain must fail loudly into the degraded
         # pure-python tier, not hang import/teardown forever (BX802)
-        subprocess.run(cmd, check=True, capture_output=True, timeout=600)
+        subprocess.run([*_CXX, "-o", tmp, *srcs], check=True,
+                       capture_output=True, timeout=600)
         os.replace(tmp, so_path)
     return so_path
 

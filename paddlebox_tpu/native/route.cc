@@ -24,8 +24,7 @@
 // then draw the same generation and read each other's seen-marks, silently
 // mis-routing an occurrence of a key both batches carried — the PR-6
 // 6/780-elements show-off-by-one flake (reproduced + pinned by
-// tools/sharded_stress_probe.py's concurrent-parity leg, BASELINE.md
-// round 12). Cost of the fix: one scratch table per ROUTING THREAD
+// tools/sharded_stress_probe.py's concurrent-parity leg). Cost of the fix: one scratch table per ROUTING THREAD
 // (~20 B per next_pow2(2K) slots, e.g. ~5 MB/thread at K=128k) instead of
 // one per index. rt_index_create itself must still finish before the
 // first concurrent consumer — the pass-cadence callers already guarantee
@@ -449,7 +448,7 @@ int64_t rt_dedup(const int32_t* ids, int64_t K, int32_t pad_base,
 // (K/n_unique is not computable before deduping; the span is its
 // cheapest sound upper bound). The round-11 benchmark shapes (ids
 // spread over the full [0, pad_base)) keep their old decline; the wired
-// production shapes now engage (BASELINE.md round 13).
+// production shapes now engage.
 //   uids[K]  ascending uniques, tail padded with pad_base+i
 //   scratch  caller int64[K] (>= n_u int32 ping-pong buffer)
 // Returns the unique count, -1 when declining (low-duplication span, or

@@ -657,6 +657,11 @@ def instrument_jit(fn: Callable, name: str,
     Flag ``device_obs`` off returns bare jax.jit(fn, ...) — identical
     call surface minus the monitoring."""
     from paddlebox_tpu.config import flags
+    from paddlebox_tpu.utils.platform import ensure_compile_cache
+    # every jit entry comes through here, so this is where the persistent
+    # compile cache gets its directory (the AOT lower().compile() below
+    # goes through the same cache as jax.jit's own dispatch)
+    ensure_compile_cache()
     if not flags.get_flag("device_obs"):
         import jax
         kw = dict(jit_kwargs)

@@ -214,6 +214,9 @@ class ShardedBoxTrainer:
                                                field="a2a_dtype")
         self.a2a_cast = self.a2a_dtype != jnp.float32
         self._slabs: Optional[jax.Array] = None
+        #: slab_placement() of the last finished pass, taken after its
+        #: write-back and before the slab stack is dropped
+        self.last_slab_placement: Optional[dict] = None
         self._prng = jax.random.PRNGKey(seed + 17)
         self._shuffle_rng = np.random.RandomState(seed + 1)
         self._step_count = 0
@@ -875,6 +878,23 @@ class ShardedBoxTrainer:
             producer.join(timeout=10.0)
 
     # ---------------------------------------------------------- pass cadence
+    def slab_placement(self) -> Optional[dict]:
+        """Where the live pass slab stack sits: its global shape and, per
+        addressable shard, the device id, the shard's shape and what that
+        device's allocator holds right now (None where the backend keeps
+        no memory_stats, i.e. the CPU). None between passes."""
+        if self._slabs is None:
+            return None
+        shards = []
+        for s in self._slabs.addressable_shards:
+            ms = s.device.memory_stats()
+            shards.append({"device": s.device.id,
+                           "shape": list(s.data.shape),
+                           "bytes_in_use": int(ms["bytes_in_use"])
+                           if ms else None})
+        shards.sort(key=lambda d: d["device"])
+        return {"shape": list(self._slabs.shape), "shards": shards}
+
     def train_pass(self, dataset: BoxDataset,
                    preloaded: bool = False) -> Dict[str, float]:
         t_pass = self.timers["pass"]
@@ -1023,6 +1043,7 @@ class ShardedBoxTrainer:
             # (the pre-round-6 full np.asarray rode here every pass)
             self.table.end_pass_write_back(self._slabs)
         self.table.check_need_limit_mem()
+        self.last_slab_placement = self.slab_placement()
         self._slabs = None
         t_pass.pause()
         mean_loss = float(np.mean(losses)) if losses else 0.0

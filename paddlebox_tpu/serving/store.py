@@ -14,8 +14,7 @@ process — serving fleet children spawn in milliseconds):
   * columnar file   — ``write_xbox_columnar`` / ``MmapXboxStore``: one
                       binary per view (sorted key column + row matrix,
                       64-byte aligned), native hash index over the mmap'd
-                      key column (~1 probe/key; 10.75M keys/s at a 30M
-                      base, BASELINE.md round-5 xbox table)
+                      key column (~1 probe/key)
   * view compile    — ``compile_view_dir``: an xbox view dir's
                       embedding.pkl → ``view.xcol`` next to it, written
                       once (atomic, mtime-gated) and shared by every

@@ -24,8 +24,8 @@ through :func:`make_lock` / :func:`make_rlock`, which
 
 When the flag is off (default) the factories return plain
 ``threading.Lock``/``RLock`` objects — a construction-time branch, zero
-per-acquire cost, measured at parity on the bench step block
-(BASELINE.md round 19).
+per-acquire cost (bench.py's lockwatch_overhead block asserts the type
+identity).
 
 The StatRegistry's own ``_lock`` is deliberately NEVER watched: the
 release path publishes hold-time samples INTO the registry, so watching

@@ -23,9 +23,6 @@ if "host_platform_device_count" not in flags:
 os.environ["PBTPU_DATASET_DISABLE_SHUFFLE"] = "1"  # strict parity
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 

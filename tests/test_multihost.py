@@ -30,19 +30,6 @@ D = 4
 NUM_SLOTS = 4
 PASSES = 2
 
-# jax 0.4.x cannot run multi-controller collectives on the CPU backend —
-# every worker dies with "Multiprocess computations aren't implemented on
-# the CPU backend" after ~30 s of cluster bring-up per test. Skip the
-# whole module there rather than burn ~4 min of tier-1 budget on doomed
-# subprocess clusters (BASELINE.md round-7 drift note); the tests run
-# unchanged on real multi-host TPU and on jax >= 0.5 CPU.
-_jax_major_minor = tuple(int(x) for x in
-                         __import__("jax").__version__.split(".")[:2])
-pytestmark = pytest.mark.skipif(
-    os.environ.get("JAX_PLATFORMS", "") == "cpu" and _jax_major_minor < (0, 5),
-    reason="jax 0.4.x CPU backend: multiprocess collectives unimplemented")
-
-
 @pytest.fixture(scope="module")
 def data(tmp_path_factory):
     out = tmp_path_factory.mktemp("mh_data")

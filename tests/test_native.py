@@ -178,8 +178,8 @@ def test_concurrent_bucketize_parity():
     GIL), so concurrent routings must be bit-identical to serial ones.
     The pre-fix per-INDEX dedup scratch let concurrent callers draw the
     same generation and read each other's seen-marks — a silently
-    mis-routed occurrence (the PR-6 show-off-by-one flake class,
-    BASELINE.md round 12); this reproduced it in the first few trials.
+    mis-routed occurrence (the PR-6 show-off-by-one flake class);
+    this reproduced it in the first few trials.
     Scratch is per-thread now."""
     from concurrent.futures import ThreadPoolExecutor
 
@@ -215,3 +215,37 @@ def test_concurrent_bucketize_parity():
                 np.testing.assert_array_equal(got.restore, want.restore)
     finally:
         pool.shutdown(wait=False)
+
+
+def test_library_name_carries_source_hash(tmp_path, monkeypatch):
+    """The .so's file name is a content hash of the three sources and the
+    compile command: a library left in _build/ by OTHER sources (the dir
+    is git-ignored and travels with copied trees, where mtimes say
+    nothing) has another name and is never loaded — the build compiles
+    the sources that are actually there."""
+    import os
+    import shutil
+
+    from paddlebox_tpu.native import build
+
+    loaded = build._lib_path()
+    assert os.path.exists(loaded)           # what this process runs on
+    src_dir = tmp_path / "native"
+    src_dir.mkdir()
+    for src in build._SOURCES:
+        shutil.copy(os.path.join(build._DIR, src), src_dir / src)
+    with open(src_dir / build._SOURCES[-1], "a") as fh:
+        fh.write("\n// edited\n")
+    stale = src_dir / "_build" / os.path.basename(loaded)
+    stale.parent.mkdir()
+    stale.write_bytes(b"not a shared object")   # the would-be stale .so
+    monkeypatch.setattr(build, "_DIR", str(src_dir))
+    monkeypatch.setattr(build, "_BUILD", str(stale.parent))
+    fresh = build._lib_path()
+    assert os.path.basename(fresh) != os.path.basename(loaded)
+    assert not os.path.exists(fresh)    # so _build() compiles, not loads
+    # the compile command is in the hash too
+    monkeypatch.setattr(build, "_CXX", build._CXX + ["-DX"])
+    assert build._lib_path() != fresh
+    monkeypatch.undo()
+    assert build._lib_path() == loaded

@@ -1,5 +1,6 @@
 """Pallas in-table adagrad kernel vs the XLA apply_push oracle (interpret
-mode on the CPU mesh; on-chip execution is covered by bench/driver runs)."""
+mode on the CPU mesh; the compiled kernel runs against the same oracle on
+the chip in chip_smoke.py's kernel leg)."""
 
 import numpy as np
 import jax
@@ -114,21 +115,44 @@ def test_flagged_push_sparse_dedup_roundtrip():
     ids = jnp.asarray(np.arange(64, dtype=np.int64))
     flags.set_flag("use_pallas_push", True)
     try:
-        # interpret path: monkeypatch via direct call comparison instead —
-        # on CPU the real kernel needs interpret, so compare the underlying
-        # update fns (the flag wiring itself is exercised by tracing)
-        import paddlebox_tpu.embedding.pallas_push as pp
-        orig = pp.pallas_apply_push
-        pp.pallas_apply_push = lambda v, g, s, l, cf, **kw: orig(
-            v, g, s, l, cf, interpret=True, **kw)
-        try:
-            out = push_sparse_dedup(slab, ids, jnp.asarray(grads),
-                                    jax.random.PRNGKey(0), layout, c)
-        finally:
-            pp.pallas_apply_push = orig
+        # the dispatch site passes no interpret: pallas_interpret()'s rule
+        # picks interpret mode on the cpu backend by itself
+        out = push_sparse_dedup(slab, ids, jnp.asarray(grads),
+                                jax.random.PRNGKey(0), layout, c)
     finally:
         flags.set_flag("use_pallas_push", False)
     want = push_sparse_dedup(slab, ids, jnp.asarray(grads),
                              jax.random.PRNGKey(0), layout, c)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
+
+
+def test_interpret_rule_cpu_tpu_or_error(monkeypatch):
+    """One rule for both kernels' dispatch: compiled on tpu, interpreted on
+    cpu, an ERROR on a backend nobody recognised (never a quiet python-rate
+    kernel)."""
+    from paddlebox_tpu.embedding import pallas_push as pp
+    assert pp.pallas_interpret() is True            # the test platform
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pp.pallas_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="interpreted on 'cpu' only"):
+        pp.pallas_interpret()
+
+
+def test_apply_push_kernel_lowers_for_tpu():
+    """Cross-lower the COMPILED kernel for the TPU at production shapes
+    (W=17, a 4,096-row update): a BlockSpec the TPU lowering refuses fails
+    here, on the CPU, instead of on the chip."""
+    layout = ValueLayout(embedx_dim=D, optimizer="adagrad")
+    n, c = 4096, conf(create_thres=0.0)
+
+    def push(vals, grads, rid):
+        return pallas_apply_push(vals, grads, jnp.int32(7), layout, c,
+                                 interpret=False, row_ids=rid)
+
+    exp = jax.export.export(jax.jit(push), platforms=["tpu"])(
+        jax.ShapeDtypeStruct((n, layout.width), jnp.float32),
+        jax.ShapeDtypeStruct((n, PushLayout(D).width), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.int32))
+    assert "tpu_custom_call" in exp.mlir_module()

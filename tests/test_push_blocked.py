@@ -9,8 +9,9 @@ Contracts under test:
     and through the sharded runners' 2-virtual-process staging. The
     staging side must pin the sorted dedup tier (dedup_ids sort=True):
     the native rt_dedup hash order would silently drop rows.
-  * push_blocked_pallas: the Mosaic placement kernel (interpreted off-
-    TPU) is a drop-in for the fori_loop of dynamic_update_slices.
+  * push_blocked_pallas: the Mosaic placement kernel (interpreted on
+    the CPU; its TPU lowering is checked by cross-lowering) is a
+    drop-in for the fori_loop of dynamic_update_slices.
   * push_onehot_rows (merge_grads_onehot): MXU one-hot accumulation for
     the hot short tail — exact for integer-representable grads (f32
     accumulation ORDER differs, so the parity pin uses integer grads).
@@ -18,7 +19,7 @@ Contracts under test:
     round to bf16 at the slab write; the header and ALL optimizer stats
     round-trip BIT-EXACTLY through encode/decode, the store/checkpoint
     round trip, and a full pass. Training quality is AUC-parity gated
-    (no bit oracle — the tolerance is recorded in BASELINE.md round 11).
+    (no bit oracle).
 """
 
 import numpy as np
@@ -147,7 +148,7 @@ def test_push_blocked_write_all_pad_and_dense():
 
 
 def test_pallas_blocked_write_matches_fori():
-    """push_blocked_pallas (interpreted off-TPU): the Mosaic grid
+    """push_blocked_pallas (interpreted on the CPU): the Mosaic grid
     placement is bit-identical to the XLA fori_loop tier."""
     import jax.numpy as jnp
 
@@ -168,6 +169,30 @@ def test_pallas_blocked_write_matches_fori():
     finally:
         flags.set_flag("push_blocked_pallas", False)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(got))
+
+
+def test_pallas_blocked_write_lowers_for_tpu():
+    """Cross-lower the COMPILED placement kernel for the TPU at production
+    shapes (1M x 17 slab, 512-row blocks, a 4,096-row update): a BlockSpec
+    the TPU lowering refuses (the pre-PR-21 (1, B) row_map block) fails
+    here, on the CPU, instead of on the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddlebox_tpu.embedding.pallas_push import pallas_blocked_write
+
+    C, W, B, NB = 1 << 20, 17, 512, 2048
+
+    def place(slab, tiles, row_map, blk_idx):
+        return pallas_blocked_write(slab, tiles, row_map, blk_idx,
+                                    interpret=False)
+
+    exp = jax.export.export(jax.jit(place), platforms=["tpu"])(
+        jax.ShapeDtypeStruct((C, W), jnp.float32),
+        jax.ShapeDtypeStruct((NB, B, W), jnp.float32),
+        jax.ShapeDtypeStruct((NB, B), jnp.int32),
+        jax.ShapeDtypeStruct((NB,), jnp.int32))
+    assert "tpu_custom_call" in exp.mlir_module()
 
 
 def test_resolve_blocked_validation():
@@ -450,9 +475,8 @@ def test_blocked_bf16_matches_scatter_bf16(data):
 def test_bf16_slab_trains_with_auc_parity(data):
     """The bf16 AUC-parity gate (no bit oracle: weights round at every
     slab write): same data, same seeds, slab f32 vs bf16 — streaming AUC
-    must stay within the recorded tolerance (measured |Δ| ≈ 2e-6 on this
-    container at this shape, gated at 0.01; BASELINE.md round 11) and
-    both clearly above chance."""
+    must stay within the tolerance (gated at 0.01) and both clearly
+    above chance."""
     from paddlebox_tpu.train import BoxTrainer
 
     files, feed = data

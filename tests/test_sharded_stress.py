@@ -2,8 +2,7 @@
 
 Chases the PR-6 flake (test_sharded_blocked_matches_scatter failed once
 under native-recompile load: 6/780 show-like elements off by one —
-never reproduced; see BASELINE.md round 12 for the accumulated
-reproduction bound). The harness lives in tools/sharded_stress_probe.py
+never reproduced). The harness lives in tools/sharded_stress_probe.py
 so campaigns can run long outside pytest; this suite keeps it honest:
 
   * the tier-flip hypothesis check runs for real (native vs numpy
@@ -31,7 +30,7 @@ def test_router_tier_flip_product_match(stress_data):
     flaky test's shape (no bucket overflow): a mid-run recompile window
     flipping the tier cannot explain the PR-6 flake here. If THIS ever
     fails, the flake mechanism is pinned — record the diff and the
-    bucketize-overflow state in BASELINE.md."""
+    bucketize-overflow state in PERF.md."""
     from tools.sharded_stress_probe import run_tier_flip
     files, feed = stress_data
     diff = run_tier_flip(files, feed, seed=13)
@@ -41,7 +40,7 @@ def test_router_tier_flip_product_match(stress_data):
 def test_seeded_stress_rep_parity(stress_data):
     """One harness rep under burner load: blocked == scatter bit-exact
     on both wires. A failure here is the PR-6 flake reproducing —
-    DON'T retry it away; capture the seed + diff into BASELINE.md."""
+    DON'T retry it away; capture the seed + diff into PERF.md."""
     from tools.sharded_stress_probe import LoadBurners, run_rep
     files, feed = stress_data
     burners = LoadBurners(2)

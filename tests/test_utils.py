@@ -91,3 +91,35 @@ def test_channel_get_many():
     got = ch.get_many(4)
     assert got == [0, 1, 2, 3]
     assert len(ch) == 6
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """ensure_compile_cache: where JAX_COMPILATION_CACHE_DIR is set the
+    caller placed the cache and the program sets nothing; unset, the cache
+    is the FIXED <checkout>/.jax_cache (the path is part of the cache key —
+    no tempfile, pid or time in it)."""
+    import os
+
+    import jax
+
+    from paddlebox_tpu.utils import platform
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert platform.DEFAULT_COMPILE_CACHE == os.path.join(repo, ".jax_cache")
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert platform.ensure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None   # left alone
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert platform.ensure_compile_cache() == \
+            platform.DEFAULT_COMPILE_CACHE
+        assert jax.config.jax_compilation_cache_dir == \
+            platform.DEFAULT_COMPILE_CACHE
+        # a directory the caller gave jax.config itself is not overridden
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert platform.ensure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

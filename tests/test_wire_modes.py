@@ -18,8 +18,8 @@ function of (slab, batch, prng) regardless of WHERE the dedup ran:
     uid_only); the step derives the maps from the a2a'd bucket ids —
     composes with the 2-process host-plane bucket exchange
 
-The measured motivation (wire bytes vs device-sort trade) is bench.py's
-e2e ladder / BASELINE.md round 8."""
+The motivation (wire bytes vs device-sort trade) is what bench.py's e2e
+ladder measures."""
 
 import dataclasses
 

@@ -1,17 +1,16 @@
 """Bench trajectory: cross-round deltas of the headline BENCH rates.
 
-Reads every ``BENCH_r*.json`` in the repo root (the driver-archived
-rounds 1-5 and the self-stamped rounds bench.py writes from round 14
-on — both use the ``{"n", "parsed"}`` envelope), orders them by round
-number, and prints one line per headline metric per consecutive pair:
-absolute values, the delta, and a REGRESSION flag when a
-higher-is-better rate drops (or ms/step rises) by more than
+Reads every ``BENCH_r*.json`` under ``--root`` (the ``{"n", "parsed"}``
+envelope bench.py writes where ``PBTPU_BENCH_OUT`` points — it writes
+nothing into the checkout by itself, so the series is one the caller
+collects), orders them by round number, and prints one line per headline
+metric per consecutive pair: absolute values, the delta, and a REGRESSION
+flag when a higher-is-better rate drops (or ms/step rises) by more than
 ``--threshold`` (default 10%).
 
-Honesty guards: rounds on different platforms (a TPU round vs a
-CPU-fallback round) are never compared — the platform column makes the
-tier visible; zero/absent values (failed rounds, pre-round fields)
-compare as "n/a" rather than as infinite regressions.
+Honesty guards: rounds on different platforms are never compared — the
+platform column makes the tier visible; zero/absent values (failed rounds,
+pre-round fields) compare as "n/a" rather than as infinite regressions.
 
 Usage:
     python tools/bench_trend.py [--root PATH] [--threshold 0.10] [--json]

@@ -29,7 +29,7 @@ median staging ms + exchange bytes/step from the hostplane counters
 product parity per rank, and per-rank received-byte imbalance. The
 acceptance bar: the hot-tier leg must cut per-rank exchange bytes vs
 key-mod (routing alone conserves total routed ids — only replication
-removes bytes from this host plane; see BASELINE.md round 13).
+removes bytes from this host plane).
 
 Usage:  timeout 900 python -u tools/hostplane_probe.py [--worlds 2,4]
             [--kb 32768] [--steps 4] [--runs 3] [--policies]

@@ -18,8 +18,7 @@ plane, not day-training.
 
 Usage: timeout 1800 python -u tools/serving_load_probe.py \
         [n_keys] [dim] [secs_per_point]
-Prints one JSON line per measurement; "stage" keys match BASELINE.md's
-round-12 table.
+Prints one JSON line per measurement, keyed by "stage".
 """
 import json
 import os

@@ -1,9 +1,8 @@
-"""Multi-device (virtual-mesh) benchmark of ShardedBoxTrainer + the stager.
+"""Multi-device benchmark of ShardedBoxTrainer + the stager.
 
-The round-3 verdict's item 2/3: BASELINE.md had no multi-device throughput
-row on ANY backend — the software overhead of sharding (host routing, push
-dedup, device_put, a2a) had never been timed. This tool measures, on the
-8-device CPU mesh (or whatever JAX exposes):
+The software overhead of sharding (host routing, push dedup, device_put,
+a2a), on whatever devices JAX exposes (JAX_PLATFORMS=cpu: eight virtual
+CPU devices; a chip host: its local chips, one process):
 
   1. stager routing throughput (keys/s) at 1 vs N threads — the
      _step_host_arrays bucketize + push-dedup stage (flag stager_threads);
@@ -12,10 +11,10 @@ dedup, device_put, a2a) had never been timed. This tool measures, on the
   3. per-step cost attribution: host routing, device_put, step dispatch.
 
 Shapes match bench.py (DeepFM 512/256/128, batch 1024/worker, 32 slots,
-1M-row pass slab) so the numbers compose with BASELINE.md's tables.
-Emits one JSON dict on stdout.
+1M-row pass slab per device). Emits one JSON dict on stdout, which names
+the platform it ran on; a CPU run's times say nothing about the chip.
 
-Run: python tools/sharded_bench.py  (forces cpu + 8 virtual devices)
+Run: python tools/sharded_bench.py
 """
 
 import json
@@ -24,9 +23,9 @@ import sys
 import time
 
 if __name__ == "__main__":
+    # only the CPU backend reads this: eight virtual devices there
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
-    os.environ["JAX_PLATFORMS"] = "cpu"
     # run as a script: sys.path[0] is tools/, the repo root isn't there
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -44,7 +43,6 @@ WARMUP = 2
 
 def build_sharded():
     import jax
-    jax.config.update("jax_platforms", "cpu")
     from tools.bench_util import make_ctr_batches
 
     from paddlebox_tpu.config.configs import (SparseOptimizerConfig,
@@ -194,7 +192,10 @@ def time_single_device() -> dict:
 
 def main():
     trainer, per_worker, P = build_sharded()
-    out = {"devices": P, "batch_per_device": BATCH,
+    import jax
+    dev = jax.devices()[0]
+    out = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "devices": P, "batch_per_device": BATCH,
            "keys_per_step": sum(b.keys.size for pw in per_worker
                                 for b in (pw[0],))}
     out["stager"] = [time_stager(trainer, per_worker, t)

@@ -35,7 +35,6 @@ the regression pin, and this harness remains the e2e-level guard.
 
 Every line is JSON; a parity mismatch prints the differing element
 count / max abs diff / affected columns + the rep's seed, and exits 1.
-BASELINE.md round 12 records the accumulated reproduction bound.
 
 Usage:
   timeout 3600 python -u tools/sharded_stress_probe.py \
@@ -53,9 +52,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddlebox_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np  # noqa: E402
 

@@ -1,7 +1,7 @@
 """Compiled-step audit: XLA cost + memory analysis of the fused train step.
 
-The bench argues from the HBM roofline (BASELINE.md): examples/sec is
-bounded by bytes-moved per example. This tool asks the COMPILER what the
+The bench argues from the HBM roofline: examples/sec is bounded by
+bytes-moved per example. This tool asks the COMPILER what the
 step actually moves — flops, bytes accessed, temp allocation — so the
 "step is byte-minimal" claim is evidence, not belief:
 
@@ -36,10 +36,6 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from paddlebox_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 
 def audit(pass_cap: int = 1 << 20, batch: int = 1024, num_slots: int = 32,
