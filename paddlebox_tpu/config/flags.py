@@ -185,8 +185,6 @@ define_flag("stream_depth", 2,
             "(peak live routed steps is this + 2: one in the consumer's "
             "hands, one in flight on the stager thread; boxps "
             "device_reader_->Next double-buffer role)")
-define_flag("profile_per_op", False,
-            "accumulate per-op timing in the train loop (TrainFilesWithProfiler)")
 define_flag("push_write", "auto",
             "how the push writes updated rows back into the pass slab: "
             "'scatter' (row scatter, cost ~ touched rows — right for CPU "

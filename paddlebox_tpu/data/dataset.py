@@ -25,6 +25,7 @@ from paddlebox_tpu.data.packer import BatchPacker, PackedBatch
 from paddlebox_tpu.data.parser import MultiSlotParser
 from paddlebox_tpu.data.slot_record import SlotRecord
 from paddlebox_tpu.obs.tracer import span as obs_span
+from paddlebox_tpu.obs.tracer import with_current_trace
 from paddlebox_tpu.utils.channel import Channel, ChannelClosed
 from paddlebox_tpu.utils.stats import stat_add
 from paddlebox_tpu.utils.timer import Timer
@@ -296,12 +297,16 @@ class BoxDataset:  # boxlint: disable=BX403
                 except ChannelClosed:
                     pass
 
+        # the pass these threads load for (train/preload.py holds its id
+        # around this call): their spans carry it, not the training pass's
+        read_worker = with_current_trace(read_worker)
         readers = [threading.Thread(target=read_worker, daemon=True)
                    for _ in range(max(1, self.read_threads))]
         for th in readers:
             th.start()
         self._preload_threads = readers
-        self._merge_thread = threading.Thread(target=merge_worker, daemon=True)
+        self._merge_thread = threading.Thread(
+            target=with_current_trace(merge_worker), daemon=True)
         self._merge_thread.start()
 
     def _put_records(self, recs: List[SlotRecord]) -> None:

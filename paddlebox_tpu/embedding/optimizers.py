@@ -254,12 +254,17 @@ def push_sparse_dedup(slab: jnp.ndarray, ids: jnp.ndarray,
     """
     K = ids.shape[0]
     trash = slab.shape[0] - 1
-    uids, inv = jnp.unique(ids, size=K, fill_value=trash, return_inverse=True)
-    merged = jnp.zeros((K, grads.shape[1]), grads.dtype).at[inv].add(grads)
-    rows = decode_slab_rows(slab[uids], layout)
-    new_rows = _dispatch_apply_push(rows, merged, prng, layout, conf,
-                                    row_ids=uids)
-    return slab.at[uids].set(encode_slab_rows(new_rows, layout))
+    with jax.named_scope("push_merge"):
+        uids, inv = jnp.unique(ids, size=K, fill_value=trash,
+                               return_inverse=True)
+        merged = jnp.zeros((K, grads.shape[1]),
+                           grads.dtype).at[inv].add(grads)
+    with jax.named_scope("push_opt"):
+        rows = decode_slab_rows(slab[uids], layout)
+        new_rows = _dispatch_apply_push(rows, merged, prng, layout, conf,
+                                        row_ids=uids)
+    with jax.named_scope("push_write"):
+        return slab.at[uids].set(encode_slab_rows(new_rows, layout))
 
 
 def rebuild_uids(ids: jnp.ndarray, perm: jnp.ndarray, inv: jnp.ndarray,
@@ -303,17 +308,19 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
     """
     new_rows = _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng,
                                 layout, conf, pulled_rows, first_idx)
-    if write == "blocked":
-        from paddlebox_tpu.config import flags
-        return push_blocked_write(slab, uids,
-                                  encode_slab_rows(new_rows, layout),
-                                  int(flags.get_flag("push_block_rows")))
-    if write != "scatter":
+    if write not in ("scatter", "blocked"):
         raise ValueError(f"hostdedup write strategy {write!r} "
                          "(scatter or blocked)")
-    # out-of-range padding ids drop; in-range ids are unique by construction
-    return slab.at[uids].set(encode_slab_rows(new_rows, layout),
-                             mode="drop", unique_indices=True)
+    with jax.named_scope("push_write"):
+        if write == "blocked":
+            from paddlebox_tpu.config import flags
+            return push_blocked_write(slab, uids,
+                                      encode_slab_rows(new_rows, layout),
+                                      int(flags.get_flag("push_block_rows")))
+        # out-of-range padding ids drop; in-range ids are unique by
+        # construction
+        return slab.at[uids].set(encode_slab_rows(new_rows, layout),
+                                 mode="drop", unique_indices=True)
 
 
 def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
@@ -331,18 +338,21 @@ def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
     first_idx[j] must be an occurrence index of uids[j] (padding tail
     entries may point anywhere: their g_show == 0 rows pass through
     untouched and are never written back)."""
-    sorted_grads = jnp.take(grads, perm, axis=0, indices_are_sorted=False,
-                            unique_indices=True)
-    merged = jax.ops.segment_sum(sorted_grads, inv_sorted,
-                                 num_segments=uids.shape[0],
-                                 indices_are_sorted=True)
-    if pulled_rows is not None and first_idx is not None:
-        rows = jnp.take(pulled_rows, first_idx, axis=0)
-    else:
-        rows = decode_slab_rows(jnp.take(slab, uids, axis=0, mode="clip"),
-                                layout)
-    return _dispatch_apply_push(rows, merged, prng, layout, conf,
-                                row_ids=uids)
+    with jax.named_scope("push_merge"):
+        sorted_grads = jnp.take(grads, perm, axis=0,
+                                indices_are_sorted=False,
+                                unique_indices=True)
+        merged = jax.ops.segment_sum(sorted_grads, inv_sorted,
+                                     num_segments=uids.shape[0],
+                                     indices_are_sorted=True)
+    with jax.named_scope("push_opt"):
+        if pulled_rows is not None and first_idx is not None:
+            rows = jnp.take(pulled_rows, first_idx, axis=0)
+        else:
+            rows = decode_slab_rows(
+                jnp.take(slab, uids, axis=0, mode="clip"), layout)
+        return _dispatch_apply_push(rows, merged, prng, layout, conf,
+                                    row_ids=uids)
 
 
 def decode_delta_uids(base: jnp.ndarray, d16: jnp.ndarray,
@@ -491,39 +501,43 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
     from paddlebox_tpu.config import flags
     K = ids.shape[0]
     U = uids.shape[0]
-    inv = jnp.searchsorted(uids, ids).astype(jnp.int32)
-    hot = int(flags.get_flag("push_onehot_rows"))
-    if hot > 0:
-        # MXU one-hot accumulation for the dense short tail (see
-        # merge_grads_onehot: measured path, integer-exact only)
-        merged = merge_grads_onehot(grads, inv, U, hot)
-    else:
-        merged = jax.ops.segment_sum(grads, inv, num_segments=U)
-    if pulled_rows is not None:
-        first = jnp.full((U,), K - 1, jnp.int32).at[inv].min(
-            jnp.arange(K, dtype=jnp.int32))
-        rows = jnp.take(pulled_rows, first, axis=0)
-    else:
-        rows = decode_slab_rows(jnp.take(slab, uids, axis=0, mode="clip"),
-                                layout)
-    new_rows = encode_slab_rows(
-        _dispatch_apply_push(rows, merged, prng, layout, conf,
-                             row_ids=uids), layout)
-    if write == "rebuild":
-        pos = jnp.full((slab.shape[0],), -1, jnp.int32).at[uids].set(
-            jnp.arange(U, dtype=jnp.int32), mode="drop",
-            unique_indices=True)
-        sel = jnp.take(new_rows, jnp.clip(pos, 0, U - 1), axis=0)
-        return jnp.where((pos >= 0)[:, None], sel, slab)
-    if write == "blocked":
-        # blocked scatter (round 11): bucketize the sorted uids into
-        # contiguous row blocks, apply per block with dynamic_update_slice
-        return push_blocked_write(slab, uids, new_rows,
-                                  int(flags.get_flag("push_block_rows")))
-    if write != "scatter":
+    if write not in ("scatter", "rebuild", "blocked"):
         raise ValueError(f"uid-wire write strategy {write!r} "
                          "(scatter, rebuild or blocked)")
-    return slab.at[uids].set(new_rows, mode="drop", unique_indices=True)
+    with jax.named_scope("push_merge"):
+        inv = jnp.searchsorted(uids, ids).astype(jnp.int32)
+        hot = int(flags.get_flag("push_onehot_rows"))
+        if hot > 0:
+            # MXU one-hot accumulation for the dense short tail (see
+            # merge_grads_onehot: measured path, integer-exact only)
+            merged = merge_grads_onehot(grads, inv, U, hot)
+        else:
+            merged = jax.ops.segment_sum(grads, inv, num_segments=U)
+    with jax.named_scope("push_opt"):
+        if pulled_rows is not None:
+            first = jnp.full((U,), K - 1, jnp.int32).at[inv].min(
+                jnp.arange(K, dtype=jnp.int32))
+            rows = jnp.take(pulled_rows, first, axis=0)
+        else:
+            rows = decode_slab_rows(
+                jnp.take(slab, uids, axis=0, mode="clip"), layout)
+        new_rows = encode_slab_rows(
+            _dispatch_apply_push(rows, merged, prng, layout, conf,
+                                 row_ids=uids), layout)
+    with jax.named_scope("push_write"):
+        if write == "rebuild":
+            pos = jnp.full((slab.shape[0],), -1, jnp.int32).at[uids].set(
+                jnp.arange(U, dtype=jnp.int32), mode="drop",
+                unique_indices=True)
+            sel = jnp.take(new_rows, jnp.clip(pos, 0, U - 1), axis=0)
+            return jnp.where((pos >= 0)[:, None], sel, slab)
+        if write == "blocked":
+            # blocked scatter (round 11): bucketize the sorted uids into
+            # contiguous row blocks, apply per block with
+            # dynamic_update_slice
+            return push_blocked_write(slab, uids, new_rows,
+                                      int(flags.get_flag("push_block_rows")))
+        return slab.at[uids].set(new_rows, mode="drop", unique_indices=True)
 
 
 def push_sparse_rebuild(slab: jnp.ndarray, uids: jnp.ndarray,
@@ -551,12 +565,13 @@ def push_sparse_rebuild(slab: jnp.ndarray, uids: jnp.ndarray,
         # the clip below would otherwise build the inverted range [0, -1];
         # an empty dedup touches nothing by definition
         return slab
-    new_rows = encode_slab_rows(
-        _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng,
-                         layout, conf, pulled_rows, first_idx), layout)
-    sel = jnp.take(new_rows, jnp.clip(pos, 0, new_rows.shape[0] - 1),
-                   axis=0)
-    return jnp.where((pos >= 0)[:, None], sel, slab)
+    new_rows = _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng,
+                                layout, conf, pulled_rows, first_idx)
+    with jax.named_scope("push_write"):
+        new_rows = encode_slab_rows(new_rows, layout)
+        sel = jnp.take(new_rows, jnp.clip(pos, 0, new_rows.shape[0] - 1),
+                       axis=0)
+        return jnp.where((pos >= 0)[:, None], sel, slab)
 
 
 def make_push_fn(layout: ValueLayout,

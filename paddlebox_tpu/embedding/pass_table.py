@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import functools
 import threading
-import time
 from typing import Optional, Tuple
 
 import jax
@@ -38,9 +37,8 @@ from paddlebox_tpu.embedding.host_store import HostEmbeddingStore
 from paddlebox_tpu.embedding.native_store import make_host_store
 from paddlebox_tpu.embedding.optimizers import apply_push
 from paddlebox_tpu.obs.device import account_d2h, account_h2d, instrument_jit
-from paddlebox_tpu.obs.tracer import record_span
+from paddlebox_tpu.obs.tracer import span as obs_span
 from paddlebox_tpu.utils.stats import gauge_set, stat_add
-from paddlebox_tpu.utils.timer import Timer
 from paddlebox_tpu.utils.lockwatch import make_lock
 
 
@@ -79,9 +77,11 @@ def _delta_promote_impl(old_slab, src, keep, new_idx, new_rows):
     Dtype-agnostic on purpose: under the bf16 slab diet the rows are
     ENCODED uint16 and must move without arithmetic (a python 0.0 would
     silently upcast the select to f32)."""
-    out = jnp.where(keep[:, None], old_slab[src],
-                    jnp.zeros((), old_slab.dtype))
-    return out.at[new_idx].set(new_rows, mode="drop")
+    with jax.named_scope("promote_permute"):
+        out = jnp.where(keep[:, None], old_slab[src],
+                        jnp.zeros((), old_slab.dtype))
+    with jax.named_scope("promote_scatter"):
+        return out.at[new_idx].set(new_rows, mode="drop")
 
 
 # donated: begin_pass consumes the previous pass's slab in place — one
@@ -356,8 +356,6 @@ class PassTable:
         self._residency_poisoned = False  # mid-pass invalidate: drop at end
         self._staged: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self.store_lock = make_lock("PassTable.store_lock")
-        self.timers = {name: Timer() for name in
-                       ("feed", "build", "pull", "push", "end")}
         # touched-row journal (round 15): when attached, end_pass appends
         # the rows it writes back and the lifecycle mutations append
         # deterministic event records (train/journal.py)
@@ -404,13 +402,12 @@ class PassTable:
         assign dense ids 0..n-1 (sorted order)."""
         if not self._in_feed_pass:
             raise RuntimeError("end_feed_pass without begin_feed_pass")
-        with_timer = self.timers["feed"]
-        with_timer.start()
-        if self._feed_keys:
-            all_keys = np.concatenate(self._feed_keys)
-            self._pass_keys = np.unique(all_keys)  # sorted unique
-        else:
-            self._pass_keys = np.empty(0, dtype=np.uint64)
+        with obs_span("feed_unique"):
+            if self._feed_keys:
+                all_keys = np.concatenate(self._feed_keys)
+                self._pass_keys = np.unique(all_keys)  # sorted unique
+            else:
+                self._pass_keys = np.empty(0, dtype=np.uint64)
         if self._pass_keys.size > self.capacity - 1:
             raise RuntimeError(
                 f"pass working set {self._pass_keys.size} exceeds table "
@@ -420,21 +417,21 @@ class PassTable:
         # when it really covers the RESIDENT key set — after a test-mode
         # pass the live index maps the eval keys instead (identity check
         # against the array end_pass recorded).
-        self._drop_prev_route()
-        if (self._resident_keys is not None
-                and self._route_for is self._resident_keys):
-            self._prev_route = self._route_index
-            self._route_index = None
-        self._drop_route_index()
-        # native key→id hash index, built once per pass and probed per
-        # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
-        # DedupKeysAndFillIdx tier at line rate
-        from paddlebox_tpu.native.build import create_route_index
-        self._route_index = create_route_index([self._pass_keys])
-        self._route_for = self._pass_keys
+        with obs_span("feed_route_index"):
+            self._drop_prev_route()
+            if (self._resident_keys is not None
+                    and self._route_for is self._resident_keys):
+                self._prev_route = self._route_index
+                self._route_index = None
+            self._drop_route_index()
+            # native key→id hash index, built once per pass and probed per
+            # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
+            # DedupKeysAndFillIdx tier at line rate
+            from paddlebox_tpu.native.build import create_route_index
+            self._route_index = create_route_index([self._pass_keys])
+            self._route_for = self._pass_keys
         self._feed_keys = []
         self._in_feed_pass = False
-        with_timer.pause()
 
     def _drop_route_index(self) -> None:
         from paddlebox_tpu.native.build import destroy_route_index
@@ -514,70 +511,79 @@ class PassTable:
             raise RuntimeError("pass already open")
         if self._pass_keys is None:
             raise RuntimeError("begin_pass before feed pass completed")
-        t = self.timers["build"]
-        t.start()
-        _t0 = time.perf_counter()
+        with obs_span("pass_begin"):
+            self._begin_pass()
+
+    def _begin_pass(self) -> None:
         n = self._pass_keys.size
         gauge_set("pass_rows", n)
         inc = (self._incremental() and self._resident_keys is not None
                and self._slab is not None)
         if inc:
-            old_pos = self._resident_pos(self._pass_keys)
-            hit = old_pos >= 0
-            miss_idx = np.nonzero(~hit)[0].astype(np.int32)
-            new_rows = self._promote_missing_rows(self._pass_keys[~hit])
-            # journal the promote delta: lookup_or_create CREATES missing
-            # features here (init rows the touched write-back may never
-            # revisit) — replay must see them; re-recording store-present
-            # non-resident rows is an idempotent upsert of equal bits
-            if not self._test_mode:
-                self._journal_rows(self._pass_keys[~hit], new_rows)
-            src = np.zeros(self.capacity, np.int32)
-            keep = np.zeros(self.capacity, bool)
-            if n:
-                src[:n][hit] = old_pos[hit]
-                keep[:n] = hit
-            m = miss_idx.size
-            pad = _pow2_pad(max(m, 1))
-            idx_p = np.full(pad, self.capacity, np.int32)  # drop sentinel
-            # promote boundary: freshly-read host f32 rows encode to the
-            # device layout here (identity for f32 slabs); resident rows
-            # move as raw bits inside _delta_promote
-            rows_p = np.zeros((pad, self.layout.device_width),
-                              self.layout.device_dtype)
-            idx_p[:m] = miss_idx
-            rows_p[:m] = encode_slab_rows_np(new_rows, self.layout)
+            with obs_span("promote_diff"):
+                old_pos = self._resident_pos(self._pass_keys)
+                hit = old_pos >= 0
+                miss_idx = np.nonzero(~hit)[0].astype(np.int32)
+            with obs_span("promote_store_read"):
+                new_rows = self._promote_missing_rows(self._pass_keys[~hit])
+                # journal the promote delta: lookup_or_create CREATES
+                # missing features here (init rows the touched write-back
+                # may never revisit) — replay must see them; re-recording
+                # store-present non-resident rows is an idempotent upsert
+                # of equal bits
+                if not self._test_mode:
+                    self._journal_rows(self._pass_keys[~hit], new_rows)
+            with obs_span("promote_stage"):
+                src = np.zeros(self.capacity, np.int32)
+                keep = np.zeros(self.capacity, bool)
+                if n:
+                    src[:n][hit] = old_pos[hit]
+                    keep[:n] = hit
+                m = miss_idx.size
+                pad = _pow2_pad(max(m, 1))
+                idx_p = np.full(pad, self.capacity, np.int32)  # drop sentinel
+                # promote boundary: freshly-read host f32 rows encode to
+                # the device layout here (identity for f32 slabs); resident
+                # rows move as raw bits inside _delta_promote
+                rows_p = np.zeros((pad, self.layout.device_width),
+                                  self.layout.device_dtype)
+                idx_p[:m] = miss_idx
+                rows_p[:m] = encode_slab_rows_np(new_rows, self.layout)
             # test mode CONSUMES the resident slab too (donated — a copy
             # would hold 2× slab HBM for the whole eval, an OOM at the
             # capacity-probe scale the chip is sized to); the eval slab
             # can't become resident (zero rows for store-missing keys),
             # so end_pass drops residency and the next train pass pays
             # one full rebuild — the pre-round-6 eval HBM profile
-            account_h2d(rows_p.nbytes + src.nbytes + keep.nbytes
-                        + idx_p.nbytes)  # promote-delta staging transfer
-            self._slab = _delta_promote(self._slab, jnp.asarray(src),
-                                        jnp.asarray(keep),
-                                        jnp.asarray(idx_p),
-                                        jnp.asarray(rows_p))
+            with obs_span("promote_dispatch"):
+                account_h2d(rows_p.nbytes + src.nbytes + keep.nbytes
+                            + idx_p.nbytes)  # promote-delta staging transfer
+                self._slab = _delta_promote(self._slab, jnp.asarray(src),
+                                            jnp.asarray(keep),
+                                            jnp.asarray(idx_p),
+                                            jnp.asarray(rows_p))
             stat_add("pass_rows_promote_hit", int(hit.sum()))
             stat_add("pass_rows_promote_new", m)
         else:
-            with self.store_lock:
-                host_rows = (self.store.lookup(self._pass_keys)
-                             if self._test_mode
-                             else self.store.lookup_or_create(self._pass_keys))
-            # full build: every pass key may have been created just now
-            if not self._test_mode:
-                self._journal_rows(self._pass_keys, host_rows)
-            # zero only the tail beyond n: a full-capacity zeros() here was
-            # pure memcpy waste — every [0, n) row is overwritten next
-            slab = np.empty((self.capacity, self.layout.device_width),
-                            dtype=self.layout.device_dtype)
-            if n:
-                slab[:n] = encode_slab_rows_np(host_rows, self.layout)
-            slab[n:] = 0
-            account_h2d(slab.nbytes)  # full slab build transfer
-            self._slab = jnp.asarray(slab)
+            with obs_span("build_store_read"):
+                with self.store_lock:
+                    host_rows = (self.store.lookup(self._pass_keys)
+                                 if self._test_mode else
+                                 self.store.lookup_or_create(self._pass_keys))
+                # full build: every pass key may have been created just now
+                if not self._test_mode:
+                    self._journal_rows(self._pass_keys, host_rows)
+            with obs_span("build_encode"):
+                # zero only the tail beyond n: a full-capacity zeros() here
+                # was pure memcpy waste — every [0, n) row is overwritten
+                slab = np.empty((self.capacity, self.layout.device_width),
+                                dtype=self.layout.device_dtype)
+                if n:
+                    slab[:n] = encode_slab_rows_np(host_rows, self.layout)
+                slab[n:] = 0
+            with obs_span("build_h2d"):
+                account_h2d(slab.nbytes)  # full slab build transfer
+                self._slab = jnp.asarray(slab)
         self._drop_prev_route()
         self._touch_seen = False
         self._residency_poisoned = False
@@ -586,8 +592,6 @@ class PassTable:
             if self._incremental():
                 self._touched = np.zeros(self.capacity, bool)
         self._in_pass = True
-        record_span("pass_begin", _t0, time.perf_counter())
-        t.pause()
 
     def note_touched(self, ids: np.ndarray) -> None:
         """Accumulate the per-pass touched-row bitmap (host mirror, OR'd
@@ -611,9 +615,24 @@ class PassTable:
         (their slab holds zero rows for store-missing keys)."""
         if not self._in_pass:
             raise RuntimeError("end_pass without begin_pass")
-        t = self.timers["end"]
-        t.start()
-        _t0 = time.perf_counter()
+        with obs_span("pass_end"):
+            self._end_pass()
+
+    def _write_back(self, keys: np.ndarray, dev_rows_fn) -> None:
+        """One write-back: dev_rows_fn() gathers the rows on the device;
+        they cross to the host, decode to host f32 (identity for f32
+        slabs), are journaled, and land in the store."""
+        with obs_span("writeback_d2h"):
+            dev_rows = np.asarray(dev_rows_fn())
+            account_d2h(dev_rows.nbytes)
+        with obs_span("writeback_decode"):
+            rows = decode_slab_rows_np(dev_rows, self.layout)
+            self._journal_rows(keys, rows)
+        with obs_span("writeback_store"):
+            with self.store_lock:
+                self.store.write_back(keys, rows)
+
+    def _end_pass(self) -> None:
         n = self._pass_keys.size
         if self._test_mode:
             # no write-back, no residency from an eval slab
@@ -622,26 +641,18 @@ class PassTable:
         else:
             if n:
                 if self._touched is not None and self._touch_seen:
-                    self._touched[self.padding_id] = False
-                    idx = np.nonzero(self._touched[:n])[0]
+                    with obs_span("writeback_select"):
+                        self._touched[self.padding_id] = False
+                        idx = np.nonzero(self._touched[:n])[0]
                     if idx.size:
-                        # writeback boundary: encoded device rows decode
-                        # back to host f32 (identity for f32 slabs)
-                        dev_rows = np.asarray(self._slab[jnp.asarray(idx)])
-                        account_d2h(dev_rows.nbytes)  # touched-row D2H
-                        rows = decode_slab_rows_np(dev_rows, self.layout)
-                        self._journal_rows(self._pass_keys[idx], rows)
-                        with self.store_lock:
-                            self.store.write_back(self._pass_keys[idx], rows)
+                        self._write_back(  # touched rows only
+                            self._pass_keys[idx],
+                            lambda: self._slab[jnp.asarray(idx)])
                     stat_add("pass_rows_written_back", int(idx.size))
                     stat_add("pass_rows_writeback_skipped", n - int(idx.size))
                 else:
-                    dev_rows = np.asarray(self._slab[:n])
-                    account_d2h(dev_rows.nbytes)  # full-slab D2H
-                    host = decode_slab_rows_np(dev_rows, self.layout)
-                    self._journal_rows(self._pass_keys, host)
-                    with self.store_lock:
-                        self.store.write_back(self._pass_keys, host)
+                    self._write_back(self._pass_keys,  # the full slab
+                                     lambda: self._slab[:n])
             if self._incremental() and not self._residency_poisoned:
                 # rows stay resident (BoxPS cadence): the slab lives on in
                 # HBM and the next begin_pass promotes only the delta
@@ -655,9 +666,8 @@ class PassTable:
         self._touched = None
         self._residency_poisoned = False
         self._in_pass = False
-        self.check_need_limit_mem()  # spill>0 invalidates internally
-        record_span("pass_end", _t0, time.perf_counter())
-        t.pause()
+        with obs_span("pass_mem_check"):
+            self.check_need_limit_mem()  # spill>0 invalidates internally
 
     def invalidate_residency(self) -> None:
         """Drop the cross-pass resident state (slab, key map, staged
@@ -806,11 +816,7 @@ class PassTable:
         """PullSparseGPU analog: per-key pull view [K, 3+D]."""
         if not self._in_pass:
             raise RuntimeError("pull outside pass")
-        t = self.timers["pull"]
-        t.start()
-        out = _pull_kernel(self._slab, ids, self.layout)
-        t.pause()
-        return out
+        return _pull_kernel(self._slab, ids, self.layout)
 
     def push(self, ids: jnp.ndarray, grads: jnp.ndarray) -> None:
         """PushSparseGPU analog: merged grads through the in-table optimizer."""
@@ -818,8 +824,6 @@ class PassTable:
             raise RuntimeError("push outside pass")
         if self._test_mode:
             return
-        t = self.timers["push"]
-        t.start()
         # direct pushes may carry ids that never went through lookup_ids
         # (raw-op callers); this is the slow per-call path, so the D2H of
         # a [K] id vector is noise next to the dispatch
@@ -827,7 +831,6 @@ class PassTable:
         self._prng, sub = jax.random.split(self._prng)
         self._slab = _push_kernel(self._slab, ids, grads, sub,
                                   self.layout, self.config.optimizer)
-        t.pause()
 
     # raw access for fused train steps that thread the slab functionally
     @property

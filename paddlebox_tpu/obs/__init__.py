@@ -31,7 +31,9 @@ platform/monitor.h + timer discipline + chrometracing profiler did
                    p2p mesh and the serving RPC boundary; spans record
                    them, tools/trace_stitch.py merges per-rank chrome
                    traces into one cluster timeline with cross-rank
-                   flow events (obs/tracer.py, round 14)
+                   flow events (obs/tracer.py, round 14); per-PASS ids
+                   (pass_trace_id) ride every span of a pass across the
+                   main, reader, prefetch and stager threads
   * exporter     — per-rank HTTP ops endpoint (flag obs_http_port,
                    port +rank): /metrics Prometheus exposition,
                    /report, /health, /stacks, /flight, /quality,
@@ -49,7 +51,10 @@ platform/monitor.h + timer discipline + chrometracing profiler did
                    jax.live_arrays() by owner at report cadence with a
                    monotonic-growth leak detector — all through the
                    StatRegistry, so reports/metrics/flight/health carry
-                   it unchanged
+                   it unchanged; compiles are ring spans (device_compile,
+                   backend_compile) and each entry's snapshot carries
+                   `scopes`, the {HLO instruction: jax.named_scope} map
+                   that makes a device trace readable by phase
 
 Import surface is deliberately jax-free: every hot-path hook (span,
 beat) must stay importable and near-free on any host — the serving
@@ -74,8 +79,9 @@ from paddlebox_tpu.obs.report import (JsonlSink, ListSink,  # noqa: F401
                                       MetricsSink, NullSink, StderrSink,
                                       StepReporter, make_sink)
 from paddlebox_tpu.obs.tracer import (SpanTracer, current_trace,  # noqa: F401
-                                      get_tracer, next_trace_id, span,
-                                      step_trace_id, trace_ctx)
+                                      get_tracer, next_trace_id,
+                                      pass_trace_id, span, step_trace_id,
+                                      trace_ctx)
 from paddlebox_tpu.obs.tracer import \
     configure_from_flags as _tracer_configure
 from paddlebox_tpu.obs.watchdog import StallWatchdog  # noqa: F401

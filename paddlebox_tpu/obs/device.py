@@ -32,6 +32,19 @@ observable through the UNCHANGED publication machinery:
     second consecutive call. Buffers below device_donation_min_bytes
     are not audited (tiny buffers are aliasing noise; the alarm exists
     for slab-scale copies).
+  * compile spans — every instrumented compile records a ring span
+    ``device_compile``; one jax.monitoring listener records EVERY
+    backend compile of the process (instrumented or not: end_pass's
+    ``slab[idx]`` gather compiles a new shape each pass) as a ring span
+    ``backend_compile`` and counts ``device_backend_compiles``. It adds
+    nothing to the entries: their ``compiles`` stay the instrumented
+    entry points' own.
+  * scope map — at an entry's first compile, ``scopes``: {HLO
+    instruction name: innermost of SCOPE_NAMES on its op_name path}
+    and ``module`` (the XLA module's name) from compiled.as_text(). A
+    device trace names a program by its module and an operation by its
+    HLO instruction, not by the jax.named_scope it was traced under;
+    these join the two (tools/scope_times.py).
   * transfer ledger — account_h2d/account_d2h: the runners' staging and
     write-back paths count ``device_transfer_bytes_{h2d,d2h}`` and feed
     the ``device_{h2d,d2h}_bytes`` fixed-bucket histograms.
@@ -67,10 +80,12 @@ the zero-risk escape hatch.
 from __future__ import annotations
 
 import inspect
+import re
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from paddlebox_tpu.obs.tracer import record_span
 from paddlebox_tpu.utils.lockwatch import make_lock, make_rlock
 from paddlebox_tpu.utils.stats import (gauge_set, hist_observe, stat_add,
                                        stat_peek)
@@ -132,6 +147,68 @@ def analyze_compiled(compiled, examples: Optional[int] = None,
     return out
 
 
+# -------------------------------------------------------------- scope map
+
+#: the jax.named_scope names of the program's device phases: the train
+#: step's (train/trainer.py, ops/, embedding/optimizers.py) and
+#: delta_promote's (embedding/pass_table.py)
+SCOPE_NAMES = frozenset((
+    "pull", "pool", "fwd_bwd", "dense_opt", "push_grads", "push_merge",
+    "push_opt", "push_write", "promote_permute", "promote_scatter"))
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE_WRAP = re.compile(r"^(?:\w+\()+|\)+$")  # transpose(jvp(pool)) -> pool
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """{HLO instruction name (no '%'): scope} over every instruction of a
+    compiled module's text: the innermost SCOPE_NAMES component of the
+    instruction's metadata op_name path (autodiff wraps a scope as
+    jvp(pool) / transpose(jvp(pool)): unwrapped), "" where the path has
+    none or the instruction carries no metadata. A fusion carries the
+    op_name of the instruction that names it."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        scope = ""
+        op = _HLO_OP_NAME.search(line)
+        if op is not None:
+            for part in reversed(op.group(1).split("/")):
+                part = _SCOPE_WRAP.sub("", part)
+                if part in SCOPE_NAMES:
+                    scope = part
+                    break
+        out[m.group(1)] = scope
+    return out
+
+
+# ---------------------------------------------------------- compile listener
+
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_listening = False  # jax.monitoring keeps listeners for the process's life
+
+
+def _on_jax_duration(event: str, secs: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        t1 = time.perf_counter()
+        record_span("backend_compile", t1 - secs, t1)
+        stat_add("device_backend_compiles", 1)
+
+
+def _listen_for_compiles() -> None:
+    """Register the one backend-compile listener (idempotent)."""
+    global _listening
+    if not _listening:
+        import jax
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+
+
 # ------------------------------------------------------------ the monitor
 
 class _JitEntry:
@@ -159,6 +236,8 @@ class _JitEntry:
         # — e.g. sharded arrays; the audit disables itself for this fn)
         self.donation_supported: Any = bool(audit_argnums)
         self.analysis: Optional[dict] = None
+        self.module: Optional[str] = None
+        self.scopes: Optional[Dict[str, str]] = None
         self.donated_bytes = 0
         self.signatures = 0
 
@@ -177,6 +256,9 @@ class _JitEntry:
                              "donated_bytes": self.donated_bytes}
         if self.analysis is not None:
             d["analysis"] = dict(self.analysis)
+        if self.scopes is not None:
+            d["module"] = self.module
+            d["scopes"] = dict(self.scopes)
         return d
 
 
@@ -469,6 +551,7 @@ class InstrumentedJit:
         # guarded-by: _lock, pruned with the cache
         self._last_missed: Dict[Any, bool] = {}
         _MONITOR.register(self._entry)
+        _listen_for_compiles()
 
     # ---------------------------------------------------------- jit surface
     def lower(self, *args, **kwargs):
@@ -486,7 +569,9 @@ class InstrumentedJit:
         from paddlebox_tpu.config import flags
         t0 = time.perf_counter()
         compiled = self._jitted.lower(*args, **kwargs).compile()
-        dt_ms = (time.perf_counter() - t0) * 1e3
+        t1 = time.perf_counter()
+        dt_ms = (t1 - t0) * 1e3
+        record_span("device_compile", t0, t1)
         hist_observe("device_compile_ms", dt_ms)
         e = self._entry
         warmup = (self._recompile_warmup
@@ -521,6 +606,13 @@ class InstrumentedJit:
             e.analysis = analyze_compiled(
                 compiled, examples=self._example_count,
                 slab_bytes=donated or None)
+            try:
+                text = compiled.as_text()
+                named = _HLO_MODULE.match(text)
+                e.module = named.group(1) if named else None
+                e.scopes = scope_map(text)
+            except Exception as err:  # noqa: BLE001 — best-effort per backend, like the analysis
+                e.analysis["scopes_error"] = repr(err)
         if steady:
             # the sentinel: a recompile past warmup is shape/dtype churn
             # in what must be a steady-state loop

@@ -70,6 +70,17 @@ def step_trace_id(rank: int, step: int) -> int:
     return ((int(rank) & 0xFFFF) << 48) | (int(step) & 0xFFFFFFFFFFFF)
 
 
+def pass_trace_id(rank: int, pass_index: int) -> int:
+    """Per-pass id: bit 61 set, rank (13 bits) at 48, pass counter below.
+    Every span of one pass carries it (the boundary on the main thread,
+    the parse of pass N+1 on the reader threads while pass N trains, the
+    chunk stager), so a pass's spans are told apart by id, not by time.
+    Bit 61 alone collides with no other mint: step ids leave 61-63 clear
+    for ranks below 8192, mesh frames set 62, request ids set 63."""
+    return ((1 << 61) | ((int(rank) & 0x1FFF) << 48)
+            | (int(pass_index) & 0xFFFFFFFFFFFF))
+
+
 def next_trace_id() -> int:
     """Per-request id for planes without a step counter (serving client
     pulls): process-salted monotonic counter, high bit set so the id
@@ -87,6 +98,19 @@ def set_trace(trace: Optional[int]) -> Optional[int]:
     prev = getattr(_TRACE_CTX, "id", None)
     _TRACE_CTX.id = trace
     return prev
+
+
+def with_current_trace(fn):
+    """``fn`` as a thread target that runs under THIS thread's current
+    trace id: a worker started for a pass (dataset readers, the promote
+    prefetcher, the chunk stager) records its spans under the pass it
+    serves, not under whatever the main thread has moved on to."""
+    trace = current_trace()
+
+    def run(*args, **kwargs):
+        set_trace(trace)
+        return fn(*args, **kwargs)
+    return run
 
 
 class trace_ctx:

@@ -32,6 +32,7 @@ def cvm_transform(pooled: jnp.ndarray, use_cvm: bool = True) -> jnp.ndarray:
     return jnp.concatenate([log_show, log_ctr, rest], axis=-1)
 
 
+@jax.named_scope("pool")
 def fused_seqpool_cvm(emb: jnp.ndarray, segments: jnp.ndarray,
                       valid: jnp.ndarray, batch_size: int, num_slots: int,
                       use_cvm: bool = True,
@@ -77,6 +78,7 @@ def cvm_conv_transform(pooled: jnp.ndarray, use_cvm: bool = True,
     return jnp.concatenate(cols + [rest], axis=-1)
 
 
+@jax.named_scope("pool")
 def seqpool_sum(emb: jnp.ndarray, segments: jnp.ndarray, valid: jnp.ndarray,
                 batch_size: int, num_slots: int) -> jnp.ndarray:
     """Plain per-slot sum pooling with NO cvm columns — the

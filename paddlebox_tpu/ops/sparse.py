@@ -27,6 +27,7 @@ from paddlebox_tpu.embedding.accessor import (PushLayout, ValueLayout,
                                               decode_slab_rows)
 
 
+@jax.named_scope("pull")
 def pull_view_from_rows(rows: jnp.ndarray,
                         layout: ValueLayout) -> jnp.ndarray:
     """Pull view [K, 3+D] (show, click, embed_w, embedx) from already
@@ -43,6 +44,7 @@ def pull_view_from_rows(rows: jnp.ndarray,
     ], axis=1)
 
 
+@jax.named_scope("pull")
 def gather_slab_rows(slab: jnp.ndarray, ids: jnp.ndarray,
                      layout: ValueLayout) -> jnp.ndarray:
     """[K, width] DECODED f32 rows gathered from the device slab — the
@@ -60,6 +62,7 @@ def pull_sparse(slab: jnp.ndarray, ids: jnp.ndarray,
     return pull_view_from_rows(gather_slab_rows(slab, ids, layout), layout)
 
 
+@jax.named_scope("push_grads")
 def build_push_grads(d_emb: jnp.ndarray, slots: jnp.ndarray,
                      clicks: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     """Per-key push rows [K, 4+D] from the model's embedding cotangent.
@@ -82,6 +85,7 @@ def build_push_grads(d_emb: jnp.ndarray, slots: jnp.ndarray,
     ], axis=1)
 
 
+@jax.named_scope("pull")
 def pull_sparse_extended(slab: jnp.ndarray, ids: jnp.ndarray,
                          layout: ValueLayout):
     """pull_box_extended_sparse (operators/pull_box_extended_sparse_op.*):
@@ -100,6 +104,7 @@ def pull_sparse_extended(slab: jnp.ndarray, ids: jnp.ndarray,
     return base, rows[:, ew0:ew0 + layout.expand_dim]
 
 
+@jax.named_scope("push_grads")
 def build_push_grads_extended(d_emb: jnp.ndarray, d_expand: jnp.ndarray,
                               slots: jnp.ndarray, clicks: jnp.ndarray,
                               valid: jnp.ndarray) -> jnp.ndarray:

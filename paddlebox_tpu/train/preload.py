@@ -29,7 +29,9 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from paddlebox_tpu.obs.tracer import span as obs_span
+from paddlebox_tpu.obs import log as obs_log
+from paddlebox_tpu.obs.tracer import (pass_trace_id, span as obs_span,
+                                      trace_ctx, with_current_trace)
 from paddlebox_tpu.utils.timer import Timer
 
 
@@ -58,8 +60,10 @@ class PromotePrefetcher:
         self._keys: List[np.ndarray] = []
         self._rows: List[np.ndarray] = []
         self._err: Optional[BaseException] = None
-        self._thread = threading.Thread(target=self._run, daemon=True,
-                                        name="promote-prefetch")
+        # the worker's spans carry the pass it reads for
+        self._thread = threading.Thread(
+            target=with_current_trace(self._run), daemon=True,
+            name="promote-prefetch")
         self._thread.start()
 
     def feed(self, keys: np.ndarray) -> None:
@@ -193,9 +197,10 @@ class PassPreloader:
                 dataset.wait_preload_done()
             pre, self._prefetch = self._prefetch, None
             if pre is not None:
-                keys, rows = pre.finish()
-                if keys.size:
-                    self.table.accept_staged_rows(keys, rows)
+                with obs_span("promote_prefetch_finish"):
+                    keys, rows = pre.finish()
+                    if keys.size:
+                        self.table.accept_staged_rows(keys, rows)
             with obs_span("ingest_feed_pass"):
                 self.table.begin_feed_pass()
                 for ks in self._buffer or []:
@@ -227,27 +232,37 @@ def run_preloaded_passes(trainer, datasets: Iterable,
     when given, runs after each pass WITH the next pass's readers already
     live — the hook for pass-cadenced work like delta saves
     (end_pass(need_save_delta)). Returns per-pass stats dicts.
+
+    Each dataset gets a pass_trace_id, held around everything done for it:
+    its preload (so the reader threads parsing pass N+1 under pass N's
+    training carry N+1's id), its wait, its train_pass and its release.
     """
     allgather = None
     if getattr(trainer, "multiprocess", False):
         allgather = trainer.fleet.all_gather
     pre = PassPreloader(trainer.table)
+    rank = obs_log.get_rank()
     results: List[Dict[str, float]] = []
     it = iter(datasets)
     cur = next(it, None)
     if cur is None:
         return results
-    pre.preload(cur)
+    with trace_ctx(pass_trace_id(rank, 0)):
+        pre.preload(cur)
     while cur is not None:
-        pre.wait(cur, allgather=allgather)
-        nxt = next(it, None)
-        if nxt is not None:
-            # start pass N+1's read threads BEFORE training pass N
-            pre.preload(nxt)
-        results.append(trainer.train_pass(cur, preloaded=True))
-        if after_pass is not None:
-            after_pass(len(results) - 1, results[-1])
-        if release:
-            cur.release_memory()
+        k = len(results)  # cur is pass k of this call
+        with trace_ctx(pass_trace_id(rank, k)):
+            pre.wait(cur, allgather=allgather)
+            nxt = next(it, None)
+            if nxt is not None:
+                # start pass N+1's read threads BEFORE training pass N
+                with trace_ctx(pass_trace_id(rank, k + 1)):
+                    pre.preload(nxt)
+            results.append(trainer.train_pass(cur, preloaded=True))
+            if after_pass is not None:
+                after_pass(k, results[-1])
+            if release:
+                with obs_span("pass_release"):
+                    cur.release_memory()
         cur = nxt
     return results

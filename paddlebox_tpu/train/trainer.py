@@ -49,7 +49,9 @@ from paddlebox_tpu.obs import make_step_reporter
 from paddlebox_tpu.obs import span as obs_span
 from paddlebox_tpu.obs.device import (account_h2d, instrument_jit,
                                       register_owner, tree_nbytes)
-from paddlebox_tpu.obs.tracer import set_trace, step_trace_id
+from paddlebox_tpu.obs.tracer import (current_trace, set_trace,
+                                      step_trace_id, trace_ctx,
+                                      with_current_trace)
 from paddlebox_tpu.ops.seqpool import fused_seqpool_cvm, seqpool_sum
 from paddlebox_tpu.ops.sparse import (build_push_grads,
                                       build_push_grads_extended,
@@ -76,12 +78,6 @@ class TrainStepFns:
     # (slab, params, opt_state, stacked, cpush, prng) -> (slab, params,
     # opt_state, losses, preds, prng) — one pull + one merged push per chunk
     scan_chunk: Optional[Callable] = None
-    # the fused step's building blocks, exposed so the staged profiling
-    # mode (train_pass_profiled) runs EXACTLY the fused semantics — cvm
-    # flag, mixed precision, rank_offset, data_norm, dedup guard included
-    forward: Optional[Callable] = None          # (params, emb, batch) -> (loss, preds)
-    sparse_push: Optional[Callable] = None      # (slab, demb, batch, sub) -> slab
-    dn_update: Optional[Callable] = None        # (params, emb, batch) -> params
     # the slab-write strategy BAKED into the uid-wire push branch at build
     # time (scatter | rebuild — derived on device, so unlike the full
     # wire it cannot follow a live push_write flip; train_pass guards)
@@ -230,8 +226,9 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
             except BaseException as e:   # surfaced at the consumer's get
                 _put(e)
 
-        producer = _threading.Thread(target=produce, daemon=True,
-                                     name="chunk-stager")
+        # the stager's host_stage spans carry the pass it stages for
+        producer = _threading.Thread(target=with_current_trace(produce),
+                                     daemon=True, name="chunk-stager")
         producer.start()
 
         def staged_chunks():
@@ -626,14 +623,16 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
         # per-key click = its instance's label (first task's label)
         key_label_src = batch["labels_" + model.task_names[0]] if multi_task \
             else batch["labels"]
-        clicks = key_label_src[batch["segments"] // num_slots]
-        if use_expand:
-            d_base, d_exp = demb
-            push_grads = build_push_grads_extended(
-                d_base, d_exp, _key_slots(batch), clicks, _key_valid(batch))
-        else:
-            push_grads = build_push_grads(demb, _key_slots(batch), clicks,
-                                          _key_valid(batch))
+        with jax.named_scope("push_grads"):
+            clicks = key_label_src[batch["segments"] // num_slots]
+            if use_expand:
+                d_base, d_exp = demb
+                push_grads = build_push_grads_extended(
+                    d_base, d_exp, _key_slots(batch), clicks,
+                    _key_valid(batch))
+            else:
+                push_grads = build_push_grads(demb, _key_slots(batch),
+                                              clicks, _key_valid(batch))
         if "perm" not in batch:
             if "uid_d16" in batch:
                 # delta-coded uid wire: decode, and DON'T reuse pulled
@@ -708,13 +707,15 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
 
         emb, rows = _pull(slab, batch)
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
-        (loss, preds), (dparams, demb) = grad_fn(params, emb)
-        updates, opt_state = dense_opt.update(dparams, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        if has_summary:
-            params = dn_update_params(
-                model, params, emb, batch["segments"], _key_valid(batch),
-                batch_size, num_slots, use_cvm, batch.get("dense"))
+        with jax.named_scope("fwd_bwd"):
+            (loss, preds), (dparams, demb) = grad_fn(params, emb)
+        with jax.named_scope("dense_opt"):
+            updates, opt_state = dense_opt.update(dparams, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            if has_summary:
+                params = dn_update_params(
+                    model, params, emb, batch["segments"], _key_valid(batch),
+                    batch_size, num_slots, use_cvm, batch.get("dense"))
         slab = _sparse_push(slab, demb, batch, sub, rows)
         return slab, params, opt_state, loss, preds, prng
 
@@ -773,9 +774,12 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
 
                 grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
                                              has_aux=True)
-                (loss, preds), (dp, dpooled) = grad_fn(params, pooled_b)
-                updates, opt_state = dense_opt.update(dp, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("fwd_bwd"):
+                    (loss, preds), (dp, dpooled) = grad_fn(params, pooled_b)
+                with jax.named_scope("dense_opt"):
+                    updates, opt_state = dense_opt.update(dp, opt_state,
+                                                          params)
+                    params = optax.apply_updates(params, updates)
                 return (params, opt_state), (loss, preds, dpooled)
 
             # the dense body never touches the [K]-sized leaves (pooling
@@ -789,10 +793,12 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                 dpooled_c.reshape((C * batch_size,) + dpooled_c.shape[2:]))
             label_key = ("labels_" + model.task_names[0] if multi_task
                          else "labels")
-            clicks_flat = stacked[label_key].reshape(
-                C * batch_size)[seg_flat // num_slots]
-            push_grads = build_push_grads(
-                d_emb_flat, seg_flat % num_slots, clicks_flat, valid_flat)
+            with jax.named_scope("push_grads"):
+                clicks_flat = stacked[label_key].reshape(
+                    C * batch_size)[seg_flat // num_slots]
+                push_grads = build_push_grads(
+                    d_emb_flat, seg_flat % num_slots, clicks_flat,
+                    valid_flat)
             if "uid_d16" in cpush:
                 # chunk-amortized uid wire, delta-coded (ONE decode +
                 # searchsorted + scatter for the whole chunk)
@@ -842,7 +848,8 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
 
         emb, rows = _pull(slab, batch)
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
-        (loss, preds), (dparams, demb) = grad_fn(params, emb)
+        with jax.named_scope("fwd_bwd"):
+            (loss, preds), (dparams, demb) = grad_fn(params, emb)
         if has_summary:
             # the host adam thread sees zero grads for the summary leaves;
             # their running-sums update happens here on device and rides
@@ -871,22 +878,11 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
     eval_step = instrument_jit(eval_step, "eval_step",
                                example_count=batch_size)
 
-    def _dn_update(params, emb, batch):
-        if not has_summary:
-            return params
-        return dn_update_params(model, params, emb, batch["segments"],
-                                _key_valid(batch), batch_size, num_slots,
-                                use_cvm, batch.get("dense"))
-
     return TrainStepFns(step=step_async if async_dense else step,
                         eval_step=eval_step,
                         batch_size=batch_size, num_slots=num_slots,
                         scan_steps=None if async_dense else scan_steps,
                         scan_chunk=scan_chunk_fn,
-                        forward=lambda params, emb, batch: forward(
-                            params, emb, batch, None),
-                        sparse_push=_sparse_push,
-                        dn_update=_dn_update,
                         uid_write=uid_write)
 
 
@@ -1189,7 +1185,7 @@ class BoxTrainer:
     def _refresh_aux(self) -> None:
         """ToHBM cadence (box_wrapper.h:83): freeze the side table's
         current rows into the non-trained aux_rows leaf — shared by ALL
-        pass drivers (train_pass, train_pass_profiled, predict_batches)
+        pass drivers (train_pass, predict_batches)
         so none runs on stale or init-zero rows."""
         if self.aux_source is not None:
             self.params = dict(self.params, aux_rows=self.aux_source
@@ -1201,8 +1197,7 @@ class BoxTrainer:
         """One full pass: feed → build → train → metrics → end."""
         from paddlebox_tpu.config import flags
         # live set_flag takes effect at pass boundaries only (mid-pass flips
-        # would mix rebuild/scatter host dicts inside one scan chunk);
-        # refreshed BEFORE the profiled-path fork so both tiers honor it
+        # would mix rebuild/scatter host dicts inside one scan chunk)
         self._push_write = resolve_push_write(
             capacity=self.table.capacity,
             batch_keys=self.feed.key_capacity())
@@ -1224,11 +1219,12 @@ class BoxTrainer:
                 "built with %r — construct a fresh trainer to change the "
                 "write strategy"
                 % (self._push_write, self.fns.uid_write))
-        if (flags.get_flag("profile_per_op") and not preloaded
-                and not self.multi_task and self.async_table is None):
-            # debug tier: staged dispatches with per-stage attribution
-            # (stages with no log products → the hostdedup scatter write)
-            return self.train_pass_profiled(dataset)
+        with obs_span("train_pass"):
+            return self._train_pass(dataset, preloaded)
+
+    def _train_pass(self, dataset: BoxDataset,
+                    preloaded: bool) -> Dict[str, float]:
+        from paddlebox_tpu.config import flags
         t_pass = self.timers["pass"]
         t_pass.start()
         if not preloaded:
@@ -1237,8 +1233,9 @@ class BoxTrainer:
             self.table.end_feed_pass()
         self._refresh_aux()
         self.table.begin_pass()
-        dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
-        worker_batches = dataset.split_batches(num_workers=1)
+        with obs_span("pass_split_batches"):
+            dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
+            worker_batches = dataset.split_batches(num_workers=1)
         losses = []
         prng = self.table.next_prng()
         chunk = max(1, self.cfg.scan_chunk)
@@ -1305,7 +1302,10 @@ class BoxTrainer:
             self.table.set_slab(state)
             losses.extend(chunk_losses)
             pending = pending[n_done:]
-        try:
+        # a step's id must not leak onto the boundary or eval spans, also
+        # when a step raises: on exit the id found here (the pass's, under
+        # run_preloaded_passes) is back for end_pass
+        with trace_ctx(current_trace()):
             for b in pending:
                 # per-step 64-bit trace id (round 14): host_stage and the
                 # dispatch spans of one step share it in the exported trace
@@ -1350,11 +1350,6 @@ class BoxTrainer:
                 self._add_metrics(preds, b)
                 if self.dump_writer is not None:
                     self._dump_batch(preds, b)
-        finally:
-            # exception-safe: a step that raises must not leak its
-            # trace id onto pass-boundary/eval spans (the sharded
-            # runners use trace_ctx for the same guarantee)
-            set_trace(None)
         self.table.end_pass()
         if self.async_table is not None:
             # pass boundary is a sync point: drain the host optimizer and
@@ -1365,13 +1360,14 @@ class BoxTrainer:
         mean_loss = float(np.mean(losses)) if losses else 0.0
         # pass boundary is always a report boundary: the window closes
         # with the pass stats + the streaming metrics' last computed AUC
-        extra = {"event": "pass_end", "loss": round(mean_loss, 6),
-                 "auc": {m.name: float(m.calculator.auc())
-                         for m in self.metrics.messages()}}
-        from paddlebox_tpu.metrics.quality import attach_pass_extras
-        attach_pass_extras(extra, self.quality)
-        self.reporter.maybe_report(self._step_count, force=True,
-                                   extra=extra)
+        with obs_span("pass_report"):
+            extra = {"event": "pass_end", "loss": round(mean_loss, 6),
+                     "auc": {m.name: float(m.calculator.auc())
+                             for m in self.metrics.messages()}}
+            from paddlebox_tpu.metrics.quality import attach_pass_extras
+            attach_pass_extras(extra, self.quality)
+            self.reporter.maybe_report(self._step_count, force=True,
+                                       extra=extra)
         if self.cfg.profile:
             from paddlebox_tpu.utils.profiler import timer_report
             obs_log.info(timer_report(self.timers, prefix="trainer."))
@@ -1404,102 +1400,6 @@ class BoxTrainer:
                 self.num_slots)
             from paddlebox_tpu.metrics import drift as _drift
             _drift.observe_preds(tensors["pred"], mask=mask)
-
-    # ------------------------------------------------------ profiled mode
-    def _profiled_stages(self):
-        """The staged jits, built ONCE per trainer (a fresh jit per pass
-        would land a full compile inside the first batch's stage timer and
-        skew the attribution report)."""
-        if getattr(self, "_staged_jits", None) is None:
-            fns = self.fns
-            layout = self.table.layout
-
-            def stage_pull(slab, ids):
-                # mirrors the fused step's _pull: keep the full rows so the
-                # push stage reuses them exactly like the fused path does
-                rows = gather_slab_rows(slab, ids, layout)
-                return pull_view_from_rows(rows, layout), rows
-
-            def stage_fwd_bwd(params, emb, batch):
-                (loss, preds), (dp, demb) = jax.value_and_grad(
-                    fns.forward, argnums=(0, 1), has_aux=True)(params, emb,
-                                                               batch)
-                return loss, preds, dp, demb
-
-            def stage_dense_opt(params, opt_state, dp, emb, batch):
-                updates, opt_state = self.dense_opt.update(dp, opt_state,
-                                                           params)
-                params = optax.apply_updates(params, updates)
-                return fns.dn_update(params, emb, batch), opt_state
-
-            self._staged_jits = (
-                instrument_jit(stage_pull, "stage_pull"),
-                instrument_jit(stage_fwd_bwd, "stage_fwd_bwd"),
-                instrument_jit(stage_dense_opt, "stage_dense_opt"),
-                instrument_jit(fns.sparse_push, "stage_push",
-                               donate_argnums=(0,)))
-        return self._staged_jits
-
-    def train_pass_profiled(self, dataset: BoxDataset) -> Dict[str, float]:
-        """TrainFilesWithProfiler analog (boxps_worker.cc:1336, enabled by
-        the profile_per_op flag): one pass with the fused step SPLIT into
-        separately dispatched, D2H-synced stages — slower than the fused
-        path by design, in exchange for per-stage attribution. Runs the
-        SAME forward/push/data_norm closures as the fused step (TrainStepFns
-        exposes them), the same shuffle cadence, nan guard, dump and step
-        accounting; prints a stage report at pass end."""
-        stage_pull, stage_fwd_bwd, stage_dense_opt, stage_push = \
-            self._profiled_stages()
-
-        timers = {n: Timer() for n in ("host_stage", "pull", "fwd_bwd",
-                                       "dense_opt", "push")}
-
-        def timed(t, fn, *a):
-            """Sync each stage on a tiny D2H scalar of every output leaf —
-            a sync that does not rest on block_until_ready, without
-            hauling (or even device-copying) slab-sized buffers."""
-            t.start()
-            out = fn(*a)
-            for leaf in jax.tree.leaves(out):
-                np.asarray(leaf[(0,) * leaf.ndim] if leaf.ndim else leaf)  # boxlint: BX931 ok (the profiled path syncs each stage on purpose: per-stage wall time IS the product here)
-            t.pause()
-            return out
-
-        self.table.begin_feed_pass()
-        dataset.load_into_memory(add_keys_fn=self.table.add_keys)
-        self.table.end_feed_pass()
-        self._refresh_aux()
-        self.table.begin_pass()
-        dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
-        losses = []
-        for b in dataset.split_batches(num_workers=1)[0]:
-            timers["host_stage"].start()
-            batch = self.device_batch(b, self.table.lookup_ids(b.keys,
-                                                               b.valid))
-            timers["host_stage"].pause()
-            emb, rows = timed(timers["pull"], stage_pull, self.table.slab,
-                              batch["ids"])
-            loss, preds, dp, demb = timed(
-                timers["fwd_bwd"], stage_fwd_bwd, self.params, emb, batch)
-            self.params, self.opt_state = timed(
-                timers["dense_opt"], stage_dense_opt, self.params,
-                self.opt_state, dp, emb, batch)
-            slab = timed(timers["push"], stage_push, self.table.slab, demb,
-                         batch, self.table.next_prng(), rows)
-            self.table.set_slab(slab)
-            self._step_count += 1
-            losses.append(float(loss))
-            if self.cfg.check_nan_inf and not np.isfinite(losses[-1]):
-                raise FloatingPointError(
-                    f"nan/inf loss at step {self._step_count}")
-            self._add_metrics(preds, b)
-            if self.dump_writer is not None:
-                self._dump_batch(preds, b)
-        self.table.end_pass()
-        from paddlebox_tpu.utils.profiler import timer_report
-        obs_log.info(timer_report(timers, prefix="stage."))
-        return {"loss": float(np.mean(losses)) if losses else 0.0,
-                "batches": len(losses), "instances": len(dataset)}
 
     # ------------------------------------------------------------- eval
     def predict_batches(self, dataset: BoxDataset) -> Tuple[np.ndarray, np.ndarray]:

@@ -23,25 +23,20 @@ def trace(logdir: str) -> Iterator[None]:
     The chrome-tracing-JSON role of platform/profiler/chrometracing_logger.
     While the trace runs, every obs.span() also opens a TraceAnnotation so
     the ring spans land in the XPlane timeline too (the ring export via
-    obs.export_chrome_trace works WITHOUT any of this — CPU container)."""
+    obs.export_chrome_trace works WITHOUT any of this — CPU container).
+    One span ``profiler_trace`` covers start_trace to stop_trace on both
+    clocks (ring and XPlane host plane): the mark to align them by."""
     import jax
 
     from paddlebox_tpu.obs import tracer as _obs_tracer
     jax.profiler.start_trace(logdir)
     _obs_tracer.set_jax_annotation(jax.profiler.TraceAnnotation)
     try:
-        yield
+        with _obs_tracer.span("profiler_trace"):
+            yield
     finally:
         _obs_tracer.set_jax_annotation(None)
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span inside a trace (platform::RecordEvent analog)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 def timer_report(timers: Dict[str, Timer], prefix: str = "") -> str:

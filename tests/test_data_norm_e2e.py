@@ -119,35 +119,6 @@ def test_mixed_precision_preserves_summary_f32():
     assert cast["dn_summary"]["batch_size"].dtype == jnp.float32
 
 
-def test_profile_per_op_mode(tmp_path):
-    """profile_per_op routes a pass through staged, D2H-synced dispatches
-    (TrainFilesWithProfiler analog) and keeps training state continuous
-    with the fused path."""
-    from paddlebox_tpu.config import flags
-
-    files, feed = _data(tmp_path)
-    # data_norm model: the profiled pass must run the SAME summary update
-    # as the fused step (it reuses the fused closures)
-    tr = BoxTrainer(CtrDnn(ModelSpec(num_slots=N_SLOTS, slot_dim=3 + D),
-                           hidden=(16,), use_data_norm=True),
-                    _table(), feed, TrainerConfig(dense_lr=1e-2))
-    try:
-        ds = BoxDataset(feed)
-        ds.set_filelist(files)
-        bs0 = float(np.asarray(tr.params["dn_summary"]["batch_size"])[0])
-        flags.set_flag("profile_per_op", True)
-        try:
-            s1 = tr.train_pass(ds)
-        finally:
-            flags.set_flag("profile_per_op", False)
-        bs1 = float(np.asarray(tr.params["dn_summary"]["batch_size"])[0])
-        assert bs1 > bs0, (bs0, bs1)
-        s2 = tr.train_pass(ds)   # fused pass continues from profiled state
-        assert s2["loss"] < s1["loss"], (s1, s2)
-    finally:
-        tr.close()
-
-
 def test_sharded_trainer_data_norm_replicated(tmp_path):
     files, feed = _data(tmp_path)
     P = len(jax.devices())

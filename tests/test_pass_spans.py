@@ -1,0 +1,364 @@
+"""The pass explains itself (ISSUE 25): live nested phase spans on the pass
+loop, a pass id on every span of a pass across threads, compiles as spans,
+named scopes in the step with the map that makes them readable."""
+
+import contextlib
+import json
+import os
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddlebox_tpu.config import flags
+from paddlebox_tpu.config.configs import (SparseOptimizerConfig, TableConfig,
+                                          TrainerConfig)
+from paddlebox_tpu.data import BoxDataset, write_synthetic_ctr_files
+from paddlebox_tpu.embedding.pass_table import PassTable, _delta_promote
+from paddlebox_tpu.models import CtrDnn
+from paddlebox_tpu.models.base import ModelSpec
+from paddlebox_tpu.obs import device as obs_device
+from paddlebox_tpu.obs import tracer as obs_tracer
+from paddlebox_tpu.obs.tracer import (get_tracer, next_trace_id,
+                                      pass_trace_id, step_trace_id)
+from paddlebox_tpu.train.preload import run_preloaded_passes
+from paddlebox_tpu.train.trainer import BoxTrainer
+from paddlebox_tpu.utils.stats import stat_get
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+D, NUM_SLOTS = 4, 4
+
+# ISSUE 25, part A: parent -> its child spans, in order
+CHILDREN = {
+    "ingest_feed_pass": ["feed_unique", "feed_route_index"],
+    "pass_end": ["writeback_select", "writeback_d2h", "writeback_decode",
+                 "writeback_store", "pass_mem_check"],
+    "train_pass": ["pass_begin", "pass_split_batches", "pass_end",
+                   "pass_report"],
+}
+FULL_BUILD = ["build_store_read", "build_encode", "build_h2d"]
+INCREMENTAL = ["promote_diff", "promote_store_read", "promote_stage",
+               "promote_dispatch"]
+ROOTS = ["ingest_wait_preload", "ingest_feed_pass", "train_pass",
+         "pass_release"]
+STEP_SCOPES = {"pull", "pool", "fwd_bwd", "dense_opt", "push_grads",
+               "push_merge", "push_opt", "push_write"}
+
+
+def table_cfg():
+    return TableConfig(
+        embedx_dim=D, pass_capacity=1 << 13,
+        optimizer=SparseOptimizerConfig(mf_create_thresholds=0.0,
+                                        mf_initial_range=1e-3,
+                                        feature_learning_rate=0.1,
+                                        mf_learning_rate=0.1))
+
+
+@contextlib.contextmanager
+def fresh_compiles():
+    """jax's persistent cache keys a program WITHOUT its metadata, so a
+    cache filled before a scope existed hands back an executable whose
+    op_names lack it: the scope maps below are read from fresh compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def two_passes(files, feed):
+    """A fresh trainer through two preloaded passes; (losses, ring spans).
+    400 examples of 32 a batch: one scan chunk of 8, then 5 single steps."""
+    get_tracer().clear()
+    trainer = BoxTrainer(
+        CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D), hidden=(16,)),
+        table_cfg(), feed, TrainerConfig(dense_lr=0.01), seed=0)
+    datasets = []
+    for _ in range(2):
+        ds = BoxDataset(feed, read_threads=1)
+        ds.set_filelist(files)
+        datasets.append(ds)
+    try:
+        stats = run_preloaded_passes(trainer, datasets)
+    finally:
+        trainer.close()
+    return [s["loss"] for s in stats], get_tracer().all_spans()
+
+
+@pytest.fixture(scope="module")
+def data(tmp_path_factory):
+    files, feed = write_synthetic_ctr_files(
+        str(tmp_path_factory.mktemp("pass_spans")), num_files=2,
+        lines_per_file=200, num_slots=NUM_SLOTS, vocab_per_slot=80,
+        max_len=3, seed=13)
+    return files, type(feed)(slots=feed.slots, batch_size=32)
+
+
+@pytest.fixture(scope="module")
+def run(data):
+    flags.set_flag("dataset_disable_shuffle", True)
+    try:
+        with fresh_compiles():
+            losses, spans = two_passes(*data)
+        snap = obs_device.snapshot()
+    finally:
+        flags.set_flag("dataset_disable_shuffle", False)
+    return {"losses": losses, "spans": spans, "snapshot": snap,
+            "main": threading.get_ident()}
+
+
+def by_pass(run, k):
+    return [s for s in run["spans"] if s[5] == pass_trace_id(0, k)]
+
+
+def test_both_passes_record_every_phase_span(run):
+    first = {s[0] for s in by_pass(run, 0)}
+    second = {s[0] for s in by_pass(run, 1)}
+    shared = set(ROOTS) | {c for cs in CHILDREN.values() for c in cs}
+    assert shared <= first and shared <= second, (
+        shared - first, shared - second)
+    # pass 0 builds the slab whole; pass 1 finds it resident and promotes
+    # the delta, after the prefetcher read pass 1's rows under pass 0
+    assert set(FULL_BUILD) <= first and not set(INCREMENTAL) & first
+    assert set(INCREMENTAL) <= second and not set(FULL_BUILD) & second
+    assert "promote_prefetch_finish" in second
+    assert {"ingest_parse", "host_stage", "scan_dispatch",
+            "chunk_drain"} <= first & second
+
+
+def test_each_child_lies_inside_its_parent_on_the_same_thread(run):
+    parents = dict(CHILDREN, pass_begin=FULL_BUILD + INCREMENTAL)
+    spans = run["spans"]
+    for parent, children in parents.items():
+        held = [s for s in spans if s[0] == parent]
+        kids = [s for s in spans if s[0] in children]
+        assert held and kids, parent
+        for name, tid, _tn, t0, t1, _tr in kids:
+            assert any(p[1] == tid and p[3] <= t0 and t1 <= p[4]
+                       for p in held), (name, parent)
+        for p in held:   # children in the table's order, none overlapping
+            inside = [s for s in kids if p[3] <= s[3] and s[4] <= p[4]]
+            assert [s[0] for s in inside] == [
+                c for c in children if c in {s[0] for s in inside}]
+            assert all(a[4] <= b[3] for a, b in zip(inside, inside[1:]))
+    for name in ROOTS:   # roots are roots: on the main thread, in no span
+        for s in (s for s in spans if s[0] == name):
+            assert s[1] == run["main"]
+            assert not any(o[1] == s[1] and o[3] <= s[3] and s[4] <= o[4]
+                           and o is not s for o in spans), name
+
+
+def test_spans_carry_their_pass_across_threads(run):
+    main = run["main"]
+    for k in (0, 1):
+        mine = by_pass(run, k)
+        # the readers parse pass k's two files under pass k's id, though
+        # pass 1's ran while pass 0 trained; the stager stages under it
+        assert sum(s[0] == "ingest_parse" for s in mine) == 2
+        assert all(s[1] != main for s in mine
+                   if s[0] in ("ingest_parse", "ingest_merge"))
+        assert any(s[0] == "host_stage" and s[1] != main for s in mine)
+    t_train0 = [s for s in by_pass(run, 0) if s[0] == "train_pass"][0]
+    parse1 = [s for s in by_pass(run, 1) if s[0] == "ingest_parse"]
+    assert all(s[4] <= t_train0[4] for s in parse1), "parsed under pass 0"
+    named = set(ROOTS) | set(FULL_BUILD) | set(INCREMENTAL) | {
+        c for cs in CHILDREN.values() for c in cs} | {
+        "ingest_parse", "promote_prefetch_finish", "scan_dispatch",
+        "chunk_drain"}
+    ids = {pass_trace_id(0, 0), pass_trace_id(0, 1)}
+    assert all(s[5] in ids for s in run["spans"] if s[0] in named)
+
+
+def test_a_step_id_does_not_outlive_the_step_loop(run):
+    """The five single steps after the scan chunk set step ids; pass_end,
+    after them, is back under the pass's id."""
+    stepped = [s for s in run["spans"]
+               if s[0] == "host_stage" and s[1] == run["main"]]
+    assert len(stepped) == 10
+    assert {s[5] for s in stepped} == {step_trace_id(0, n)
+                                       for n in (9, 10, 11, 12, 13,
+                                                 22, 23, 24, 25, 26)}
+    ends = [s for s in run["spans"] if s[0] == "pass_end"]
+    assert [s[5] for s in ends] == [pass_trace_id(0, 0), pass_trace_id(0, 1)]
+
+
+def test_trace_ids_of_different_kinds_never_collide():
+    pid = pass_trace_id(3, 7)
+    assert pid >> 61 == 1 and (pid >> 48) & 0x1FFF == 3 and pid & 0xFFFF == 7
+    assert pass_trace_id(3, 7) != pass_trace_id(3, 8) != pass_trace_id(4, 8)
+    assert step_trace_id(3, 7) >> 61 == 0
+    assert next_trace_id() >> 63 == 1
+    assert ((1 << 62) | step_trace_id(3, 7)) >> 61 == 2   # a mesh frame's
+
+
+def test_pass_begin_and_pass_end_open_a_trace_annotation():
+    opened = []
+
+    class Stub:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            opened.append("/" + self.name)
+
+    table = PassTable(table_cfg(), seed=0)
+    table.begin_feed_pass()
+    table.add_keys(np.arange(1, 50, dtype=np.uint64))
+    table.end_feed_pass()
+    obs_tracer.set_jax_annotation(Stub)
+    try:
+        table.begin_pass()
+        table.note_touched(np.arange(10))
+        table.end_pass()
+    finally:
+        obs_tracer.set_jax_annotation(None)
+    assert opened[0] == "pass_begin" and opened[-1] == "/pass_end"
+    i, j = opened.index("/pass_begin"), opened.index("pass_end")
+    assert i < j
+    assert opened[1:i] == [x for c in FULL_BUILD for x in (c, "/" + c)]
+    assert "writeback_d2h" in opened[j:]
+
+
+def test_a_fresh_jit_records_one_backend_compile_and_no_entry(run):
+    x = jnp.arange(7.0)
+
+    def count():
+        return sum(s[0] == "backend_compile"
+                   for s in get_tracer().all_spans())
+
+    def compiles():
+        return {n: e["compiles"]
+                for n, e in obs_device.snapshot()["entries"].items()}
+
+    spans0, stat0, entries0 = (count(), stat_get("device_backend_compiles"),
+                               compiles())
+    y = jax.jit(lambda v: v * 3.0 + 1.0)(x)  # boxlint: disable=BX901
+    assert count() == spans0 + 1
+    assert stat_get("device_backend_compiles") == stat0 + 1
+    assert compiles() == entries0
+    assert np.asarray(y)[1] == 4.0
+    last = [s for s in get_tracer().all_spans()
+            if s[0] == "backend_compile"][-1]
+    assert 0.0 < last[4] - last[3] < 60.0
+
+
+def test_an_instrumented_compile_records_a_device_compile_span(run):
+    compiled = [s for s in run["spans"] if s[0] == "device_compile"]
+    inner = [s for s in run["spans"] if s[0] == "backend_compile"]
+    assert compiled and inner
+    # scan_steps and train_step compile inside pass 0's train_pass; each
+    # device_compile holds the backend compile it caused
+    for s in compiled:
+        assert any(s[3] <= b[3] and b[4] <= s[4] and b[1] == s[1]
+                   for b in inner)
+
+
+@pytest.mark.parametrize("entry", ["scan_steps", "train_step"])
+def test_the_scope_map_names_every_phase_of_the_step(run, entry):
+    e = run["snapshot"]["entries"][entry]
+    assert e["module"].startswith("jit_")
+    assert STEP_SCOPES <= set(e["scopes"].values()), (
+        STEP_SCOPES - set(e["scopes"].values()))
+    assert "" in e["scopes"].values()       # parameters, the loop, tuples
+    assert not any(k.startswith("%") for k in e["scopes"])
+
+
+def test_the_scope_map_names_delta_promote_s_phases():
+    cap, width = 64, 8
+    with fresh_compiles():
+        text = _delta_promote.lower(
+            jnp.zeros((cap, width)), jnp.zeros(cap, jnp.int32),
+            jnp.zeros(cap, bool), jnp.zeros(4, jnp.int32),
+            jnp.zeros((4, width))).compile().as_text()
+    assert {"promote_permute", "promote_scatter"} <= set(
+        obs_device.scope_map(text).values())
+
+
+def test_scope_map_reads_the_innermost_scope_through_autodiff_wrappers():
+    text = "\n".join([
+        "HloModule jit_f, is_scheduled=true",
+        "%fused_computation (p: f32[4]) -> f32[4] {",
+        '  %p = f32[4]{0} parameter(0)',
+        '  ROOT %add.1 = f32[4]{0} add(%p, %p), metadata={op_name='
+        '"jit(f)/jit(main)/while/body/closed_call/fwd_bwd/'
+        'transpose(jvp(pool))/add" source_file="x.py"}',
+        "}",
+        "ENTRY %main (a: f32[4]) -> f32[4] {",
+        '  %a = f32[4]{0} parameter(0), metadata={op_name="a"}',
+        '  %fusion.2 = f32[4]{0} fusion(%a), kind=kLoop, '
+        'calls=%fused_computation, metadata={op_name='
+        '"jit(f)/jit(main)/push_write/scatter-add"}',
+        '  ROOT %copy.3 = f32[4]{0} copy(%fusion.2)',
+        "}"])
+    assert obs_device.scope_map(text) == {
+        "p": "", "add.1": "pool", "a": "", "fusion.2": "push_write",
+        "copy.3": ""}
+
+
+def test_with_obs_trace_off_no_span_and_the_same_losses(run, data):
+    flags.set_flag("obs_trace", False)
+    flags.set_flag("dataset_disable_shuffle", True)
+    try:
+        losses, spans = two_passes(*data)
+    finally:
+        flags.set_flag("obs_trace", True)
+        flags.set_flag("dataset_disable_shuffle", False)
+        obs_tracer.configure_from_flags()
+    assert spans == []
+    assert losses == run["losses"]
+
+
+def test_profiler_trace_marks_the_traced_stretch(monkeypatch, tmp_path):
+    from paddlebox_tpu.utils import profiler
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    get_tracer().clear()
+    with profiler.trace(str(tmp_path)):
+        with obs_tracer.span("inside"):
+            assert obs_tracer._JAX_ANNOTATE is jax.profiler.TraceAnnotation
+    assert obs_tracer._JAX_ANNOTATE is None and calls == ["start", "stop"]
+    spans = {s[0]: s for s in get_tracer().all_spans()}
+    outer, inner = spans["profiler_trace"], spans["inside"]
+    assert outer[3] <= inner[3] and inner[4] <= outer[4]
+
+
+def test_scope_times_sums_to_the_ops_total():
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    try:
+        import scope_times
+    finally:
+        sys.path.pop(0)
+    tr = scope_times.tr
+    trace = tr.load(os.path.join(ROOT, "benchmarks", "testdata",
+                                 "small.xplane.pb"))
+    snapshot = {"entries": {
+        "scan_steps": {"module": "jit_scan_steps", "scopes": {
+            "fusion.8": "fwd_bwd", "copy.11": "", "copy-start.1": "pull",
+            "copy-done.1": "pull", "while": "pool"}},
+        "no_map": {"compiles": 1}}}
+    snapshot = json.loads(json.dumps(snapshot))    # as a dumped one reads
+    got = scope_times.scope_times(trace, snapshot)
+    assert set(got) == {"jit_scan_steps"}
+    ops = [(tr.short_name(n), e - s) for n, s, e
+           in trace["devices"]["/device:TPU:0"][tr.OPS_LINE]]
+    want = sum(d for (_op, code), d in ops if code not in tr.CONTAINERS)
+    acc = got["jit_scan_steps"]
+    assert sum(acc.values()) == pytest.approx(want, rel=1e-9)
+    assert set(acc) == {"fwd_bwd", "pull", scope_times.NO_SCOPE,
+                        scope_times.NOT_IN_MAP}     # %copy-start, -done
+    assert acc["fwd_bwd"] == pytest.approx(
+        sum(d for (op, _c), d in ops if op == "%fusion.8"), rel=1e-9)
+    assert scope_times.main(["scope_times.py"]) == 2
