@@ -306,18 +306,25 @@ define_flag("sharding_hot_cap", 1024,
             "replicated set defeats the wire saving it exists for)")
 define_flag("incremental_pass", True,
             "incremental pass lifecycle (BeginPass/EndPass delta, the "
-            "BoxPS keep-rows-resident cadence): begin_pass diffs the new "
-            "pass's key set against the rows already resident in the slab "
-            "and promotes only NEW keys (device-side permute instead of a "
-            "full host rebuild + H2D); end_pass transfers and writes back "
-            "only the rows the pass actually touched. Bit-parity with the "
-            "full path (tests/test_pass_incremental.py). Memory: the "
-            "single-chip slab stays resident in HBM between passes (no "
-            "extra copy); the SHARDED table instead keeps a host-DRAM "
-            "mirror of each owned shard's slab between passes (~slab "
-            "bytes of host RAM — small next to the host store itself, "
-            "but not free). Off = rebuild the whole slab every pass (the "
-            "pre-round-6 behavior, no residency anywhere)")
+            "BoxPS keep-rows-resident cadence): the slab stays in HBM "
+            "between passes and begin_pass promotes only the keys that "
+            "ARRIVED (host-store read, H2D and one in-place scatter of "
+            "those rows instead of a full host rebuild + H2D); end_pass "
+            "transfers and writes back only the rows the pass actually "
+            "touched. WHICH row a key has does not depend on this flag: "
+            "the feed pass diffs the new key set against the last pass's "
+            "row assignment either way (a key that stays keeps its row, "
+            "rows of keys that left are freed, keys that arrive take free "
+            "rows; invalidate_residency and a test-mode pass reset it to "
+            "rows by rank), so slab rows, store and journal are bit for "
+            "bit the same on and off (tests/test_pass_incremental.py), "
+            "created embeddings' init draws included (addressed by slab "
+            "row). Memory: the single-chip slab stays resident in HBM "
+            "between passes (no extra copy); the SHARDED table instead "
+            "keeps a host-DRAM mirror of each owned shard's slab between "
+            "passes (~slab bytes of host RAM — small next to the host "
+            "store itself, but not free). Off = rebuild the whole slab "
+            "from the store every pass, each key at its assigned row")
 define_flag("obs_trace", True,
             "record named spans into the per-thread ring tracer "
             "(obs/tracer.py — the cheap always-on tier of the reference's "

@@ -3,10 +3,11 @@
 Re-design of the reconstructed boxps::BoxPSBase contract (SURVEY.md, every
 call site in box_wrapper.{h,cc}) around XLA's static-shape model:
 
-  BeginFeedPass/AddKeys/EndFeedPass  → collect the pass's key set, assign
-        DENSE pass-local ids (sorted-unique + searchsorted, replacing the
-        device hash table: the feed pass gives the exact working set, so the
-        pass table IS dense — the insight behind BeginFeedPass)
+  BeginFeedPass/AddKeys/EndFeedPass  → collect the pass's key set and
+        assign each key its slab row (embedding/row_map.py: a resident key
+        keeps the row it has, an arriving key takes a free one; the first
+        pass is dense, rows 0..n-1 by sorted rank), replacing the device
+        hash table: the feed pass gives the exact working set
   BeginPass  → promote host rows → device HBM slab  [capacity, width]
   PullSparse → gather rows by id (keys pre-translated to ids at pack time,
         so DedupKeysAndFillIdx becomes a host-side searchsorted)
@@ -36,6 +37,7 @@ from paddlebox_tpu.embedding.accessor import (PushLayout, ValueLayout,
 from paddlebox_tpu.embedding.host_store import HostEmbeddingStore
 from paddlebox_tpu.embedding.native_store import make_host_store
 from paddlebox_tpu.embedding.optimizers import apply_push
+from paddlebox_tpu.embedding.row_map import RowMap, sorted_member
 from paddlebox_tpu.obs.device import account_d2h, account_h2d, instrument_jit
 from paddlebox_tpu.obs.tracer import span as obs_span
 from paddlebox_tpu.utils.stats import gauge_set, stat_add
@@ -68,20 +70,15 @@ _push_kernel = instrument_jit(_push_kernel_impl, "table_push",
                               static_argnames=("layout", "conf"))
 
 
-def _delta_promote_impl(old_slab, src, keep, new_idx, new_rows):
-    """Pure bit-move: new_slab[i] = old_slab[src[i]] where keep[i] (the key
-    at new sorted position i was resident at old position src[i]), zeros
-    elsewhere, then the freshly promoted host rows scatter into their new
-    positions. new_idx is padded to a power-of-two bucket with `capacity`
-    (out of range, mode='drop') so promote counts don't recompile per pass.
+def _delta_promote_impl(slab, new_idx, new_rows):
+    """The arrived keys' rows scatter into the (donated) resident slab at
+    the rows the RowMap gave them; every other row stays where it is.
+    new_idx is padded to a power-of-two bucket with `capacity` (out of
+    range, mode='drop') so promote counts don't recompile per pass.
     Dtype-agnostic on purpose: under the bf16 slab diet the rows are
-    ENCODED uint16 and must move without arithmetic (a python 0.0 would
-    silently upcast the select to f32)."""
-    with jax.named_scope("promote_permute"):
-        out = jnp.where(keep[:, None], old_slab[src],
-                        jnp.zeros((), old_slab.dtype))
+    ENCODED uint16 and must move without arithmetic."""
     with jax.named_scope("promote_scatter"):
-        return out.at[new_idx].set(new_rows, mode="drop")
+        return slab.at[new_idx].set(new_rows, mode="drop")
 
 
 # donated: begin_pass consumes the previous pass's slab in place — one
@@ -109,20 +106,6 @@ def _pow2_pad(m: int) -> int:
     while p < m:
         p <<= 1
     return p
-
-
-def sorted_member(sorted_keys: np.ndarray, keys: np.ndarray):
-    """(pos, hit) membership probe of `keys` against a SORTED UNIQUE key
-    array: pos[i] is the index of keys[i] in sorted_keys where hit[i],
-    clamped garbage elsewhere. The ONE definition of the searchsorted+
-    equality idiom every incremental-lifecycle diff uses (resident diff
-    fallback, staged-promote matching, prefetcher known-sets)."""
-    if sorted_keys.size == 0:
-        return (np.zeros(keys.size, np.int64),
-                np.zeros(keys.size, bool))
-    pos = np.minimum(np.searchsorted(sorted_keys, keys),
-                     sorted_keys.size - 1)
-    return pos, sorted_keys[pos] == keys
 
 
 def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
@@ -335,22 +318,26 @@ class PassTable:
         self.capacity = table.pass_capacity
         self._feed_keys: list = []
         self._pass_keys: Optional[np.ndarray] = None  # sorted unique
-        self._route_index = None  # native key→id hash index for the pass
+        # key → slab row of _pass_keys (row_map.py owns the assignment)
+        self._rows: Optional[RowMap] = None
         self._slab: Optional[jnp.ndarray] = None
         self._in_feed_pass = False
         self._in_pass = False
         self._test_mode = False
         self._prng = jax.random.PRNGKey(seed)
-        # incremental pass lifecycle (BoxPS keep-rows-resident cadence):
-        # after end_pass the slab stays in HBM and _resident_keys records
-        # which key occupies which row; the next begin_pass promotes only
-        # the delta. _prev_route keeps the ended pass's native hash index
-        # alive across the feed boundary so the diff is a probe, not a
-        # searchsorted. store_lock serializes host-store access between
-        # end_pass and the preload promote stager.
-        self._resident_keys: Optional[np.ndarray] = None
-        self._prev_route = None
-        self._route_for: Optional[np.ndarray] = None  # keys _route_index maps
+        # pass-to-pass state (BoxPS keep-rows-resident cadence): after
+        # end_pass _resident records which key occupies which row, and the
+        # next feed pass assigns its rows as that map's successor. With
+        # incremental_pass the slab stays in HBM too and begin_pass
+        # promotes only the keys that arrived; without, it rebuilds the
+        # slab from the store AT THOSE ROWS, so a key has one row whichever
+        # way the flag stands. _residency_gen counts _resident's changes:
+        # a feed pass's assignment holds while it reads what it read then.
+        # store_lock serializes host-store access between end_pass and the
+        # preload promote stager.
+        self._resident: Optional[RowMap] = None
+        self._residency_gen = 0
+        self._planned_gen = -1
         self._touched: Optional[np.ndarray] = None  # bool[capacity] mirror
         self._touch_seen = False  # any mark this pass? (else full writeback)
         self._residency_poisoned = False  # mid-pass invalidate: drop at end
@@ -399,7 +386,7 @@ class PassTable:
 
     def end_feed_pass(self) -> None:
         """EndFeedPass (box_wrapper.cc:153): freeze the pass key set and
-        assign dense ids 0..n-1 (sorted order)."""
+        assign each key its slab row (see _assign_rows)."""
         if not self._in_feed_pass:
             raise RuntimeError("end_feed_pass without begin_feed_pass")
         with obs_span("feed_unique"):
@@ -412,63 +399,38 @@ class PassTable:
             raise RuntimeError(
                 f"pass working set {self._pass_keys.size} exceeds table "
                 f"pass_capacity {self.capacity} (raise TableConfig.pass_capacity)")
-        # the outgoing index maps resident keys → slab rows: keep it for
-        # the incremental begin_pass diff (one hash probe per key). Only
-        # when it really covers the RESIDENT key set — after a test-mode
-        # pass the live index maps the eval keys instead (identity check
-        # against the array end_pass recorded).
-        with obs_span("feed_route_index"):
-            self._drop_prev_route()
-            if (self._resident_keys is not None
-                    and self._route_for is self._resident_keys):
-                self._prev_route = self._route_index
-                self._route_index = None
-            self._drop_route_index()
-            # native key→id hash index, built once per pass and probed per
-            # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
-            # DedupKeysAndFillIdx tier at line rate
-            from paddlebox_tpu.native.build import create_route_index
-            self._route_index = create_route_index([self._pass_keys])
-            self._route_for = self._pass_keys
+        self._assign_rows()
         self._feed_keys = []
         self._in_feed_pass = False
 
-    def _drop_route_index(self) -> None:
-        from paddlebox_tpu.native.build import destroy_route_index
-        destroy_route_index(self._route_index)
-        self._route_index = None
+    def _assign_rows(self) -> None:
+        """Ask the row owner for this key set's rows. They succeed the
+        resident map's: keys that stay keep their rows, rows of keys that
+        left are freed, keys that arrive take free rows. With no resident
+        map (first pass, after invalidate_residency or a test-mode pass),
+        rows 0..n-1 by sorted rank."""
+        if self._resident is None:
+            # padding_id is never assigned
+            rows = RowMap.by_rank(self._pass_keys, self.capacity - 1)
+        else:
+            with obs_span("promote_diff"):
+                rows = self._resident.succeed(self._pass_keys)
+        with obs_span("feed_route_index"):
+            # native key→row hash index, built once per pass and probed per
+            # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
+            # DedupKeysAndFillIdx tier at line rate
+            rows.build_index()
+        self._rows = rows
+        self._planned_gen = self._residency_gen
 
-    def _drop_prev_route(self) -> None:
-        from paddlebox_tpu.native.build import destroy_route_index
-        destroy_route_index(self._prev_route)
-        self._prev_route = None
-
-    def __del__(self):
-        try:
-            self._drop_route_index()
-            self._drop_prev_route()
-        except Exception:  # rationale: __del__ may run with a
-            # half-torn-down interpreter where even logging fails;
-            # the explicit drop paths are the loud ones
-            pass
+    def _set_resident(self, rows: Optional[RowMap]) -> None:
+        self._resident = rows
+        self._residency_gen += 1
 
     @staticmethod
     def _incremental() -> bool:
         from paddlebox_tpu.config import flags
         return bool(flags.get_flag("incremental_pass"))
-
-    def _resident_pos(self, keys: np.ndarray) -> np.ndarray:
-        """[n] int32 resident slab row per key, -1 when not resident —
-        the delta-promote diff. Native hash probe over the previous pass's
-        index when available, sorted searchsorted fallback."""
-        res = self._resident_keys
-        if self._prev_route is not None:
-            from paddlebox_tpu.native.build import route_lookup_serve
-            return route_lookup_serve(self._prev_route, keys, -1)
-        if res is None:
-            return np.full(keys.size, -1, np.int32)
-        pos, hit = sorted_member(res, keys)
-        return np.where(hit, pos, -1).astype(np.int32)
 
     def _promote_missing_rows(self, missing_keys: np.ndarray) -> np.ndarray:
         """Host rows for the keys being promoted this pass. Rows the
@@ -498,15 +460,18 @@ class PassTable:
         """BeginPass (box_wrapper.cc:171): promote the working set into the
         device slab.
 
-        Incremental mode (incremental_pass flag, default on): the previous
-        pass's slab stayed resident in HBM, so this diffs the new key set
-        against the resident one, moves surviving rows into their new
-        (sorted) positions with one on-device permute — compaction instead
-        of reallocation — and promotes only the NEW keys (host-store read
-        + H2D for the delta alone). A pass with 90% key overlap does ~10%
-        of the full build's host and wire work. Bit-parity with the full
-        path: ids stay the sorted-unique positions, row bits move without
-        arithmetic, the tail (and trash row) zero exactly as before."""
+        The feed pass assigned this key set's rows as the resident map's
+        successor, so a resident key's row does not move. Incremental mode
+        (incremental_pass flag, default on): the previous pass's slab
+        stayed in HBM and only the keys that ARRIVED are promoted:
+        host-store read, H2D and one in-place scatter into the donated
+        slab, for the delta alone. A pass with 90% key overlap does ~10%
+        of the full build's host and wire work; one with 100% moves
+        nothing. Rows freed by keys that left keep stale bits until a new
+        key overwrites them whole. With no slab (first pass, after
+        invalidate_residency or a test-mode pass, flag off) the slab is
+        built whole from the store, every key at its assigned row: row for
+        row the bits the incremental path holds."""
         if self._in_pass:
             raise RuntimeError("pass already open")
         if self._pass_keys is None:
@@ -517,37 +482,32 @@ class PassTable:
     def _begin_pass(self) -> None:
         n = self._pass_keys.size
         gauge_set("pass_rows", n)
-        inc = (self._incremental() and self._resident_keys is not None
-               and self._slab is not None)
-        if inc:
-            with obs_span("promote_diff"):
-                old_pos = self._resident_pos(self._pass_keys)
-                hit = old_pos >= 0
-                miss_idx = np.nonzero(~hit)[0].astype(np.int32)
+        if self._planned_gen != self._residency_gen:
+            # residency changed since the feed pass (invalidated, or this
+            # is a second pass over one feed): assign against what is there
+            self._assign_rows()
+        rows = self._rows
+        if self._slab is not None:
             with obs_span("promote_store_read"):
-                new_rows = self._promote_missing_rows(self._pass_keys[~hit])
+                new_keys = self._pass_keys[rows.arrived]
+                new_rows = self._promote_missing_rows(new_keys)
                 # journal the promote delta: lookup_or_create CREATES
                 # missing features here (init rows the touched write-back
                 # may never revisit) — replay must see them; re-recording
                 # store-present non-resident rows is an idempotent upsert
                 # of equal bits
                 if not self._test_mode:
-                    self._journal_rows(self._pass_keys[~hit], new_rows)
+                    self._journal_rows(new_keys, new_rows)
             with obs_span("promote_stage"):
-                src = np.zeros(self.capacity, np.int32)
-                keep = np.zeros(self.capacity, bool)
-                if n:
-                    src[:n][hit] = old_pos[hit]
-                    keep[:n] = hit
-                m = miss_idx.size
+                m = new_keys.size
                 pad = _pow2_pad(max(m, 1))
                 idx_p = np.full(pad, self.capacity, np.int32)  # drop sentinel
                 # promote boundary: freshly-read host f32 rows encode to
                 # the device layout here (identity for f32 slabs); resident
-                # rows move as raw bits inside _delta_promote
+                # rows stay where they are
                 rows_p = np.zeros((pad, self.layout.device_width),
                                   self.layout.device_dtype)
-                idx_p[:m] = miss_idx
+                idx_p[:m] = rows.rows[rows.arrived]
                 rows_p[:m] = encode_slab_rows_np(new_rows, self.layout)
             # test mode CONSUMES the resident slab too (donated — a copy
             # would hold 2× slab HBM for the whole eval, an OOM at the
@@ -556,13 +516,10 @@ class PassTable:
             # so end_pass drops residency and the next train pass pays
             # one full rebuild — the pre-round-6 eval HBM profile
             with obs_span("promote_dispatch"):
-                account_h2d(rows_p.nbytes + src.nbytes + keep.nbytes
-                            + idx_p.nbytes)  # promote-delta staging transfer
-                self._slab = _delta_promote(self._slab, jnp.asarray(src),
-                                            jnp.asarray(keep),
-                                            jnp.asarray(idx_p),
+                account_h2d(rows_p.nbytes + idx_p.nbytes)  # promote delta
+                self._slab = _delta_promote(self._slab, jnp.asarray(idx_p),
                                             jnp.asarray(rows_p))
-            stat_add("pass_rows_promote_hit", int(hit.sum()))
+            stat_add("pass_rows_promote_hit", n - m)
             stat_add("pass_rows_promote_new", m)
         else:
             with obs_span("build_store_read"):
@@ -574,17 +531,26 @@ class PassTable:
                 if not self._test_mode:
                     self._journal_rows(self._pass_keys, host_rows)
             with obs_span("build_encode"):
-                # zero only the tail beyond n: a full-capacity zeros() here
-                # was pure memcpy waste — every [0, n) row is overwritten
-                slab = np.empty((self.capacity, self.layout.device_width),
-                                dtype=self.layout.device_dtype)
+                shape = (self.capacity, self.layout.device_width)
+                if rows.dense:
+                    # zero only the tail beyond n: a full-capacity zeros()
+                    # here was pure memcpy waste — every [0, n) row is
+                    # overwritten
+                    slab = np.empty(shape, dtype=self.layout.device_dtype)
+                    slab[n:] = 0
+                    where = slice(0, n)
+                else:
+                    slab = np.zeros(shape, dtype=self.layout.device_dtype)
+                    where = rows.rows
                 if n:
-                    slab[:n] = encode_slab_rows_np(host_rows, self.layout)
-                slab[n:] = 0
+                    slab[where] = encode_slab_rows_np(host_rows, self.layout)
             with obs_span("build_h2d"):
                 account_h2d(slab.nbytes)  # full slab build transfer
                 self._slab = jnp.asarray(slab)
-        self._drop_prev_route()
+        stat_add("pass_rows_freed", rows.freed)
+        gauge_set("pass_free_rows", rows.free_rows)
+        # the slab now holds _rows' assignment; end_pass makes it resident
+        self._set_resident(None)
         self._touch_seen = False
         self._residency_poisoned = False
         if not self._test_mode:
@@ -637,32 +603,33 @@ class PassTable:
         if self._test_mode:
             # no write-back, no residency from an eval slab
             self._slab = None
-            self._resident_keys = None
         else:
             if n:
                 if self._touched is not None and self._touch_seen:
                     with obs_span("writeback_select"):
-                        self._touched[self.padding_id] = False
-                        idx = np.nonzero(self._touched[:n])[0]
+                        keys, idx = self._rows.touched(self._touched)
                     if idx.size:
                         self._write_back(  # touched rows only
-                            self._pass_keys[idx],
-                            lambda: self._slab[jnp.asarray(idx)])
+                            keys, lambda: self._slab[jnp.asarray(idx)])
                     stat_add("pass_rows_written_back", int(idx.size))
                     stat_add("pass_rows_writeback_skipped", n - int(idx.size))
                 else:
-                    self._write_back(self._pass_keys,  # the full slab
-                                     lambda: self._slab[:n])
-            if self._incremental() and not self._residency_poisoned:
-                # rows stay resident (BoxPS cadence): the slab lives on in
-                # HBM and the next begin_pass promotes only the delta
-                self._resident_keys = self._pass_keys
-            else:
-                # flag off, or a mid-pass store mutation poisoned the
-                # residency (invalidate_residency during the pass must
-                # not be undone here)
+                    rows = self._rows
+                    self._write_back(  # every assigned row
+                        self._pass_keys,
+                        lambda: (self._slab[:n] if rows.dense else
+                                 self._slab[jnp.asarray(rows.rows)]))
+            if not self._residency_poisoned:
+                # each key keeps its row (BoxPS cadence): the next feed
+                # pass assigns its rows as this map's successor
+                self._set_resident(self._rows)
+            if self._residency_poisoned or not self._incremental():
+                # a mid-pass store mutation poisoned the residency
+                # (invalidate_residency during the pass must not be undone
+                # here), or flag off: the next begin_pass builds the slab
+                # whole. Else it lives on in HBM and the next begin_pass
+                # promotes only the keys that arrive
                 self._slab = None
-                self._resident_keys = None
         self._touched = None
         self._residency_poisoned = False
         self._in_pass = False
@@ -670,20 +637,22 @@ class PassTable:
             self.check_need_limit_mem()  # spill>0 invalidates internally
 
     def invalidate_residency(self) -> None:
-        """Drop the cross-pass resident state (slab, key map, staged
-        promote rows). Must be called after ANY host-store mutation that
-        bypasses the pass cadence — aging, shrink/decay, spill, checkpoint
-        stat rewrites, load — or the next delta promote would reuse stale
-        row bits. The next begin_pass falls back to a full build. Called
-        mid-pass, the live slab survives (the pass still needs it) but a
-        poison flag stops end_pass from re-establishing residency."""
+        """Drop the cross-pass resident state (slab, row assignment,
+        staged promote rows). Must be called after ANY host-store mutation
+        that bypasses the pass cadence — aging, shrink/decay, spill,
+        checkpoint stat rewrites, load — or the next delta promote would
+        reuse stale row bits. The next begin_pass falls back to a full
+        build, rows by rank: every checkpoint save lands here, so a run
+        resumed from one (a fresh table, rows by rank) assigns the rows
+        the uninterrupted run does. Called mid-pass, the live slab
+        survives (the pass still needs it) but a poison flag stops
+        end_pass from re-establishing residency."""
         if self._in_pass:
             self._residency_poisoned = True
         else:
             self._slab = None
-        self._resident_keys = None
+        self._set_resident(None)
         self._staged = None
-        self._drop_prev_route()
 
     # ------------------------------------------------- preload promote hooks
     def promote_prefetch_ctx(self):
@@ -761,36 +730,17 @@ class PassTable:
 
     def lookup_ids(self, keys: np.ndarray,
                    valid: Optional[np.ndarray] = None) -> np.ndarray:
-        """Translate feasign keys → dense pass-local ids (host-side analog of
-        DedupKeysAndFillIdx). Positions where ``valid`` is False (packer
-        padding) map to the trash row. Native hash-index fast path (~1 probe
-        per key); numpy searchsorted fallback."""
+        """Translate feasign keys → pass-local ids, the keys' slab rows
+        (host-side analog of DedupKeysAndFillIdx). Positions where ``valid``
+        is False (packer padding) map to the trash row. Native hash-index
+        fast path (~1 probe per key); numpy searchsorted fallback."""
         keys = np.asarray(keys, dtype=np.uint64)
-        if self._pass_keys is None:
+        if self._rows is None:
             raise RuntimeError("no active pass key set")
-        if self._route_index is not None:
-            from paddlebox_tpu.native.build import route_lookup
-            ids = route_lookup(self._route_index, keys, valid,
-                               self.padding_id)
-            # every staged train batch flows through here, so this is the
-            # ONE accumulation point for the touched-row bitmap (uids are
-            # a subset of these ids; h2d_lean stages no uids at all)
-            self.note_touched(ids)
-            return ids
-        ids = np.searchsorted(self._pass_keys, keys)
-        ids = np.minimum(ids, max(self._pass_keys.size - 1, 0))
-        if self._pass_keys.size:
-            hit = self._pass_keys[ids] == keys
-        else:
-            hit = np.zeros(keys.shape, bool)
-        if valid is not None:
-            ids = np.where(valid, ids, self.padding_id)
-            hit = hit | ~valid
-        if not hit.all():
-            missing = keys[~hit][:5]
-            raise KeyError(
-                f"keys not registered in feed pass (first few: {missing})")
-        ids = ids.astype(np.int32)
+        ids = self._rows.lookup(keys, valid, self.padding_id)
+        # every staged train batch flows through here, so this is the
+        # ONE accumulation point for the touched-row bitmap (uids are
+        # a subset of these ids; h2d_lean stages no uids at all)
         self.note_touched(ids)
         return ids
 

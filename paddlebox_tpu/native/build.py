@@ -134,7 +134,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
                                          P(c.c_uint8)]
     # batch key routing
     lib.rt_index_create.restype = c.c_void_p
-    lib.rt_index_create.argtypes = [P(c.c_uint64), P(c.c_int64), c.c_int32]
+    lib.rt_index_create.argtypes = [P(c.c_uint64), P(c.c_int64), c.c_int32,
+                                    P(c.c_int32)]
     lib.rt_index_destroy.restype = None
     lib.rt_index_destroy.argtypes = [c.c_void_p]
     lib.rt_bucketize.restype = c.c_int64
@@ -170,11 +171,14 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def create_route_index(shard_keys) -> Optional[int]:
+def create_route_index(shard_keys, ids=None) -> Optional[int]:
     """Build the native pass key→id hash index from per-shard SORTED key
     arrays (rt_index_create copies the keys into its own table). Returns the
     opaque handle, or None when the native lib is unavailable or the pass is
-    empty. The single-shard PassTable is just the P=1 case."""
+    empty. The single-shard PassTable is just the P=1 case. ids, when
+    given, holds one int32 array per shard aligned with its keys: the id
+    each key maps to (a slab row); without it a key maps to its position
+    in its shard's array."""
     import numpy as np
     lib = get_lib()
     shard_keys = [np.ascontiguousarray(k, dtype=np.uint64)
@@ -197,10 +201,19 @@ def create_route_index(shard_keys) -> Optional[int]:
             else np.ascontiguousarray(np.concatenate(shard_keys)))
     off = np.zeros(len(shard_keys) + 1, np.int64)
     np.cumsum([k.size for k in shard_keys], out=off[1:])
+    flat_ids = None
+    if ids is not None:
+        ids = [np.ascontiguousarray(i, dtype=np.int32) for i in ids]
+        if [i.size for i in ids] != [k.size for k in shard_keys]:
+            raise ValueError("create_route_index: ids not aligned with keys")
+        flat_ids = (ids[0] if len(ids) == 1
+                    else np.ascontiguousarray(np.concatenate(ids)))
     return lib.rt_index_create(
         flat.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
         off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        len(shard_keys))
+        len(shard_keys),
+        None if flat_ids is None
+        else flat_ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
 
 
 def destroy_route_index(handle) -> None:

@@ -54,7 +54,8 @@ inline uint64_t next_pow2(uint64_t v) {
 }
 
 struct RouteIndex {
-  // pass map: key -> local id (position in its shard's sorted key list).
+  // pass map: key -> local id (the id rt_index_create was given for it,
+  // else its position in its shard's sorted key list).
   // PROBE-ONLY after rt_index_create — safely shared across threads.
   uint64_t cap = 0, mask = 0;
   uint64_t* keys = nullptr;
@@ -232,9 +233,12 @@ inline int64_t bucketize_impl(RouteIndex* ix, const uint64_t* keys,
 extern "C" {
 
 // Build the pass index from the concatenated sorted shard key lists.
-// sk_flat: all shards' sorted pass keys, sk_off[P+1] offsets.
+// sk_flat: all shards' sorted pass keys, sk_off[P+1] offsets. ids, when
+// given, is aligned with sk_flat and holds the local id each key maps to
+// (a resident key's slab row); null maps a key to its position in its
+// shard's list.
 void* rt_index_create(const uint64_t* sk_flat, const int64_t* sk_off,
-                      int32_t P) {
+                      int32_t P, const int32_t* ids) {
   RouteIndex* ix = new RouteIndex();
   int64_t total = sk_off[P];
   ix->cap = next_pow2(static_cast<uint64_t>(total) * 2 + 8);
@@ -248,18 +252,20 @@ void* rt_index_create(const uint64_t* sk_flat, const int64_t* sk_off,
   memset(ix->keys, 0xFF, ix->cap * 8);
   for (int32_t s = 0; s < P; ++s) {
     const uint64_t* sk = sk_flat + sk_off[s];
+    const int32_t* sid = ids ? ids + sk_off[s] : nullptr;
     int64_t n = sk_off[s + 1] - sk_off[s];
     for (int64_t i = 0; i < n; ++i) {
       uint64_t k = sk[i];
+      int32_t id = sid ? sid[i] : static_cast<int32_t>(i);
       if (k == kEmpty) {  // sentinel-colliding key lives out-of-band
         ix->has_max_key = true;
-        ix->max_key_pos = static_cast<int32_t>(i);
+        ix->max_key_pos = id;
         continue;
       }
       uint64_t h = mix64(k) & ix->mask;
       while (ix->keys[h] != kEmpty) h = (h + 1) & ix->mask;
       ix->keys[h] = k;
-      ix->pos[h] = static_cast<int32_t>(i);
+      ix->pos[h] = id;
     }
   }
   return ix;

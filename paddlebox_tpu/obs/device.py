@@ -154,7 +154,7 @@ def analyze_compiled(compiled, examples: Optional[int] = None,
 #: delta_promote's (embedding/pass_table.py)
 SCOPE_NAMES = frozenset((
     "pull", "pool", "fwd_bwd", "dense_opt", "push_grads", "push_merge",
-    "push_opt", "push_write", "promote_permute", "promote_scatter"))
+    "push_opt", "push_write", "promote_scatter"))
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")

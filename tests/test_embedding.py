@@ -312,9 +312,9 @@ def test_native_lookup_matches_searchsorted():
     batch = rng.choice(keys, 128).astype(np.uint64)
     valid = rng.rand(128) > 0.25
     got = pt.lookup_ids(batch, valid)
-    ri, pt._route_index = pt._route_index, None
+    ri, pt._rows._index = pt._rows._index, None
     want = pt.lookup_ids(batch, valid)
-    pt._route_index = ri
+    pt._rows._index = ri
     np.testing.assert_array_equal(got, want)
     assert (got[~valid] == pt.padding_id).all()
     if ri is not None:

@@ -1,12 +1,17 @@
-"""Bit-parity: incremental pass lifecycle vs the full rebuild path.
+"""Parity per key: incremental pass lifecycle vs the full rebuild path.
 
-The incremental lifecycle (delta promote + touched-row writeback +
-cross-pass HBM residency, flags.incremental_pass) must be byte-identical
-to the full begin_pass/end_pass round trip: same slab contents after
-every begin_pass, same host-store contents (values INCLUDING optimizer
-state columns) after every end_pass, across consecutive overlapping
-passes, at 0% overlap, and through a test_mode (no-create, no-writeback)
-eval pass in the middle."""
+The incremental lifecycle (a resident key keeps its slab row, only the
+keys that arrive are promoted, touched-row writeback, cross-pass HBM
+residency, flags.incremental_pass) must hold, for every key, the bits the
+full begin_pass/end_pass round trip holds: the same slab row contents
+under ``lookup_ids`` after every begin_pass and push, the same host-store
+contents (values INCLUDING optimizer state columns) after every end_pass,
+and a journal that replays to the same rows — across consecutive
+overlapping passes, at 0% overlap, and through a test_mode (no-create,
+no-writeback) eval pass in the middle. WHICH row a key occupies is the
+same in both (the assignment is the table's and outlives the flag: an
+embedding a push creates draws its init from its slab row) and is held by
+the row-assignment tests."""
 
 import numpy as np
 import jax
@@ -15,16 +20,18 @@ import pytest
 
 from paddlebox_tpu.config import flags
 from paddlebox_tpu.config.configs import SparseOptimizerConfig, TableConfig
-from paddlebox_tpu.embedding.pass_table import PassTable
+from paddlebox_tpu.embedding.pass_table import PassTable, _delta_promote
+from paddlebox_tpu.obs import device as obs_device
 from paddlebox_tpu.parallel.sharded_table import ShardedPassTable
+from paddlebox_tpu.utils.stats import gauge_get, stat_get
 
 D = 4
 CAP = 1 << 10
 
 
-def table_cfg():
+def table_cfg(capacity=CAP):
     return TableConfig(
-        embedx_dim=D, pass_capacity=CAP,
+        embedx_dim=D, pass_capacity=capacity,
         optimizer=SparseOptimizerConfig(mf_create_thresholds=0.0,
                                         mf_initial_range=1e-3))
 
@@ -57,58 +64,103 @@ def sorted_store_items(store):
     return keys[order], vals[order]
 
 
+class ReplayedJournal:
+    """Stands in for train.journal.TouchedRowJournal: folds the row
+    records into the state a replay would reach (key -> last row)."""
+
+    def __init__(self):
+        self.state = {}
+        self.events = []
+
+    def append_rows(self, keys, rows):
+        for k, r in zip(keys.tolist(), np.array(rows)):
+            self.state[k] = r
+
+    def append_event(self, code):
+        self.events.append(code)
+
+    def append_move(self, op, keys):
+        self.events.append(("move", op, tuple(keys.tolist())))
+
+    def items(self):
+        keys = np.array(sorted(self.state), np.uint64)
+        return keys, np.stack([self.state[k] for k in keys.tolist()])
+
+
+def feed(t, keys):
+    t.begin_feed_pass()
+    t.add_keys(keys)
+    t.end_feed_pass()
+
+
+def push_some(t, ids):
+    """Real device pushes on `ids` (with a deterministic gradient)."""
+    pl = t.push_layout
+    g = np.zeros((ids.size, pl.width), np.float32)
+    g[:, pl.SHOW] = 1.0
+    g[:, pl.CLICK] = (np.arange(ids.size) % 2).astype(np.float32)
+    g[:, pl.EMBED_G] = 0.05
+    g[:, pl.embedx_g:] = 0.01
+    t.push(jnp.asarray(ids), jnp.asarray(g))
+
+
+def rows_of(t, keys):
+    """The slab's bits per key: what a pull of `keys` would be made of."""
+    return np.asarray(t.slab)[t._rows.probe(keys)]
+
+
 def run_single(passes, incremental, test_pass=None, seed=11):
     """Drive a PassTable through the passes with real device pushes;
-    returns per-pass (slab_after_pushes, store_keys, store_vals).
-    test_pass, when given, is a key set run in test_mode between the
-    train passes (after the first one)."""
+    returns per-pass (rows per key after the pushes, store_keys,
+    store_vals, replayed journal, each key's slab row). test_pass, when
+    given, is a key set run in test_mode between the train passes (after
+    the first one)."""
     flags.set_flag("incremental_pass", incremental)
     t = PassTable(table_cfg(), seed=seed)
-    pl = t.push_layout
+    journal = ReplayedJournal()
+    t.attach_journal(journal)
     out = []
     for pi, ks in enumerate(passes):
         if test_pass is not None and pi == 1:
             # eval pass in the middle: no create, no writeback
             t.set_test_mode(True)
-            t.begin_feed_pass()
-            t.add_keys(test_pass)
-            t.end_feed_pass()
+            feed(t, test_pass)
             t.begin_pass()
             eval_ids = t.lookup_ids(test_pass)
             eval_rows = np.asarray(t.pull(jnp.asarray(eval_ids)))
             t.end_pass()
             t.set_test_mode(False)
             ek, ev = sorted_store_items(t.store)
-            out.append(("eval", eval_rows, ek, ev))
-        t.begin_feed_pass()
-        t.add_keys(ks)
-        t.end_feed_pass()
+            out.append(("eval", eval_rows, ek, ev, journal.items(),
+                        eval_ids))
+        feed(t, ks)
         t.begin_pass()
         # push gradients on a deterministic subset (with repeats, so the
         # dedup + merge path runs), leave the rest untouched
         sub = np.concatenate([ks[: max(1, ks.size // 2)], ks[:7]])
-        ids = t.lookup_ids(sub)
-        g = np.zeros((ids.size, pl.width), np.float32)
-        g[:, pl.SHOW] = 1.0
-        g[:, pl.CLICK] = (np.arange(ids.size) % 2).astype(np.float32)
-        g[:, pl.EMBED_G] = 0.05
-        g[:, pl.embedx_g:] = 0.01
-        t.push(jnp.asarray(ids), jnp.asarray(g))
-        slab = np.asarray(t.slab)
+        push_some(t, t.lookup_ids(sub))
+        by_key = rows_of(t, ks)
         t.end_pass()
         k, v = sorted_store_items(t.store)
-        out.append(("train", slab, k, v))
+        out.append(("train", by_key, k, v, journal.items(),
+                    t._rows.rows.copy()))
     return out
 
 
 def assert_runs_equal(full, inc):
     assert len(full) == len(inc)
-    for (tag_f, slab_f, k_f, v_f), (tag_i, slab_i, k_i, v_i) in zip(full,
-                                                                    inc):
+    for (tag_f, rows_f, k_f, v_f, j_f, at_f), (tag_i, rows_i, k_i, v_i, j_i,
+                                               at_i) in zip(full, inc):
         assert tag_f == tag_i
-        np.testing.assert_array_equal(slab_f, slab_i)
+        # a key has one slab row whichever way the flag stands: an
+        # embedding a push creates draws its init from (prng, slab row)
+        # (optimizers._fresh_uniform), so the bits below depend on it
+        np.testing.assert_array_equal(at_f, at_i)
+        np.testing.assert_array_equal(rows_f, rows_i)
         np.testing.assert_array_equal(k_f, k_i)
         np.testing.assert_array_equal(v_f, v_i)
+        np.testing.assert_array_equal(j_f[0], j_i[0])
+        np.testing.assert_array_equal(j_f[1], j_i[1])
 
 
 def test_pass_table_parity_overlapping(incremental_flag):
@@ -121,7 +173,7 @@ def test_pass_table_parity_overlapping(incremental_flag):
 def test_pass_table_parity_zero_overlap(incremental_flag):
     rng = np.random.RandomState(1)
     # disjoint ranges: 0% overlap — the incremental worst case must still
-    # be bit-exact (every row evicted + promoted each pass)
+    # be exact per key (every row freed + promoted each pass)
     passes = [np.unique((rng.randint(0, 1 << 20, 300)
                          + (p << 32)).astype(np.uint64))
               for p in range(3)]
@@ -144,15 +196,43 @@ def test_pass_table_parity_through_test_mode(incremental_flag):
     assert_runs_equal(full, inc)
     # the eval pass must not have created the unseen keys in either run
     for run in (full, inc):
-        tag, _, keys, _ = run[1]
+        tag, _, keys = run[1][:3]
         assert tag == "eval"
         assert not np.isin(unseen, keys).any()
+
+
+def test_rows_outlive_a_flag_flip(incremental_flag):
+    """The assignment is the table's, not the resident slab's: flag off
+    builds the slab whole with every key at the row it had, and a flip
+    back to on finds the rows where the off passes left them."""
+    passes = make_passes(np.random.RandomState(12), n_passes=5, overlap=0.8)
+    want = run_single(passes, incremental=True)
+    flags.set_flag("incremental_pass", False)
+    t = PassTable(table_cfg(), seed=11)
+    hit = stat_get("pass_rows_promote_hit")
+    for pi, ks in enumerate(passes):
+        flags.set_flag("incremental_pass", pi in (0, 3, 4))
+        feed(t, ks)
+        t.begin_pass()
+        sub = np.concatenate([ks[: max(1, ks.size // 2)], ks[:7]])
+        push_some(t, t.lookup_ids(sub))
+        np.testing.assert_array_equal(t._rows.rows, want[pi][5])
+        np.testing.assert_array_equal(rows_of(t, ks), want[pi][1])
+        t.end_pass()
+        assert (t.slab is None) == (pi in (1, 2))
+    assert not t._rows.dense                # the set drifted: rows != rank
+    # passes 1 and 4 found the slab the on-pass before them left; 0, 2
+    # and 3 built theirs whole
+    stayed = sum(np.isin(passes[p], passes[p - 1]).sum() for p in (1, 4))
+    assert stat_get("pass_rows_promote_hit") - hit == stayed
+    k, v = sorted_store_items(t.store)
+    np.testing.assert_array_equal(k, want[-1][2])
+    np.testing.assert_array_equal(v, want[-1][3])
 
 
 def test_pass_table_delta_path_actually_ran(incremental_flag):
     """Guard against the delta promote silently falling back to full
     builds: at high overlap the resident-hit stat must move."""
-    from paddlebox_tpu.utils.stats import stat_get
     passes = make_passes(np.random.RandomState(3), n_passes=3, overlap=0.9)
     before = stat_get("pass_rows_promote_hit")
     run_single(passes, incremental=True)
@@ -161,8 +241,8 @@ def test_pass_table_delta_path_actually_ran(incremental_flag):
 
 def test_pass_table_invalidation_forces_full_build(incremental_flag):
     """A store mutation outside the pass cadence (end_day aging) must
-    drop residency — and the next pass must still be bit-exact vs a
-    full-path table subjected to the same cadence."""
+    drop residency — and the next pass must still hold, per key, the bits
+    of a full-path table subjected to the same cadence."""
     passes = make_passes(np.random.RandomState(4), n_passes=2, overlap=0.9)
 
     def run(incremental):
@@ -170,9 +250,7 @@ def test_pass_table_invalidation_forces_full_build(incremental_flag):
         t = PassTable(table_cfg(), seed=5)
         outs = []
         for ks in passes:
-            t.begin_feed_pass()
-            t.add_keys(ks)
-            t.end_feed_pass()
+            feed(t, ks)
             t.begin_pass()
             ids = t.lookup_ids(ks[: ks.size // 2])
             pl = t.push_layout
@@ -180,7 +258,9 @@ def test_pass_table_invalidation_forces_full_build(incremental_flag):
             g[:, pl.SHOW] = 1.0
             g[:, pl.EMBED_G] = 0.1
             t.push(jnp.asarray(ids), jnp.asarray(g))
-            outs.append(np.asarray(t.slab))
+            outs.append(rows_of(t, ks))
+            # every pass after an end_day is a full build: rows by rank
+            np.testing.assert_array_equal(t._rows.rows, np.arange(ks.size))
             t.end_pass()
             t.end_day()  # ages + shrinks between every pass
         return outs, sorted_store_items(t.store)
@@ -191,6 +271,241 @@ def test_pass_table_invalidation_forces_full_build(incremental_flag):
         np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(store_f[0], store_i[0])
     np.testing.assert_array_equal(store_f[1], store_i[1])
+
+
+# -------------------------------------------------------- row assignment
+def begin_counts(t):
+    """One begin_pass; the counters' and the gauge's reading of it."""
+    names = ("pass_rows_promote_hit", "pass_rows_promote_new",
+             "pass_rows_freed")
+    before = [stat_get(c) for c in names]
+    t.begin_pass()
+    got = dict(zip(("hit", "new", "freed"),
+                   (stat_get(c) - b for c, b in zip(names, before))))
+    got["free"] = gauge_get("pass_free_rows")
+    return got
+
+
+def assert_assignment_sound(t, keys):
+    """Distinct rows below the padding row, and no row lost or made."""
+    rows = t._rows.probe(keys)
+    assert rows.min() >= 0 and rows.max() < t.padding_id
+    assert np.unique(rows).size == keys.size
+    assert gauge_get("pass_free_rows") + keys.size == t.capacity - 1
+    free = np.concatenate([t._rows.holes,
+                           np.arange(t._rows.top, t.padding_id)])
+    assert free.size == t._rows.free_rows == gauge_get("pass_free_rows")
+    assert not np.isin(free, rows).any()
+
+
+def test_same_key_set_twice_moves_nothing(incremental_flag):
+    flags.set_flag("incremental_pass", True)
+    keys = make_passes(np.random.RandomState(20), n_passes=1)[0]
+    t = PassTable(table_cfg(), seed=3)
+    feed(t, keys)
+    t.begin_pass()
+    first = t.lookup_ids(keys)
+    push_some(t, first[:100])
+    t.end_pass()
+    feed(t, keys)
+    got = begin_counts(t)
+    assert got == {"hit": keys.size, "new": 0, "freed": 0,
+                   "free": CAP - 1 - keys.size}
+    np.testing.assert_array_equal(t.lookup_ids(keys), first)
+    t.end_pass()
+    # a second pass over ONE feed finds its own rows resident, too
+    got = begin_counts(t)
+    assert (got["hit"], got["new"], got["freed"]) == (keys.size, 0, 0)
+    np.testing.assert_array_equal(t.lookup_ids(keys), first)
+    t.end_pass()
+
+
+def test_overlap_keeps_rows_and_fills_free_rows(incremental_flag):
+    flags.set_flag("incremental_pass", True)
+    a, b = make_passes(np.random.RandomState(21), n_passes=2, overlap=0.9)
+    t = PassTable(table_cfg(), seed=3)
+    feed(t, a)
+    t.begin_pass()
+    rows_a = t.lookup_ids(a)
+    t.end_pass()
+    feed(t, b)
+    got = begin_counts(t)
+    stayed = np.isin(b, a)
+    left = ~np.isin(a, b)
+    assert got["hit"] == stayed.sum() and got["new"] == (~stayed).sum()
+    assert got["freed"] == left.sum() > 0
+    rows_b = t.lookup_ids(b)
+    # every surviving key keeps its row
+    np.testing.assert_array_equal(rows_b[stayed], rows_a[np.isin(a, b)])
+    # every new key has a row of its own: freed ones first, lowest first,
+    # then never-used ones; none is the padding row
+    want = np.sort(rows_a[left])[: got["new"]]
+    want = np.concatenate([want, np.arange(
+        a.size, a.size + got["new"] - want.size)])
+    np.testing.assert_array_equal(rows_b[~stayed], want)
+    assert_assignment_sound(t, b)
+    t.end_pass()
+
+
+def test_churn_at_full_capacity_leaks_no_row(incremental_flag):
+    """20 passes at a working set of capacity - 1, 0-100% overlap drawn
+    per pass: rows always distinct, free + assigned = capacity - 1, and
+    every key reads its own bits."""
+    flags.set_flag("incremental_pass", True)
+    cap = 1 << 8
+    rng = np.random.RandomState(22)
+    t = PassTable(table_cfg(cap), seed=3)
+    pool = np.unique(rng.randint(1, 1 << 40, 4 * cap).astype(np.uint64))
+    cur = np.sort(rng.choice(pool, cap - 1, replace=False))
+    overlaps = [1.0, 0.0] + list(rng.rand(18))
+    for i, overlap in enumerate(overlaps):
+        keep = cur[rng.rand(cur.size) < overlap]
+        fresh = np.setdiff1d(pool, cur)
+        cur = np.sort(np.concatenate(
+            [keep, rng.choice(fresh, cap - 1 - keep.size, replace=False)]))
+        feed(t, cur)
+        got = begin_counts(t)
+        assert got["free"] == 0
+        # the first pass builds the slab whole; every later one promotes
+        assert got["hit"] + got["new"] == (cap - 1 if i else 0)
+        assert got["new"] == got["freed"] == cap - 1 - (keep.size if i else
+                                                        cap - 1)
+        assert_assignment_sound(t, cur)
+        ids = t.lookup_ids(cur)
+        push_some(t, ids[::3])
+        slab = np.asarray(t.slab)
+        t.end_pass()
+        with t.store_lock:
+            np.testing.assert_array_equal(slab[ids], t.store.lookup(cur))
+
+
+def test_a_returning_key_reads_its_own_bits(incremental_flag):
+    """A key leaves, a new key takes its row, the old key returns later:
+    each reads its own bits from the store, not the other's."""
+    flags.set_flag("incremental_pass", True)
+    base = np.arange(10, 60, dtype=np.uint64)
+    old, new = np.uint64(5), np.uint64(7)      # both sort first
+    t = PassTable(table_cfg(), seed=3)
+
+    def one_pass(keys, pushed):
+        feed(t, np.sort(keys))
+        t.begin_pass()
+        row = {int(k): int(r) for k, r in zip(keys, t.lookup_ids(keys))}
+        push_some(t, t.lookup_ids(np.array([pushed] * 3, np.uint64)))
+        bits = {int(k): np.asarray(t.slab)[row[int(k)]].copy()
+                for k in keys}
+        t.end_pass()
+        return row, bits
+
+    row1, bits1 = one_pass(np.append(base, old), old)
+    row2, bits2 = one_pass(np.append(base, new), new)
+    assert row2[int(new)] == row1[int(old)]     # the freed row, reused
+    assert not np.array_equal(bits2[int(new)], bits1[int(old)])
+    row3, bits3 = one_pass(np.concatenate([base, [old, new]]), base[0])
+    assert row3[int(new)] == row2[int(new)] != row3[int(old)]
+    np.testing.assert_array_equal(bits3[int(old)], bits1[int(old)])
+    np.testing.assert_array_equal(bits3[int(new)], bits2[int(new)])
+    with t.store_lock:
+        got = t.store.lookup(np.array([old, new], np.uint64))
+    np.testing.assert_array_equal(got[0], bits1[int(old)])
+    np.testing.assert_array_equal(got[1], bits2[int(new)])
+
+
+@pytest.mark.parametrize("how", ["test_mode", "invalidate",
+                                 "invalidate_after_feed"])
+def test_fallback_to_the_full_build_resets_the_assignment(incremental_flag,
+                                                          how):
+    flags.set_flag("incremental_pass", True)
+    a, b, c = make_passes(np.random.RandomState(23), n_passes=3, overlap=0.7)
+    t = PassTable(table_cfg(), seed=3)
+    for ks in (a, b):
+        feed(t, ks)
+        t.begin_pass()
+        t.lookup_ids(ks)
+        t.end_pass()
+    assert not np.array_equal(t._rows.rows, np.arange(b.size))  # churned
+    if how == "test_mode":
+        t.set_test_mode(True)
+        feed(t, a[:50])
+        t.begin_pass()       # consumes the resident slab
+        t.end_pass()
+        t.set_test_mode(False)
+    elif how == "invalidate":
+        t.invalidate_residency()
+    feed(t, c)
+    if how == "invalidate_after_feed":
+        # the feed pass planned c's rows on the resident map; begin_pass
+        # must not keep that plan for a slab it builds whole
+        assert not np.array_equal(t._rows.rows, np.arange(c.size))
+        t.invalidate_residency()
+    got = begin_counts(t)
+    assert (got["hit"], got["new"], got["freed"]) == (0, 0, 0)  # full build
+    np.testing.assert_array_equal(t.lookup_ids(c), np.arange(c.size))
+    assert t._rows.holes.size == 0 and t._rows.top == c.size
+    assert_assignment_sound(t, c)
+    with t.store_lock:
+        np.testing.assert_array_equal(rows_of(t, c), t.store.lookup(c))
+    t.end_pass()
+
+
+def test_searchsorted_fallback_returns_the_native_rows(incremental_flag):
+    """Without the native library the owner's searchsorted tier must
+    assign, probe and look up the same rows as the hash index."""
+    import unittest.mock as mock
+    flags.set_flag("incremental_pass", True)
+    passes = make_passes(np.random.RandomState(24), n_passes=3, overlap=0.8)
+
+    def run():
+        t = PassTable(table_cfg(), seed=3)
+        out = []
+        for ks in passes:
+            feed(t, ks)
+            t.begin_pass()
+            valid = np.arange(ks.size) % 5 != 0
+            out.append((t.lookup_ids(ks), t.lookup_ids(ks, valid),
+                        t._rows._index is not None))
+            with pytest.raises(KeyError):
+                t.lookup_ids(np.array([ks.max() + 1], np.uint64))
+            t.end_pass()
+        return out
+
+    native = run()
+    with mock.patch("paddlebox_tpu.native.build.get_lib", return_value=None):
+        plain = run()
+    assert not any(p[2] for p in plain)
+    for (ids_n, masked_n, _), (ids_p, masked_p, _) in zip(native, plain):
+        np.testing.assert_array_equal(ids_n, ids_p)
+        np.testing.assert_array_equal(masked_n, masked_p)
+        assert ids_p.dtype == np.int32
+
+
+def test_delta_promote_scatters_in_place_on_the_donated_slab():
+    """The program of begin_pass: its output aliases the donated slab and
+    no slab-sized temporary (the old whole-slab gather) survives; it is
+    still the entry `delta_promote`, scope `promote_scatter`."""
+    cap, width, bucket = 1 << 14, 24, 64
+    slab_bytes = cap * width * 4
+    compiled = _delta_promote.lower(
+        jax.ShapeDtypeStruct((cap, width), jnp.float32),
+        jax.ShapeDtypeStruct((bucket,), jnp.int32),
+        jax.ShapeDtypeStruct((bucket, width), jnp.float32)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == ma.output_size_in_bytes == slab_bytes
+    assert ma.temp_size_in_bytes < slab_bytes // 100
+    text = compiled.as_text()
+    assert "gather(" not in text
+    slab = jnp.arange(cap * width, dtype=jnp.float32).reshape(cap, width)
+    want = np.asarray(slab).copy()
+    want[[3, 9]] = 1.0
+    idx = np.full(bucket, cap, np.int32)        # the drop sentinel
+    idx[:2] = [3, 9]
+    out = _delta_promote(slab, jnp.asarray(idx),
+                         jnp.ones((bucket, width), jnp.float32))
+    np.testing.assert_array_equal(np.asarray(out), want)
+    entry = obs_device.snapshot()["entries"]["delta_promote"]
+    assert entry["module"] == "jit__delta_promote_impl"
+    assert entry["donate_argnums"] == [0]
+    assert entry["analysis"]["temp_includes_slab_copy"] is False
 
 
 # --------------------------------------------------------------- sharded

@@ -33,15 +33,17 @@ D, NUM_SLOTS = 4, 4
 
 # ISSUE 25, part A: parent -> its child spans, in order
 CHILDREN = {
-    "ingest_feed_pass": ["feed_unique", "feed_route_index"],
+    "ingest_feed_pass": ["feed_unique", "promote_diff", "feed_route_index"],
     "pass_end": ["writeback_select", "writeback_d2h", "writeback_decode",
                  "writeback_store", "pass_mem_check"],
     "train_pass": ["pass_begin", "pass_split_batches", "pass_end",
                    "pass_report"],
 }
 FULL_BUILD = ["build_store_read", "build_encode", "build_h2d"]
-INCREMENTAL = ["promote_diff", "promote_store_read", "promote_stage",
-               "promote_dispatch"]
+# ISSUE 26: the resident diff assigns the rows the index is built from, so
+# promote_diff runs in the feed pass, and only over a resident slab
+FEED_INCREMENTAL = ["promote_diff"]
+INCREMENTAL = ["promote_store_read", "promote_stage", "promote_dispatch"]
 ROOTS = ["ingest_wait_preload", "ingest_feed_pass", "train_pass",
          "pass_release"]
 STEP_SCOPES = {"pull", "pool", "fwd_bwd", "dense_opt", "push_grads",
@@ -121,13 +123,15 @@ def by_pass(run, k):
 def test_both_passes_record_every_phase_span(run):
     first = {s[0] for s in by_pass(run, 0)}
     second = {s[0] for s in by_pass(run, 1)}
-    shared = set(ROOTS) | {c for cs in CHILDREN.values() for c in cs}
+    shared = (set(ROOTS) | {c for cs in CHILDREN.values() for c in cs}
+              ) - set(FEED_INCREMENTAL)
     assert shared <= first and shared <= second, (
         shared - first, shared - second)
     # pass 0 builds the slab whole; pass 1 finds it resident and promotes
     # the delta, after the prefetcher read pass 1's rows under pass 0
-    assert set(FULL_BUILD) <= first and not set(INCREMENTAL) & first
-    assert set(INCREMENTAL) <= second and not set(FULL_BUILD) & second
+    promote = set(INCREMENTAL) | set(FEED_INCREMENTAL)
+    assert set(FULL_BUILD) <= first and not promote & first
+    assert promote <= second and not set(FULL_BUILD) & second
     assert "promote_prefetch_finish" in second
     assert {"ingest_parse", "host_stage", "scan_dispatch",
             "chunk_drain"} <= first & second
@@ -277,11 +281,10 @@ def test_the_scope_map_names_delta_promote_s_phases():
     cap, width = 64, 8
     with fresh_compiles():
         text = _delta_promote.lower(
-            jnp.zeros((cap, width)), jnp.zeros(cap, jnp.int32),
-            jnp.zeros(cap, bool), jnp.zeros(4, jnp.int32),
+            jnp.zeros((cap, width)), jnp.zeros(4, jnp.int32),
             jnp.zeros((4, width))).compile().as_text()
-    assert {"promote_permute", "promote_scatter"} <= set(
-        obs_device.scope_map(text).values())
+    scopes = set(obs_device.scope_map(text).values())
+    assert "promote_scatter" in scopes and "promote_permute" not in scopes
 
 
 def test_scope_map_reads_the_innermost_scope_through_autodiff_wrappers():

@@ -203,6 +203,65 @@ def test_crash_resume_parity_with_shuffle_enabled(data, tmp_path):
     np.testing.assert_allclose(v_got, v_ref, rtol=1e-6, atol=1e-7)
 
 
+def test_crash_resume_matches_uninterrupted_on_a_drifting_key_set(tmp_path):
+    """Each pass reads another file, so the working set drifts: a key
+    that stays keeps its slab row, and a row is then the key's history,
+    not its rank. A push creates embeddings at random (mf_initial_range
+    1e-3), drawn from (prng, slab row). Every checkpoint save resets the
+    assignment (invalidate_residency), so the run resumed from one, whose
+    fresh table assigns rows by rank, draws what the uninterrupted run
+    draws: bit for bit the same weights, embeddings and optimizer state.
+    (delta_score and unseen_days are left out: a key absent from the
+    resumed passes reads one save's stat rewrite more or less after a
+    resume, before this assignment existed too.)"""
+    from paddlebox_tpu.embedding import accessor as acc
+    files, feed = write_synthetic_ctr_files(
+        str(tmp_path / "drift"), num_files=4, lines_per_file=200,
+        num_slots=NUM_SLOTS, vocab_per_slot=400, max_len=3, seed=23)
+    feed = type(feed)(slots=feed.slots, batch_size=32)
+
+    def one_file_a_pass(n=4):
+        out = []
+        for f in files[:n]:
+            ds = BoxDataset(feed, read_threads=1)
+            ds.set_filelist([f])
+            out.append(ds)
+        return out
+
+    # the drift is real: with no save between the passes rows leave rank
+    plain = make_trainer(feed)
+    sets = []
+    for ds in one_file_a_pass(2):
+        plain.train_pass(ds)
+        sets.append(plain.table._pass_keys)
+    assert 0 < np.isin(sets[1], sets[0]).sum() < sets[1].size
+    assert not plain.table._rows.dense
+
+    oracle = make_trainer(feed)
+    r0 = RecoverableRunner(oracle, CheckpointManager(
+        ckpt_cfg(tmp_path, "dr_oracle"), oracle.table), day="d1")
+    r0.run(one_file_a_pass())
+    assert oracle.table._rows.dense      # every save reset the assignment
+
+    cfg = ckpt_cfg(tmp_path, "dr_crash")
+    t1 = make_trainer(feed)
+    r1 = RecoverableRunner(t1, CheckpointManager(cfg, t1.table), day="d1")
+    r1.run(one_file_a_pass(2))  # "crash" after 2 completed passes
+
+    t2 = make_trainer(feed, seed=0)
+    r2 = RecoverableRunner(t2, CheckpointManager(cfg, t2.table), day="d1")
+    assert r2.completed_passes() == 2
+    r2.run(one_file_a_pass())
+
+    k_ref, v_ref = _store_state(oracle)
+    k_got, v_got = _store_state(t2)
+    np.testing.assert_array_equal(k_got, k_ref)
+    cols = np.setdiff1d(np.arange(v_ref.shape[1]),
+                        [acc.DELTA_SCORE, acc.UNSEEN_DAYS])
+    assert (v_ref[:, acc.MF_SIZE] > 0).any()     # embeddings were created
+    np.testing.assert_array_equal(v_got[:, cols], v_ref[:, cols])
+
+
 def test_sharded_crash_resume_matches_uninterrupted(data, tmp_path):
     """The same pass-boundary recovery loop over the SHARDED trainer:
     per-pass base checkpoints ride the store_view facade, a restarted
