@@ -2,19 +2,26 @@
 TPU v5e at the real shapes and print memory_analysis(): what the chip's
 compiler refuses, it refuses here at no chip time. Nothing runs, so this
 says nothing about results or times and is never reported as a chip run.
+That compiler refuses temporaries that do not fit; it does not add the
+arguments and outputs to them (18 GB of outputs compiled here, PR 29), so
+hold `peak_estimate_bytes` against `limit_bytes` yourself: a program over
+it compiles here and is refused its memory when it runs.
 
     JAX_PLATFORMS=cpu python benchmarks/compile_v5e.py [--config NAME]
+    JAX_PLATFORMS=cpu python benchmarks/compile_v5e.py --cell seq-probe.seq-pass
 
 The batch's leaves and dtypes come from the program's own host staging of
-one chunk (run on the CPU against a small table); the slab has the
-configuration's pass_capacity.
+one chunk (run on the CPU against a table no larger than the
+configuration's own); the slab has the configuration's pass_capacity.
 
     JAX_PLATFORMS=cpu python benchmarks/compile_v5e.py --reference \
-        --params 5.2e8 --rows 131072 --embedx 2048 --examples 8 --slots 2048
+        --params 6.03e8 --rows 25024 --embedx 2048 --examples 4 --slots 4096
 
 compiles the REFERENCE's step (harness/reference._step) the same way, at
 shapes no configuration has yet: what a next configuration's check pass
-may be sized to (README, "What the reference needs"). The dense
+may be sized to (README, "What the reference needs"; --rows is the
+smaller of the occurrences of a scan chunk and the vocabulary, as
+reference.follow pads its table). The dense
 parameters are a stand-in stack of matmuls over `pooled`, each layer
 under jax.checkpoint, as a configuration's forward may block itself.
 """
@@ -34,6 +41,8 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
+LIMIT_BYTES = int(15.75 * 2 ** 30)      # what one program may hold on a v5e
+
 
 def compile_config(cell_name: str, capacity: int = 0) -> dict:
     import jax
@@ -51,7 +60,11 @@ def compile_config(cell_name: str, capacity: int = 0) -> dict:
     cfg, cfg_mod = dict(spec["cfg"]), spec["cfg_mod"]
     if capacity:
         cfg["pass_capacity"] = capacity
-    small = dict(cfg, occupied_rows=400_000, pass_capacity=1 << 19)
+    # the CPU stand-in's table: no larger than the configuration's own (a
+    # wide row makes a 1 << 19 slab gigabytes on the host)
+    small = dict(cfg,
+                 occupied_rows=min(400_000, int(cfg["occupied_rows"])),
+                 pass_capacity=min(1 << 19, int(cfg["pass_capacity"])))
     mix = dict(spec["mix"], pool_files=8, files_per_pass=8, stride=1)
     tf = traffic.Traffic(small, mix, 1,
                          bench.trainer_config(small).scan_chunk)
@@ -106,6 +119,7 @@ def compile_config(cell_name: str, capacity: int = 0) -> dict:
                                     layout.device_dtype, sharding=one))
     out["scan_steps"] = memory_of(fns.scan_steps, args)
     out["delta_promote"] = memory_of(_delta_promote, promote)
+    out["limit_bytes"] = LIMIT_BYTES
     return out
 
 
@@ -177,19 +191,23 @@ def compile_reference(params: float, rows: int, embedx: int, examples: int,
             "memory": memory_of(
                 reference._step, (forward, reference.mm_f32, rows,
                                   reference.sparse_settings(cfg)) + args),
-            "limit_bytes": int(15.75 * 2 ** 30)}
+            "limit_bytes": LIMIT_BYTES}
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default=None,
                     help="a configuration of BENCHMARK.json; default all")
+    ap.add_argument("--cell", default=None,
+                    help="one cell, or a <config>.<mix> that runs by name")
     ap.add_argument("--capacity", type=int, default=0,
                     help="try another pass_capacity than the file's")
     ap.add_argument("--reference", action="store_true",
                     help="compile the reference's step at the shapes below")
     ap.add_argument("--params", type=float, default=5.2e8)
-    ap.add_argument("--rows", type=int, default=131072)
+    ap.add_argument("--rows", type=int, default=131072,
+                    help="the reference's table: min(scan_chunk x examples "
+                         "x slots, occupied_rows), as reference.follow pads")
     ap.add_argument("--embedx", type=int, default=2048)
     ap.add_argument("--examples", type=int, default=8)
     ap.add_argument("--slots", type=int, default=2048)
@@ -198,6 +216,10 @@ def main() -> int:
         print(json.dumps(compile_reference(
             args.params, args.rows, args.embedx, args.examples,
             args.slots)), flush=True)
+        return 0
+    if args.cell:
+        print(json.dumps(compile_config(args.cell, args.capacity)),
+              flush=True)
         return 0
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     seen = set()

@@ -210,9 +210,12 @@ def follow(cfg: dict, cfg_mod, params0: Dict, rows: np.ndarray,
     B = rows.shape[0] // steps
     uniq, inv = np.unique(rows, return_inverse=True)
     idx = inv.reshape(rows.shape).astype(np.int32)
-    # tables padded to the most rows the steps could touch: the shapes,
-    # and so the compiled step, are the same for every seed
-    R, D, n = int(rows.size), int(cfg["embedx_dim"]), uniq.size
+    # tables padded to the most rows the steps could touch, which is never
+    # more than the vocabulary (one table shared by thousands of slots):
+    # both are the configuration's, so the shapes, and the compiled step,
+    # are the same for every seed
+    R = min(int(rows.size), int(cfg["occupied_rows"]))
+    D, n = int(cfg["embedx_dim"]), uniq.size
 
     def col(name, shape, dtype=F32):
         out = np.zeros((R,) + shape, dtype)
@@ -352,6 +355,14 @@ def _axis_gap(prog: np.ndarray, ref: np.ndarray, cos: float):
     return 1.0 if np.any(prog) else None
 
 
+def _relative_gap(p: float, r: float) -> float:
+    """|p - r| / |r|; a reference of exactly nought (a saturated loss) has
+    no relative gap: nought where the program agrees, else infinite."""
+    if r:
+        return abs(p - r) / abs(r)
+    return 0.0 if p == r else float("inf")
+
+
 def leaf_gaps(prog: dict, ref: dict) -> dict:
     """Every gap that compare() takes the widest of, for a look by hand:
     per step the loss's, per moving leaf the gradient's and the change's
@@ -359,7 +370,7 @@ def leaf_gaps(prog: dict, ref: dict) -> dict:
     pg, pc = leaf_norms(prog)
     rg, rc = leaf_norms(ref)
     moving = moving_leaves(rg)
-    return {"loss": [abs(p - r) / abs(r)
+    return {"loss": [_relative_gap(p, r)
                      for p, r in zip(prog["loss"], ref["loss"])],
             "grad": leaf_gap_table(pg, rg, moving),
             "change": leaf_gap_table(pc, rc, moving)}

@@ -4,7 +4,11 @@ the table the run starts from, and the arrays the comparison needs.
 Vectorised numpy; no per-token Python.
 
 Every seed gives the same sizes (tables, files, lines); the seed changes
-only which ids, labels, dense values and starting rows are drawn. A file's
+only which ids, labels, dense values and starting rows are drawn. A
+configuration's slots draw from disjoint tables, one a slot, unless it
+says ``"slot_tables": "shared"``: then one table of ``occupied_rows`` ids
+lies under every slot (the positions of a sequence over one vocabulary),
+and an example may hold a key more than once. A file's
 lines depend on (seed, file index) alone, so files are drawn and written
 by a few threads.
 
@@ -45,7 +49,10 @@ def table_sizes(num_slots: int, occupied: int, smallest: int) -> np.ndarray:
     lo, hi = 1.0, 1e6
 
     def total(r):
-        return sum(smallest * r ** i for i in range(num_slots))
+        try:
+            return sum(smallest * r ** i for i in range(num_slots))
+        except OverflowError:   # r ** i past a float: too large a ratio
+            return float("inf")
     for _ in range(200):
         mid = (lo * hi) ** 0.5
         if total(mid) < occupied:
@@ -101,8 +108,14 @@ class Traffic:
         self.embedx_dim = int(cfg["embedx_dim"])
         self.batch = int(cfg["batch_size"])
         self.occupied = int(cfg["occupied_rows"])
-        self.sizes = table_sizes(self.num_slots, self.occupied,
-                                 int(mix["smallest_table"]))
+        tables = cfg.get("slot_tables", "per_slot")
+        if tables not in ("per_slot", "shared"):
+            raise ValueError("slot_tables is %r, not per_slot or shared"
+                             % (tables,))
+        # one entry under "shared": the draw broadcasts it over the slots
+        self.sizes = table_sizes(
+            1 if tables == "shared" else self.num_slots, self.occupied,
+            int(mix["smallest_table"]))
         self.offsets = np.concatenate([[0], np.cumsum(self.sizes)[:-1]])
         self.mults = np.array([_spread(int(n)) for n in self.sizes], np.int64)
         self.lines = self.batch * int(mix["batches_per_file"])

@@ -7,9 +7,10 @@ no branch on a cell's or a configuration's name (benchmarks/README.md).
 
 Without a TPU (or with fewer chips than the cell asks for) it exits 3 and
 prints no result. ``--rehearse`` walks set-up -> window -> last line at
-tiny shapes on whatever backend is there and prints no device metric and
-no rate. ``--control 1`` also puts the lower-precision control and each
-planted fault in the program's place and passes it through the same
+tiny shapes (REHEARSE, then the configuration's own `rehearse` keys) on
+whatever backend is there and prints no device metric and no rate.
+``--control 1`` also puts the lower-precision control and each planted
+fault in the program's place and passes it through the same
 comparison (its verdict goes under `controls`); ``--control 2 --seeds
 a,b,c`` does only that, with no program and no window, one line a seed
 (for setting limits; the driver's runs never ask for either).
@@ -47,6 +48,13 @@ HOST_SPANS = ("pass_begin", "pass_end", "ingest_feed_pass", "host_stage",
 # the counters the exact counts need; the metric files name the others
 EXACT_COUNTERS = ("ingest_keys_parsed",)
 SAMPLE_ROWS = 200_000
+
+
+def rehearsal(cfg: dict) -> dict:
+    """The configuration at the sizes --rehearse walks it at: REHEARSE,
+    then the configuration's own `rehearse` object, any keys (a tower
+    shrinks its slots, widths and layers there)."""
+    return {**cfg, **REHEARSE, **cfg.get("rehearse", {})}
 
 
 def log(*a) -> None:
@@ -243,7 +251,7 @@ class Run:
         self.cell, self.mix = self.spec["cell"], self.spec["mix"]
         self.cfg, self.cfg_mod = dict(self.spec["cfg"]), self.spec["cfg_mod"]
         if args.rehearse:
-            self.cfg.update(REHEARSE)
+            self.cfg = rehearsal(self.cfg)
         else:
             require_chips(int(self.cell["chips"]))
         self.seed = int(args.seed if seed is None else seed)
@@ -568,6 +576,7 @@ class Run:
         shift = window[0] - self.t0
         trace["host"] += [(n, a + shift, b + shift)
                           for n, a, b in self.spans]
+        log_spans(self.spans, self.passes)
         uniq = np.mean([np.unique(ex.rows[i * tf.batch:(i + 1) * tf.batch]
                                   ).size for i in range(self.check_steps)])
         ctx = {"spans": self.spans, "passes": self.passes,
@@ -680,6 +689,17 @@ def log_trace(trace: dict, window, tr) -> None:
             mods[n] = mods.get(n, 0.0) + (e0 - s0)
     log("trace: module seconds %s" % sorted(
         mods.items(), key=lambda kv: -kv[1])[:12])
+
+
+def log_spans(spans, passes: int) -> None:
+    """Every span of the slice, ms a pass, for a look by hand (standard
+    error): a span that no metric file names is read here."""
+    totals = {}
+    for n, a, b in spans:
+        totals[n] = totals.get(n, 0.0) + (b - a)
+    log("spans, ms a pass: %s" % json.dumps(
+        {n: round(1000.0 * t / max(passes, 1), 3)
+         for n, t in sorted(totals.items())}))
 
 
 def strip(compared: dict) -> dict:
