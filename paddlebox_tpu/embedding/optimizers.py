@@ -292,7 +292,9 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
     assign pass-local ids, so the dedup rides the (overlapped) host stage
     instead (DedupKeysAndFillIdx done host-side, box_wrapper_impl.h:129).
 
-    uids:       [K] unique ids; tail padded with ids >= capacity, which
+    uids:       [U] unique ids, U <= K the push's unique-row domain
+                (pass_table.push_domain; K when the host stages one slot
+                an occurrence); tail padded with ids >= capacity, which
                 drop at the scatter
     perm:       [K] occurrence indices grouped by unique id
     inv_sorted: [K] nondecreasing merged-row index per permuted occurrence
@@ -330,11 +332,13 @@ def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
     rebuild) consume these rows — keep them in one place so merge or
     lazy-init fixes can't diverge between the two.
 
-    pulled_rows [K, width] + first_idx [K]: the step's pull already
+    pulled_rows [K, width] + first_idx [U]: the step's pull already
     gathered every occurrence's full row (DECODED f32 under the bf16 slab
     diet) from this same pre-update slab, so when given, each unique's row
-    comes from pulled_rows[first_idx[j]] (a [K]-domain gather; host stages
+    comes from pulled_rows[first_idx[j]] (a [U]-domain gather; host stages
     first_idx next to the dedup) instead of a second slab-wide gather.
+    The merge, the row take and the update all run over uids.shape[0]
+    slots, padding included: the staged domain is their whole cost.
     first_idx[j] must be an occurrence index of uids[j] (padding tail
     entries may point anywhere: their g_show == 0 rows pass through
     untouched and are never written back)."""

@@ -108,17 +108,33 @@ def _pow2_pad(m: int) -> int:
     return p
 
 
+def push_domain(n_u: int, K: int, floor: int = 0) -> int:
+    """Static size U of a push's unique-row domain, from the dedup's own
+    count: the power-of-two bucket that holds the n_u real uids (the rule
+    begin_pass uses for its scatter), capped at K, one slot an occurrence
+    (a key vector with no repeats keeps the [K] program), and never under
+    ``floor``, the caller's high-water mark for this K, so a stager
+    compiles one program a bucket it has ever reached. The push's device
+    cost is per index, padding included: the slots past n_u write
+    nothing and cost as much as a row that does."""
+    return max(min(_pow2_pad(n_u), K), floor)
+
+
 def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
     """Host-side per-batch id dedup for push_sparse_hostdedup: the device
     analog (jnp.unique) is an XLA sort of the whole key vector inside every
     train step; here it rides the already-overlapped host batch stage
     (DedupKeysAndFillIdx host-side, box_wrapper_impl.h:129).
 
-    Returns (uids, perm, inv) int32 [K] arrays:
+    Returns (uids, perm, inv, n_u): three int32 [K] arrays and a count:
       uids — unique ids (tail padded with pad_base+i: unique and
       out-of-slab → scatter-dropped); perm — occurrence indices grouped by
       unique id; inv — merged-row index per PERMUTED occurrence,
-      nondecreasing so the device merge is a sorted segment-sum.
+      nondecreasing so the device merge is a sorted segment-sum; n_u — how
+      many of uids are real: they are uids[:n_u] and every inv value is
+      below n_u, so uids[:U] with any U >= n_u is the same push on fewer
+      padded slots (push_domain picks U; perm and inv are per occurrence
+      and keep their [K]).
 
     Fast path: native rt_dedup (hash dedup + counting sort, no comparison
     sort); numpy argsort fallback.
@@ -156,7 +172,7 @@ def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
             inv.ctypes.data_as(i32p),
             scratch.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
         if n_u >= 0:
-            return uids, perm, inv
+            return uids, perm, inv, int(n_u)
     perm = np.argsort(ids, kind="stable").astype(np.int32)
     sorted_ids = ids[perm]
     newseg = np.empty(K, dtype=bool)
@@ -169,7 +185,7 @@ def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
     n_u = real.shape[0]
     uids[:n_u] = real
     uids[n_u:] = pad_base + np.arange(K - n_u, dtype=np.int32)
-    return uids, perm, inv
+    return uids, perm, inv, n_u
 
 
 def dedup_uids_sorted(ids: np.ndarray, pad_base: int) -> np.ndarray:

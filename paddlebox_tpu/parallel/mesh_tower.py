@@ -226,7 +226,7 @@ class MeshTowerTrainer:
             # eval never pushes — skip the dedup + transfers; uids ride the
             # host stage (device reconstruction is a scatter), and rebuild
             # mode stages the pos map for the scatter-free slab write
-            uids, perm, inv = self.table.dedup_for_push(
+            uids, perm, inv, _n_u = self.table.dedup_for_push(
                 ids, sort=self._push_write == "blocked")
             host.update(perm=perm, inv=inv, uids=uids)
             if self._push_write == "rebuild":

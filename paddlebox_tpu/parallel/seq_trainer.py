@@ -244,8 +244,8 @@ class SeqCtrTrainer:
             push_ids = np.concatenate([ids, seq_ids.reshape(-1)]).astype(
                 np.asarray(ids).dtype)
             from paddlebox_tpu.embedding.pass_table import dedup_ids
-            _uids, perm, inv = dedup_ids(push_ids,
-                                         self.table.config.pass_capacity)
+            _uids, perm, inv, _n_u = dedup_ids(
+                push_ids, self.table.config.pass_capacity)
             out.update(push_ids=jnp.asarray(push_ids),
                        perm=jnp.asarray(perm), inv=jnp.asarray(inv))
         return out

@@ -391,8 +391,8 @@ def stage_push_dedup(buckets, local_positions, num_devices: int,
         else:
             # sort_uids: push_write='blocked' consumes these products and
             # its device bucketize trusts sorted uids (see dedup_ids)
-            uids, perm, inv = dedup_ids(incoming_of(d), shard_cap,
-                                        sort=sort_uids)
+            uids, perm, inv, _n_u = dedup_ids(incoming_of(d), shard_cap,
+                                              sort=sort_uids)
         if note_touched is not None:
             # every id this destination shard will push rides these uids —
             # the per-pass touched-row accumulation point (incremental

@@ -40,7 +40,8 @@ from paddlebox_tpu.embedding.optimizers import (decode_delta_uids,
 from paddlebox_tpu.embedding.pass_table import (PassTable, dedup_ids,
                                                 delta_encode_uids,
                                                 first_occurrence_idx,
-                                                pos_for_rebuild)
+                                                pos_for_rebuild,
+                                                push_domain)
 from paddlebox_tpu.metrics.auc import MetricRegistry
 from paddlebox_tpu.models.base import ModelSpec
 from paddlebox_tpu.obs import beat as obs_beat
@@ -58,6 +59,7 @@ from paddlebox_tpu.ops.sparse import (build_push_grads,
                                       gather_slab_rows,
                                       pull_sparse, pull_sparse_extended,
                                       pull_view_from_rows)
+from paddlebox_tpu.utils.stats import stat_add
 from paddlebox_tpu.utils.timer import Timer
 
 
@@ -967,6 +969,10 @@ class BoxTrainer:
         register_owner("dense_params", lambda: getattr(_w(), "params", None))
         register_owner("opt_state", lambda: getattr(_w(), "opt_state", None))
         self._stage_pool = None  # lazy host-staging thread pool
+        # largest unique-row domain staged so far, by occurrence count K
+        # (a batch's keys; a chunk's under sparse_chunk_sync): never
+        # shrinks, so the step compiles once a bucket (_trim_push_domain)
+        self._push_domain_mark: Dict[int, int] = {}
         self._step_count = 0
         self._shuffle_rng = np.random.RandomState(seed + 1)
         self.multi_task = len(getattr(model, "task_names", ("ctr",))) > 1
@@ -1035,13 +1041,39 @@ class BoxTrainer:
             self._stage_pool = (n, pool)
         return pool
 
-    def _stage_one(self, b: PackedBatch) -> Dict[str, np.ndarray]:
+    def _stage_one(self, b: PackedBatch
+                   ) -> Tuple[Dict[str, np.ndarray], Optional[int]]:
         # chunk-sync megasteps use ONE chunk-level dedup (_stack_batches);
         # computing the per-batch products here would be pure waste in the
         # staging hot path (tail batches go through host_batch directly
         # and still get them)
-        return self.host_batch(b, self.table.lookup_ids(b.keys, b.valid),
-                               skip_push_dedup=self.sparse_chunk_sync)
+        return self._host_batch(b, self.table.lookup_ids(b.keys, b.valid),
+                                skip_push_dedup=self.sparse_chunk_sync)
+
+    def _trim_push_domain(self, hosts: List[Dict[str, np.ndarray]],
+                          n_us: List[Optional[int]],
+                          keys: Tuple[str, ...] = ("uids", "first_idx")
+                          ) -> None:
+        """Cut the per-unique-row leaves of host dicts staged with the
+        full-wire dedup to ONE static domain U (pass_table.push_domain
+        of the largest real count among them): uids[:U] holds every real
+        uid, so the push merges, updates and writes the same rows to the
+        same bits over U index slots instead of one an occurrence. perm,
+        inv and ids are per occurrence and keep their [K]; the rebuild
+        map names real uids only and is the same either way. The mark
+        makes every dict of a trainer's life agree on U (tail batches
+        too) until a batch outgrows the bucket."""
+        if not hosts or n_us[0] is None:
+            return          # eval, lean wire, chunk-sync: no host dedup
+        K = hosts[0][keys[0]].shape[0]
+        U = push_domain(max(n_us), K, self._push_domain_mark.get(K, 0))
+        self._push_domain_mark[K] = U
+        stat_add("push_index_slots", U * len(hosts))
+        stat_add("push_unique_rows", sum(n_us))
+        for h in hosts:
+            for k in keys:
+                if k in h:
+                    h[k] = h[k][:U]
 
     def _stack_batches_host(self, group: List[PackedBatch]):
         """Stack a chunk of packed batches on a leading scan axis as HOST
@@ -1050,9 +1082,11 @@ class BoxTrainer:
         H2D path) so N chunks can share one transfer per leaf."""
         pool = self._host_pool()
         if pool is not None and len(group) > 1:
-            hosts = list(pool.map(self._stage_one, group))
+            staged = list(pool.map(self._stage_one, group))
         else:
-            hosts = [self._stage_one(b) for b in group]
+            staged = [self._stage_one(b) for b in group]
+        hosts = [h for h, _ in staged]
+        self._trim_push_domain(hosts, [n for _, n in staged])
         if self.sparse_chunk_sync:
             # chunk-synchronous sparse: ONE dedup over the chunk's flat
             # occurrence space (the per-batch products were never computed
@@ -1066,7 +1100,7 @@ class BoxTrainer:
                 cpush = {}
                 self._stage_uid_wire(cpush, ids_flat)
             else:
-                uids, perm, inv = dedup_ids(
+                uids, perm, inv, n_u = dedup_ids(
                     ids_flat, self.table.capacity,
                     sort=self._push_write == "blocked")
                 cpush = {"uids": uids, "perm": perm, "inv": inv,
@@ -1074,6 +1108,7 @@ class BoxTrainer:
                 if self._push_write == "rebuild":
                     cpush["pos"] = pos_for_rebuild(uids,
                                                    self.table.capacity)
+                self._trim_push_domain([cpush], [n_u], ("uids", "first"))
             return ({k: np.stack([h[k] for h in hosts]) for k in hosts[0]},
                     cpush)
         return {k: np.stack([h[k] for h in hosts]) for k in hosts[0]}
@@ -1094,8 +1129,15 @@ class BoxTrainer:
         fixed per-transfer cost amortizes /G (the MiniBatchGpuPack
         stacked-pinned-copy role, data_feed.h:519-680).
         Per-chunk views are device-side slices of the grouped arrays."""
-        sizes = [d["ids"].shape[0] for d in staged_list]
         account_h2d(tree_nbytes(staged_list))  # device transfer ledger
+        shapes = [{k: v.shape[1:] for k, v in d.items()}
+                  for d in staged_list]
+        if any(s != shapes[0] for s in shapes):
+            # the push's unique-row domain grew inside this group: the
+            # chunks on either side of the growth cannot share a buffer
+            return [{k: jnp.asarray(v) for k, v in d.items()}
+                    for d in staged_list]
+        sizes = [d["ids"].shape[0] for d in staged_list]
         big = {k: jnp.asarray(np.concatenate([d[k] for d in staged_list]))
                for k in staged_list[0]}
         out, off = [], 0
@@ -1123,6 +1165,18 @@ class BoxTrainer:
 
     def host_batch(self, b: PackedBatch, ids: np.ndarray,
                    skip_push_dedup: bool = False) -> Dict[str, np.ndarray]:
+        """One batch's host dict as the one-step program takes it (tail
+        batches, eval): the push's unique-row domain cut like a chunk's."""
+        out, n_u = self._host_batch(b, ids, skip_push_dedup)
+        self._trim_push_domain([out], [n_u])
+        return out
+
+    def _host_batch(self, b: PackedBatch, ids: np.ndarray,
+                    skip_push_dedup: bool = False
+                    ) -> Tuple[Dict[str, np.ndarray], Optional[int]]:
+        """(host dict, the full-wire dedup's real unique count or None
+        where no such dedup was staged); the per-unique-row leaves are
+        still [K]: the caller cuts them (_trim_push_domain)."""
         # per-key slots/valid are derived on device (make_train_step);
         # ids/segments/perm/inv/uids ride the H2D path, plus the [capacity]
         # push_pos map in push_write=rebuild mode (the largest transfer —
@@ -1137,6 +1191,7 @@ class BoxTrainer:
             "ins_valid": b.ins_valid,
             "labels": b.labels,
         }
+        n_u = None
         from paddlebox_tpu.config import flags as _flags
         if not self.table.test_mode and not skip_push_dedup \
                 and _flags.get_flag("h2d_lean"):
@@ -1153,7 +1208,7 @@ class BoxTrainer:
             # batches never push, so skip the dedup + extra transfers
             # blocked write: the device bucketize trusts SORTED uids, so
             # the staging pins the sorted dedup tier (see dedup_ids)
-            uids, perm, inv = self.table.dedup_for_push(
+            uids, perm, inv, n_u = self.table.dedup_for_push(
                 ids, sort=self._push_write == "blocked")
             out.update(perm=perm, inv=inv, uids=uids)
             if not getattr(self.model, "use_expand", False):
@@ -1174,7 +1229,7 @@ class BoxTrainer:
             packed = b.task_labels or {}
             for t in self.model.task_names:
                 out["labels_" + t] = packed.get(t, b.labels)
-        return out
+        return out, n_u
 
     def device_batch(self, b: PackedBatch,
                      ids: np.ndarray) -> Dict[str, jnp.ndarray]:

@@ -226,7 +226,7 @@ def test_hostdedup_push_matches_device_dedup(init_range):
     slab0 = pt.slab
     ref = push_sparse_dedup(slab0, jnp.asarray(ids), jnp.asarray(grads),
                             prng, pt.layout, table.optimizer)
-    uids, perm, inv = pt.dedup_for_push(ids)
+    uids, perm, inv, _ = pt.dedup_for_push(ids)
     got = push_sparse_hostdedup(slab0, jnp.asarray(uids), jnp.asarray(perm),
                                 jnp.asarray(inv), jnp.asarray(grads), prng,
                                 pt.layout, table.optimizer)
@@ -268,8 +268,8 @@ def test_dedup_for_push_invariants():
     for native in (True, False):
         if native and not _native_available():
             continue
-        uids, perm, inv = (pt.dedup_for_push(ids) if native
-                           else _numpy_dedup(pt, ids))
+        uids, perm, inv, got_n_u = (pt.dedup_for_push(ids) if native
+                                    else _numpy_dedup(pt, ids))
         # all uids distinct (unique scatter contract)
         assert np.unique(uids).size == uids.size
         # inv nondecreasing over the permuted occurrence order (sorted
@@ -281,6 +281,7 @@ def test_dedup_for_push_invariants():
         np.testing.assert_array_equal(uids[inv], ids[perm])
         # padding ids out of range exactly beyond the unique count
         n_u = np.unique(ids).size
+        assert got_n_u == n_u and inv.max() < n_u
         assert (uids[:n_u] < table.pass_capacity).all()
         assert (uids[n_u:] >= table.pass_capacity).all()
     pt.end_pass()
@@ -426,16 +427,16 @@ def test_first_occurrence_idx_alignment():
     for trial in range(4):
         K = int(rng.randint(3, 200))
         ids = rng.randint(0, 40, K).astype(np.int32)
-        uids, perm, inv = dedup_ids(ids, pad_base=1000)
+        uids, perm, inv, n_u = dedup_ids(ids, pad_base=1000)
         first = first_occurrence_idx(perm, inv)
-        n_u = int((uids < 1000).sum())
+        assert n_u == int((uids < 1000).sum())
         np.testing.assert_array_equal(ids[first[:n_u]], uids[:n_u])
         # numpy fallback path must satisfy the same contract
         import paddlebox_tpu.native.build as nb
         saved = nb.get_lib
         nb.get_lib = lambda: None
         try:
-            uids2, perm2, inv2 = dedup_ids(ids, pad_base=1000)
+            uids2, perm2, inv2, _ = dedup_ids(ids, pad_base=1000)
         finally:
             nb.get_lib = saved
         first2 = first_occurrence_idx(perm2, inv2)
@@ -469,7 +470,7 @@ def test_push_pull_row_reuse_matches_slab_gather():
     grads[~valid] = 0.0
     prng = jax.random.PRNGKey(3)
     slab0 = pt.slab
-    uids, perm, inv = pt.dedup_for_push(ids)
+    uids, perm, inv, _ = pt.dedup_for_push(ids)
     first = first_occurrence_idx(perm, inv)
     pulled = slab0[jnp.asarray(ids)]
     args = (jnp.asarray(uids), jnp.asarray(perm), jnp.asarray(inv),

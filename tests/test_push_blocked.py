@@ -92,7 +92,7 @@ def test_push_blocked_write_unit_parity():
 
     layout, conf, push, slab, ids, grads, prng = _unit_setup()
     cap = slab.shape[0]
-    uids, perm, inv = dedup_ids(ids, cap, sort=True)
+    uids, perm, inv, _ = dedup_ids(ids, cap, sort=True)
     assert np.all(np.diff(uids.astype(np.int64)) > 0)
     oracle = push_sparse_hostdedup(
         jnp.asarray(slab), jnp.asarray(uids), jnp.asarray(perm),
@@ -261,7 +261,7 @@ def test_dedup_ids_sort_option():
     rng = np.random.RandomState(21)
     for K, space in ((256, 50), (512, 500), (64, 8)):
         ids = rng.randint(0, space, K).astype(np.int32)
-        uids, perm, inv = dedup_ids(ids, space, sort=True)
+        uids, perm, inv, _ = dedup_ids(ids, space, sort=True)
         assert np.all(np.diff(uids.astype(np.int64)) > 0)
         assert np.array_equal(np.sort(perm), np.arange(K))
         assert (np.diff(inv) >= 0).all()

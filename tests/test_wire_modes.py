@@ -257,7 +257,7 @@ def test_push_sparse_uidwire_unit():
     grads[ids == cap - 1] = 0.0               # padding rows all-zero
     prng = jax.random.PRNGKey(7)
 
-    uids, perm, inv = dedup_ids(ids, cap)
+    uids, perm, inv, _ = dedup_ids(ids, cap)
     first = first_occurrence_idx(perm, inv)
     pulled = jnp.asarray(slab[ids])
     host = push_sparse_hostdedup(jnp.asarray(slab), jnp.asarray(uids),
@@ -434,7 +434,7 @@ def test_two_virtual_process_uid_staging():
     grads[incoming == shard_cap - 1] = 0.0
     slab = rng.rand(shard_cap, layout.width).astype(np.float32)
     prng = jax.random.PRNGKey(1)
-    uids, perm, inv = dedup_ids(incoming, shard_cap)
+    uids, perm, inv, _ = dedup_ids(incoming, shard_cap)
     host = push_sparse_hostdedup(
         jnp.asarray(slab), jnp.asarray(uids), jnp.asarray(perm),
         jnp.asarray(inv), jnp.asarray(grads), prng, layout, conf)
@@ -482,7 +482,7 @@ def test_dedup_uids_sorted_contract_all_paths(data):
     # order, dedup_uids_sorted must still be sorted
     ids = rng.randint(0, 2000, 1024).astype(np.int32)
     _assert_strictly_ascending(dedup_uids_sorted(ids, 2048), "vs rt_dedup")
-    uids_raw, _, _ = dedup_ids(ids, 2048)
+    uids_raw, _, _, _ = dedup_ids(ids, 2048)
     assert set(uids_raw.tolist()) == set(
         dedup_uids_sorted(ids, 2048).tolist())
 
