@@ -32,10 +32,6 @@ def main() -> None:
                     help="slab write strategy (auto = rebuild below a "
                          "capacity/batch-keys crossover on tpu, scatter "
                          "elsewhere)")
-    ap.add_argument("--sparse-chunk-sync", action="store_true",
-                    help="one merged table update per scan chunk "
-                         "(effective sparse batch = chunk x batch; dense "
-                         "adam stays exact per batch)")
     ap.add_argument("--workdir", default=None)
     args = ap.parse_args()
 
@@ -77,8 +73,7 @@ def main() -> None:
         model,
         table, feed,
         TrainerConfig(dense_lr=1e-3,
-                      compute_dtype="bfloat16" if args.bf16 else "float32",
-                      sparse_chunk_sync=args.sparse_chunk_sync),
+                      compute_dtype="bfloat16" if args.bf16 else "float32"),
         seed=0)
     trainer.metrics.init_metric("auc", "label", "pred", mask_var="mask")
 

@@ -194,15 +194,3 @@ class TrainerConfig:
     # merges and updates in f32 (grads upcast after transport). The slab
     # and its state columns are untouched — only the wire format changes.
     a2a_dtype: str = "float32"
-    # chunk-synchronous sparse: decouple the sparse and dense batch sizes.
-    # The table sees ONE pull + ONE merged push per scan chunk (effective
-    # sparse batch = scan_chunk × batch_size; pulls read chunk-start
-    # state), while dense adam keeps its exact per-batch cadence inside
-    # the chunk. The sparse analog of K-step dense sync / the reference's
-    # async-table staleness (boxps_worker.cc:57-366) — a throughput mode
-    # for runtimes where per-batch table ops dominate (unmeasured on the
-    # chip). scan_chunk=1 (or chunks of 1) is bit-identical
-    # to exact mode; chunks whose batches share no keys are bit-identical
-    # at any chunk size. Unsupported with expand / data_norm / async
-    # dense (construction-time error).
-    sparse_chunk_sync: bool = False

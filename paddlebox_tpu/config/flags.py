@@ -139,39 +139,15 @@ define_flag("chunk_prefetch_depth", 1,
             "thread while the device trains (the shard_batches stager "
             "role; peak extra memory = this many staged chunks); 0 = "
             "stage inline between dispatches")
-define_flag("h2d_lean", False,
-            "input-bound deployments (slow host->device links): stage "
-            "train batches on the LEAN wire — no perm/inv/first_idx/pos "
-            "host products. With h2d_uid_wire (default) the sorted [K] "
-            "uid vector still ships and the step runs the FAST push "
-            "(device-derived maps by searchsorted — no jnp.unique sort); "
-            "with it off, ids only ship and the step pays the on-device "
-            "unique sort. Meant for passes whose H2D bytes dominate; "
-            "whether the attached chip has such a regime is not measured")
 define_flag("h2d_uid_wire", True,
-            "lean-wire push reunification (round 8): under h2d_lean, ship "
-            "the [K] int32 SORTED deduped uid vector next to the ids and "
-            "derive perm/inverse/position maps on device (searchsorted + "
-            "segment scatter-add + scatter-min) — the fast host-dedup "
-            "push at lean-wire byte cost, bit-identical to the host-"
-            "staged path. Also switches the sharded runners' push staging "
-            "to uid-only (per-destination perm/inv/pos derived on device "
-            "from the a2a'd bucket ids). Off = the round-5 ids-only wire "
-            "(single-host trainer) / full host product staging (sharded)")
-define_flag("wire_delta_ids", False,
-            "measured wire experiment: ship the sorted uid vector as "
-            "(int32 base, int16 deltas) — 2 bytes/key less H2D, one "
-            "device cumsum to decode, pull-row reuse disabled (in-range "
-            "padding recode; see pass_table.delta_encode_uids). Raises "
-            "when an inter-uid gap exceeds int16 (very sparse pass "
-            "shapes). Single-host uid wire only")
-define_flag("h2d_stack_chunks", 1,
-            "scan chunks whose host-staged batch arrays share ONE device "
-            "transfer per leaf (a per-transfer fixed cost amortizes over "
-            "the group; per-chunk views are device-side slices; that "
-            "cost is not measured on the chip). 1 = one transfer set per "
-            "chunk; "
-            "peak staged host memory grows with the group")
+            "the sharded runners' push staging (ShardedBoxTrainer, the "
+            "pipeline runner; sharded_table.stage_push_dedup): on = stage "
+            "ONLY the per-destination SORTED deduped uid vectors and "
+            "derive perm/inverse/position maps on device from the a2a'd "
+            "bucket ids (push_sparse_uidwire: searchsorted + segment "
+            "scatter-add + scatter-min); off = stage the full host "
+            "products. Bit-identical either way. The one-chip BoxTrainer "
+            "does not read it: its wire is _host_batch's")
 define_flag("stack_threads", 4,
             "host batch-staging threads per scan chunk (lookup + dedup; "
             "the feed-thread pool role, box_wrapper.h:862); <=1 = serial")
@@ -189,8 +165,9 @@ define_flag("push_write", "auto",
             "how the push writes updated rows back into the pass slab: "
             "'scatter' (row scatter, cost ~ touched rows — right for CPU "
             "and small batches), 'rebuild' (pos map + full slab "
-            "gather/select, flat cost ~ slab bytes; pos host-staged on "
-            "the full wire, device-derived on the uid wire), or 'auto' "
+            "gather/select, flat cost ~ slab bytes; pos host-staged by "
+            "BoxTrainer, device-derived on the sharded uid wire), or "
+            "'auto' "
             "(rebuild below a capacity/batch-keys crossover on the TPU — "
             "a crossover not yet measured on the chip; "
             "scatter on CPU). The round-5 'log' mode was deleted in "
@@ -204,8 +181,8 @@ define_flag("push_block_rows", 1024,
             "divide the table's pass_capacity (resolve_push_write "
             "validates). Cost class ~ min(touched_blocks) * block bytes: "
             "small blocks approach scatter's touched-rows cost, large "
-            "blocks approach rebuild's slab-bytes cost — bench.py "
-            "push_ladder records the crossover")
+            "blocks approach rebuild's slab-bytes cost (the crossover is "
+            "not measured on the chip: ROADMAP S1)")
 define_flag("push_blocked_pallas", False,
             "route push_write=blocked's per-block tile placement through "
             "the hand-written Mosaic kernel (pallas_blocked_write: grid "
@@ -213,17 +190,6 @@ define_flag("push_blocked_pallas", False,
             "aliased in place) instead of the XLA fori_loop of "
             "dynamic_update_slices. Compiled on tpu; on cpu (tests) it "
             "runs interpreted; any other backend is an error")
-define_flag("push_onehot_rows", 0,
-            "MXU one-hot matmul accumulation for the first N merged rows "
-            "of the uid-wire push (merge_grads_onehot): rows [0, N) merge "
-            "as onehot(inv) @ grads on the MXU — cost flat in batch keys "
-            "— while the tail keeps the VPU segment scatter-add, whose "
-            "cost is flat in duplicates. Wins when a dense short tail of "
-            "hot keys absorbs most of the batch's occurrences. f32 "
-            "accumulation ORDER differs from "
-            "the sorted segment-sum — a measured opt-in, not "
-            "bit-parity with the oracle (exact for integer grads). "
-            "0 = off (the default, oracle-exact path)")
 define_flag("slab_embed_dtype", "float32",
             "DEVICE slab storage precision for the embedding weight "
             "columns (round-11 dtype diet): 'float32' = the classic "
@@ -586,8 +552,8 @@ define_flag("device_obs", True,
             "when a donated buffer was copied instead of aliased — the "
             "regime-step mechanism), and the HBM live-buffer ledger "
             "sampled at report cadence. Off = bare jax.jit everywhere "
-            "(zero added cost, zero device signals); bench.py's "
-            "device_overhead block holds the on-cost at <=2%")
+            "(zero added cost, zero device signals; the on-cost is not "
+            "measured on the chip)")
 define_flag("device_recompile_warmup", 3,
             "compiles each instrumented fn may accumulate before the "
             "recompile sentinel treats further compiles as steady-state "

@@ -359,39 +359,6 @@ def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
                                     row_ids=uids)
 
 
-def decode_delta_uids(base: jnp.ndarray, d16: jnp.ndarray,
-                      cut: jnp.ndarray, capacity: int) -> jnp.ndarray:
-    """Reconstruct the sorted uid vector from the delta wire
-    (wire_delta_ids flag, pass_table.delta_encode_uids): data positions
-    i < cut decode as base + cumsum(d16)[i]; the trash/padding tail
-    i >= cut is arithmetic, (capacity-1) + (i-cut). One [K] int32 cumsum
-    + select — the ~2 bytes/key wire saving costs a prefix sum instead
-    of nothing."""
-    dec = base + jnp.cumsum(d16.astype(jnp.int32))
-    i = jnp.arange(d16.shape[0], dtype=jnp.int32)
-    return jnp.where(i >= cut, (capacity - 1) + (i - cut), dec)
-
-
-def merge_grads_onehot(grads: jnp.ndarray, inv: jnp.ndarray, num_rows: int,
-                       hot_rows: int) -> jnp.ndarray:
-    """MXU one-hot matmul accumulation for the dense short tail of hot
-    keys (flag ``push_onehot_rows``): merged rows [0, hot_rows) accumulate
-    as onehot(inv) @ grads — a [H, K] x [K, G] matmul the MXU runs at line
-    rate — while the long tail keeps the VPU segment scatter-add. The
-    scatter-add's per-index cost is flat in duplicates; the matmul's cost
-    is flat in K, so it wins exactly when few merged rows absorb most of
-    the batch's occurrences (hot-key skew). f32 accumulation order differs
-    from the sorted segment-sum, so this is an opt-in measured path, NOT
-    bit-parity with the oracle (exact for integer-representable grads —
-    how the parity test pins it)."""
-    H = min(int(hot_rows), num_rows)
-    inv_cold = jnp.where(inv < H, num_rows, inv)  # hot occurrences drop
-    merged = jax.ops.segment_sum(grads, inv_cold, num_segments=num_rows)
-    onehot = (inv[None, :] == jnp.arange(H, dtype=inv.dtype)[:, None]
-              ).astype(grads.dtype)
-    return merged.at[:H].set(onehot @ grads)
-
-
 def push_blocked_write(slab: jnp.ndarray, uids: jnp.ndarray,
                        new_rows: jnp.ndarray,
                        block_rows: int) -> jnp.ndarray:
@@ -475,9 +442,9 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
                         conf: SparseOptimizerConfig,
                         pulled_rows: Optional[jnp.ndarray] = None,
                         write: str = "scatter") -> jnp.ndarray:
-    """Uid-wire push (round 8 — the lean wire and the fast push reunified):
-    the host ships ONLY the SORTED deduped uid vector ([K] int32); every
-    other dedup product derives on device —
+    """Uid-wire push (round 8; the sharded runners' staging under
+    h2d_uid_wire): the host ships ONLY the SORTED deduped uid vector
+    ([K] int32); every other dedup product derives on device —
 
       inv    binary search of each occurrence's id against the sorted
              uids (jnp.searchsorted: ~log2 K gather/compare rounds, no
@@ -486,7 +453,7 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
              occurrence addition order as push_sparse_hostdedup's sorted
              segment-sum, so the merged grads are bit-identical
       first  scatter-min of occurrence indices (the pull-row-reuse index
-             first_occurrence_idx stages host-side on the full wire)
+             first_occurrence_idx stages host-side for BoxTrainer)
       pos    (write='rebuild') one [capacity] int32 scatter — the map
              pos_for_rebuild stages host-side, at 4 bytes/slab-row H2D
 
@@ -495,14 +462,13 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
           fast path returns hash order; sortedness is load-bearing here).
     ids:  [K] the batch's per-occurrence ids (already on the wire for the
           pull); every entry must be present in uids.
-    pulled_rows: optional pull-gather reuse. Callers staging IN-RANGE
-          padding uids (the delta wire's no-trash-row edge) must pass
-          None: an inactive row's pass-through value then comes from a
-          real slab gather, never from an arbitrary occurrence's row.
+    pulled_rows: optional pull-gather reuse. A caller staging IN-RANGE
+          padding uids must pass None: an inactive row's pass-through
+          value then comes from a real slab gather, never from an
+          arbitrary occurrence's row.
     Reference work shape: PushSparseGradCaseGPU merge + update
     (box_wrapper_impl.h:373-522); dedup never skipped (impl.h:129).
     """
-    from paddlebox_tpu.config import flags
     K = ids.shape[0]
     U = uids.shape[0]
     if write not in ("scatter", "rebuild", "blocked"):
@@ -510,13 +476,7 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
                          "(scatter, rebuild or blocked)")
     with jax.named_scope("push_merge"):
         inv = jnp.searchsorted(uids, ids).astype(jnp.int32)
-        hot = int(flags.get_flag("push_onehot_rows"))
-        if hot > 0:
-            # MXU one-hot accumulation for the dense short tail (see
-            # merge_grads_onehot: measured path, integer-exact only)
-            merged = merge_grads_onehot(grads, inv, U, hot)
-        else:
-            merged = jax.ops.segment_sum(grads, inv, num_segments=U)
+        merged = jax.ops.segment_sum(grads, inv, num_segments=U)
     with jax.named_scope("push_opt"):
         if pulled_rows is not None:
             first = jnp.full((U,), K - 1, jnp.int32).at[inv].min(
@@ -539,6 +499,7 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
             # blocked scatter (round 11): bucketize the sorted uids into
             # contiguous row blocks, apply per block with
             # dynamic_update_slice
+            from paddlebox_tpu.config import flags
             return push_blocked_write(slab, uids, new_rows,
                                       int(flags.get_flag("push_block_rows")))
         return slab.at[uids].set(new_rows, mode="drop", unique_indices=True)

@@ -251,35 +251,6 @@ def dedup_uids_sorted(ids: np.ndarray, pad_base: int) -> np.ndarray:
     return out
 
 
-def delta_encode_uids(uids: np.ndarray, pad_base: int):
-    """(base, d16, cut) int16-delta wire coding of a SORTED uid vector
-    (wire_delta_ids flag). DATA ids (< pad_base-1, i.e. below the trash
-    row) carry real deltas: uids[i] = base + cumsum(d16)[i] for i < cut,
-    d16[0] = 0. Everything from the trash id up (trash + the out-of-slab
-    padding tail — jumps far beyond int16) is NOT delta-coded at all:
-    the device reconstructs position i >= cut as (pad_base-1) + (i-cut),
-    which reproduces the exact [trash, pad_base, pad_base+1, ...] tail
-    when the trash id is present. When it is absent, position `cut`
-    decodes to the trash id anyway — no occurrence maps to it (its
-    merged g_show is 0), so the one possible in-range write is the trash
-    row's own unchanged bits (the pulled_rows=None contract in
-    push_sparse_uidwire). A DATA-id gap > 32767 cannot be coded in int16
-    and raises — disable the flag for pass shapes that sparse (this is a
-    measured wire experiment, not a default)."""
-    uids = np.asarray(uids, np.int32)
-    cut = int(np.searchsorted(uids, pad_base - 1))
-    d = np.zeros(uids.shape[0], np.int32)
-    if cut:
-        d[1:cut] = np.diff(uids[:cut])
-    if d.size and int(d.max(initial=0)) > np.iinfo(np.int16).max:
-        raise ValueError(
-            "wire_delta_ids: inter-uid gap %d exceeds int16 — this pass "
-            "shape is too sparse for the delta wire (unset the flag)"
-            % int(d.max()))
-    base = uids[0] if cut else np.int32(0)
-    return np.int32(base), d.astype(np.int16), np.int32(cut)
-
-
 def first_occurrence_idx(perm: np.ndarray, inv: np.ndarray) -> np.ndarray:
     """[K] int32 occurrence index of each dedup unique's FIRST occurrence:
     first_idx[j] is a position into the batch's key vector whose id is
@@ -756,7 +727,7 @@ class PassTable:
         ids = self._rows.lookup(keys, valid, self.padding_id)
         # every staged train batch flows through here, so this is the
         # ONE accumulation point for the touched-row bitmap (uids are
-        # a subset of these ids; h2d_lean stages no uids at all)
+        # a subset of these ids)
         self.note_touched(ids)
         return ids
 
@@ -765,11 +736,6 @@ class PassTable:
         dedup_ids): padding ids start at this table's capacity. sort=True
         = sorted-uids contract (push_write='blocked' staging)."""
         return dedup_ids(ids, self.capacity, sort=sort)
-
-    def uids_for_push(self, ids: np.ndarray) -> np.ndarray:
-        """Sorted uid-wire dedup product (see dedup_uids_sorted): padding
-        ids start at this table's capacity."""
-        return dedup_uids_sorted(ids, self.capacity)
 
     def pos_for_rebuild(self, uids: np.ndarray) -> np.ndarray:
         """[capacity] int32 inverse of the dedup's uids for the

@@ -62,10 +62,9 @@ Mechanism: the wrapper runs jax.jit through the explicit AOT path —
 lower().compile() once per (pytree structure, shape, dtype) signature,
 cached here — so compile COUNT and WALL TIME are exact (not inferred
 from call latency) and the cost/memory analyses come free with the
-executable instead of a second compile. Dispatch parity with the C++
-jit fast path is measured in bench.py's device_overhead block (<=2%
-bar); instrumented-vs-bare bit-parity on the e2e trainer is pinned by
-tests/test_device_obs.py. Signature keying is CONSERVATIVE: python
+executable instead of a second compile. Dispatch cost against the C++
+jit fast path is not measured on the chip; instrumented-vs-bare
+bit-parity on the e2e trainer is pinned by tests/test_device_obs.py. Signature keying is CONSERVATIVE: python
 scalar args re-key by value (jax.jit would retrace only on dtype
 change) — none of the instrumented entry points take bare scalars, and
 a finer key can only add a counted compile, never reuse a wrong

@@ -57,9 +57,6 @@ class MeshTowerTrainer:
                  seed: int = 0) -> None:
         self.model = model
         self.cfg = trainer_cfg or TrainerConfig()
-        if getattr(self.cfg, "sparse_chunk_sync", False):
-            raise ValueError("sparse_chunk_sync is a single-host "
-                             "BoxTrainer mode (not mesh-tower)")
         self.feed = feed
         if mesh is None:
             devs = np.array(jax.devices()[:model.n_shards])

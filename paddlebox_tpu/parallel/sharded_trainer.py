@@ -99,11 +99,6 @@ class ShardedBoxTrainer:
         self.fleet = fleet
         # multi-process topology: this process owns the mesh positions whose
         # device it hosts (per-node PS shard layout, box_wrapper.h:433-436)
-        if getattr(self.cfg, "sparse_chunk_sync", False):
-            raise ValueError(
-                "sparse_chunk_sync is a single-host BoxTrainer mode; the "
-                "sharded trainer's pull/push ride the per-step a2a (use "
-                "the exact path here)")
         self.multiprocess = jax.process_count() > 1
         mesh_devs = list(self.mesh.devices.flat)
         pid = jax.process_index()

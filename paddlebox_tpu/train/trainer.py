@@ -32,15 +32,10 @@ from paddlebox_tpu.config.configs import (DataFeedConfig, TableConfig,
 from paddlebox_tpu.data.dataset import BoxDataset
 from paddlebox_tpu.data.packer import PackedBatch
 from paddlebox_tpu.embedding.accessor import ValueLayout
-from paddlebox_tpu.embedding.optimizers import (decode_delta_uids,
-                                                push_sparse_hostdedup,
-                                                push_sparse_rebuild,
-                                                push_sparse_uidwire,
-                                                rebuild_uids)
-from paddlebox_tpu.embedding.pass_table import (PassTable, dedup_ids,
-                                                delta_encode_uids,
+from paddlebox_tpu.embedding.optimizers import (push_sparse_hostdedup,
+                                                push_sparse_rebuild)
+from paddlebox_tpu.embedding.pass_table import (PassTable,
                                                 first_occurrence_idx,
-                                                pos_for_rebuild,
                                                 push_domain)
 from paddlebox_tpu.metrics.auc import MetricRegistry
 from paddlebox_tpu.models.base import ModelSpec
@@ -76,13 +71,10 @@ class TrainStepFns:
     # axis), amortizing dispatch overhead (its size on the chip is not
     # measured)
     scan_steps: Optional[Callable] = None
-    # chunk-synchronous sparse megastep (TrainerConfig.sparse_chunk_sync):
-    # (slab, params, opt_state, stacked, cpush, prng) -> (slab, params,
-    # opt_state, losses, preds, prng) — one pull + one merged push per chunk
-    scan_chunk: Optional[Callable] = None
-    # the slab-write strategy BAKED into the uid-wire push branch at build
-    # time (scatter | rebuild — derived on device, so unlike the full
-    # wire it cannot follow a live push_write flip; train_pass guards)
+    # the slab-write strategy the step was built with: blocked-vs-scatter
+    # is BAKED into the jitted push, so a live push_write flip on or off
+    # 'blocked' cannot retarget it (train_pass guards); scatter<->rebuild
+    # follows the batch's push_pos leaf
     uid_write: str = "scatter"
 
 
@@ -117,10 +109,7 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
                     stack_fn: Callable, carry: Tuple,
                     on_chunk: Callable, timer=None,
                     n_items: Optional[int] = None,
-                    chunk1_ok: bool = False,
-                    prefetch_depth: int = 0,
-                    transfer_group: int = 1,
-                    group_fn: Optional[Callable] = None):
+                    prefetch_depth: int = 0):
     """Drive the megastep over full chunks of `items`, double-buffered:
     chunk i+1 is host-stacked and dispatched BEFORE chunk i's results are
     pulled to host, so H2D staging and metric extraction overlap device
@@ -143,23 +132,13 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
     compute instead of serially between dispatches. stack_fn must be
     safe to call off-thread (the table is read-only during a pass). Peak
     extra memory = prefetch_depth staged chunks.
-
-    transfer_group > 1 + group_fn: stack_fn returns HOST-staged items and
-    group_fn(list_of_staged) converts that many chunks to device items
-    with ONE H2D transfer per leaf for the whole group — a per-transfer
-    fixed cost amortizes over the group instead of being paid per chunk
-    per leaf (the MiniBatchGpuPack pinned-buffer stacking role,
-    data_feed.h:519-680; the branch is unmeasured on the chip).
     Returns (carry, losses, n_consumed)."""
     losses_all: List[float] = []
     if n_items is None:
         n_items = len(items)
     it = iter(items)
-    # chunk=1 normally means "megastep off" (per-step path); chunk1_ok
-    # forces chunking anyway — the chunk-sync sparse mode needs its
-    # 1-batch chunks to run through the chunk scan, not fall through
-    n_full = ((n_items // chunk) * chunk
-              if (chunk > 1 or chunk1_ok) else 0)
+    # chunk=1 means "megastep off": everything falls to the per-step path
+    n_full = (n_items // chunk) * chunk if chunk > 1 else 0
     pending = None  # (lo, group, losses_dev, preds_dev)
 
     def drain(p):
@@ -183,26 +162,6 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
                 staged = stack_fn(group)
             yield lo, group, staged
 
-    def transfer(src):
-        # grouped H2D: buffer G host-staged chunks, device-ize together
-        if group_fn is None or transfer_group <= 1:
-            yield from src
-            return
-        buf = []
-
-        def emit(b):
-            for (lo, group, _), dev in zip(b, group_fn(
-                    [x[2] for x in b])):
-                yield lo, group, dev
-
-        for item in src:
-            buf.append(item)
-            if len(buf) == transfer_group:
-                yield from emit(buf)
-                buf = []
-        if buf:
-            yield from emit(buf)
-
     stop = None
     producer = None
     if prefetch_depth > 0 and n_full:
@@ -222,7 +181,7 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
 
         def produce():
             try:
-                for item in transfer(chunks()):
+                for item in chunks():
                     if not _put(item):
                         return
             except BaseException as e:   # surfaced at the consumer's get
@@ -241,7 +200,7 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
                 yield item
         source = staged_chunks()
     else:
-        source = transfer(chunks())
+        source = chunks()
 
     try:
         for lo, group, stacked in source:
@@ -340,23 +299,9 @@ def resolve_push_write(capacity: Optional[int] = None,
     The 16x crossover and the ranking of the three writes are NOT
     measured on the chip (ROADMAP S2 runs the ladder there and re-derives
     or deletes the rule).
-
-    Wire interaction: the full wire stages the rebuild pos map on the
-    host; the uid wire derives it on device (push_sparse_uidwire), same
-    regime policy. Only the ids-only lean wire (h2d_lean with
-    h2d_uid_wire off) forces scatter — it ships no uid vector to derive
-    anything from.
     """
     from paddlebox_tpu.config import flags
     mode = flags.get_flag("push_write")
-    if flags.get_flag("h2d_lean") and not flags.get_flag("h2d_uid_wire"):
-        # ids-only wire: no host dedup products, no device-derivable maps
-        if mode not in ("auto", "scatter"):
-            raise ValueError(
-                f"h2d_lean without h2d_uid_wire stages no push products; "
-                f"push_write={mode!r} needs them — use 'auto' or "
-                "'scatter'")
-        return "scatter"
     if mode == "auto":
         if jax.default_backend() != "tpu":
             return "scatter"
@@ -526,7 +471,6 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                     use_cvm: bool = True,
                     async_dense: bool = False,
                     compute_dtype: str = "float32",
-                    sparse_chunk: int = 0,
                     uid_write: str = "scatter") -> TrainStepFns:
     conf = table.optimizer
     multi_task = len(getattr(model, "task_names", ("ctr",))) > 1
@@ -559,16 +503,14 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
     def _key_slots(batch):
         return batch["segments"] % num_slots
 
-    def forward(params, emb, batch, dn_extra, pooled=None):
+    def forward(params, emb, batch, dn_extra):
         expand_emb = None
         if use_expand:
             emb, expand_emb = emb
-        if pooled is None:
-            # packer/columnar batches carry nondecreasing segments by
-            # contract
-            pooled = fused_seqpool_cvm(
-                emb, batch["segments"], _key_valid(batch), batch_size,
-                num_slots, use_cvm=use_cvm, sorted_segments=True)
+        # packer/columnar batches carry nondecreasing segments by contract
+        pooled = fused_seqpool_cvm(
+            emb, batch["segments"], _key_valid(batch), batch_size,
+            num_slots, use_cvm=use_cvm, sorted_segments=True)
         dense_in = batch.get("dense")
         if mixed:
             # matmuls ride the MXU in bf16; logits return to f32 for the
@@ -636,60 +578,24 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                 push_grads = build_push_grads(demb, _key_slots(batch),
                                               clicks, _key_valid(batch))
         if "perm" not in batch:
-            if "uid_d16" in batch:
-                # delta-coded uid wire: decode, and DON'T reuse pulled
-                # rows — the decoded tail can name the trash row when it
-                # was absent from the batch, and its pass-through bits
-                # must come from a real slab gather
-                uids = decode_delta_uids(batch["uid_base"],
-                                         batch["uid_d16"],
-                                         batch["uid_cut"],
-                                         table.pass_capacity)
-                return push_sparse_uidwire(
-                    slab, uids, batch["ids"], push_grads, sub, layout,
-                    conf, pulled_rows=None, write=uid_write)
-            if "uids" in batch:
-                # uid wire (round 8): the host shipped ONLY the sorted
-                # uid vector; inv/first (and the rebuild pos) derive on
-                # device — the fast push at lean-wire byte cost
-                return push_sparse_uidwire(
-                    slab, batch["uids"], batch["ids"], push_grads, sub,
-                    layout, conf, pulled_rows=pulled_rows,
-                    write=uid_write)
-            from paddlebox_tpu.config import flags as _flags
-            if _flags.get_flag("h2d_lean"):
-                # ids-only wire (h2d_uid_wire off): the dedup runs on
-                # device (jnp.unique sort — the cost the uid wire
-                # removes); kept as the measured fallback for links where
-                # even the uid vector's bytes dominate
-                from paddlebox_tpu.embedding.optimizers import (
-                    push_sparse_dedup)
-                return push_sparse_dedup(slab, batch["ids"], push_grads,
-                                         sub, layout, conf)
             # never fall back to the on-device jnp.unique sort silently —
             # that is the dominant step cost this path exists to remove
             raise KeyError(
                 "train batch lacks host dedup (perm/inv) — host_batch must "
                 "run dedup_for_push for train batches")
-        # uids ride the (overlapped) host stage when present — the on-device
-        # rebuild_uids reconstruction is a [K] scatter (unmeasured on the
-        # chip)
-        uids = batch.get("uids")
-        if uids is None:
-            uids = rebuild_uids(batch["ids"], batch["perm"], batch["inv"],
-                                table.pass_capacity)
         # pull-gather reuse: the pull already gathered every occurrence's
         # full row from this same pre-update slab
         fi = batch.get("first_idx") if pulled_rows is not None else None
         rows = pulled_rows if fi is not None else None
         if "push_pos" in batch:
-            return push_sparse_rebuild(slab, uids, batch["push_pos"],
-                                       batch["perm"], batch["inv"],
-                                       push_grads, sub, layout, conf,
-                                       pulled_rows=rows, first_idx=fi)
-        return push_sparse_hostdedup(slab, uids, batch["perm"], batch["inv"],
-                                     push_grads, sub, layout, conf,
-                                     pulled_rows=rows, first_idx=fi,
+            return push_sparse_rebuild(slab, batch["uids"],
+                                       batch["push_pos"], batch["perm"],
+                                       batch["inv"], push_grads, sub,
+                                       layout, conf, pulled_rows=rows,
+                                       first_idx=fi)
+        return push_sparse_hostdedup(slab, batch["uids"], batch["perm"],
+                                     batch["inv"], push_grads, sub, layout,
+                                     conf, pulled_rows=rows, first_idx=fi,
                                      write=("blocked"
                                             if uid_write == "blocked"
                                             else "scatter"))
@@ -724,120 +630,6 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
     step = instrument_jit(_step_impl, "train_step", donate_argnums=(0,),
                           example_count=batch_size)
     scan_steps = make_scan(_step_impl)
-
-    scan_chunk_fn = None
-    if sparse_chunk:
-        if use_expand or has_summary or async_dense:
-            raise ValueError(
-                "sparse_chunk_sync is unsupported with expand embeddings, "
-                "data_norm summary params, or async dense — these need "
-                "per-batch table/emb state")
-        C = sparse_chunk
-
-        def scan_chunk_fn(slab, params, opt_state, stacked, cpush, prng):
-            """Chunk-synchronous sparse megastep (TrainerConfig.
-            sparse_chunk_sync): ONE pull at chunk-start state + ONE merged
-            push for the whole chunk; dense adam scans per batch exactly.
-            The C seqpools fuse into one segment-sum by offsetting each
-            batch's segment ids (out_dim stays per (ins, slot)); the dense
-            bwd emits pooled-space cotangents [B, S, out] per batch (far
-            smaller than key space), which one pool-VJP expands back to
-            per-key push grads for the merged update.
-
-            cpush: chunk-level host dedup over the flat [C*K] occurrence
-            space (uids/perm/inv/first, pos in rebuild mode)."""
-            prng, sub = jax.random.split(prng)
-            K = stacked["ids"].shape[1]
-            ids_flat = stacked["ids"].reshape(C * K)
-            rows = gather_slab_rows(slab, ids_flat, layout)
-            valid_flat = ids_flat != padding_id
-            seg_dtype = stacked["segments"].dtype
-            seg_flat = (stacked["segments"]
-                        + (jnp.arange(C, dtype=seg_dtype)
-                           * (batch_size * num_slots))[:, None]
-                        ).reshape(C * K)
-            emb_flat = pull_view_from_rows(rows, layout)
-
-            def pool(e):
-                return fused_seqpool_cvm(
-                    e, seg_flat, valid_flat, C * batch_size, num_slots,
-                    use_cvm=use_cvm, sorted_segments=True)
-
-            pooled, pool_vjp = jax.vjp(pool, emb_flat)
-            pooled_c = pooled.reshape((C, batch_size) + pooled.shape[1:])
-
-            def body(carry, xs):
-                params, opt_state = carry
-                pooled_b, batch = xs
-
-                def loss_fn(params, pooled_b):
-                    return forward(params, None, batch, None,
-                                   pooled=pooled_b)
-
-                grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
-                                             has_aux=True)
-                with jax.named_scope("fwd_bwd"):
-                    (loss, preds), (dp, dpooled) = grad_fn(params, pooled_b)
-                with jax.named_scope("dense_opt"):
-                    updates, opt_state = dense_opt.update(dp, opt_state,
-                                                          params)
-                    params = optax.apply_updates(params, updates)
-                return (params, opt_state), (loss, preds, dpooled)
-
-            # the dense body never touches the [K]-sized leaves (pooling
-            # already happened) — scanning them as xs would pay a per-
-            # iteration slice on each, which is ms-scale on some runtimes
-            dense_xs = {k: v for k, v in stacked.items()
-                        if k not in ("ids", "segments")}
-            (params, opt_state), (losses, preds, dpooled_c) = jax.lax.scan(
-                body, (params, opt_state), (pooled_c, dense_xs))
-            (d_emb_flat,) = pool_vjp(
-                dpooled_c.reshape((C * batch_size,) + dpooled_c.shape[2:]))
-            label_key = ("labels_" + model.task_names[0] if multi_task
-                         else "labels")
-            with jax.named_scope("push_grads"):
-                clicks_flat = stacked[label_key].reshape(
-                    C * batch_size)[seg_flat // num_slots]
-                push_grads = build_push_grads(
-                    d_emb_flat, seg_flat % num_slots, clicks_flat,
-                    valid_flat)
-            if "uid_d16" in cpush:
-                # chunk-amortized uid wire, delta-coded (ONE decode +
-                # searchsorted + scatter for the whole chunk)
-                slab = push_sparse_uidwire(
-                    slab, decode_delta_uids(cpush["uid_base"],
-                                            cpush["uid_d16"],
-                                            cpush["uid_cut"],
-                                            table.pass_capacity),
-                    ids_flat, push_grads, sub, layout, conf,
-                    pulled_rows=None, write=uid_write)
-            elif "perm" not in cpush:
-                # chunk-amortized uid wire: one [C*K] sorted uid vector
-                # serves every batch of the chunk — dedup maps derive on
-                # device once per DISPATCH, not once per batch
-                slab = push_sparse_uidwire(
-                    slab, cpush["uids"], ids_flat, push_grads, sub,
-                    layout, conf, pulled_rows=rows, write=uid_write)
-            elif "pos" in cpush:
-                slab = push_sparse_rebuild(
-                    slab, cpush["uids"], cpush["pos"], cpush["perm"],
-                    cpush["inv"], push_grads, sub, layout, conf,
-                    pulled_rows=rows, first_idx=cpush["first"])
-            else:
-                slab = push_sparse_hostdedup(
-                    slab, cpush["uids"], cpush["perm"], cpush["inv"],
-                    push_grads, sub, layout, conf,
-                    pulled_rows=rows, first_idx=cpush["first"],
-                    write=("blocked" if uid_write == "blocked"
-                           else "scatter"))
-            return slab, params, opt_state, losses, preds, prng
-
-        # no example_count: the dense lax.scan body counts once (= one
-        # batch) but the chunk-wide sparse gather/pool/push operate on
-        # all C*K flat ids OUTSIDE the scan — no single divisor
-        # normalizes both, so the snapshot keeps honest totals
-        scan_chunk_fn = instrument_jit(
-            scan_chunk_fn, "scan_chunk", donate_argnums=(0,))
 
     def step_async(slab, params, batch, prng):
         """Async-dense variant: dense grads come back flat for the host
@@ -884,7 +676,6 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                         eval_step=eval_step,
                         batch_size=batch_size, num_slots=num_slots,
                         scan_steps=None if async_dense else scan_steps,
-                        scan_chunk=scan_chunk_fn,
                         uid_write=uid_write)
 
 
@@ -921,9 +712,6 @@ class BoxTrainer:
         self.quality = _quality.make_from_flags()
         self.async_mode = (self.cfg.async_mode
                            or self.cfg.sync_mode == "async")
-        self.sparse_chunk_sync = bool(self.cfg.sparse_chunk_sync)
-        if self.sparse_chunk_sync and self.cfg.scan_chunk < 1:
-            raise ValueError("sparse_chunk_sync needs scan_chunk >= 1")
         # resolved once here and refreshed at pass start — never per batch,
         # so one scan chunk can't mix rebuild and scatter host dicts (and an
         # invalid flag value fails at construction, not in a staging thread)
@@ -940,8 +728,6 @@ class BoxTrainer:
             feed.batch_size, self.num_slots, use_cvm,
             async_dense=self.async_mode,
             compute_dtype=self.cfg.compute_dtype,
-            sparse_chunk=(self.cfg.scan_chunk
-                          if self.sparse_chunk_sync else 0),
             uid_write=self._push_write)
         self.async_table = None
         self._unravel = None
@@ -969,9 +755,9 @@ class BoxTrainer:
         register_owner("dense_params", lambda: getattr(_w(), "params", None))
         register_owner("opt_state", lambda: getattr(_w(), "opt_state", None))
         self._stage_pool = None  # lazy host-staging thread pool
-        # largest unique-row domain staged so far, by occurrence count K
-        # (a batch's keys; a chunk's under sparse_chunk_sync): never
-        # shrinks, so the step compiles once a bucket (_trim_push_domain)
+        # largest unique-row domain staged so far, by a batch's occurrence
+        # count K: never shrinks, so the step compiles once a bucket
+        # (_trim_push_domain)
         self._push_domain_mark: Dict[int, int] = {}
         self._step_count = 0
         self._shuffle_rng = np.random.RandomState(seed + 1)
@@ -1043,43 +829,35 @@ class BoxTrainer:
 
     def _stage_one(self, b: PackedBatch
                    ) -> Tuple[Dict[str, np.ndarray], Optional[int]]:
-        # chunk-sync megasteps use ONE chunk-level dedup (_stack_batches);
-        # computing the per-batch products here would be pure waste in the
-        # staging hot path (tail batches go through host_batch directly
-        # and still get them)
-        return self._host_batch(b, self.table.lookup_ids(b.keys, b.valid),
-                                skip_push_dedup=self.sparse_chunk_sync)
+        return self._host_batch(b, self.table.lookup_ids(b.keys, b.valid))
 
     def _trim_push_domain(self, hosts: List[Dict[str, np.ndarray]],
-                          n_us: List[Optional[int]],
-                          keys: Tuple[str, ...] = ("uids", "first_idx")
-                          ) -> None:
-        """Cut the per-unique-row leaves of host dicts staged with the
-        full-wire dedup to ONE static domain U (pass_table.push_domain
-        of the largest real count among them): uids[:U] holds every real
-        uid, so the push merges, updates and writes the same rows to the
-        same bits over U index slots instead of one an occurrence. perm,
-        inv and ids are per occurrence and keep their [K]; the rebuild
-        map names real uids only and is the same either way. The mark
-        makes every dict of a trainer's life agree on U (tail batches
-        too) until a batch outgrows the bucket."""
+                          n_us: List[Optional[int]]) -> None:
+        """Cut the per-unique-row leaves (uids, first_idx) of staged train
+        dicts to ONE static domain U (pass_table.push_domain of the
+        largest real count among them): uids[:U] holds every real uid, so
+        the push merges, updates and writes the same rows to the same
+        bits over U index slots instead of one an occurrence. perm, inv
+        and ids are per occurrence and keep their [K]; the rebuild map
+        names real uids only and is the same either way. The mark makes
+        every dict of a trainer's life agree on U (tail batches too)
+        until a batch outgrows the bucket."""
         if not hosts or n_us[0] is None:
-            return          # eval, lean wire, chunk-sync: no host dedup
-        K = hosts[0][keys[0]].shape[0]
+            return          # eval batches carry no push leaves
+        K = hosts[0]["uids"].shape[0]
         U = push_domain(max(n_us), K, self._push_domain_mark.get(K, 0))
         self._push_domain_mark[K] = U
         stat_add("push_index_slots", U * len(hosts))
         stat_add("push_unique_rows", sum(n_us))
         for h in hosts:
-            for k in keys:
+            for k in ("uids", "first_idx"):
                 if k in h:
                     h[k] = h[k][:U]
 
-    def _stack_batches_host(self, group: List[PackedBatch]):
+    def _stack_batches_host(self, group: List[PackedBatch]
+                            ) -> Dict[str, np.ndarray]:
         """Stack a chunk of packed batches on a leading scan axis as HOST
-        arrays: dict, or (dict, mpos|cpush) in log / chunk-sync modes.
-        The device conversion is separate (_stack_batches / the grouped
-        H2D path) so N chunks can share one transfer per leaf."""
+        arrays (the device conversion is _stack_batches)."""
         pool = self._host_pool()
         if pool is not None and len(group) > 1:
             staged = list(pool.map(self._stage_one, group))
@@ -1087,104 +865,36 @@ class BoxTrainer:
             staged = [self._stage_one(b) for b in group]
         hosts = [h for h, _ in staged]
         self._trim_push_domain(hosts, [n for _, n in staged])
-        if self.sparse_chunk_sync:
-            # chunk-synchronous sparse: ONE dedup over the chunk's flat
-            # occurrence space (the per-batch products were never computed
-            # — _stage_one staged with skip_push_dedup)
-            ids_flat = np.concatenate([h["ids"] for h in hosts])
-            from paddlebox_tpu.config import flags as _flags
-            if _flags.get_flag("h2d_lean") and _flags.get_flag(
-                    "h2d_uid_wire"):
-                # chunk-amortized uid wire: the sorted [C*K] uid vector is
-                # the ONLY staged product; the megastep derives the maps
-                cpush = {}
-                self._stage_uid_wire(cpush, ids_flat)
-            else:
-                uids, perm, inv, n_u = dedup_ids(
-                    ids_flat, self.table.capacity,
-                    sort=self._push_write == "blocked")
-                cpush = {"uids": uids, "perm": perm, "inv": inv,
-                         "first": first_occurrence_idx(perm, inv)}
-                if self._push_write == "rebuild":
-                    cpush["pos"] = pos_for_rebuild(uids,
-                                                   self.table.capacity)
-                self._trim_push_domain([cpush], [n_u], ("uids", "first"))
-            return ({k: np.stack([h[k] for h in hosts]) for k in hosts[0]},
-                    cpush)
         return {k: np.stack([h[k] for h in hosts]) for k in hosts[0]}
 
-    def _stack_batches(self, group: List[PackedBatch]):
-        """Host-stack + one H2D per leaf (the single-chunk transfer path)."""
+    def _stack_batches(self, group: List[PackedBatch]
+                       ) -> Dict[str, jnp.ndarray]:
+        """Host-stack + one H2D per leaf."""
         staged = self._stack_batches_host(group)
         account_h2d(tree_nbytes(staged))  # device transfer ledger
-        if isinstance(staged, tuple):
-            stacked, cpush = staged
-            return ({k: jnp.asarray(v) for k, v in stacked.items()},
-                    {k: jnp.asarray(v) for k, v in cpush.items()})
         return {k: jnp.asarray(v) for k, v in staged.items()}
 
-    def _group_to_device(self, staged_list):
-        """Round-5 verdict item 4: convert G host-staged chunks to device
-        chunks with ONE jnp.asarray per LEAF for the whole group — the
-        fixed per-transfer cost amortizes /G (the MiniBatchGpuPack
-        stacked-pinned-copy role, data_feed.h:519-680).
-        Per-chunk views are device-side slices of the grouped arrays."""
-        account_h2d(tree_nbytes(staged_list))  # device transfer ledger
-        shapes = [{k: v.shape[1:] for k, v in d.items()}
-                  for d in staged_list]
-        if any(s != shapes[0] for s in shapes):
-            # the push's unique-row domain grew inside this group: the
-            # chunks on either side of the growth cannot share a buffer
-            return [{k: jnp.asarray(v) for k, v in d.items()}
-                    for d in staged_list]
-        sizes = [d["ids"].shape[0] for d in staged_list]
-        big = {k: jnp.asarray(np.concatenate([d[k] for d in staged_list]))
-               for k in staged_list[0]}
-        out, off = [], 0
-        for i in range(len(staged_list)):
-            out.append({k: big[k][off:off + sizes[i]] for k in big})
-            off += sizes[i]
-        return out
-
-    def _stage_uid_wire(self, out: Dict[str, np.ndarray],
-                        ids: np.ndarray) -> None:
-        """Stage the uid-wire dedup product into `out`: the sorted [K]
-        uid vector (round 8), or its (int32 base, int16 delta) coding
-        under wire_delta_ids. Used per batch (host_batch) and per chunk
-        (the chunk-sync cpush) — one definition so the wire format can't
-        diverge between the two."""
-        from paddlebox_tpu.config import flags as _flags
-        uids = self.table.uids_for_push(ids)
-        if _flags.get_flag("wire_delta_ids"):
-            base, d16, cut = delta_encode_uids(uids, self.table.capacity)
-            out["uid_base"] = base
-            out["uid_d16"] = d16
-            out["uid_cut"] = cut
-        else:
-            out["uids"] = uids
-
-    def host_batch(self, b: PackedBatch, ids: np.ndarray,
-                   skip_push_dedup: bool = False) -> Dict[str, np.ndarray]:
+    def host_batch(self, b: PackedBatch,
+                   ids: np.ndarray) -> Dict[str, np.ndarray]:
         """One batch's host dict as the one-step program takes it (tail
         batches, eval): the push's unique-row domain cut like a chunk's."""
-        out, n_u = self._host_batch(b, ids, skip_push_dedup)
+        out, n_u = self._host_batch(b, ids)
         self._trim_push_domain([out], [n_u])
         return out
 
-    def _host_batch(self, b: PackedBatch, ids: np.ndarray,
-                    skip_push_dedup: bool = False
+    def _host_batch(self, b: PackedBatch, ids: np.ndarray
                     ) -> Tuple[Dict[str, np.ndarray], Optional[int]]:
-        """(host dict, the full-wire dedup's real unique count or None
-        where no such dedup was staged); the per-unique-row leaves are
-        still [K]: the caller cuts them (_trim_push_domain)."""
-        # per-key slots/valid are derived on device (make_train_step);
-        # ids/segments/perm/inv/uids ride the H2D path, plus the [capacity]
-        # push_pos map in push_write=rebuild mode (the largest transfer —
-        # it buys removing the slab scatter from the step).
+        """The ONE producer of a batch's wire: (host dict, the push
+        dedup's real unique count; None for an eval batch, which carries
+        no push leaves). A train batch ships ids, segments, ins_valid,
+        labels [, dense, rank_offset, aux_offset, labels_<task>] plus the
+        host dedup that _sparse_push eats: uids, perm[K], inv[K]
+        [, first_idx][, push_pos[capacity]]. The per-unique-row leaves
+        are still [K] here: the caller cuts them (_trim_push_domain)."""
+        # per-key slots/valid are derived on device (make_train_step).
         # Touched-row accounting for the incremental EndPass happens in
         # table.lookup_ids (the `ids` passed here already marked the pass
-        # bitmap) — ONE accumulation point that covers every write path,
-        # including h2d_lean where no uids/perm/inv are staged at all.
+        # bitmap) — ONE accumulation point that covers every write path.
         out = {
             "ids": ids,
             "segments": b.segments,
@@ -1192,17 +902,7 @@ class BoxTrainer:
             "labels": b.labels,
         }
         n_u = None
-        from paddlebox_tpu.config import flags as _flags
-        if not self.table.test_mode and not skip_push_dedup \
-                and _flags.get_flag("h2d_lean"):
-            # lean wire: with h2d_uid_wire (default) the sorted uid vector
-            # is the ONLY staged dedup product (maps derive on device,
-            # round-8 reunification); with it off, nothing stages and the
-            # step dedups on device (see _sparse_push's branches)
-            if _flags.get_flag("h2d_uid_wire"):
-                self._stage_uid_wire(out, ids)
-            skip_push_dedup = True
-        if not self.table.test_mode and not skip_push_dedup:
+        if not self.table.test_mode:
             # train batches carry the host-precomputed push dedup (uids
             # included: rebuilding them on device is a scatter); eval
             # batches never push, so skip the dedup + extra transfers
@@ -1216,6 +916,8 @@ class BoxTrainer:
                 # and never consumes it, so don't compute/transfer it there
                 out["first_idx"] = first_occurrence_idx(perm, inv)
             if self._push_write == "rebuild":
+                # the largest transfer: it buys removing the slab scatter
+                # from the step
                 out["push_pos"] = self.table.pos_for_rebuild(uids)
         if b.dense is not None:
             out["dense"] = b.dense
@@ -1250,25 +952,21 @@ class BoxTrainer:
     def train_pass(self, dataset: BoxDataset,
                    preloaded: bool = False) -> Dict[str, float]:
         """One full pass: feed → build → train → metrics → end."""
-        from paddlebox_tpu.config import flags
         # live set_flag takes effect at pass boundaries only (mid-pass flips
         # would mix rebuild/scatter host dicts inside one scan chunk)
         self._push_write = resolve_push_write(
             capacity=self.table.capacity,
             batch_keys=self.feed.key_capacity())
-        if self._push_write != self.fns.uid_write and (
-                (flags.get_flag("h2d_lean")
-                 and flags.get_flag("h2d_uid_wire"))
-                or "blocked" in (self._push_write, self.fns.uid_write)):
-            # the uid wire derives its slab-write strategy ON DEVICE, and
-            # the full wire bakes blocked-vs-scatter into the jitted step
-            # too (round 11) — a live push_write flip cannot retarget
-            # either silently. Worse than silent: a flip OFF 'blocked'
-            # stops the staging sort (dedup_ids sort=False → native hash
-            # order) while the baked step still runs the blocked
-            # bucketize, which silently drops rows (the round-11
-            # sortedness hazard). Full-wire scatter<->rebuild stays live-
-            # retargetable: the push_pos dict structure retraces the step.
+        if self._push_write != self.fns.uid_write and "blocked" in (
+                self._push_write, self.fns.uid_write):
+            # blocked-vs-scatter is baked into the jitted step (round 11):
+            # a live push_write flip cannot retarget it silently. Worse
+            # than silent: a flip OFF 'blocked' stops the staging sort
+            # (dedup_ids sort=False → native hash order) while the baked
+            # step still runs the blocked bucketize, which silently drops
+            # rows (the round-11 sortedness hazard). scatter<->rebuild
+            # stays live-retargetable: the push_pos dict structure
+            # retraces the step.
             raise ValueError(
                 "push_write resolved to %r but the jitted step was "
                 "built with %r — construct a fresh trainer to change the "
@@ -1296,8 +994,7 @@ class BoxTrainer:
         chunk = max(1, self.cfg.scan_chunk)
         pending = worker_batches[0]
         state = self.table.slab
-        use_scan = (self.fns.scan_chunk is not None or
-                    (self.fns.scan_steps is not None and chunk > 1))
+        use_scan = self.fns.scan_steps is not None and chunk > 1
         if use_scan and len(pending) >= chunk:
             # megastep path: scan whole chunks in one dispatch each; the
             # remainder falls through to the per-step loop below
@@ -1326,33 +1023,18 @@ class BoxTrainer:
                     if self.dump_writer is not None:
                         self._dump_batch(preds_j, b)
 
-            if self.sparse_chunk_sync:
-                def scan_call(carry, staged):
-                    stacked, cpush = staged
-                    slab, params, opt_state, losses, preds, prng = \
-                        self.fns.scan_chunk(carry[0], carry[1], carry[2],
-                                            stacked, cpush, carry[3])
-                    return (slab, params, opt_state, prng), losses, preds
-            else:
-                def scan_call(carry, stacked):
-                    slab, params, opt_state, losses, preds, prng = \
-                        self.fns.scan_steps(carry[0], carry[1], carry[2],
-                                            stacked, carry[3])
-                    return (slab, params, opt_state, prng), losses, preds
+            def scan_call(carry, stacked):
+                slab, params, opt_state, losses, preds, prng = \
+                    self.fns.scan_steps(carry[0], carry[1], carry[2],
+                                        stacked, carry[3])
+                return (slab, params, opt_state, prng), losses, preds
 
             carry = (state, self.params, self.opt_state, prng)
-            tg = max(1, int(flags.get_flag("h2d_stack_chunks")))
-            if self.sparse_chunk_sync:
-                tg = 1   # cpush aux arrays keep their own per-chunk H2D
             carry, chunk_losses, n_done = run_scan_chunks(
-                scan_call, pending, chunk,
-                self._stack_batches_host if tg > 1 else self._stack_batches,
+                scan_call, pending, chunk, self._stack_batches,
                 carry, on_chunk, timer=self.timers["step"],
-                chunk1_ok=self.sparse_chunk_sync,
                 prefetch_depth=max(0, int(
-                    flags.get_flag("chunk_prefetch_depth"))),
-                transfer_group=tg,
-                group_fn=self._group_to_device if tg > 1 else None)
+                    flags.get_flag("chunk_prefetch_depth"))))
             state, self.params, self.opt_state, prng = carry
             self.table.set_slab(state)
             losses.extend(chunk_losses)

@@ -24,8 +24,7 @@ through :func:`make_lock` / :func:`make_rlock`, which
 
 When the flag is off (default) the factories return plain
 ``threading.Lock``/``RLock`` objects — a construction-time branch, zero
-per-acquire cost (bench.py's lockwatch_overhead block asserts the type
-identity).
+per-acquire cost (tests/test_lockwatch.py asserts the type identity).
 
 The StatRegistry's own ``_lock`` is deliberately NEVER watched: the
 release path publishes hold-time samples INTO the registry, so watching

@@ -519,29 +519,6 @@ def test_next_trace_id_unique_and_disjoint():
     assert step_trace_id(3, 12) >> 63 == 0         # step-id space
 
 
-# ------------------------------------------------------------ bench trend
-
-def test_bench_trend_deltas_and_regression_flag(tmp_path):
-    from tools.bench_trend import load_rounds, trend
-
-    def mk(n, value, platform="cpu", ms=10.0):
-        with open(tmp_path / ("BENCH_r%02d.json" % n), "w") as fh:
-            json.dump({"n": n, "parsed": {
-                "value": value, "platform": platform,
-                "steady_ms_per_step": ms}}, fh)
-
-    mk(1, 100.0)
-    mk(2, 85.0, ms=13.0)            # -15% rate, +30% ms: both regress
-    mk(3, 90.0, platform="tpu")     # platform flip: never compared
-    rounds = load_rounds(str(tmp_path))
-    assert [r["round"] for r in rounds] == [1, 2, 3]
-    out = trend(rounds, threshold=0.10)
-    flagged = {(r["metric"], r["to_round"]) for r in out["regressions"]}
-    assert flagged == {("value", 2), ("steady_ms_per_step", 2)}
-    cross = [r for r in out["rows"] if r["to_round"] == 3]
-    assert all(r["delta_pct"] is None for r in cross)
-
-
 # ------------------------------------------------------------ chaos leg
 
 @pytest.mark.slow

@@ -4,17 +4,14 @@ Contracts under test:
 
   * push_write='blocked' (push_blocked_write): bucketize the SORTED uid
     vector into contiguous row blocks, place each touched block with one
-    dynamic_update_slice — must be BIT-IDENTICAL to the scatter oracle on
-    every wire (host dedup products, uid wire), at chunk>1, multi-pass,
-    and through the sharded runners' 2-virtual-process staging. The
+    dynamic_update_slice — must be BIT-IDENTICAL to the scatter oracle
+    on BoxTrainer's wire at chunk>1, multi-pass, and through the sharded
+    runners' stagings (full products and uid wire, 2 virtual processes). The
     staging side must pin the sorted dedup tier (dedup_ids sort=True):
     the native rt_dedup hash order would silently drop rows.
   * push_blocked_pallas: the Mosaic placement kernel (interpreted on
     the CPU; its TPU lowering is checked by cross-lowering) is a
     drop-in for the fori_loop of dynamic_update_slices.
-  * push_onehot_rows (merge_grads_onehot): MXU one-hot accumulation for
-    the hot short tail — exact for integer-representable grads (f32
-    accumulation ORDER differs, so the parity pin uses integer grads).
   * slab_embed_dtype='bfloat16' (accessor slab codec): weight columns
     round to bf16 at the slab write; the header and ALL optimizer stats
     round-trip BIT-EXACTLY through encode/decode, the store/checkpoint
@@ -50,7 +47,7 @@ def data(tmp_path_factory):
 
 # ------------------------------------------------------------- unit tier
 
-def _unit_setup(seed=3, cap=512, K=96, hot_frac=0.0, int_grads=False):
+def _unit_setup(seed=3, cap=512, K=96):
     import jax
 
     from paddlebox_tpu.embedding.accessor import PushLayout, ValueLayout
@@ -61,18 +58,9 @@ def _unit_setup(seed=3, cap=512, K=96, hot_frac=0.0, int_grads=False):
                                  mf_initial_range=1e-3)
     push = PushLayout(D)
     slab = rng.rand(cap, layout.width).astype(np.float32)
-    if hot_frac:
-        # skewed batch: most occurrences hit a few hot keys
-        hot = rng.rand(K) < hot_frac
-        ids = np.where(hot, rng.randint(0, 4, K),
-                       rng.randint(0, cap // 2, K)).astype(np.int32)
-    else:
-        ids = rng.randint(0, cap // 2, K).astype(np.int32)
+    ids = rng.randint(0, cap // 2, K).astype(np.int32)
     ids[rng.rand(K) < 0.2] = cap - 1              # padding occurrences
-    if int_grads:
-        grads = rng.randint(-3, 4, (K, push.width)).astype(np.float32)
-    else:
-        grads = rng.randn(K, push.width).astype(np.float32)
+    grads = rng.randn(K, push.width).astype(np.float32)
     grads[:, push.SHOW] = 1.0
     grads[ids == cap - 1] = 0.0
     prng = jax.random.PRNGKey(11)
@@ -214,44 +202,6 @@ def test_resolve_blocked_validation():
         flags.set_flag("push_write", "auto")
 
 
-def test_merge_grads_onehot_exact_for_integer_grads():
-    """push_onehot_rows: the MXU one-hot merge == segment-sum merge
-    exactly when grads are integer-representable (f32 addition is exact
-    on small integers regardless of order) — and the full uid-wire push
-    under the flag stays bit-identical to the oracle on such grads."""
-    import jax.numpy as jnp
-
-    from paddlebox_tpu.embedding.optimizers import (merge_grads_onehot,
-                                                    push_sparse_uidwire)
-    from paddlebox_tpu.embedding.pass_table import dedup_uids_sorted
-
-    layout, conf, push, slab, ids, grads, prng = _unit_setup(
-        seed=9, hot_frac=0.7, int_grads=True)
-    cap = slab.shape[0]
-    K = ids.shape[0]
-    suids = dedup_uids_sorted(ids, cap)
-    inv = np.searchsorted(suids, ids).astype(np.int32)
-    import jax.ops
-    ref = jax.ops.segment_sum(jnp.asarray(grads), jnp.asarray(inv),
-                              num_segments=K)
-    for hot in (1, 4, K):
-        got = merge_grads_onehot(jnp.asarray(grads), jnp.asarray(inv), K,
-                                 hot)
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got),
-                                      err_msg=f"hot={hot}")
-    oracle = push_sparse_uidwire(jnp.asarray(slab), jnp.asarray(suids),
-                                 jnp.asarray(ids), jnp.asarray(grads),
-                                 prng, layout, conf)
-    flags.set_flag("push_onehot_rows", 4)
-    try:
-        got = push_sparse_uidwire(jnp.asarray(slab), jnp.asarray(suids),
-                                  jnp.asarray(ids), jnp.asarray(grads),
-                                  prng, layout, conf)
-    finally:
-        flags.set_flag("push_onehot_rows", 0)
-    np.testing.assert_array_equal(np.asarray(oracle), np.asarray(got))
-
-
 def test_dedup_ids_sort_option():
     """dedup_ids(sort=True): strictly ascending uids with consistent
     perm/inv (the blocked-write staging contract), even when the native
@@ -380,16 +330,13 @@ def test_bf16_differentiable_pull_fails_loud():
 
 # -------------------------------------------------------------- e2e tier
 
-def run_mode(files, feed, mode, wire=None, block=256, passes=2,
+def run_mode(files, feed, mode, block=256, passes=2,
              embed_dtype="float32", seed=0):
     """Train the single-host trainer; returns (losses, store keys/values,
-    dense params). wire None = full host products, 'uid' = uid wire."""
+    dense params)."""
     flags.set_flag("push_write", mode)
     flags.set_flag("push_block_rows", block)
     flags.set_flag("slab_embed_dtype", embed_dtype)
-    if wire is not None:
-        flags.set_flag("h2d_lean", True)
-        flags.set_flag("h2d_uid_wire", wire == "uid")
     try:
         table = TableConfig(
             embedx_dim=D, pass_capacity=2048,
@@ -415,8 +362,6 @@ def run_mode(files, feed, mode, wire=None, block=256, passes=2,
         flags.set_flag("push_write", "auto")
         flags.set_flag("push_block_rows", 1024)
         flags.set_flag("slab_embed_dtype", "float32")
-        flags.set_flag("h2d_lean", False)
-        flags.set_flag("h2d_uid_wire", True)
 
 
 def assert_identical(a, b):
@@ -430,27 +375,12 @@ def assert_identical(a, b):
         assert np.array_equal(np.asarray(xa), np.asarray(xb))
 
 
-@pytest.mark.slow
 def test_blocked_e2e_matches_scatter_full_wire(data):
-    """push_write=blocked on the FULL host wire (sorted dedup staging) at
-    chunk>1 over 2 passes: bit-identical training to scatter.
-
-    Slow tier (round 14, budget): a 2-pass composition of contracts
-    tier-1 keeps pinned individually — unit blocked-vs-scatter parity,
-    the uid-wire e2e below (the default wire), and the dedup sort=True
-    staging contract in test_wire_modes."""
+    """push_write=blocked on BoxTrainer's wire (sorted dedup staging) at
+    chunk>1 over 2 passes: bit-identical training to scatter."""
     files, feed = data
     base = run_mode(files, feed, "scatter")
     blocked = run_mode(files, feed, "blocked")
-    assert_identical(base, blocked)
-
-
-def test_blocked_e2e_matches_scatter_uid_wire(data):
-    """push_write=blocked on the uid wire (device-derived maps over the
-    sorted staged uids): bit-identical to the scatter uid wire."""
-    files, feed = data
-    base = run_mode(files, feed, "scatter", wire="uid", passes=1)
-    blocked = run_mode(files, feed, "blocked", wire="uid", passes=1)
     assert_identical(base, blocked)
 
 

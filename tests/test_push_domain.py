@@ -165,7 +165,7 @@ def data(tmp_path_factory):
     return files, type(feed)(slots=feed.slots, batch_size=64)
 
 
-def _trainer(feed, scan_chunk=2, seed=0, chunk_sync=False):
+def _trainer(feed, scan_chunk=2, seed=0):
     from paddlebox_tpu.train import BoxTrainer
     table = TableConfig(
         embedx_dim=D, pass_capacity=2048,
@@ -174,9 +174,7 @@ def _trainer(feed, scan_chunk=2, seed=0, chunk_sync=False):
     model = CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D),
                    hidden=(16,))
     return BoxTrainer(model, table, feed,
-                      TrainerConfig(scan_chunk=scan_chunk,
-                                    sparse_chunk_sync=chunk_sync),
-                      seed=seed)
+                      TrainerConfig(scan_chunk=scan_chunk), seed=seed)
 
 
 def _fake_hosts(K, n_us):
@@ -187,8 +185,8 @@ def _fake_hosts(K, n_us):
 
 def test_domain_is_a_high_water_mark_on_the_trainer(data):
     """U over a trainer's life: the bucket of the largest count seen for
-    this K, never smaller again; perm stays [K]; another K (a chunk's
-    flat occurrence space) keeps a mark of its own."""
+    this K, never smaller again; perm stays [K]; another K keeps a mark
+    of its own."""
     _files, feed = data
     tr = _trainer(feed)
     try:
@@ -204,7 +202,7 @@ def test_domain_is_a_high_water_mark_on_the_trainer(data):
         other = _fake_hosts(4 * K, [50])
         tr._trim_push_domain(other, [50])
         assert other[0]["uids"].shape == (64,)
-        # a stage with no host dedup (eval, lean wire) is left alone
+        # a stage with no host dedup (eval) is left alone
         plain = [{"ids": np.arange(K, dtype=np.int32)}]
         tr._trim_push_domain(plain, [None])
         assert plain[0]["ids"].shape == (K,)
@@ -232,7 +230,6 @@ def _run_pass(files, feed, padded: bool):
     rule = (lambda n_u, K, floor=0: K) if padded else push_domain
     try:
         with mock.patch.object(trainer_mod, "push_domain", rule):
-            flags.set_flag("h2d_stack_chunks", 2)   # the spied stage path
             ds = BoxDataset(feed, read_threads=1)
             ds.set_filelist(files)
             loss = tr.train_pass(ds)["loss"]
@@ -262,11 +259,9 @@ def test_one_bucket_one_program_and_the_padded_stagings_store(data):
     np.testing.assert_array_equal(vals_t, vals_p)
 
 
-@pytest.mark.parametrize("chunk_sync", [False, True],
-                         ids=["per_batch", "chunk_sync"])
-def test_counters_add_slots_and_rows_a_staged_step(data, chunk_sync):
+def test_counters_add_slots_and_rows_a_staged_step(data):
     files, feed = data
-    tr = _trainer(feed, chunk_sync=chunk_sync)
+    tr = _trainer(feed)
     try:
         ds = BoxDataset(feed, read_threads=1)
         ds.set_filelist(files[:1])
@@ -280,24 +275,16 @@ def test_counters_add_slots_and_rows_a_staged_step(data, chunk_sync):
         slots0 = stat_get("push_index_slots")
         rows0 = stat_get("push_unique_rows")
         staged = tr._stack_batches_host(batches)
-        if chunk_sync:
-            _stacked, cpush = staged
-            U, pushes = cpush["uids"].shape[0], 1
-            want_rows = np.unique(np.concatenate(per_batch)).size
-            assert cpush["first"].shape == (U,)
-            assert cpush["perm"].shape == (2 * feed.key_capacity(),)
-        else:
-            U, pushes = staged["uids"].shape[1], 2
-            want_rows = sum(u.size for u in per_batch)
-        assert stat_get("push_index_slots") - slots0 == U * pushes
-        assert stat_get("push_unique_rows") - rows0 == want_rows
+        U = staged["uids"].shape[1]
+        assert stat_get("push_index_slots") - slots0 == U * 2
+        assert stat_get("push_unique_rows") - rows0 == sum(
+            u.size for u in per_batch)
         # the one-step program's batch follows the same mark
         one = tr.host_batch(batches[0],
                             tr.table.lookup_ids(batches[0].keys,
                                                 batches[0].valid))
-        if not chunk_sync:
-            assert one["uids"].shape == (U,)
-            assert stat_get("push_index_slots") - slots0 == U * 3
+        assert one["uids"].shape == (U,)
+        assert stat_get("push_index_slots") - slots0 == U * 3
         tr.table.end_pass()
         ds.release_memory()
     finally:

@@ -1,27 +1,34 @@
-"""H2D wire modes — the round-8 lean-wire push reunification.
+"""The train batch's wire, and the sharded runners' uid wire.
 
-Contract under test: every wire the trainer can stage a train batch on
-must train BIT-IDENTICALLY to the full host-staged oracle (the
-perm/inv/uids/first_idx wire), because the content-addressed lazy-init
-randoms and the ascending-occurrence merge order make the push a pure
-function of (slab, batch, prng) regardless of WHERE the dedup ran:
+A BoxTrainer train batch has ONE wire. BoxTrainer._host_batch makes it on
+the stager thread: ids, segments, ins_valid, labels [, dense, rank_offset,
+aux_offset, labels_<task>] plus the host dedup of the batch's ids, uids[U]
+and first_idx[U] over the push's unique-row domain (pass_table.push_domain),
+perm[K] and inv[K] per occurrence, push_pos[capacity] under
+push_write=rebuild; an eval batch carries no push leaf. _sparse_push inside
+make_train_step is the one consumer: push_sparse_rebuild where push_pos is
+there, push_sparse_hostdedup(write=scatter|blocked) otherwise.
 
-  * uid wire (h2d_lean + h2d_uid_wire, the default lean config): the
-    sorted [K] uid vector ships; inv/first (and the rebuild pos) derive
-    on device by searchsorted — push_sparse_uidwire
-  * ids-only wire (h2d_uid_wire off): the round-5 tier — nothing ships,
-    jnp.unique dedups in the step
-  * delta wire (wire_delta_ids): uids ship as (int32 base, int16 deltas)
-  * chunk-amortized: sparse_chunk_sync stages ONE uid vector per scan
-    chunk ([C*K]) that serves every batch of the chunk
-  * sharded: only per-destination uids stage (stage_push_dedup
-    uid_only); the step derives the maps from the a2a'd bucket ids —
-    composes with the 2-process host-plane bucket exchange
+Contracts under test:
 
-The motivation (wire bytes vs device-sort trade) is what bench.py's e2e
-ladder measures."""
+  * whatever the write and the chunking, the wire trains BIT-IDENTICALLY to
+    the plain reference, push_sparse_dedup (device jnp.unique over the
+    batch's ids, no host product): the content-addressed lazy-init randoms
+    and the ascending-occurrence merge order make the push a pure function
+    of (slab, ids, grads, prng) regardless of WHERE the dedup ran;
+  * the exact leaf set, shapes and dtypes of host_batch's and
+    _stack_batches_host's dicts;
+  * predict_batches after a trained pass returns the same bits whichever
+    chunking trained it;
+  * the switches that selected the deleted wires fail loud;
+  * sharded: only per-destination sorted uids stage (h2d_uid_wire,
+    stage_push_dedup uid_only); the step derives the maps from the a2a'd
+    bucket ids (push_sparse_uidwire) and composes with the 2-process
+    host-plane bucket exchange; the sorted-uid contract holds on every
+    sharded staging path."""
 
-import dataclasses
+import contextlib
+import unittest.mock as mock
 
 import numpy as np
 import pytest
@@ -30,12 +37,15 @@ from paddlebox_tpu.config import flags
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig, TableConfig,
                                           TrainerConfig)
 from paddlebox_tpu.data import BoxDataset, write_synthetic_ctr_files
+from paddlebox_tpu.embedding.pass_table import push_domain
 from paddlebox_tpu.models import CtrDnn
 from paddlebox_tpu.models.base import ModelSpec
 from paddlebox_tpu.train import BoxTrainer
 
 D = 4
 NUM_SLOTS = 4
+CAPACITY = 2048
+WRITES = ("scatter", "rebuild", "blocked")
 
 
 @pytest.fixture(scope="module")
@@ -50,39 +60,89 @@ def data(tmp_path_factory):
     return files, feed
 
 
-def run_mode(files, feed, mode, wire=None, scan_chunk=2, passes=2,
-             chunk_sync=False):
-    """wire: None = full host products | 'uid' | 'ids_only' | 'delta'."""
+@contextlib.contextmanager
+def push_write(mode):
     flags.set_flag("push_write", mode)
-    if wire is not None:
-        flags.set_flag("h2d_lean", True)
-        flags.set_flag("h2d_uid_wire", wire != "ids_only")
-        flags.set_flag("wire_delta_ids", wire == "delta")
+    flags.set_flag("push_block_rows", 256)
     try:
-        table = TableConfig(
-            embedx_dim=D, pass_capacity=2048,
-            optimizer=SparseOptimizerConfig(
-                mf_create_thresholds=0.0, mf_initial_range=1e-3))
-        model = CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D),
-                       hidden=(16,))
-        tr = BoxTrainer(model, table, feed, TrainerConfig(
-            scan_chunk=scan_chunk, sparse_chunk_sync=chunk_sync), seed=0)
-        losses = []
-        for p in range(passes):
-            ds = BoxDataset(feed, read_threads=1)
-            ds.set_filelist(files)
-            losses.append(tr.train_pass(ds)["loss"])
-            ds.release_memory()
-        keys, vals = tr.table.store.state_items()
-        order = np.argsort(keys)
-        params = tr.params
-        tr.close()
-        return losses, keys[order], vals[order], params
+        yield
     finally:
         flags.set_flag("push_write", "auto")
-        flags.set_flag("h2d_lean", False)
-        flags.set_flag("h2d_uid_wire", True)
-        flags.set_flag("wire_delta_ids", False)
+        flags.set_flag("push_block_rows", 1024)
+
+
+@contextlib.contextmanager
+def reference_push(on=True):
+    """Test-only: while a step is traced under this, the two push calls
+    of _sparse_push hand the batch's ids (as the pull saw them) and the
+    same grads to push_sparse_dedup: device jnp.unique, none of the
+    host's dedup products read. Yields a dict whose "pushes" counts the
+    substituted calls, so that a run can show it took this road; with
+    on=False nothing is substituted and None is yielded."""
+    if not on:
+        yield None
+        return
+    import paddlebox_tpu.train.trainer as trainer_mod
+    from paddlebox_tpu.embedding.optimizers import push_sparse_dedup
+    seen = {"pushes": 0}
+    gather = trainer_mod.gather_slab_rows
+
+    def spy_gather(slab, ids, layout):
+        seen["ids"] = ids
+        return gather(slab, ids, layout)
+
+    def ref_hostdedup(slab, uids, perm, inv, grads, prng, layout, conf,
+                      **_kw):
+        seen["pushes"] += 1
+        return push_sparse_dedup(slab, seen["ids"], grads, prng, layout,
+                                 conf)
+
+    def ref_rebuild(slab, uids, pos, *rest, **kw):
+        return ref_hostdedup(slab, uids, *rest, **kw)
+
+    with mock.patch.object(trainer_mod, "gather_slab_rows", spy_gather), \
+            mock.patch.object(trainer_mod, "push_sparse_hostdedup",
+                              ref_hostdedup), \
+            mock.patch.object(trainer_mod, "push_sparse_rebuild",
+                              ref_rebuild):
+        yield seen
+
+
+def make_trainer(feed, scan_chunk=2):
+    table = TableConfig(
+        embedx_dim=D, pass_capacity=CAPACITY,
+        optimizer=SparseOptimizerConfig(
+            mf_create_thresholds=0.0, mf_initial_range=1e-3))
+    model = CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D),
+                   hidden=(16,))
+    return BoxTrainer(model, table, feed,
+                      TrainerConfig(scan_chunk=scan_chunk), seed=0)
+
+
+def train(tr, files, feed, passes):
+    losses = []
+    for _ in range(passes):
+        ds = BoxDataset(feed, read_threads=1)
+        ds.set_filelist(files)
+        losses.append(tr.train_pass(ds)["loss"])
+        ds.release_memory()
+    return losses
+
+
+def run_mode(files, feed, mode, scan_chunk=2, passes=2, reference=False):
+    """(losses, store keys, store values, dense params) of a trainer built
+    and trained under push_write=mode; reference=True trains it through
+    reference_push instead of the wire's own push."""
+    with push_write(mode), reference_push(reference) as ref:
+        tr = make_trainer(feed, scan_chunk)
+        try:
+            losses = train(tr, files, feed, passes)
+            assert ref is None or ref["pushes"] > 0
+            keys, vals = tr.table.store.state_items()
+            order = np.argsort(keys)
+            return losses, keys[order], vals[order], tr.params
+        finally:
+            tr.close()
 
 
 def assert_identical(a, b):
@@ -96,49 +156,177 @@ def assert_identical(a, b):
         assert np.array_equal(np.asarray(xa), np.asarray(xb))
 
 
-# ------------------------------------------------------ single-host wires
-def test_uid_wire_matches_host_dedup_chunked(data):
-    """The reunified lean wire at scan_chunk>1 and multiple passes must be
-    bit-identical to the full host-staged scatter oracle."""
+# ------------------------------------------- the wire against the reference
+@pytest.fixture(scope="module")
+def reference_runs(data):
+    """One reference run a chunking, shared by the three writes."""
     files, feed = data
-    base = run_mode(files, feed, "scatter")
-    uid = run_mode(files, feed, "scatter", wire="uid")
-    assert_identical(base, uid)
+    runs = {}
+
+    def get(scan_chunk):
+        if scan_chunk not in runs:
+            runs[scan_chunk] = run_mode(files, feed, "scatter", scan_chunk,
+                                        reference=True)
+        return runs[scan_chunk]
+    return get
 
 
-def test_uid_wire_rebuild_matches_host_rebuild(data):
-    """push_write=rebuild under the uid wire (pos derived ON DEVICE by an
-    int32 scatter) vs the host-staged [capacity] pos map."""
+@pytest.mark.parametrize("scan_chunk", [1, 2])
+@pytest.mark.parametrize("write", WRITES)
+def test_full_wire_matches_reference_push(data, reference_runs, write,
+                                          scan_chunk):
+    """Two passes over recurring keys, embeddings created on the way
+    (mf_initial_range > 0): losses, store rows and dense params of the
+    host-dedup wire equal, bit for bit, those of a run whose push is
+    push_sparse_dedup over the same ids and grads — per step (chunk 1)
+    and through scan_steps (chunk 2)."""
     files, feed = data
-    base = run_mode(files, feed, "rebuild", passes=1)
-    uid = run_mode(files, feed, "rebuild", wire="uid", passes=1)
-    assert_identical(base, uid)
+    ref = reference_runs(scan_chunk)
+    assert len(ref[1]) and np.isfinite(ref[0]).all()
+    assert_identical(ref, run_mode(files, feed, write, scan_chunk))
 
 
-def test_delta_wire_matches(data):
-    """wire_delta_ids: (base, int16 delta)-coded uids decode on device to
-    the same sorted vector — identical training, 2 bytes/key less wire."""
+def _leaf_specs(tree):
+    return {k: (v.shape, v.dtype) for k, v in tree.items()}
+
+
+@pytest.mark.parametrize("mode", ["train", "test_mode"])
+@pytest.mark.parametrize("write", WRITES)
+def test_wire_contract(data, write, mode):
+    """The exact leaves of host_batch's dict and of _stack_batches_host's
+    (a dict with a leading chunk axis, never a tuple): uids/first_idx of
+    length U = push_domain(...), perm/inv of K, push_pos only under
+    rebuild, uids ascending under blocked, NO push leaf in test mode."""
     files, feed = data
-    base = run_mode(files, feed, "scatter", passes=1)
-    delta = run_mode(files, feed, "scatter", wire="delta", passes=1)
-    assert_identical(base, delta)
+    K, B = feed.key_capacity(), feed.batch_size
+    i32 = np.dtype(np.int32)
+    with push_write(write):
+        tr = make_trainer(feed)
+        try:
+            tr.table.set_test_mode(mode == "test_mode")
+            ds = BoxDataset(feed, read_threads=1)
+            ds.set_filelist(files[:1])
+            tr.table.begin_feed_pass()
+            ds.load_into_memory(add_keys_fn=tr.table.add_keys)
+            tr.table.end_feed_pass()
+            tr.table.begin_pass()
+            batches = ds.split_batches(num_workers=1)[0][:3]
+            ids = [tr.table.lookup_ids(b.keys, b.valid) for b in batches]
+            want = {"ids": ((K,), i32),
+                    "segments": ((K,), batches[0].segments.dtype),
+                    "ins_valid": ((B,), np.dtype(bool)),
+                    "labels": ((B,), batches[0].labels.dtype)}
+            n_us = [np.unique(x).size for x in ids]
+            if mode == "train":
+                U1 = push_domain(n_us[0], K)
+                U2 = push_domain(max(n_us[1:]), K, U1)
+                want.update(uids=((U1,), i32), first_idx=((U1,), i32),
+                            perm=((K,), i32), inv=((K,), i32))
+                if write == "rebuild":
+                    want["push_pos"] = ((CAPACITY,), i32)
+            one = tr.host_batch(batches[0], ids[0])
+            assert _leaf_specs(one) == want
+            np.testing.assert_array_equal(one["ids"], ids[0])
+            staged = tr._stack_batches_host(batches[1:])
+            assert isinstance(staged, dict)
+            for k in ("uids", "first_idx"):
+                if k in want:
+                    want[k] = ((U2,), i32)
+            assert _leaf_specs(staged) == {
+                k: ((2,) + shp, dt) for k, (shp, dt) in want.items()}
+            if mode == "train":
+                assert U1 < K
+                real = np.sort(one["uids"][:n_us[0]])
+                np.testing.assert_array_equal(real, np.unique(ids[0]))
+                assert (one["uids"][n_us[0]:] >= CAPACITY).all()
+                if write == "blocked":
+                    assert (np.diff(one["uids"].astype(np.int64)) > 0).all()
+                    assert (np.diff(staged["uids"].astype(np.int64),
+                                    axis=1) > 0).all()
+            tr.table.end_pass()
+            tr.table.set_test_mode(False)
+            ds.release_memory()
+        finally:
+            tr.close()
 
 
-def test_ids_only_lean_matches_host_dedup(data):
-    """The round-5 ids-only wire (h2d_uid_wire off): device-side
-    jnp.unique dedup with the minimal wire — the content-addressed
-    lazy-init randoms make created rows independent of WHERE the dedup
-    ran."""
+# --------------------------------------------------------- eval after train
+def _train_and_predict(files, feed, scan_chunk, reference=False):
+    with reference_push(reference) as ref:
+        tr = make_trainer(feed, scan_chunk)
+        try:
+            train(tr, files, feed, 1)
+            assert ref is None or ref["pushes"] > 0
+            ds = BoxDataset(feed, read_threads=1)
+            ds.set_filelist(files[:1])
+            ds.load_into_memory()
+            return tr.predict_batches(ds)
+        finally:
+            tr.close()
+
+
+@pytest.fixture(scope="module")
+def reference_preds(data):
     files, feed = data
-    base = run_mode(files, feed, "scatter", passes=1)
-    lean = run_mode(files, feed, "auto", wire="ids_only", passes=1)
-    assert_identical(base, lean)
+    return _train_and_predict(files, feed, 1, reference=True)
 
 
-def test_ids_only_lean_rejects_host_map_modes(data):
+@pytest.mark.parametrize("scan_chunk", [1, 2, 4])
+def test_eval_after_train_bit_equal(data, reference_preds, scan_chunk):
+    """SetTestMode after one trained pass: eval batches stage no push
+    product, create nothing, and predict_batches returns the bits that a
+    per-step run on the reference push returns, whichever chunking
+    trained the table."""
     files, feed = data
-    with pytest.raises(ValueError, match="h2d_lean"):
-        run_mode(files, feed, "rebuild", wire="ids_only", passes=1)
+    preds, labels = _train_and_predict(files, feed, scan_chunk)
+    assert preds.size and np.array_equal(labels, reference_preds[1])
+    assert np.array_equal(preds, reference_preds[0])
+
+
+# ------------------------------------------------------- what went, loudly
+REMOVED_SWITCHES = [      # (name, is a flag; else a TrainerConfig field)
+    ("h2d_lean", True), ("wire_delta_ids", True), ("h2d_stack_chunks", True),
+    ("push_onehot_rows", True), ("sparse_chunk_sync", False)]
+
+
+@pytest.mark.parametrize("name,is_flag", REMOVED_SWITCHES,
+                         ids=[n for n, _ in REMOVED_SWITCHES])
+def test_removed_switches_fail_loud(name, is_flag):
+    """The four flags and the TrainerConfig field that selected the lean
+    wires, the grouped transfer, the one-hot merge and the
+    chunk-synchronous step are gone: setting one is an error, not a
+    silent no-op."""
+    if is_flag:
+        with pytest.raises(KeyError, match=name):
+            flags.set_flag(name, 1)
+    else:
+        with pytest.raises(TypeError, match=name):
+            TrainerConfig(**{name: True})
+
+
+def test_train_batch_without_perm_raises(data):
+    """A train batch that reaches the step without the host dedup raises
+    at trace time; the step never falls back to a device sort."""
+    files, feed = data
+    tr = make_trainer(feed)
+    try:
+        ds = BoxDataset(feed, read_threads=1)
+        ds.set_filelist(files[:1])
+        tr.table.begin_feed_pass()
+        ds.load_into_memory(add_keys_fn=tr.table.add_keys)
+        tr.table.end_feed_pass()
+        tr.table.begin_pass()
+        b = ds.split_batches(num_workers=1)[0][0]
+        batch = tr.device_batch(b, tr.table.lookup_ids(b.keys, b.valid))
+        for k in ("perm", "inv", "uids", "first_idx"):
+            del batch[k]
+        with pytest.raises(KeyError, match="host dedup"):
+            tr.fns.step(tr.table.slab, tr.params, tr.opt_state, batch,
+                        tr.table.next_prng())
+        tr.table.end_pass()
+        ds.release_memory()
+    finally:
+        tr.close()
 
 
 def test_push_write_log_deleted(data):
@@ -147,82 +335,6 @@ def test_push_write_log_deleted(data):
     files, feed = data
     with pytest.raises(ValueError, match="round 8"):
         run_mode(files, feed, "log", passes=1)
-
-
-def test_grouped_h2d_matches_per_chunk(data):
-    """h2d_stack_chunks>1 (round-5 verdict item 4): G chunks sharing one
-    transfer per leaf — with device-side slicing back to per-chunk views
-    — must be bit-identical to per-chunk transfers, on the full AND the
-    uid wire."""
-    files, feed = data
-    for wire in (None, "uid"):
-        base = run_mode(files, feed, "scatter", wire=wire)
-        flags.set_flag("h2d_stack_chunks", 4)
-        try:
-            grouped = run_mode(files, feed, "scatter", wire=wire)
-        finally:
-            flags.set_flag("h2d_stack_chunks", 1)
-        assert_identical(base, grouped)
-
-
-# ------------------------------------------------- chunk-amortized dedup
-def test_chunk_sync_uid_wire_matches(data):
-    """sparse_chunk_sync + uid wire: ONE sorted [C*K] uid vector per scan
-    chunk serves every batch (the chunk-amortized dedup) — bit-identical
-    to the chunk-sync path with full host-staged cpush products."""
-    files, feed = data
-    base = run_mode(files, feed, "scatter", chunk_sync=True)
-    uid = run_mode(files, feed, "scatter", wire="uid", chunk_sync=True)
-    assert_identical(base, uid)
-
-
-def test_chunk_sync_delta_wire_matches(data):
-    files, feed = data
-    base = run_mode(files, feed, "scatter", chunk_sync=True, passes=1)
-    delta = run_mode(files, feed, "scatter", wire="delta", chunk_sync=True,
-                     passes=1)
-    assert_identical(base, delta)
-
-
-# ------------------------------------------------------------- test_mode
-def test_uid_wire_test_mode(data):
-    """SetTestMode under the uid wire: eval batches stage no push
-    products on ANY wire (no creation, no write-back), and a uid-wire-
-    trained table serves bit-identical predictions to the host-wire
-    oracle."""
-    files, feed = data
-
-    def train_and_predict(wire):
-        if wire is not None:
-            flags.set_flag("h2d_lean", True)
-        try:
-            table = TableConfig(
-                embedx_dim=D, pass_capacity=2048,
-                optimizer=SparseOptimizerConfig(
-                    mf_create_thresholds=0.0, mf_initial_range=1e-3))
-            model = CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D),
-                           hidden=(16,))
-            tr = BoxTrainer(model, table, feed,
-                            TrainerConfig(scan_chunk=2), seed=0)
-            ds = BoxDataset(feed, read_threads=1)
-            ds.set_filelist(files)
-            tr.train_pass(ds)
-            ds.release_memory()
-            ds = BoxDataset(feed, read_threads=1)
-            ds.set_filelist(files[:1])
-            tr.table.begin_feed_pass()
-            ds.load_into_memory(add_keys_fn=tr.table.add_keys)
-            tr.table.end_feed_pass()
-            preds, labels = tr.predict_batches(ds)
-            tr.close()
-            return preds, labels
-        finally:
-            flags.set_flag("h2d_lean", False)
-
-    p_base, l_base = train_and_predict(None)
-    p_uid, l_uid = train_and_predict("uid")
-    assert np.array_equal(l_base, l_uid)
-    assert np.array_equal(p_base, p_uid)
 
 
 # ------------------------------------------------------------ unit tier
@@ -281,45 +393,6 @@ def test_push_sparse_uidwire_unit():
                                   jnp.asarray(ids), jnp.asarray(grads),
                                   prng, layout, conf, write="rebuild")
     np.testing.assert_array_equal(np.asarray(host_rb), np.asarray(wire_rb))
-
-
-def test_delta_encode_decode_unit():
-    """Host coding invariants: exact round trip, padding recode to
-    in-range ids stays unique/nondecreasing, oversize gaps fail loud."""
-    import jax.numpy as jnp
-
-    from paddlebox_tpu.embedding.optimizers import decode_delta_uids
-    from paddlebox_tpu.embedding.pass_table import (dedup_uids_sorted,
-                                                    delta_encode_uids)
-
-    cap = 1 << 14
-    ids = np.array([5, 9, 5, 100, 2, cap - 1, cap - 1, 9], np.int32)
-    uids = dedup_uids_sorted(ids, cap)
-    assert np.all(np.diff(uids.astype(np.int64)) > 0)
-    base, d16, cut = delta_encode_uids(uids, cap)
-    assert d16.dtype == np.int16 and d16[0] == 0
-    dec = np.asarray(decode_delta_uids(jnp.asarray(base),
-                                       jnp.asarray(d16),
-                                       jnp.asarray(cut), cap))
-    # trash id (cap-1) present -> exact round trip incl. padding tail
-    np.testing.assert_array_equal(dec, uids)
-    # the data region is exempt from the trash jump: gaps beyond int16
-    # only count BELOW the trash id, so this shape still encodes
-    assert cut == 4
-
-    # no trash id in the batch -> the tail decodes to [trash, padding...]
-    # (trash maps no occurrence; only its own bits can be written back)
-    ids2 = np.array([5, 9, 5, 2], np.int32)
-    uids2 = dedup_uids_sorted(ids2, cap)
-    base2, d2, cut2 = delta_encode_uids(uids2, cap)
-    dec2 = np.asarray(decode_delta_uids(jnp.asarray(base2),
-                                        jnp.asarray(d2),
-                                        jnp.asarray(cut2), cap))
-    np.testing.assert_array_equal(dec2[:3], [2, 5, 9])
-    assert dec2[3] == cap - 1 and np.all(np.diff(dec2) > 0)
-
-    with pytest.raises(ValueError, match="int16"):
-        delta_encode_uids(np.array([0, 1 << 20], np.int32), 1 << 21)
 
 
 # -------------------------------------------------------------- sharded
@@ -460,12 +533,12 @@ def _assert_strictly_ascending(uids, where):
         "(first break at %d)" % (where, int(np.argmin(d > 0)))
 
 
-def test_dedup_uids_sorted_contract_all_paths(data):
-    """Round-10 satellite: assert the sorted-uid contract on EVERY host
-    staging path — the raw helper (whose native rt_dedup sibling returns
-    hash-probe ORDER, so a refactor absorbing one into the other would
-    corrupt silently), the single-host batch wire, the chunk-amortized
-    chunk-sync wire, and the per-destination sharded staging."""
+def test_dedup_uids_sorted_contract_all_paths():
+    """Round-10 satellite: assert the sorted-uid contract on every host
+    staging path of the uid wire — the raw helper (whose native rt_dedup
+    sibling returns hash-probe ORDER, so a refactor absorbing one into
+    the other would corrupt silently) and the per-destination sharded
+    staging."""
     from paddlebox_tpu.embedding.pass_table import (dedup_ids,
                                                     dedup_uids_sorted)
 
@@ -485,38 +558,6 @@ def test_dedup_uids_sorted_contract_all_paths(data):
     uids_raw, _, _, _ = dedup_ids(ids, 2048)
     assert set(uids_raw.tolist()) == set(
         dedup_uids_sorted(ids, 2048).tolist())
-
-    # single-host batch wire: host_batch stages out["uids"] under h2d_lean
-    files, feed = data
-    flags.set_flag("h2d_lean", True)
-    try:
-        table = TableConfig(
-            embedx_dim=D, pass_capacity=2048,
-            optimizer=SparseOptimizerConfig(mf_create_thresholds=0.0,
-                                            mf_initial_range=1e-3))
-        model = CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D),
-                       hidden=(16,))
-        tr = BoxTrainer(model, table, feed, TrainerConfig(scan_chunk=2),
-                        seed=0)
-        ds = BoxDataset(feed, read_threads=1)
-        ds.set_filelist(files[:1])
-        tr.table.begin_feed_pass()
-        ds.load_into_memory(add_keys_fn=tr.table.add_keys)
-        tr.table.end_feed_pass()
-        tr.table.begin_pass()
-        batches = ds.split_batches(num_workers=1)[0]
-        for b in batches[:3]:
-            staged = tr.host_batch(b, tr.table.lookup_ids(b.keys, b.valid))
-            _assert_strictly_ascending(staged["uids"], "host_batch uid wire")
-        # chunk-amortized wire: ONE [C*K] vector per scan chunk
-        tr.sparse_chunk_sync = True
-        _, cpush = tr._stack_batches_host(batches[:2])
-        _assert_strictly_ascending(cpush["uids"], "chunk-sync cpush")
-        tr.sparse_chunk_sync = False
-        tr.table.end_pass()
-        tr.close()
-    finally:
-        flags.set_flag("h2d_lean", False)
 
     # per-destination sharded staging (single-process + 2-virtual-rank
     # p2p pre-wire dedup): every destination's staged vector is sorted

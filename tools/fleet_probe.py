@@ -151,9 +151,8 @@ def ladder_rung(root: str, keys, view: str, hot_path: str, boxes: int,
             fc.close()
     return {"boxes": boxes, "replicas": 1,
             "keys_per_sec": int(pulled / wall),
-            # answered-pull rate (round 20): the fleet_qps headline
-            # bench_trend tracks — drive() counts keys, so pulls =
-            # keys / batch
+            # answered-pull rate (round 20): drive() counts keys, so
+            # pulls = keys / batch
             "qps": round(pulled / batch / wall, 1),
             "p99_us": st["p99_us"], "p50_us": st["p50_us"],
             "errors": errors, "parity": "ok"}

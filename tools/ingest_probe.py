@@ -19,10 +19,8 @@ Per tier: `runs` timed full loads, MEDIAN wall + records/s landed on
 this rank, plus shuffle wire bytes from the shuffle stat counters.
 NOTE the tiers are END-TO-END loads: record-tcp includes the Python
 record parse (the record path's production reality — SlotRecords are
-what that codec moves), the block tiers the native columnar parse. The
-CODEC-ONLY ladder (same pre-parsed input both ways) lives in bench.py's
-"ingest" block; this probe records the pipeline each config actually
-runs.
+what that codec moves), the block tiers the native columnar parse: this
+probe records the pipeline each config actually runs.
 
 Usage:  timeout 900 python -u tools/ingest_probe.py [--worlds 2]
             [--lines 4000] [--files 2] [--runs 3]

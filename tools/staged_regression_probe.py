@@ -62,12 +62,6 @@ FLOORS = {
     # ride under both); floors = ~40% of recorded
     "ingest_parse_keys_per_sec": (27.2e6, 10e6),
     "ingest_shuffle_records_per_sec": (1.53e6, 600e3),
-    # round-8: the uid-lean wire END TO END on CPU (host stage + H2D +
-    # jitted scan + D2H, small DeepFM shape below) — guards the whole
-    # staged path so a wire regression fails loud on a CPU.
-    # Recorded on a LOADED round-8 container (sibling rows at ~60% of
-    # their quiet-box rates the same run); floor = ~40% of it
-    "e2e_lean_examples_per_sec": (6.8e3, 2.7e3),
     # round-9: the p2p host-plane bucket a2a, two in-process mesh
     # endpoints over loopback (keys = one rank's n_local*P*KB per step);
     # the multi-process ladder in tools/hostplane_probe.py recorded
@@ -158,14 +152,14 @@ CEILINGS = {
     # the 870s tier-1 budget (even at 60s it is <7% of it).
     "boxlint_full_tree_secs": (6.0, 60.0),
     "boxlint_changed_secs": (6.0, 60.0),
-    # round-20: staged H2D bytes per step at the e2e-lean bench shape
-    # (batch 256 x 16 slots x max_len 4, uid wire) — DETERMINISTIC
-    # (bytes, not time; the obs/device.py transfer ledger counts them),
-    # so the ceiling is tight: ~1.5x recorded catches any fat field
-    # sneaking into the staged batch (a resurrected full-wire perm/inv
-    # pair alone would roughly double it). Recorded quiet 2026-08-04
-    # (394,496 B/step: ids+segments+labels+valid+uids at the uid-lean
-    # wire); ceiling = ~1.5x
+    # round-20: staged H2D bytes per step at the small probe shape
+    # (batch 256 x 16 slots x max_len 4) — DETERMINISTIC (bytes, not
+    # time; the obs/device.py transfer ledger counts them), so the
+    # ceiling is tight: ~1.5x recorded catches any fat field sneaking
+    # into the staged batch. Recorded 2026-08-04 (394,496 B/step: six
+    # [K] int32 leaves ids+segments+perm+inv+uids+first_idx at U = K,
+    # + labels + ins_valid; less since the push's domain is cut to U);
+    # ceiling = ~1.5x
     "device_h2d_bytes_per_step": (394.5e3, 600e3),
     # round-19 streaming plane: drop-to-journal-poll freshness — the
     # time from an atomic file drop to a serving JournalDeltaSource
@@ -490,44 +484,6 @@ def section_ingest(rng, K):
         return reps * n_recs / (time.perf_counter() - t0)
 
     report("ingest_shuffle_records_per_sec", m_codec(), remeasure=m_codec)
-
-
-def section_e2e(rng, K):
-    # --- uid-lean wire e2e tier (round 8) ----------------------------
-    # host stage (lookup + uid sort) + H2D + jitted scan + loss D2H over
-    # a small DeepFM shape — the whole staged path the uid wire carries
-    import jax
-    from paddlebox_tpu.config import flags as _flags
-    from paddlebox_tpu.config.configs import TrainerConfig
-    from tools.bench_util import make_bench_trainer, make_ctr_batches
-    _flags.set_flag("h2d_lean", True)
-    try:
-        tr, feed = make_bench_trainer(
-            1 << 18, batch=256, num_slots=16, max_len=4, d=8,
-            trainer_cfg=TrainerConfig(dense_lr=1e-3))
-        chunk = 4
-        batches = make_ctr_batches(feed, chunk, 16, 4, seed=0)
-        tr.table.begin_feed_pass()
-        for b in batches:
-            tr.table.add_keys(b.keys[b.valid])
-        tr.table.end_feed_pass()
-        tr.table.begin_pass()
-        state = [tr.table.slab, tr.params, tr.opt_state,
-                 tr.table.next_prng()]
-
-        def one_chunk():
-            stacked = tr._stack_batches(batches)
-            slab, params, opt, losses, _p, key = tr.fns.scan_steps(
-                state[0], state[1], state[2], stacked, state[3])
-            state[:] = slab, params, opt, key
-            assert np.isfinite(np.asarray(losses)).all()
-
-        measure = lambda: timed_rate(one_chunk, chunk * 256,  # noqa: E731
-                                     secs=4.0)
-        report("e2e_lean_examples_per_sec", measure(), remeasure=measure)
-        tr.close()
-    finally:
-        _flags.set_flag("h2d_lean", False)
 
 
 def section_push(rng, K):
@@ -1096,7 +1052,6 @@ SECTIONS = (
     ("p2p", section_p2p),
     ("parse", section_parse),
     ("ingest", section_ingest),
-    ("e2e", section_e2e),
     ("push", section_push),
     ("serving", section_serving),
     ("fleet", section_fleet),
