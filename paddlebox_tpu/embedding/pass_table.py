@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -281,6 +281,20 @@ def pos_for_rebuild(uids: np.ndarray, capacity: int) -> np.ndarray:
     return pos
 
 
+# the base of a pass that has begun: no resident map is this object
+_SPENT = object()
+
+
+class FeedPlan(NamedTuple):
+    """What a feed pass derives, apart from the table that will run it:
+    the pass's sorted unique keys and the RowMap (native index built)
+    that succeeds ``base``, by rank where ``base`` is None. It holds
+    while ``base`` is the map the slab holds (PassTable.install_feed_plan)."""
+    keys: np.ndarray
+    rows: RowMap
+    base: Optional[RowMap]
+
+
 class PassTable:
     """Single-shard (one-device or host-replicated) sparse table with the
     BoxPS pass lifecycle. The pod-sharded variant composes these per shard
@@ -318,13 +332,13 @@ class PassTable:
         # incremental_pass the slab stays in HBM too and begin_pass
         # promotes only the keys that arrived; without, it rebuilds the
         # slab from the store AT THOSE ROWS, so a key has one row whichever
-        # way the flag stands. _residency_gen counts _resident's changes:
-        # a feed pass's assignment holds while it reads what it read then.
+        # way the flag stands. _rows_base is the map _rows succeeded: the
+        # assignment holds while that object is the resident one, and is
+        # _SPENT once the pass has begun (and before any is installed).
         # store_lock serializes host-store access between end_pass and the
         # preload promote stager.
         self._resident: Optional[RowMap] = None
-        self._residency_gen = 0
-        self._planned_gen = -1
+        self._rows_base = _SPENT
         self._touched: Optional[np.ndarray] = None  # bool[capacity] mirror
         self._touch_seen = False  # any mark this pass? (else full writeback)
         self._residency_poisoned = False  # mid-pass invalidate: drop at end
@@ -373,46 +387,80 @@ class PassTable:
 
     def end_feed_pass(self) -> None:
         """EndFeedPass (box_wrapper.cc:153): freeze the pass key set and
-        assign each key its slab row (see _assign_rows)."""
+        assign each key its slab row: plan on what is resident now, then
+        install, back to back."""
         if not self._in_feed_pass:
             raise RuntimeError("end_feed_pass without begin_feed_pass")
-        with obs_span("feed_unique"):
-            if self._feed_keys:
-                all_keys = np.concatenate(self._feed_keys)
-                self._pass_keys = np.unique(all_keys)  # sorted unique
-            else:
-                self._pass_keys = np.empty(0, dtype=np.uint64)
-        if self._pass_keys.size > self.capacity - 1:
-            raise RuntimeError(
-                f"pass working set {self._pass_keys.size} exceeds table "
-                f"pass_capacity {self.capacity} (raise TableConfig.pass_capacity)")
-        self._assign_rows()
+        plan = self.plan_feed_pass(self._feed_keys, self._resident)
         self._feed_keys = []
         self._in_feed_pass = False
+        self.install_feed_plan(plan)
 
-    def _assign_rows(self) -> None:
-        """Ask the row owner for this key set's rows. They succeed the
-        resident map's: keys that stay keep their rows, rows of keys that
-        left are freed, keys that arrive take free rows. With no resident
-        map (first pass, after invalidate_residency or a test-mode pass),
-        rows 0..n-1 by sorted rank."""
-        if self._resident is None:
+    def next_base(self) -> Optional[RowMap]:
+        """The map the slab will hold at the next pass boundary, which a
+        plan made ahead of it succeeds: the rows of the installed pass
+        that has not ended (open, or its base not yet spent by a
+        begin_pass), else the resident map. A poisoned or test-mode pass
+        ends with another, and install_feed_plan sees it."""
+        pending = self._in_pass or self._rows_base is not _SPENT
+        return self._rows if pending else self._resident
+
+    def plan_feed_pass(self, chunks: Sequence[np.ndarray],
+                       base: Optional[RowMap]) -> FeedPlan:
+        """Derive a pass from its registered key chunks: the sorted
+        unique keys, the capacity check, the rows that succeed ``base``.
+        Reads the chunks and ``base`` and writes no field of the table,
+        so the preloader runs it on a thread of its own while the pass
+        before trains (the stager probes ``base``'s index beside it:
+        route.cc's index is probe-only once built)."""
+        with obs_span("feed_unique"):
+            if len(chunks):
+                keys = np.unique(np.concatenate(
+                    [np.asarray(c, np.uint64) for c in chunks]))
+            else:
+                keys = np.empty(0, dtype=np.uint64)
+        if keys.size > self.capacity - 1:
+            raise RuntimeError(
+                f"pass working set {keys.size} exceeds table "
+                f"pass_capacity {self.capacity} (raise TableConfig.pass_capacity)")
+        return self._assign_rows(keys, base)
+
+    def _assign_rows(self, keys: np.ndarray,
+                     base: Optional[RowMap]) -> FeedPlan:
+        """Ask the row owner for this key set's rows. They succeed
+        ``base``'s: keys that stay keep their rows, rows of keys that left
+        are freed, keys that arrive take free rows. With no base (first
+        pass, after invalidate_residency or a test-mode pass), rows
+        0..n-1 by sorted rank."""
+        if base is None:
             # padding_id is never assigned
-            rows = RowMap.by_rank(self._pass_keys, self.capacity - 1)
+            rows = RowMap.by_rank(keys, self.capacity - 1)
         else:
             with obs_span("promote_diff"):
-                rows = self._resident.succeed(self._pass_keys)
+                rows = base.succeed(keys)
         with obs_span("feed_route_index"):
             # native key→row hash index, built once per pass and probed per
             # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
             # DedupKeysAndFillIdx tier at line rate
             rows.build_index()
-        self._rows = rows
-        self._planned_gen = self._residency_gen
+        return FeedPlan(keys, rows, base)
 
-    def _set_resident(self, rows: Optional[RowMap]) -> None:
-        self._resident = rows
-        self._residency_gen += 1
+    def install_feed_plan(self, plan: FeedPlan) -> None:
+        """Make a plan the active pass: O(1) while its base is the object
+        that is resident now. Where it is not (invalidate_residency, a
+        test-mode pass or a poisoned one came between), the assignment is
+        redone here from the plan's keys on what is resident."""
+        if self._in_pass:
+            raise RuntimeError("feed plan installed under an open pass")
+        if plan.base is self._resident:
+            stat_add("feed_plan_installed")
+        else:
+            plan = self._redo(plan.keys)
+        self._pass_keys, self._rows, self._rows_base = plan
+
+    def _redo(self, keys: np.ndarray) -> FeedPlan:
+        stat_add("feed_plan_redone")
+        return self._assign_rows(keys, self._resident)
 
     @staticmethod
     def _incremental() -> bool:
@@ -469,10 +517,11 @@ class PassTable:
     def _begin_pass(self) -> None:
         n = self._pass_keys.size
         gauge_set("pass_rows", n)
-        if self._planned_gen != self._residency_gen:
-            # residency changed since the feed pass (invalidated, or this
+        if self._rows_base is not self._resident:
+            # residency changed since the install (invalidated, or this
             # is a second pass over one feed): assign against what is there
-            self._assign_rows()
+            self._pass_keys, self._rows, self._rows_base = self._redo(
+                self._pass_keys)
         rows = self._rows
         if self._slab is not None:
             with obs_span("promote_store_read"):
@@ -536,8 +585,11 @@ class PassTable:
                 self._slab = jnp.asarray(slab)
         stat_add("pass_rows_freed", rows.freed)
         gauge_set("pass_free_rows", rows.free_rows)
-        # the slab now holds _rows' assignment; end_pass makes it resident
-        self._set_resident(None)
+        # the slab now holds _rows' assignment; end_pass makes it resident.
+        # The base is spent (and let go: a map owns a native index): a
+        # second begin_pass over this feed assigns on what is resident then
+        self._resident = None
+        self._rows_base = _SPENT
         self._touch_seen = False
         self._residency_poisoned = False
         if not self._test_mode:
@@ -609,7 +661,7 @@ class PassTable:
             if not self._residency_poisoned:
                 # each key keeps its row (BoxPS cadence): the next feed
                 # pass assigns its rows as this map's successor
-                self._set_resident(self._rows)
+                self._resident = self._rows
             if self._residency_poisoned or not self._incremental():
                 # a mid-pass store mutation poisoned the residency
                 # (invalidate_residency during the pass must not be undone
@@ -638,7 +690,7 @@ class PassTable:
             self._residency_poisoned = True
         else:
             self._slab = None
-        self._set_resident(None)
+        self._resident = None
         self._staged = None
 
     # ------------------------------------------------- preload promote hooks

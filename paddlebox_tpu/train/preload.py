@@ -5,10 +5,22 @@ box_wrapper.h:1131-1172): the dataset's read/parse/merge threads for the
 NEXT pass run concurrently with the device steps of the CURRENT pass.
 
 Key registration buffers OUTSIDE the table (a plain list) so the active
-pass's routing state (_shard_keys / pass index) is untouched while the
-next pass streams in; the cheap unique+sort+index build (end_feed_pass)
-stays on the pass boundary, exactly the part the reference also leaves in
-EndFeedPass (box_wrapper.cc:153-168).
+pass's routing state (_pass_keys / _rows) is untouched while the next
+pass streams in. The feed pass itself (np.unique over every registered
+and parsed key, the resident diff, the native index build) is the most
+expensive thing a pass does with the device idle, and nothing it reads is
+unknown while the previous pass trains: its keys are the buffer, and the
+map the slab will hold at the boundary is the installed pass's own
+(PassTable.next_base). So where the table can derive a pass apart from
+making it the active one (PassTable.plan_feed_pass / install_feed_plan), a
+feed-ahead thread joins the load and plans under pass N's steps, and the
+boundary installs the finished plan: O(1) while the plan's base is the
+object that is resident then, else the assignment is redone there from
+the plan's keys (after a save's invalidate_residency, an eval pass, a
+poisoned pass). A table that offers no plan (ShardedPassTable: its
+end_feed_pass writes the active pass's routing state in place and may run
+a host collective) keeps its feed pass on the boundary, the part the
+reference also leaves in EndFeedPass (box_wrapper.cc:153-168).
 
 Incremental promote overlap (round-6): with the incremental pass
 lifecycle, most of begin_pass's remaining host cost is store reads for
@@ -128,21 +140,64 @@ class PromotePrefetcher:
         self._thread.join(timeout=30.0)
 
 
+class FeedAhead:
+    """The feed pass of the next pass, planned on a thread of its own
+    under the current pass's training: joins the dataset's load (the
+    final concat and the quality pass run on that join), then plans over
+    the buffered keys on ``base`` (PassTable.plan_feed_pass, which writes
+    no field of the table). Its spans carry the pass it plans for."""
+
+    def __init__(self, table, dataset, buffer: List[np.ndarray],
+                 base) -> None:
+        self._plan = None
+        self._err: Optional[BaseException] = None
+        self._thread = threading.Thread(
+            target=with_current_trace(self._run),
+            args=(table, dataset, buffer, base), daemon=True,
+            name="feed-ahead")
+        self._thread.start()
+
+    def _run(self, table, dataset, buffer, base) -> None:
+        try:
+            with obs_span("ingest_feed_ahead"):
+                with obs_span("ingest_load_join"):
+                    dataset.wait_preload_done()
+                self._plan = table.plan_feed_pass(buffer, base)
+        except BaseException as e:  # surfaced at finish()
+            self._err = e
+
+    def finish(self):
+        """Join the worker and return its FeedPlan, or raise what it
+        raised (the load's error, the plan's capacity check)."""
+        self._thread.join()
+        if self._err is not None:
+            raise self._err
+        return self._plan
+
+
 class PassPreloader:
     """One in-flight preload at a time, like BoxHelper's single feed agent."""
+
+    # the main thread's spans in wait(): what the overlap did not hide,
+    # then the install (the streaming runner names its own)
+    WAIT_SPAN = "ingest_wait_preload"
+    FEED_SPAN = "ingest_feed_pass"
 
     def __init__(self, table) -> None:
         self.table = table
         self._buffer: Optional[List[np.ndarray]] = None
         self._dataset = None
         self._prefetch: Optional[PromotePrefetcher] = None
+        self._ahead: Optional[FeedAhead] = None
         self.timers = {"wait": Timer()}
 
     def preload(self, dataset) -> None:
         """Start the next pass's read threads; returns immediately. When
         the incremental lifecycle is active, a PromotePrefetcher also
         starts pulling the next pass's non-resident rows from the host
-        store under the current pass's training."""
+        store under the current pass's training. A table that can plan a
+        feed pass apart from installing it (PassTable) gets a feed-ahead
+        thread, which joins the load and plans under that training too."""
         if self._dataset is not None:
             raise RuntimeError("a preload is already in flight")
         self._buffer = []
@@ -162,6 +217,11 @@ class PassPreloader:
                 dataset.preload_into_memory(add_keys_fn=add)
             else:
                 dataset.preload_into_memory(add_keys_fn=self._buffer.append)
+            if hasattr(self.table, "plan_feed_pass"):
+                # the base is read here, on the thread that installs and
+                # ends passes: the pass installed now has not begun
+                self._ahead = FeedAhead(self.table, dataset, self._buffer,
+                                        self.table.next_base())
         except BaseException:
             # a failed launch must not wedge the preloader (or leave the
             # prefetch worker parked on its queue forever)
@@ -177,49 +237,65 @@ class PassPreloader:
                 self._prefetch.stop()
             finally:
                 self._prefetch = None
+        self._ahead = None
         self._buffer = None
         self._dataset = None
 
-    def wait(self, dataset, allgather=None) -> None:
-        """Join the load and run the table's feed pass over the buffered
-        keys (WaitFeedPassDone: dataset_->WaitPreLoadDone() +
-        EndFeedPass). On ANY error the preloader resets — a retrying
-        driver can preload again."""
+    def wait(self, dataset, allgather=None, admit_fn=None) -> bool:
+        """Join the load and make the buffered keys the table's pass
+        (WaitFeedPassDone: dataset_->WaitPreLoadDone() + EndFeedPass):
+        the feed-ahead thread's plan is installed, or, for a table that
+        offers none, its feed pass runs here. admit_fn(dataset), when
+        given, is asked after the join: a refusal drops the keys, the
+        plan and the prefetcher's staged rows, leaves the table as it
+        was and returns False. However it ends, errors included, the
+        preloader resets: a retrying driver can preload again."""
         if dataset is not self._dataset:
             raise RuntimeError("wait() for a dataset that was not preloaded")
         t = self.timers["wait"]
         t.start()
         try:
-            # the WaitFeedPassDone stall: whatever parse/shuffle tail the
-            # overlap did NOT hide shows up as this span's width in the
-            # exported trace (round 17 — the ingest plane's obs view)
-            with obs_span("ingest_wait_preload"):
-                dataset.wait_preload_done()
+            # the WaitFeedPassDone stall: whatever of the load and of the
+            # feed-ahead plan the overlap did NOT hide shows up as this
+            # span's width in the exported trace
+            plan = None
+            with obs_span(self.WAIT_SPAN):
+                if self._ahead is None:
+                    dataset.wait_preload_done()
+                else:
+                    plan = self._ahead.finish()
+            if admit_fn is not None and not admit_fn(dataset):
+                return False
             pre, self._prefetch = self._prefetch, None
             if pre is not None:
                 with obs_span("promote_prefetch_finish"):
                     keys, rows = pre.finish()
                     if keys.size:
                         self.table.accept_staged_rows(keys, rows)
-            with obs_span("ingest_feed_pass"):
-                self.table.begin_feed_pass()
-                for ks in self._buffer or []:
-                    self.table.add_keys(ks)
-                import inspect
-                params = inspect.signature(
-                    self.table.end_feed_pass).parameters
-                if "allgather" in params:
-                    self.table.end_feed_pass(allgather=allgather)
-                else:  # single-chip PassTable takes no allgather
-                    self.table.end_feed_pass()
-        except BaseException:
-            self._reset()
-            raise
-        else:
-            self._buffer = None
-            self._dataset = None
+            with obs_span(self.FEED_SPAN):
+                if plan is not None:
+                    self.table.install_feed_plan(plan)
+                else:
+                    self._feed_on_the_boundary(allgather)
+            return True
         finally:
+            # done, refused or failed: nothing of this preload is kept
+            self._reset()
             t.pause()
+
+    def _feed_on_the_boundary(self, allgather) -> None:
+        """A table without a plan (ShardedPassTable: its end_feed_pass
+        writes the active pass's routing state in place and, across
+        processes, runs a host collective)."""
+        self.table.begin_feed_pass()
+        for ks in self._buffer or []:
+            self.table.add_keys(ks)
+        import inspect
+        params = inspect.signature(self.table.end_feed_pass).parameters
+        if "allgather" in params:
+            self.table.end_feed_pass(allgather=allgather)
+        else:  # a single-chip table takes no allgather
+            self.table.end_feed_pass()
 
 
 def run_preloaded_passes(trainer, datasets: Iterable,
@@ -235,7 +311,8 @@ def run_preloaded_passes(trainer, datasets: Iterable,
 
     Each dataset gets a pass_trace_id, held around everything done for it:
     its preload (so the reader threads parsing pass N+1 under pass N's
-    training carry N+1's id), its wait, its train_pass and its release.
+    training, and the feed-ahead thread planning it, carry N+1's id), its
+    wait, its train_pass and its release.
     """
     allgather = None
     if getattr(trainer, "multiprocess", False):
@@ -255,7 +332,8 @@ def run_preloaded_passes(trainer, datasets: Iterable,
             pre.wait(cur, allgather=allgather)
             nxt = next(it, None)
             if nxt is not None:
-                # start pass N+1's read threads BEFORE training pass N
+                # start pass N+1's read threads and its feed-ahead
+                # plan BEFORE training pass N
                 with trace_ctx(pass_trace_id(rank, k + 1)):
                     pre.preload(nxt)
             results.append(trainer.train_pass(cur, preloaded=True))

@@ -47,50 +47,12 @@ from paddlebox_tpu.utils.stats import gauge_set, stat_add
 
 
 class _GatedPreloader(PassPreloader):
-    """PassPreloader with an admission gate between the load join and
-    the table's feed pass: refusing a window must leave the table (and
-    the store — the prefetcher's staged rows are discarded, never
-    accepted) exactly as it was."""
+    """PassPreloader.wait with the runner's admission gate (its admit_fn:
+    refusing a window leaves the table and the store exactly as they
+    were) under the streaming timeline's span names."""
 
-    def wait_admit(self, dataset, admit_fn=None, allgather=None) -> bool:
-        if dataset is not self._dataset:
-            raise RuntimeError("wait_admit() for a dataset that was not "
-                               "preloaded")
-        t = self.timers["wait"]
-        t.start()
-        try:
-            with obs_span("streaming_wait_ingest"):
-                dataset.wait_preload_done()
-            if admit_fn is not None and not admit_fn(dataset):
-                # refused: drop the buffered keys AND the prefetcher's
-                # staged store rows without touching the table
-                self._reset()
-                return False
-            pre, self._prefetch = self._prefetch, None
-            if pre is not None:
-                keys, rows = pre.finish()
-                if keys.size:
-                    self.table.accept_staged_rows(keys, rows)
-            with obs_span("streaming_feed_pass"):
-                self.table.begin_feed_pass()
-                for ks in self._buffer or []:
-                    self.table.add_keys(ks)
-                import inspect
-                params = inspect.signature(
-                    self.table.end_feed_pass).parameters
-                if "allgather" in params:
-                    self.table.end_feed_pass(allgather=allgather)
-                else:
-                    self.table.end_feed_pass()
-        except BaseException:
-            self._reset()
-            raise
-        else:
-            self._buffer = None
-            self._dataset = None
-        finally:
-            t.pause()
-        return True
+    WAIT_SPAN = "streaming_wait_ingest"
+    FEED_SPAN = "streaming_feed_pass"
 
 
 class StreamingRunner:
@@ -296,9 +258,9 @@ class StreamingRunner:
                 # same trace id, and the published watermark forwards
                 # it to the serving tailer's apply span
                 set_trace(step_trace_id(obs_log.get_rank(), cur.index))
-                admitted = pre.wait_admit(
-                    cur.dataset, admit_fn=lambda _ds: self._admit(win),
-                    allgather=allgather)
+                admitted = pre.wait(
+                    cur.dataset, allgather=allgather,
+                    admit_fn=lambda _ds: self._admit(win))
                 ingest_wait = cur_wait + (time.perf_counter() - t0)
                 # overlap: window N+1's readers start BEFORE N trains
                 nxt = self._next(block=False)

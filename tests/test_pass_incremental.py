@@ -448,6 +448,161 @@ def test_fallback_to_the_full_build_resets_the_assignment(incremental_flag,
     t.end_pass()
 
 
+# ---- ISSUE 33: a feed pass is planned ahead of its boundary, then installed
+OVERLAPS = [1.0, 0.9, 0.0]
+
+
+def three_passes(seed, overlap):
+    if overlap == 1.0:
+        return make_passes(np.random.RandomState(seed), n_passes=1) * 3
+    return make_passes(np.random.RandomState(seed), n_passes=3,
+                       overlap=overlap)
+
+
+def map_fields(rows):
+    return (rows.keys, rows.rows, rows.arrived, rows.holes, rows.top,
+            rows.freed, rows.dense, rows.limit)
+
+
+def run_open_pass(t, ks, plan_during=None):
+    """begin_pass, look up and push, end_pass; plan_during() is called
+    while the pass is open. Returns what the open pass read and wrote."""
+    t.begin_pass()
+    sub = np.concatenate([ks[: max(1, ks.size // 2)], ks[:7]])
+    ids = t.lookup_ids(sub)
+    planned = plan_during() if plan_during is not None else None
+    t.note_touched(ids[:3])
+    push_some(t, ids)
+    touched = t._touched.copy()
+    by_key = rows_of(t, ks)
+    t.end_pass()
+    return planned, (ids, touched, by_key) + sorted_store_items(t.store)
+
+
+@pytest.mark.parametrize("when", ["before_begin", "while_open"])
+@pytest.mark.parametrize("overlap", OVERLAPS)
+def test_a_plan_made_ahead_installs_what_the_boundary_derives(
+        incremental_flag, overlap, when):
+    """The plan of pass N+1, made once pass N is installed (before its
+    begin_pass, or while it is open), writes nothing the open pass reads,
+    and installs the keys, rows, arrived mask and free rows that
+    end_feed_pass derives on the boundary."""
+    flags.set_flag("incremental_pass", True)
+    passes = three_passes(31, overlap)
+    ref, t = PassTable(table_cfg(), seed=3), PassTable(table_cfg(), seed=3)
+    counts = [stat_get("feed_plan_installed"), stat_get("feed_plan_redone")]
+    plan = t.plan_feed_pass([passes[0]], t.next_base())
+    for i, ks in enumerate(passes):
+        feed(ref, ks)
+        t.install_feed_plan(plan)
+        for got, want in zip(map_fields(t._rows), map_fields(ref._rows)):
+            np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(t._pass_keys, ref._pass_keys)
+        assert t._rows._index is not None
+        nxt = passes[i + 1] if i + 1 < len(passes) else None
+
+        def make_plan():
+            held = (t._pass_keys, t._rows, t._resident, t._slab,
+                    None if t._touched is None else t._touched.copy())
+            # chunks as a preload buffers them: unsorted, with repeats
+            made = t.plan_feed_pass([nxt[::-1], nxt[:9]], t.next_base())
+            assert made.base is t._rows
+            now = (t._pass_keys, t._rows, t._resident, t._slab, t._touched)
+            assert all(a is b for a, b in zip(held[:4], now[:4]))
+            if held[4] is not None:
+                np.testing.assert_array_equal(held[4], now[4])
+            return made
+
+        if nxt is not None and when == "before_begin":
+            plan = make_plan()
+        made, got = run_open_pass(
+            t, ks, make_plan if nxt is not None and when == "while_open"
+            else None)
+        plan = made if made is not None else plan
+        _, want = run_open_pass(ref, ks)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+    # ref's three feeds and t's three installs each held; none was redone
+    assert stat_get("feed_plan_installed") - counts[0] == 6
+    assert stat_get("feed_plan_redone") == counts[1]
+
+
+@pytest.mark.parametrize("how", ["invalidate", "test_mode", "poison"])
+@pytest.mark.parametrize("overlap", OVERLAPS)
+def test_a_plan_whose_base_is_gone_is_redone_at_the_install(
+        incremental_flag, overlap, how):
+    """A save's invalidate_residency, an eval pass or a poisoned pass
+    between the plan and its boundary: the base is not resident at the
+    install, the assignment is redone there (rows by rank, as the full
+    build that follows needs them), and no row is stale."""
+    flags.set_flag("incremental_pass", True)
+    a, b, c = three_passes(32, overlap)
+    t = PassTable(table_cfg(), seed=3)
+    feed(t, a)
+    run_open_pass(t, a)
+    feed(t, b)
+    plan = t.plan_feed_pass([c], t.next_base())
+    assert plan.base is t._rows
+    if overlap not in (0.0, 1.0):
+        assert not plan.rows.dense              # churned: rows != rank
+    if how == "poison":
+        run_open_pass(t, b, t.invalidate_residency)
+    else:
+        run_open_pass(t, b)
+    if how == "invalidate":
+        t.invalidate_residency()
+    elif how == "test_mode":
+        t.set_test_mode(True)
+        feed(t, a[:50])
+        t.begin_pass()       # consumes the resident slab
+        t.end_pass()
+        t.set_test_mode(False)
+    assert t._resident is None
+    redone = stat_get("feed_plan_redone")
+    installed = stat_get("feed_plan_installed")
+    t.install_feed_plan(plan)
+    assert stat_get("feed_plan_redone") == redone + 1
+    assert stat_get("feed_plan_installed") == installed
+    assert t._rows is not plan.rows and t._rows.dense
+    got = begin_counts(t)
+    assert (got["hit"], got["new"], got["freed"]) == (0, 0, 0)  # full build
+    np.testing.assert_array_equal(t.lookup_ids(c), np.arange(c.size))
+    assert_assignment_sound(t, c)
+    with t.store_lock:
+        np.testing.assert_array_equal(rows_of(t, c), t.store.lookup(c))
+    t.end_pass()
+
+
+def test_a_plan_is_not_installed_under_an_open_pass(incremental_flag):
+    flags.set_flag("incremental_pass", True)
+    a, b = make_passes(np.random.RandomState(33), n_passes=2)
+    t = PassTable(table_cfg(), seed=3)
+    feed(t, a)
+    t.begin_pass()
+    plan = t.plan_feed_pass([b], t.next_base())
+    with pytest.raises(RuntimeError, match="open pass"):
+        t.install_feed_plan(plan)
+    np.testing.assert_array_equal(t.lookup_ids(a), np.arange(a.size))
+    t.end_pass()
+    t.install_feed_plan(plan)
+    assert t._rows is plan.rows
+
+
+def test_a_plan_over_capacity_is_refused_and_writes_nothing():
+    t = PassTable(table_cfg(capacity=64), seed=3)
+    keys = np.arange(1, 40, dtype=np.uint64)
+    feed(t, keys)
+    held = (t._pass_keys, t._rows)
+    with pytest.raises(RuntimeError, match="pass_capacity"):
+        t.plan_feed_pass([np.arange(1, 100, dtype=np.uint64)], t.next_base())
+    assert (t._pass_keys, t._rows) == held
+    t.begin_feed_pass()
+    t.add_keys(np.arange(1, 100, dtype=np.uint64))
+    with pytest.raises(RuntimeError, match="pass_capacity"):
+        t.end_feed_pass()
+    assert (t._pass_keys, t._rows) == held
+
+
 def test_searchsorted_fallback_returns_the_native_rows(incremental_flag):
     """Without the native library the owner's searchsorted tier must
     assign, probe and look up the same rows as the hash index."""

@@ -31,9 +31,12 @@ from paddlebox_tpu.utils.stats import stat_get
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 D, NUM_SLOTS = 4, 4
 
-# ISSUE 25, part A: parent -> its child spans, in order
+# ISSUE 25, part A: parent -> its child spans, in order. ISSUE 33: the
+# feed pass is planned on the feed-ahead thread, under ingest_feed_ahead;
+# ingest_feed_pass, on the main thread, installs the plan
 CHILDREN = {
-    "ingest_feed_pass": ["feed_unique", "promote_diff", "feed_route_index"],
+    "ingest_feed_ahead": ["ingest_load_join", "feed_unique", "promote_diff",
+                          "feed_route_index"],
     "pass_end": ["writeback_select", "writeback_d2h", "writeback_decode",
                  "writeback_store", "pass_mem_check"],
     "train_pass": ["pass_begin", "pass_split_batches", "pass_end",
@@ -178,6 +181,33 @@ def test_spans_carry_their_pass_across_threads(run):
         "chunk_drain"}
     ids = {pass_trace_id(0, 0), pass_trace_id(0, 1)}
     assert all(s[5] in ids for s in run["spans"] if s[0] in named)
+
+
+def test_the_feed_pass_of_pass_1_is_planned_under_pass_0(run):
+    """ISSUE 33: the feed-ahead thread joins pass 1's load and plans its
+    feed pass, under pass 1's id, after pass 0 was installed and before
+    pass 0's train_pass ends; the boundary's ingest_feed_pass installs and
+    derives nothing."""
+    main = run["main"]
+    installed0 = [s for s in by_pass(run, 0) if s[0] == "ingest_feed_pass"]
+    train0 = [s for s in by_pass(run, 0) if s[0] == "train_pass"]
+    assert len(installed0) == len(train0) == 1
+    ahead = [s for s in run["spans"] if s[0] == "ingest_feed_ahead"]
+    assert [s[5] for s in ahead] == [pass_trace_id(0, 0), pass_trace_id(0, 1)]
+    assert all(s[1] != main for s in ahead)
+    a1 = ahead[1]
+    assert installed0[0][4] <= a1[3] and a1[4] <= train0[0][4]
+    planned = [s for s in by_pass(run, 1)
+               if s[0] in CHILDREN["ingest_feed_ahead"]]
+    assert [s[0] for s in planned] == CHILDREN["ingest_feed_ahead"]
+    assert all(s[1] == a1[1] and a1[3] <= s[3] and s[4] <= a1[4]
+               for s in planned)
+    derived = {"feed_unique", "promote_diff", "feed_route_index"}
+    for f in (s for s in run["spans"] if s[0] == "ingest_feed_pass"):
+        assert f[1] == main
+        assert not [s for s in run["spans"] if s[0] in derived
+                    and s[1] == main and f[3] <= s[3] and s[4] <= f[4]]
+    assert not [s for s in run["spans"] if s[0] in derived and s[1] == main]
 
 
 def test_a_step_id_does_not_outlive_the_step_loop(run):
