@@ -10,6 +10,7 @@ from paddlebox_tpu.models.nn_cross import CtrDnnExpand
 from paddlebox_tpu.models.aux_input import CtrDnnAux
 from paddlebox_tpu.models.bst import BstSeqCtr
 from paddlebox_tpu.models.wide_tower import EpMMoE, TpDeepFM
+from paddlebox_tpu.models.afmoe import AfMoE
 
 MODEL_ZOO = {
     "ctr_dnn": CtrDnn,
@@ -24,9 +25,10 @@ MODEL_ZOO = {
     "bst_seq_ctr": BstSeqCtr,
     "tp_deepfm": TpDeepFM,
     "ep_mmoe": EpMMoE,
+    "afmoe": AfMoE,
 }
 
 __all__ = ["mlp_init", "mlp_apply", "CtrDnn", "DeepFM", "WideDeep", "DLRM",
            "MMoE", "ESMM", "JoinPvDnn", "CtrDnnExpand",
-           "CtrDnnAux", "BstSeqCtr", "TpDeepFM", "EpMMoE",
+           "CtrDnnAux", "BstSeqCtr", "TpDeepFM", "EpMMoE", "AfMoE",
            "MODEL_ZOO"]

@@ -150,10 +150,14 @@ def analyze_compiled(compiled, examples: Optional[int] = None,
 
 #: the jax.named_scope names of the program's device phases: the train
 #: step's (train/trainer.py, ops/, embedding/optimizers.py) and
-#: delta_promote's (embedding/pass_table.py)
+#: delta_promote's (embedding/pass_table.py); inside fwd_bwd a sequence
+#: tower's kernels (models/afmoe.py, ops/attention.py,
+#: ops/routed_experts.py): the innermost name on an operation's path wins,
+#: and the backward pass's operations carry the forward's
 SCOPE_NAMES = frozenset((
     "pull", "pool", "fwd_bwd", "dense_opt", "push_grads", "push_merge",
-    "push_opt", "push_write", "promote_scatter"))
+    "push_opt", "push_write", "promote_scatter",
+    "attn_window", "attn_full", "moe_route", "moe_experts", "dense_mlp"))
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
@@ -167,22 +171,32 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
     instruction's metadata op_name path (autodiff wraps a scope as
     jvp(pool) / transpose(jvp(pool)): unwrapped), "" where the path has
     none or the instruction carries no metadata. A fusion carries the
-    op_name of the instruction that names it."""
+    op_name of the instruction that names it; an instruction printed over
+    several lines (a Mosaic kernel's custom-call) carries its metadata on
+    a later one."""
     out: Dict[str, str] = {}
+    open_instr = None   # an instruction whose text runs on, no op_name yet
     for line in hlo_text.splitlines():
         m = _HLO_INSTR.match(line)
-        if m is None:
-            continue
-        scope = ""
         op = _HLO_OP_NAME.search(line)
-        if op is not None:
-            for part in reversed(op.group(1).split("/")):
-                part = _SCOPE_WRAP.sub("", part)
-                if part in SCOPE_NAMES:
-                    scope = part
-                    break
-        out[m.group(1)] = scope
+        if m is None:
+            # a Mosaic kernel's custom-call prints its kernel_metadata
+            # over several lines, its own metadata on the last
+            if open_instr is not None and op is not None:
+                out[open_instr] = _innermost_scope(op.group(1))
+                open_instr = None
+            continue
+        out[m.group(1)] = "" if op is None else _innermost_scope(op.group(1))
+        open_instr = m.group(1) if op is None else None
     return out
+
+
+def _innermost_scope(op_name: str) -> str:
+    for part in reversed(op_name.split("/")):
+        part = _SCOPE_WRAP.sub("", part)
+        if part in SCOPE_NAMES:
+            return part
+    return ""
 
 
 # ---------------------------------------------------------- compile listener

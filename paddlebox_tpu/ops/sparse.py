@@ -75,13 +75,18 @@ def build_push_grads(d_emb: jnp.ndarray, slots: jnp.ndarray,
     valid:  [K] bool — False for padding key slots
     g_show is 1 per occurrence; the table's push kernel segment-sums
     duplicates so a key seen in k instances gets g_show=k (PushMergeCopy).
+    The embedding cotangent goes in NEGATED, PushCopy's -1: the in-table
+    rules add what they are pushed (update_value_work: w += ratio * g /
+    show), so a pushed -dL/d(emb) is descent. PushCopy's further factor,
+    the batch size, is left to the learning rate: d_emb is the gradient of
+    the batch's MEAN loss and is pushed at that scale.
     """
     v = valid.astype(d_emb.dtype)[:, None]
     return jnp.concatenate([
         slots.astype(d_emb.dtype)[:, None],
         v,                                     # show = 1 per occurrence
         clicks.astype(d_emb.dtype)[:, None] * v,
-        d_emb[:, 2:] * v,                      # embed_g + embedx_g
+        -d_emb[:, 2:] * v,                     # -(embed_g + embedx_g)
     ], axis=1)
 
 
@@ -109,14 +114,14 @@ def build_push_grads_extended(d_emb: jnp.ndarray, d_expand: jnp.ndarray,
                               slots: jnp.ndarray, clicks: jnp.ndarray,
                               valid: jnp.ndarray) -> jnp.ndarray:
     """Push rows [K, 4+D+E] including the expand-block gradient
-    (push_box_extended_sparse backward)."""
+    (push_box_extended_sparse backward), negated as build_push_grads'."""
     v = valid.astype(d_emb.dtype)[:, None]
     return jnp.concatenate([
         slots.astype(d_emb.dtype)[:, None],
         v,
         clicks.astype(d_emb.dtype)[:, None] * v,
-        d_emb[:, 2:] * v,
-        d_expand * v,
+        -d_emb[:, 2:] * v,
+        -d_expand * v,
     ], axis=1)
 
 

@@ -86,7 +86,13 @@ def make_scan(step_fn: Callable, extra_carry: int = 0) -> Callable:
 
     extra_carry: number of additional state leaves threaded through the
     scan after prng (the sharded trainer's device metric state rides here;
-    they are donated like the slab)."""
+    they are donated like the slab).
+
+    The slab, the dense weights and the optimizer's state are DONATED: a
+    dense tower's weights and moments are then resident once (12 B a
+    parameter under adam, not 24 while a chunk runs). Their input buffers
+    are dead after the call: rebind all three from the outputs before any
+    further read."""
 
     def scan_steps(slab, params, opt_state, stacked, prng, *extra):
         def body(carry, batch):
@@ -102,7 +108,7 @@ def make_scan(step_fn: Callable, extra_carry: int = 0) -> Callable:
 
     return instrument_jit(
         scan_steps, "scan_steps",
-        donate_argnums=(0, *range(5, 5 + extra_carry)))
+        donate_argnums=(0, 1, 2, *range(5, 5 + extra_carry)))
 
 
 def run_scan_chunks(scan_call: Callable, items, chunk: int,
@@ -451,11 +457,25 @@ def cast_for_compute(tree, dtype, preserve=("dn_summary",)):
     return jax.tree.map(_cast, tree)
 
 
-def apply_mixed_precision(params, pooled, dense_in, cdtype):
+def strong_typed(tree):
+    """``tree`` with no weakly typed leaf. A step's outputs are strongly
+    typed, so a weight that came weakly typed (``jnp.where(c, -1.0, 1.0)``,
+    ``jnp.ones(n) * 2``; an optimizer's zeros_like of one) would compile
+    the step once for the caller's arrays and once more for its own
+    outputs. Strongly typed leaves pass through untouched, uncopied."""
+    return jax.tree.map(
+        lambda a: (jax.lax.convert_element_type(a, a.dtype)
+                   if getattr(a, "weak_type", False) else a), tree)
+
+
+def apply_mixed_precision(params, pooled, dense_in, cdtype, f32_params=()):
     """The one casting contract both trainers share: inputs+params to the
-    compute dtype (logits are cast back by mixed_logits_to_f32)."""
+    compute dtype (logits are cast back by mixed_logits_to_f32). A model's
+    ``f32_params`` (top-level leaves it computes with in float32: norms, a
+    router) stay uncast, as the data_norm summary does."""
     pooled = pooled.astype(cdtype)
-    params = cast_for_compute(params, cdtype)
+    params = cast_for_compute(params, cdtype,
+                              preserve=("dn_summary", *f32_params))
     if dense_in is not None:
         dense_in = dense_in.astype(cdtype)
     return params, pooled, dense_in
@@ -492,6 +512,11 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
         raise ValueError("expand embedding + data_norm summary is not "
                          "supported in one model")
     wants_aux = bool(getattr(model, "use_aux_input", False))
+    # counts a model's apply puts into the ``counters`` dict it is handed:
+    # a train step hands them back beside its predictions and train_pass
+    # adds them to utils/stats at the chunk's drain (_take_step_counters)
+    step_counters = tuple(getattr(model, "step_counters", ()))
+    f32_params = tuple(getattr(model, "f32_params", ()))
 
     # per-key slots/valid are DERIVED on device, not transferred: the packer
     # guarantees segments = ins*num_slots + slot and lookup_ids maps every
@@ -516,7 +541,8 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
             # matmuls ride the MXU in bf16; logits return to f32 for the
             # loss (master params/opt state stay f32 outside)
             params, pooled, dense_in = apply_mixed_precision(
-                params, pooled, dense_in, cdtype)
+                params, pooled, dense_in, cdtype, f32_params)
+        counts = {}
         if use_expand:
             pooled_exp = seqpool_sum(expand_emb, batch["segments"],
                                      _key_valid(batch), batch_size,
@@ -534,6 +560,8 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
             # offsets; apply raises loudly if the feed lacks the leaf
             logits = model.apply(params, pooled, dense_in,
                                  aux_offset=batch.get("aux_offset"))
+        elif step_counters:
+            logits = model.apply(params, pooled, dense_in, counters=counts)
         else:
             logits = model.apply(params, pooled, dense_in)
         if mixed:
@@ -552,7 +580,7 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
             loss = jnp.where(ins_valid, bce, 0.0).sum() / denom
             main_pred = jax.nn.sigmoid(logits)
             preds = {"ctr": main_pred}
-        return loss, preds
+        return loss, (preds, counts)
 
     def _pull(state, batch):
         """(emb_view, full_rows) — full_rows kept for the push's row reuse
@@ -602,9 +630,11 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
 
     # The slab is DONATED into the step: at production pass capacities the
     # slab is hundreds of MB and the pass holds exactly one live copy, so
-    # non-donated steps would double peak HBM. Donation is honored on every
-    # backend incl. CPU: the input slab buffer is DEAD after the call —
-    # rebind (set_slab/carry) before any further read.
+    # non-donated steps would double peak HBM. The dense weights and the
+    # optimizer's state go with it (a tower of 600M parameters is 7 GB of
+    # them). Donation is honored on every backend incl. CPU: the input
+    # buffers are DEAD after the call: rebind (set_slab/carry, self.params,
+    # self.opt_state) before any further read.
     def _step_impl(slab, params, opt_state, batch, prng):
         # split on device: host-side per-step RNG dispatch costs more than
         # the whole compiled step (2 sync dispatches ≈ 200us)
@@ -616,7 +646,7 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
         emb, rows = _pull(slab, batch)
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
         with jax.named_scope("fwd_bwd"):
-            (loss, preds), (dparams, demb) = grad_fn(params, emb)
+            (loss, (preds, counts)), (dparams, demb) = grad_fn(params, emb)
         with jax.named_scope("dense_opt"):
             updates, opt_state = dense_opt.update(dparams, opt_state, params)
             params = optax.apply_updates(params, updates)
@@ -625,9 +655,10 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                     model, params, emb, batch["segments"], _key_valid(batch),
                     batch_size, num_slots, use_cvm, batch.get("dense"))
         slab = _sparse_push(slab, demb, batch, sub, rows)
-        return slab, params, opt_state, loss, preds, prng
+        return slab, params, opt_state, loss, {**preds, **counts}, prng
 
-    step = instrument_jit(_step_impl, "train_step", donate_argnums=(0,),
+    step = instrument_jit(_step_impl, "train_step",
+                          donate_argnums=(0, 1, 2),
                           example_count=batch_size)
     scan_steps = make_scan(_step_impl)
 
@@ -643,7 +674,7 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
         emb, rows = _pull(slab, batch)
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
         with jax.named_scope("fwd_bwd"):
-            (loss, preds), (dparams, demb) = grad_fn(params, emb)
+            (loss, (preds, _)), (dparams, demb) = grad_fn(params, emb)
         if has_summary:
             # the host adam thread sees zero grads for the summary leaves;
             # their running-sums update happens here on device and rides
@@ -666,7 +697,7 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
 
     def eval_step(slab, params, batch):
         emb, _ = _pull(slab, batch)
-        _, preds = forward(params, emb, batch, None)
+        _, (preds, _) = forward(params, emb, batch, None)
         return preds
 
     eval_step = instrument_jit(eval_step, "eval_step",
@@ -985,6 +1016,9 @@ class BoxTrainer:
             dataset.load_into_memory(add_keys_fn=self.table.add_keys)
             self.table.end_feed_pass()
         self._refresh_aux()
+        # a caller may have put weights of its own in the program's place
+        self.params, self.opt_state = strong_typed(
+            (self.params, self.opt_state))
         self.table.begin_pass()
         with obs_span("pass_split_batches"):
             dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
@@ -1009,6 +1043,7 @@ class BoxTrainer:
                         chunk_losses).all():
                     raise FloatingPointError(
                         f"nan/inf loss by step {self._step_count}")
+                preds = self._take_step_counters(preds)
                 # ONE D2H per task per chunk, sliced on host — per-batch
                 # device slices would each pay a full transfer round-trip.
                 # Skipped entirely when nothing consumes preds.
@@ -1067,6 +1102,7 @@ class BoxTrainer:
                         self.table.slab, self.params, self.opt_state, batch,
                         prng)
                     self.table.set_slab(state)
+                    preds = self._take_step_counters(preds)
                 self.timers["step"].pause()
                 self._step_count += 1
                 obs_beat("step")
@@ -1111,6 +1147,19 @@ class BoxTrainer:
         return {"loss": mean_loss,
                 "batches": len(worker_batches[0]),
                 "instances": len(dataset)}
+
+    def _take_step_counters(self, preds: Dict[str, jnp.ndarray]
+                            ) -> Dict[str, jnp.ndarray]:
+        """Add the counts a train step handed back beside its predictions
+        (model.step_counters; a chunk's are stacked) to utils/stats and
+        return the predictions alone. A D2H a counter: at a chunk's drain
+        the host already waits; a tail step of a model that counts waits
+        for its own step here (a model without counters pays nothing)."""
+        names = getattr(self.model, "step_counters", ())
+        for name in names:
+            stat_add(name, int(np.asarray(preds[name]).sum()))
+        return ({t: p for t, p in preds.items() if t not in names}
+                if names else preds)
 
     def _add_metrics(self, preds: Dict[str, jnp.ndarray],
                      b: PackedBatch) -> None:

@@ -498,3 +498,68 @@ def test_flag_off_returns_bare_jit():
     out = j(_big(), _big())
     assert np.asarray(out[1]) == pytest.approx(64 * 1024.0)
     assert "bare" not in device.snapshot()["entries"]
+
+
+# ------------------------------------------- the sequence tower's scopes
+
+@pytest.mark.parametrize("name", ["attn_window", "attn_full", "moe_route",
+                                  "moe_experts", "dense_mlp"])
+def test_tower_scope_resolves_through_autodiff_and_checkpoint(name):
+    """A kernel's scope inside fwd_bwd, under jax.checkpoint and
+    value_and_grad: the backward pass's operations, the recomputed
+    forward's among them, carry the forward's scope (jvp / transpose
+    wrappers and the checkpoint's own path components are seen
+    through), and the innermost name wins over fwd_bwd."""
+    import re
+
+    assert name in device.SCOPE_NAMES
+
+    @jax.checkpoint
+    def layer(w, x):
+        with jax.named_scope(name):
+            return jnp.tanh(x @ w)
+
+    def loss(w, x):
+        with jax.named_scope("fwd_bwd"):
+            return layer(w, layer(w, x)).sum()
+
+    w, x = jnp.ones((8, 8)), jnp.ones((4, 8))
+    text = jax.jit(jax.grad(loss)).lower(w, x).compile().as_text()
+    scopes = device.scope_map(text)
+    seen = {"backward": 0, "checkpoint": 0, "recomputed": 0}
+    for line in text.splitlines():
+        instr = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+        path = re.search(r'op_name="([^"]*)"', line)
+        if instr is None or path is None:
+            continue
+        parts = path.group(1).split("/")
+        if name not in parts:
+            continue
+        assert scopes[instr.group(1)] == name, line
+        seen["backward"] += "transpose(jvp(fwd_bwd))" in parts
+        seen["checkpoint"] += "checkpoint" in parts
+        seen["recomputed"] += "rematted_computation" in parts
+    assert all(seen.values()), seen
+    # the scope itself under the wrappers, as autodiff names it when the
+    # scope is the outermost of the differentiated function
+    wrapped = "\n".join([
+        "HloModule jit_f",
+        "ENTRY %main (a: f32[4]) -> f32[4] {",
+        '  %a = f32[4]{0} parameter(0)',
+        '  ROOT %k.1 = f32[4]{0} custom-call(%a), metadata={op_name='
+        '"jit(f)/while/body/checkpoint/transpose(jvp(fwd_bwd))/'
+        'transpose(jvp(' + name + '))/vmap(pallas_call)"}',
+        "}"])
+    assert device.scope_map(wrapped)["k.1"] == name
+    # a Mosaic kernel's custom-call is printed over several lines, its
+    # metadata on the last: the instruction named on the first gets it
+    spread = "\n".join([
+        "ENTRY %main (a: f32[4]) -> f32[4] {",
+        '  %splash_fwd.3 = (f32[4]{0}, f32[4]{0}) custom-call(%a), '
+        'custom_call_target="tpu_custom_call", '
+        'frontend_attributes={kernel_metadata={',
+        '}}, metadata={op_name="jit(f)/jvp(fwd_bwd)/checkpoint/'
+        + name + '/jit(_splash_attention)/pallas_call"}, backend_config={}',
+        '  %gte.4 = f32[4]{0} get-tuple-element(%splash_fwd.3), index=0',
+        "}"])
+    assert device.scope_map(spread) == {"splash_fwd.3": name, "gte.4": ""}
