@@ -14,7 +14,8 @@ The preload/wait split mirrors BoxHelper::PreLoadIntoMemory/WaitFeedPassDone
 from __future__ import annotations
 
 import threading
-from typing import Callable, List, Optional, Sequence
+from collections.abc import Sequence
+from typing import Callable, List, Optional
 
 import numpy as np
 
@@ -32,6 +33,31 @@ from paddlebox_tpu.utils.timer import Timer
 
 # add_keys_fn(keys: np.ndarray) registers pass keys (PSAgent AddKeys analog)
 AddKeysFn = Callable[[np.ndarray], None]
+
+
+class BatchPlan(Sequence):
+    """One worker's share of BoxDataset.split_batches: which records make
+    up each of its batches (a slice of the permutation, or of the record
+    list, a batch) and the function that packs one. Read-only. plan[i] and
+    iteration pack, each time they are asked and on the asking thread;
+    len() and a slice pack nothing. Counter ingest_batches_packed_lazy
+    counts the packs."""
+
+    __slots__ = ("_pack", "_chunks")
+
+    def __init__(self, pack: Callable[..., PackedBatch], chunks) -> None:
+        self._pack = pack
+        self._chunks = tuple(chunks)
+
+    def __len__(self) -> int:
+        return len(self._chunks)
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return BatchPlan(self._pack, self._chunks[i])
+        chunk = self._chunks[i]
+        stat_add("ingest_batches_packed_lazy", 1)
+        return self._pack(chunk)
 
 
 # its only Lock guards method-local state (the read-worker file cursor,
@@ -451,10 +477,21 @@ class BoxDataset:  # boxlint: disable=BX403
 
     def split_batches(self, num_workers: int,
                       equalize: Optional[Callable[[int], int]] = None
-                      ) -> List[List[PackedBatch]]:
+                      ) -> List[BatchPlan]:
         """Equalized per-worker batch split (compute_paddlebox_thread_batch,
         data_set.cc:2690-2755): every worker gets the SAME number of batches
         so lockstep collectives never deadlock; short workers wrap around.
+
+        Returns the split, not the batches: per worker a BatchPlan, a
+        read-only sequence that knows which records make up each batch. A
+        batch is PACKED WHEN IT IS TAKEN (`plan[i]`, iteration) and on the
+        thread that takes it: BoxTrainer's chunk-stager, one chunk ahead of
+        the device; a caller that walks its batches twice says list(plan).
+        `len(plan)` costs nothing and `plan[a:b]` is a plan that has packed
+        nothing. A plan holds the block (or the records) and the
+        permutation as they were at the split, never the dataset, so
+        release_memory(), a reload or another local_shuffle() cannot change
+        a batch that is still to be taken.
 
         equalize: optional allreduce-max over hosts of the local batch count
         (MPI allreduce analog); receives local count, returns global max.
@@ -465,49 +502,35 @@ class BoxDataset:  # boxlint: disable=BX403
         local_batches = (per_worker + bs - 1) // bs if n else 0
         target = equalize(local_batches) if equalize else local_batches
         if self._load_columnar:
-            return self._split_batches_columnar(num_workers, per_worker,
-                                                target)
-        out: List[List[PackedBatch]] = []
+            from paddlebox_tpu.data.columnar import pack_columnar
+            block, feed = self._block, self.feed
+            sparse_slots = feed.used_sparse_slots()
+            max_lens = np.array([s.max_len for s in sparse_slots], np.int64)
+            kcap = feed.key_capacity()
+            num_slots = len(sparse_slots)
+            recs_all = (self._perm if self._perm is not None
+                        else np.arange(n, dtype=np.int64))
+
+            def pack(chunk):
+                return pack_columnar(block, chunk, feed, kcap, num_slots,
+                                     max_lens)
+        else:
+            # local_shuffle() shuffles the list in place: the slices below
+            # are the plan's own copies
+            recs_all = self._records
+            pack = self.packer.pack
+        out: List[BatchPlan] = []
         for w in range(num_workers):
             lo = w * per_worker
-            hi = min(lo + per_worker, n)
-            recs = self._records[lo:hi]
-            batches: List[PackedBatch] = []
+            recs = recs_all[lo:min(lo + per_worker, n)]
+            chunks = []
             for b in range(target):
                 chunk = recs[b * bs:(b + 1) * bs]
-                if not chunk and recs:
+                if not len(chunk) and len(recs):
                     # wrap around to equalize step counts
                     chunk = recs[:bs]
-                if not chunk:
-                    chunk = self._records[:bs]
-                batches.append(self.packer.pack(chunk))
-            out.append(batches)
-        return out
-
-    def _split_batches_columnar(self, num_workers: int, per_worker: int,
-                                target: int) -> List[List[PackedBatch]]:
-        from paddlebox_tpu.data.columnar import pack_columnar
-        bs = self.feed.batch_size
-        n = len(self)
-        perm = (self._perm if self._perm is not None
-                else np.arange(n, dtype=np.int64))
-        sparse_slots = self.feed.used_sparse_slots()
-        max_lens = np.array([s.max_len for s in sparse_slots], np.int64)
-        kcap = self.feed.key_capacity()
-        num_slots = len(sparse_slots)
-        out: List[List[PackedBatch]] = []
-        for w in range(num_workers):
-            lo = w * per_worker
-            hi = min(lo + per_worker, n)
-            recs = perm[lo:hi]
-            batches: List[PackedBatch] = []
-            for b in range(target):
-                chunk = recs[b * bs:(b + 1) * bs]
-                if chunk.size == 0 and recs.size:
-                    chunk = recs[:bs]
-                if chunk.size == 0:
-                    chunk = perm[:bs]
-                batches.append(pack_columnar(self._block, chunk, self.feed,
-                                             kcap, num_slots, max_lens))
-            out.append(batches)
+                if not len(chunk):
+                    chunk = recs_all[:bs]
+                chunks.append(chunk)
+            out.append(BatchPlan(pack, chunks))
         return out

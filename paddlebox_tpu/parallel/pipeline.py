@@ -261,6 +261,8 @@ def _grouped_train_pass(runner, dataset, begin_pass, end_pass,
     if n_groups_cap is not None:
         n_groups = n_groups_cap(n_groups)
     losses = []
+    # a group is a slice of the split's plan and packs nothing here: the
+    # runners walk a group several times, so it is listed where it is used
     groups = [batches[lo:lo + M] for lo in range(0, n_groups * M, M)]
     from paddlebox_tpu.config import flags
     depth = max(0, int(flags.get_flag("stream_depth")))
@@ -281,6 +283,7 @@ def _grouped_train_pass(runner, dataset, begin_pass, end_pass,
             try:
                 for g in groups:
                     with obs_span("pipe_stage"):
+                        g = list(g)
                         staged = runner.device_batch(g)
                     while not stop.is_set():
                         try:
@@ -345,7 +348,7 @@ def _grouped_train_pass(runner, dataset, begin_pass, end_pass,
                     getattr(runner, "_obs_rank", 0),
                     getattr(runner, "_step_count", 0) + 1)), \
                     obs_span("pipe_step"):
-                losses.append(runner.train_step(g))
+                losses.append(runner.train_step(list(g)))
             obs_beat("pipeline_step")
             _pipe_note_step(runner, len(losses))
     end_pass()
@@ -468,7 +471,7 @@ def _pipeline_predict(runner, dataset, begin_pass, end_pass, slab_of):
         M = runner.batches_per_step
         preds_all, labels_all = [], []
         for lo in range(0, len(batches) - M + 1, M):
-            group = batches[lo:lo + M]
+            group = list(batches[lo:lo + M])
             batch = runner.device_batch(group)
             preds = np.asarray(runner._eval(runner.params, slab_of(),
                                             batch))

@@ -911,10 +911,12 @@ class ShardedBoxTrainer:
             else self.table.build_slabs(), sharding)
         self.timers["build"].pause()
         dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
-        per_worker = dataset.split_batches(
+        # packed here, once: the metrics read raw_steps and the stager
+        # reads per_worker[w][i], and a plan packs every time it is asked
+        per_worker = [list(plan) for plan in dataset.split_batches(
             num_workers=self.n_local if self.multiprocess else self.P,
             equalize=(self.fleet.equalize_batches()
-                      if self.multiprocess else None))
+                      if self.multiprocess else None))]
         losses = []
         raw_steps = list(zip(*per_worker)) if per_worker[0] else []
         n_steps = len(raw_steps)
@@ -1105,10 +1107,10 @@ class ShardedBoxTrainer:
                 self.table.build_owned_slabs() if self.multiprocess
                 else self.table.build_slabs(), sharding)
             nw = self.n_local if self.multiprocess else self.P
-            per_worker = dataset.split_batches(
+            per_worker = [list(plan) for plan in dataset.split_batches(
                 num_workers=nw,
                 equalize=(self.fleet.equalize_batches()
-                          if self.multiprocess else None))
+                          if self.multiprocess else None))]
             raw_steps = list(zip(*per_worker)) if per_worker[0] else []
             # equalization pads short workers with WRAPPED (duplicate)
             # batches so collectives stay lockstep; those batches still run

@@ -122,10 +122,15 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
     compute (the MiniBatchGpuPack pinned-async-copy role,
     data_feed.h:519-680 — one chunk of pipelining, bounded memory).
 
-    items: a list, or a bounded iterator (the sharded trainer's streamed
-    input) with n_items passed explicitly. Exactly n_consumed items are
-    pulled either way, so the caller's per-step loop may continue from the
-    same iterator (or from items[n_consumed:]).
+    items: a sequence (a list, or a BatchPlan of BoxDataset.split_batches,
+    whose batches are PACKED AT THE PULL), or a bounded iterator (the
+    sharded trainer's streamed input) with n_items passed explicitly.
+    A chunk's items are pulled under the span ingest_pack, a sibling of
+    host_stage and before it, on the thread that stages: the chunk-stager
+    when prefetch_depth > 0, so a plan's packing runs one chunk ahead of
+    the device and under its steps; the caller's thread otherwise. Exactly
+    n_consumed items are pulled either way, so the caller's per-step loop
+    may continue from the same iterator (or from items[n_consumed:]).
 
     scan_call(carry, stacked) -> (carry, losses_dev, preds_dev) dispatches
     one chunk; the carry tuple is opaque to this driver (each trainer
@@ -163,7 +168,8 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
                 # not just between queue puts (a long native dedup here
                 # would otherwise keep reading the caller's table)
                 return
-            group = [next(it) for _ in range(chunk)]
+            with obs_span("ingest_pack"):
+                group = [next(it) for _ in range(chunk)]
             with obs_span("host_stage"):
                 staged = stack_fn(group)
             yield lo, group, staged
@@ -193,7 +199,8 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
             except BaseException as e:   # surfaced at the consumer's get
                 _put(e)
 
-        # the stager's host_stage spans carry the pass it stages for
+        # the stager's ingest_pack and host_stage spans carry the pass it
+        # works for
         producer = _threading.Thread(target=with_current_trace(produce),
                                      daemon=True, name="chunk-stager")
         producer.start()
@@ -1021,6 +1028,8 @@ class BoxTrainer:
             (self.params, self.opt_state))
         self.table.begin_pass()
         with obs_span("pass_split_batches"):
+            # the shuffle and the plan of the split: a batch is packed when
+            # the stager (or the tail loop below) takes it
             dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
             worker_batches = dataset.split_batches(num_workers=1)
         losses = []
@@ -1078,10 +1087,13 @@ class BoxTrainer:
         # when a step raises: on exit the id found here (the pass's, under
         # run_preloaded_passes) is back for end_pass
         with trace_ctx(current_trace()):
-            for b in pending:
-                # per-step 64-bit trace id (round 14): host_stage and the
-                # dispatch spans of one step share it in the exported trace
+            for i in range(len(pending)):
+                # per-step 64-bit trace id (round 14): the pack, host_stage
+                # and the dispatch spans of one step share it in the
+                # exported trace
                 set_trace(step_trace_id(0, self._step_count + 1))
+                with obs_span("ingest_pack"):
+                    b = pending[i]
                 with obs_span("host_stage"):
                     ids = self.table.lookup_ids(b.keys, b.valid)
                     batch = self.device_batch(b, ids)

@@ -415,7 +415,7 @@ def test_two_process_sharded_pipeline(data, pipeline_cluster):
             ds = BoxDataset(feed, read_threads=1)
             ds.set_filelist(files[lo:lo + 4])
             ds.load_into_memory(add_keys_fn=runner.table.add_keys)
-            halves.append(ds.split_batches(num_workers=1)[0])
+            halves.append(list(ds.split_batches(num_workers=1)[0]))
         runner.table.end_feed_pass()
         runner.begin_pass()
         n_groups = min(len(h) for h in halves) // N_MICRO

@@ -109,6 +109,7 @@ def data(tmp_path_factory):
 @pytest.fixture(scope="module")
 def run(data):
     flags.set_flag("dataset_disable_shuffle", True)
+    packed0 = stat_get("ingest_batches_packed_lazy")
     try:
         with fresh_compiles():
             losses, spans = two_passes(*data)
@@ -116,7 +117,8 @@ def run(data):
     finally:
         flags.set_flag("dataset_disable_shuffle", False)
     return {"losses": losses, "spans": spans, "snapshot": snap,
-            "main": threading.get_ident()}
+            "main": threading.get_ident(),
+            "packed": stat_get("ingest_batches_packed_lazy") - packed0}
 
 
 def by_pass(run, k):
@@ -136,7 +138,7 @@ def test_both_passes_record_every_phase_span(run):
     assert set(FULL_BUILD) <= first and not promote & first
     assert promote <= second and not set(FULL_BUILD) & second
     assert "promote_prefetch_finish" in second
-    assert {"ingest_parse", "host_stage", "scan_dispatch",
+    assert {"ingest_parse", "ingest_pack", "host_stage", "scan_dispatch",
             "chunk_drain"} <= first & second
 
 
@@ -208,6 +210,36 @@ def test_the_feed_pass_of_pass_1_is_planned_under_pass_0(run):
         assert not [s for s in run["spans"] if s[0] in derived
                     and s[1] == main and f[3] <= s[3] and s[4] <= f[4]]
     assert not [s for s in run["spans"] if s[0] in derived and s[1] == main]
+
+
+def test_a_pass_s_batches_are_packed_at_the_pull_under_its_steps(run):
+    """ISSUE 35: pass_split_batches, in train_pass on the main thread,
+    plans; the chunk's eight batches are packed by the stager's pull,
+    ingest_pack, under the pass's id and before (not inside) its
+    host_stage; the five tail batches by the step loop, a step an id."""
+    main = run["main"]
+    assert run["packed"] == 2 * 13
+    for k in (0, 1):
+        mine = by_pass(run, k)
+        train, = [s for s in mine if s[0] == "train_pass"]
+        split, = [s for s in mine if s[0] == "pass_split_batches"]
+        assert split[1] == main
+        assert train[3] <= split[3] and split[4] <= train[4]
+        pack, = [s for s in mine if s[0] == "ingest_pack"]
+        assert pack[1] != main and pack[2] == "chunk-stager"
+        stage, = [s for s in mine
+                  if s[0] == "host_stage" and s[1] == pack[1]]
+        assert split[4] <= pack[3] and pack[4] <= stage[3]
+        assert stage[4] <= train[4]
+    tail = [s for s in run["spans"]
+            if s[0] == "ingest_pack" and s[1] == main]
+    staged = [s for s in run["spans"]
+              if s[0] == "host_stage" and s[1] == main]
+    assert [s[5] for s in tail] == [s[5] for s in staged]
+    assert all(p[4] <= h[3] for p, h in zip(tail, staged))
+    assert {s[5] for s in tail} == {step_trace_id(0, n)
+                                    for n in (9, 10, 11, 12, 13,
+                                              22, 23, 24, 25, 26)}
 
 
 def test_a_step_id_does_not_outlive_the_step_loop(run):
