@@ -11,6 +11,7 @@ from paddlebox_tpu.models.aux_input import CtrDnnAux
 from paddlebox_tpu.models.bst import BstSeqCtr
 from paddlebox_tpu.models.wide_tower import EpMMoE, TpDeepFM
 from paddlebox_tpu.models.afmoe import AfMoE
+from paddlebox_tpu.models.granite_hybrid import GraniteHybrid
 
 MODEL_ZOO = {
     "ctr_dnn": CtrDnn,
@@ -26,9 +27,10 @@ MODEL_ZOO = {
     "tp_deepfm": TpDeepFM,
     "ep_mmoe": EpMMoE,
     "afmoe": AfMoE,
+    "granite_hybrid": GraniteHybrid,
 }
 
 __all__ = ["mlp_init", "mlp_apply", "CtrDnn", "DeepFM", "WideDeep", "DLRM",
            "MMoE", "ESMM", "JoinPvDnn", "CtrDnnExpand",
            "CtrDnnAux", "BstSeqCtr", "TpDeepFM", "EpMMoE", "AfMoE",
-           "MODEL_ZOO"]
+           "GraniteHybrid", "MODEL_ZOO"]

@@ -151,13 +151,15 @@ def analyze_compiled(compiled, examples: Optional[int] = None,
 #: the jax.named_scope names of the program's device phases: the train
 #: step's (train/trainer.py, ops/, embedding/optimizers.py) and
 #: delta_promote's (embedding/pass_table.py); inside fwd_bwd a sequence
-#: tower's kernels (models/afmoe.py, ops/attention.py,
-#: ops/routed_experts.py): the innermost name on an operation's path wins,
-#: and the backward pass's operations carry the forward's
+#: tower's kernels (models/afmoe.py, models/granite_hybrid.py,
+#: ops/attention.py, ops/routed_experts.py, ops/ssd.py): the innermost name
+#: on an operation's path wins, and the backward pass's operations carry
+#: the forward's
 SCOPE_NAMES = frozenset((
     "pull", "pool", "fwd_bwd", "dense_opt", "push_grads", "push_merge",
     "push_opt", "push_write", "promote_scatter",
-    "attn_window", "attn_full", "moe_route", "moe_experts", "dense_mlp"))
+    "attn_window", "attn_full", "moe_route", "moe_experts", "dense_mlp",
+    "ssm_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm"))
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")

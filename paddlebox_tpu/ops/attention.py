@@ -57,8 +57,10 @@ def _splash(seq: int, group: int, window: Optional[int], bq: int, bkv: int,
 
 
 def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                      window: Optional[int] = None) -> jnp.ndarray:
-    """softmax(q k^T / sqrt(D) under the mask) v.
+                      window: Optional[int] = None,
+                      scale: Optional[float] = None) -> jnp.ndarray:
+    """softmax(scale * q k^T under the mask) v; ``scale`` None is
+    1 / sqrt(D).
 
     q [B, Hq, S, D]; k, v [B, Hkv, S, D], Hq a multiple of Hkv. Position
     i attends to j <= i, and with ``window`` only to i - j < window.
@@ -73,7 +75,9 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if window is not None and window >= S:
         window = None           # the window never cuts: plain causal
     padded, bq, bkv = _blocks(S)
-    q = (q * (D ** -0.5)).astype(q.dtype).reshape(B, Hkv, group, S, D)
+    if scale is None:
+        scale = D ** -0.5
+    q = (q * scale).astype(q.dtype).reshape(B, Hkv, group, S, D)
     if padded != S:
         pad = [(0, 0)] * 5
         pad[3] = (0, padded - S)

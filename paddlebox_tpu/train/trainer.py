@@ -368,8 +368,32 @@ def make_dense_optimizer(cfg: TrainerConfig) -> optax.GradientTransformation:
         # one fused update over the concatenated parameter vector instead of
         # an op chain per parameter tensor — identical numbers (these
         # optimizers are elementwise), fewer dispatches
-        opt = optax.flatten(opt)
+        opt = _flatten_small(opt)
     return opt
+
+
+# dense parameters past which the update is left per tensor: optax.flatten
+# concatenates every gradient and splits every update, a second copy of
+# both, which pays where the dense side is many small tensors and costs a
+# sequence tower of 746M parameters 6 GB of a step's memory
+FLATTEN_DENSE_MAX = 1 << 24
+
+
+def _flatten_small(opt: optax.GradientTransformation
+                   ) -> optax.GradientTransformation:
+    """optax.flatten(opt) for a tree of at most FLATTEN_DENSE_MAX values,
+    ``opt`` itself for a larger one: picked from the tree's shapes, so the
+    same tree always gets the same state."""
+    flat = optax.flatten(opt)
+
+    def pick(tree):
+        n = sum(int(np.prod(np.shape(leaf))) for leaf in jax.tree.leaves(tree))
+        return flat if n <= FLATTEN_DENSE_MAX else opt
+
+    def update(updates, state, params=None):
+        return pick(updates).update(updates, state, params)
+    return optax.GradientTransformation(
+        lambda params: pick(params).init(params), update)
 
 
 def _multi_task_loss(logits, labels_dict, ins_valid, loss_mode: str = "sum"):
@@ -759,7 +783,10 @@ class BoxTrainer:
         self.dense_opt = make_dense_optimizer(self.cfg)
         rng = jax.random.PRNGKey(seed)
         self.params = model.init(rng)
-        self.opt_state = self.dense_opt.init(self.params)
+        # made at its first read (the opt_state property): a caller that
+        # installs its own weights and a fresh state for them (a benchmark,
+        # a restore) never holds two states of a large tower at once
+        self._opt_state = None
         self.num_slots = len(feed.used_sparse_slots())
         self.fns = make_train_step(
             model, self.table.layout, table_cfg, self.dense_opt,
@@ -1159,6 +1186,18 @@ class BoxTrainer:
         return {"loss": mean_loss,
                 "batches": len(worker_batches[0]),
                 "instances": len(dataset)}
+
+    @property
+    def opt_state(self):
+        """The dense optimizer's state, initialised from ``params`` when
+        first read."""
+        if self._opt_state is None:
+            self._opt_state = self.dense_opt.init(self.params)
+        return self._opt_state
+
+    @opt_state.setter
+    def opt_state(self, state) -> None:
+        self._opt_state = state
 
     def _take_step_counters(self, preds: Dict[str, jnp.ndarray]
                             ) -> Dict[str, jnp.ndarray]:

@@ -230,6 +230,62 @@ def test_blocked_attention_matches_naive(window, monkeypatch):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
+def scaled_naive(q, k, v, scale):
+    """naive_attention with the scores times ``scale``: its own 1 /
+    sqrt(D) is undone on q."""
+    return naive_attention(q * (scale * np.sqrt(q.shape[-1])), k, v, None)
+
+
+@pytest.mark.parametrize("heads, kv_heads, dim, scale", [
+    (32, 8, 64, 1.0 / 64),      # granite-4.0-h-micro's attention layer
+    (8, 2, 64, 1.0 / 64),
+    (8, 2, 16, 0.5),
+], ids=["32over8-of-64-scale-1/64", "8over2-of-64-scale-1/64",
+        "8over2-of-16-scale-1/2"])
+def test_blocked_attention_scale_matches_plain_softmax(heads, kv_heads, dim,
+                                                       scale, monkeypatch):
+    """``scale`` in place of 1 / sqrt(D): 200 positions in blocks of 128,
+    heads of 64 grouped four to a key-value head; forward and gradient
+    against the plain softmax of scale * q.k."""
+    from paddlebox_tpu.ops import attention
+    monkeypatch.setattr(attention, "BLOCK_Q", 128)
+    monkeypatch.setattr(attention, "BLOCK_KV", 128)
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    # scores of a few units, so that the softmax is no flat average
+    q = 4.0 * jax.random.normal(keys[0], (1, heads, 200, dim))
+    k = 4.0 * jax.random.normal(keys[1], (1, kv_heads, 200, dim))
+    v = jax.random.normal(keys[2], (1, kv_heads, 200, dim))
+    t = jax.random.normal(keys[3], (1, heads, 200, dim))
+    got = jax.jit(lambda q, k, v: blocked_attention(q, k, v, None, scale))(
+        q, k, v)
+    np.testing.assert_allclose(got, scaled_naive(q, k, v, scale),
+                               rtol=2e-5, atol=2e-5)
+    g = jax.jit(jax.grad(lambda q, k, v: (blocked_attention(
+        q, k, v, None, scale) * t).sum(), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(lambda q, k, v: (scaled_naive(q, k, v, scale) * t).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["full", "window"])
+def test_blocked_attention_scale_none_is_inverse_sqrt_bit_for_bit(
+        window, monkeypatch):
+    """scale=None is D ** -0.5, the arithmetic trinity-mini's program has
+    always had: equal to the last bit to the same number handed over."""
+    from paddlebox_tpu.ops import attention
+    monkeypatch.setattr(attention, "BLOCK_Q", 128)
+    monkeypatch.setattr(attention, "BLOCK_KV", 128)
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    q = jax.random.normal(keys[0], (2, 4, 200, 16), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (2, 2, 200, 16), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (2, 2, 200, 16), jnp.bfloat16)
+    run = jax.jit(blocked_attention, static_argnums=(3, 4))
+    np.testing.assert_array_equal(
+        np.asarray(run(q, k, v, window, None), np.float32),
+        np.asarray(run(q, k, v, window, 16 ** -0.5), np.float32))
+
+
 # ------------------------------------- (c) the share ties to the model
 def test_shares_add_up_to_the_uncut_layer(tower):
     """Eight chips hold one expert each of a layer's eight: their routed

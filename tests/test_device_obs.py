@@ -503,7 +503,8 @@ def test_flag_off_returns_bare_jit():
 # ------------------------------------------- the sequence tower's scopes
 
 @pytest.mark.parametrize("name", ["attn_window", "attn_full", "moe_route",
-                                  "moe_experts", "dense_mlp"])
+                                  "moe_experts", "dense_mlp", "ssm_proj",
+                                  "ssm_conv", "ssd_scan", "ssm_gate_norm"])
 def test_tower_scope_resolves_through_autodiff_and_checkpoint(name):
     """A kernel's scope inside fwd_bwd, under jax.checkpoint and
     value_and_grad: the backward pass's operations, the recomputed

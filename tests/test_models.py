@@ -62,7 +62,8 @@ def test_zoo_registry():
     assert set(MODEL_ZOO) == {"ctr_dnn", "deepfm", "wide_deep", "dlrm",
                               "mmoe", "esmm", "join_pv_dnn",
                               "ctr_dnn_expand", "ctr_dnn_aux",
-                              "bst_seq_ctr", "tp_deepfm", "ep_mmoe", "afmoe"}
+                              "bst_seq_ctr", "tp_deepfm", "ep_mmoe", "afmoe",
+                              "granite_hybrid"}
 
 
 def test_esmm_entire_space_loss():
