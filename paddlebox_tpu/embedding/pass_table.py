@@ -92,6 +92,36 @@ _delta_promote = instrument_jit(_delta_promote_impl, "delta_promote",
                                 donate_argnums=(0,), recompile_warmup=32)
 
 
+def _writeback_gather_impl(slab, idx):
+    """One chunk of end_pass's write-back: the rows at idx, a fixed
+    [chunk] index whatever the pass touched (the tail of the last chunk
+    repeats a valid row; the host drops it), so every boundary of the
+    table's life runs the one program. Dtype-agnostic as _delta_promote
+    is: ENCODED uint16 rows move without arithmetic."""
+    with jax.named_scope("writeback_gather"):
+        return slab[idx]
+
+
+# not donated: the slab lives on as the next pass's resident slab
+_writeback_gather = instrument_jit(_writeback_gather_impl,
+                                   "writeback_gather")
+
+# what one chunk of the write-back holds on the device, of the order of:
+# large enough that a chunk's dispatch and transfer set-up are noise,
+# small enough that two in flight are noise beside the slab
+_WRITEBACK_CHUNK_BYTES = 32 << 20
+
+
+def _writeback_chunk_rows(layout: ValueLayout, capacity: int) -> int:
+    """Rows a write-back chunk gathers: the power of two whose device
+    bytes fit _WRITEBACK_CHUNK_BYTES, never more than the slab has.
+    From the row's bytes alone, so it holds for the table's life: a rule
+    on the count of touched rows would compile at the pass that crosses
+    its edge."""
+    fit = max(_WRITEBACK_CHUNK_BYTES // layout.device_bytes_per_row, 1)
+    return min(1 << (fit.bit_length() - 1), _pow2_pad(capacity))
+
+
 def _slab_embed_dtype() -> str:
     """Resolve the slab_embed_dtype flag at table construction: the
     DEVICE slab's weight-column precision (round-11 dtype diet). Read
@@ -317,6 +347,8 @@ class PassTable:
         self.store = (store if store is not None
                       else make_host_store(self.layout, table, seed))  # guarded-by: store_lock
         self.capacity = table.pass_capacity
+        self._writeback_rows = _writeback_chunk_rows(self.layout,
+                                                     self.capacity)
         self._feed_keys: list = []
         self._pass_keys: Optional[np.ndarray] = None  # sorted unique
         # key → slab row of _pass_keys (row_map.py owns the assignment)
@@ -623,19 +655,42 @@ class PassTable:
         with obs_span("pass_end"):
             self._end_pass()
 
-    def _write_back(self, keys: np.ndarray, dev_rows_fn) -> None:
-        """One write-back: dev_rows_fn() gathers the rows on the device;
-        they cross to the host, decode to host f32 (identity for f32
-        slabs), are journaled, and land in the store."""
-        with obs_span("writeback_d2h"):
-            dev_rows = np.asarray(dev_rows_fn())
-            account_d2h(dev_rows.nbytes)
-        with obs_span("writeback_decode"):
-            rows = decode_slab_rows_np(dev_rows, self.layout)
-            self._journal_rows(keys, rows)
-        with obs_span("writeback_store"):
-            with self.store_lock:
-                self.store.write_back(keys, rows)
+    def _write_back(self, keys: np.ndarray, idx: np.ndarray) -> None:
+        """Write slab rows idx back as keys' rows, a chunk of
+        _writeback_rows at a time: a chunk is gathered on the device,
+        crosses to the host, decodes to host f32 (identity for f32
+        slabs), is journaled and lands in the store, while the next
+        chunk's gather and copy are already under way. Two chunks live
+        on the device at most; every row is in the store when this
+        returns."""
+        R = self._writeback_rows
+        m = idx.size
+
+        def start(lo: int):
+            chunk = np.empty(R, np.int32)
+            c = min(R, m - lo)
+            chunk[:c] = idx[lo:lo + c]
+            chunk[c:] = chunk[0]  # any valid row
+            dev = _writeback_gather(self._slab, jnp.asarray(chunk))
+            dev.copy_to_host_async()
+            return dev
+
+        ahead = None
+        for lo in range(0, m, R):
+            hi = min(lo + R, m)
+            with obs_span("writeback_d2h"):
+                dev = start(lo) if ahead is None else ahead
+                ahead = start(hi) if hi < m else None
+                dev_rows = np.asarray(dev)[:hi - lo]
+                account_d2h(dev.nbytes)
+                del dev
+            stat_add("pass_writeback_chunks")
+            with obs_span("writeback_decode"):
+                rows = decode_slab_rows_np(dev_rows, self.layout)
+                self._journal_rows(keys[lo:hi], rows)
+            with obs_span("writeback_store"):
+                with self.store_lock:
+                    self.store.write_back(keys[lo:hi], rows)
 
     def _end_pass(self) -> None:
         n = self._pass_keys.size
@@ -647,17 +702,11 @@ class PassTable:
                 if self._touched is not None and self._touch_seen:
                     with obs_span("writeback_select"):
                         keys, idx = self._rows.touched(self._touched)
-                    if idx.size:
-                        self._write_back(  # touched rows only
-                            keys, lambda: self._slab[jnp.asarray(idx)])
                     stat_add("pass_rows_written_back", int(idx.size))
                     stat_add("pass_rows_writeback_skipped", n - int(idx.size))
-                else:
-                    rows = self._rows
-                    self._write_back(  # every assigned row
-                        self._pass_keys,
-                        lambda: (self._slab[:n] if rows.dense else
-                                 self._slab[jnp.asarray(rows.rows)]))
+                else:  # every assigned row
+                    keys, idx = self._pass_keys, self._rows.rows
+                self._write_back(keys, idx)
             if not self._residency_poisoned:
                 # each key keeps its row (BoxPS cadence): the next feed
                 # pass assigns its rows as this map's successor

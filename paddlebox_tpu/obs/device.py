@@ -34,8 +34,9 @@ observable through the UNCHANGED publication machinery:
     for slab-scale copies).
   * compile spans — every instrumented compile records a ring span
     ``device_compile``; one jax.monitoring listener records EVERY
-    backend compile of the process (instrumented or not: end_pass's
-    ``slab[idx]`` gather compiles a new shape each pass) as a ring span
+    backend compile of the process (instrumented or not: an eager
+    ``slab[idx]`` at a pass boundary is seven small programs for every
+    new length of ``idx``, which no entry would show) as a ring span
     ``backend_compile`` and counts ``device_backend_compiles``. It adds
     nothing to the entries: their ``compiles`` stay the instrumented
     entry points' own.
@@ -150,14 +151,14 @@ def analyze_compiled(compiled, examples: Optional[int] = None,
 
 #: the jax.named_scope names of the program's device phases: the train
 #: step's (train/trainer.py, ops/, embedding/optimizers.py) and
-#: delta_promote's (embedding/pass_table.py); inside fwd_bwd a sequence
-#: tower's kernels (models/afmoe.py, models/granite_hybrid.py,
-#: ops/attention.py, ops/routed_experts.py, ops/ssd.py): the innermost name
-#: on an operation's path wins, and the backward pass's operations carry
-#: the forward's
+#: delta_promote's and writeback_gather's (embedding/pass_table.py);
+#: inside fwd_bwd a sequence tower's kernels (models/afmoe.py,
+#: models/granite_hybrid.py, ops/attention.py, ops/routed_experts.py,
+#: ops/ssd.py): the innermost name on an operation's path wins, and the
+#: backward pass's operations carry the forward's
 SCOPE_NAMES = frozenset((
     "pull", "pool", "fwd_bwd", "dense_opt", "push_grads", "push_merge",
-    "push_opt", "push_write", "promote_scatter",
+    "push_opt", "push_write", "promote_scatter", "writeback_gather",
     "attn_window", "attn_full", "moe_route", "moe_experts", "dense_mlp",
     "ssm_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm"))
 

@@ -17,6 +17,7 @@ from paddlebox_tpu.config import flags
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig, TableConfig,
                                           TrainerConfig)
 from paddlebox_tpu.data import BoxDataset, write_synthetic_ctr_files
+from paddlebox_tpu.embedding import pass_table
 from paddlebox_tpu.embedding.pass_table import PassTable, _delta_promote
 from paddlebox_tpu.models import CtrDnn
 from paddlebox_tpu.models.base import ModelSpec
@@ -255,6 +256,42 @@ def test_a_step_id_does_not_outlive_the_step_loop(run):
     assert [s[5] for s in ends] == [pass_trace_id(0, 0), pass_trace_id(0, 1)]
 
 
+def test_a_chunked_write_back_s_spans_lie_inside_pass_end(data, monkeypatch):
+    """ISSUE 38: the write-back crosses a chunk at a time. With the chunk
+    cut to 16 rows, every chunk's writeback_d2h, writeback_decode and
+    writeback_store are children of pass_end on the main thread under the
+    pass's id, in that order; pass_writeback_chunks counts ceil(m / R)."""
+    R = 16
+    monkeypatch.setattr(pass_table, "_WRITEBACK_CHUNK_BYTES",
+                        R * PassTable(table_cfg()).layout.device_bytes_per_row)
+    written = []
+    plain = PassTable._write_back
+
+    def counting(self, keys, idx):
+        assert self._writeback_rows == R
+        written.append(idx.size)
+        plain(self, keys, idx)
+
+    monkeypatch.setattr(PassTable, "_write_back", counting)
+    chunks0 = stat_get("pass_writeback_chunks")
+    _, spans = two_passes(*data)
+    want = [-(-m // R) for m in written]
+    assert len(want) == 2 and min(want) > 1, written
+    assert stat_get("pass_writeback_chunks") - chunks0 == sum(want)
+    per_chunk = ["writeback_d2h", "writeback_decode", "writeback_store"]
+    main = threading.get_ident()
+    for k, n_chunks in enumerate(want):
+        mine = [s for s in spans if s[5] == pass_trace_id(0, k)]
+        (end,) = [s for s in mine if s[0] == "pass_end"]
+        kids = sorted((s for s in spans if s[0] in per_chunk),
+                      key=lambda s: s[3])
+        kids = [s for s in kids if end[3] <= s[3] and s[4] <= end[4]]
+        assert [s[0] for s in kids] == per_chunk * n_chunks
+        assert all(s[1] == main == end[1] and s[5] == end[5] for s in kids)
+        assert all(a[4] <= b[3] for a, b in zip(kids, kids[1:]))
+    assert sum(s[0] in per_chunk for s in spans) == 3 * sum(want)
+
+
 def test_trace_ids_of_different_kinds_never_collide():
     pid = pass_trace_id(3, 7)
     assert pid >> 61 == 1 and (pid >> 48) & 0x1FFF == 3 and pid & 0xFFFF == 7
@@ -347,6 +384,13 @@ def test_the_scope_map_names_delta_promote_s_phases():
             jnp.zeros((4, width))).compile().as_text()
     scopes = set(obs_device.scope_map(text).values())
     assert "promote_scatter" in scopes and "promote_permute" not in scopes
+
+
+def test_the_scope_map_names_the_write_back_s_gather():
+    with fresh_compiles():
+        text = pass_table._writeback_gather.lower(
+            jnp.zeros((64, 8)), jnp.zeros(4, jnp.int32)).compile().as_text()
+    assert "writeback_gather" in set(obs_device.scope_map(text).values())
 
 
 def test_scope_map_reads_the_innermost_scope_through_autodiff_wrappers():
