@@ -29,7 +29,6 @@ from paddlebox_tpu.obs.tracer import span as obs_span
 from paddlebox_tpu.obs.tracer import with_current_trace
 from paddlebox_tpu.utils.channel import Channel, ChannelClosed
 from paddlebox_tpu.utils.stats import stat_add
-from paddlebox_tpu.utils.timer import Timer
 
 # add_keys_fn(keys: np.ndarray) registers pass keys (PSAgent AddKeys analog)
 AddKeysFn = Callable[[np.ndarray], None]
@@ -85,7 +84,6 @@ class BoxDataset:  # boxlint: disable=BX403
         self._channel: Optional[Channel] = None
         self._add_keys_fn: Optional[AddKeysFn] = None
         self._load_error: Optional[BaseException] = None
-        self.timers = {n: Timer() for n in ("read", "merge", "shuffle")}
         # columnar fast path: native C++ parser → struct-of-arrays blocks,
         # numpy-only batch packing (no per-record Python objects). Default:
         # on whenever the native lib builds — round 17: a cross-host
@@ -185,7 +183,6 @@ class BoxDataset:  # boxlint: disable=BX403
         cursor = {"i": 0}
 
         def read_worker():
-            t = self.timers["read"]
             try:
                 while True:
                     with lock:
@@ -193,7 +190,6 @@ class BoxDataset:  # boxlint: disable=BX403
                             return
                         path = files[cursor["i"]]
                         cursor["i"] += 1
-                    t.start()
                     if use_columnar:
                         with obs_span("ingest_parse"):
                             block = self._native_parser.parse_file_columnar(
@@ -213,7 +209,6 @@ class BoxDataset:  # boxlint: disable=BX403
                                 batch = []
                         if batch:
                             self._put_records(batch)
-                    t.pause()
             except BaseException as e:  # surfaced in wait_preload_done
                 self._load_error = e
 
@@ -227,7 +222,6 @@ class BoxDataset:  # boxlint: disable=BX403
             CONVERTS here with a loud warning instead of failing: one
             stray shard must not kill a cluster pass load (round-17
             review), but the degraded rate must never be silent."""
-            t = self.timers["merge"]
             blocks = []
             mixed_warned = [False]
 
@@ -248,7 +242,6 @@ class BoxDataset:  # boxlint: disable=BX403
                         items = self._channel.get_many(256)
                     except ChannelClosed:
                         break
-                    t.start()
                     stray = [it for it in items
                              if isinstance(it, ColumnarBlock)
                              is not use_columnar]
@@ -272,7 +265,6 @@ class BoxDataset:  # boxlint: disable=BX403
                                     self._add_keys_fn(block.keys)
                                 blocks.append(block)
                                 stat_add("dataset_ins_merged", block.n_recs)
-                        t.pause()
                         continue
                     recs = items
                     if stray:
@@ -299,7 +291,6 @@ class BoxDataset:  # boxlint: disable=BX403
                                     self._add_keys_fn(np.concatenate(keys))
                             self._records.extend(recs)
                         stat_add("dataset_ins_merged", len(recs))
-                    t.pause()
                 if use_columnar:
                     self._block = ColumnarBlock.concat(blocks)
                     if self._block.n_recs:

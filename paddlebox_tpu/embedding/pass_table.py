@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -319,10 +319,16 @@ class FeedPlan(NamedTuple):
     """What a feed pass derives, apart from the table that will run it:
     the pass's sorted unique keys and the RowMap (native index built)
     that succeeds ``base``, by rank where ``base`` is None. It holds
-    while ``base`` is the map the slab holds (PassTable.install_feed_plan)."""
+    while ``base`` is the map the slab holds (PassTable.install_feed_plan).
+    ``stamps`` is the plan's clock: {span name: (t0, t1)} of the live
+    spans it was derived under (feed_unique, promote_diff where there is
+    a base, feed_route_index; the preloader's feed-ahead thread adds its
+    own two), for the pass that consumes the plan to account
+    (PassPreloader.wait). All 0.0 while tracing is off."""
     keys: np.ndarray
     rows: RowMap
     base: Optional[RowMap]
+    stamps: Dict[str, Tuple[float, float]]
 
 
 class PassTable:
@@ -445,7 +451,7 @@ class PassTable:
         so the preloader runs it on a thread of its own while the pass
         before trains (the stager probes ``base``'s index beside it:
         route.cc's index is probe-only once built)."""
-        with obs_span("feed_unique"):
+        with obs_span("feed_unique") as unique:
             if len(chunks):
                 keys = np.unique(np.concatenate(
                     [np.asarray(c, np.uint64) for c in chunks]))
@@ -455,27 +461,30 @@ class PassTable:
             raise RuntimeError(
                 f"pass working set {keys.size} exceeds table "
                 f"pass_capacity {self.capacity} (raise TableConfig.pass_capacity)")
-        return self._assign_rows(keys, base)
+        return self._assign_rows(
+            keys, base, {"feed_unique": (unique.t0, unique.t1)})
 
-    def _assign_rows(self, keys: np.ndarray,
-                     base: Optional[RowMap]) -> FeedPlan:
+    def _assign_rows(self, keys: np.ndarray, base: Optional[RowMap],
+                     stamps: Dict[str, Tuple[float, float]]) -> FeedPlan:
         """Ask the row owner for this key set's rows. They succeed
         ``base``'s: keys that stay keep their rows, rows of keys that left
         are freed, keys that arrive take free rows. With no base (first
         pass, after invalidate_residency or a test-mode pass), rows
-        0..n-1 by sorted rank."""
+        0..n-1 by sorted rank. Its two spans' stamps join ``stamps``."""
         if base is None:
             # padding_id is never assigned
             rows = RowMap.by_rank(keys, self.capacity - 1)
         else:
-            with obs_span("promote_diff"):
+            with obs_span("promote_diff") as diff:
                 rows = base.succeed(keys)
-        with obs_span("feed_route_index"):
+            stamps["promote_diff"] = (diff.t0, diff.t1)
+        with obs_span("feed_route_index") as index:
             # native key→row hash index, built once per pass and probed per
             # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
             # DedupKeysAndFillIdx tier at line rate
             rows.build_index()
-        return FeedPlan(keys, rows, base)
+        stamps["feed_route_index"] = (index.t0, index.t1)
+        return FeedPlan(keys, rows, base, stamps)
 
     def install_feed_plan(self, plan: FeedPlan) -> None:
         """Make a plan the active pass: O(1) while its base is the object
@@ -488,11 +497,11 @@ class PassTable:
             stat_add("feed_plan_installed")
         else:
             plan = self._redo(plan.keys)
-        self._pass_keys, self._rows, self._rows_base = plan
+        self._pass_keys, self._rows, self._rows_base = plan[:3]
 
     def _redo(self, keys: np.ndarray) -> FeedPlan:
         stat_add("feed_plan_redone")
-        return self._assign_rows(keys, self._resident)
+        return self._assign_rows(keys, self._resident, {})
 
     @staticmethod
     def _incremental() -> bool:
@@ -553,7 +562,7 @@ class PassTable:
             # residency changed since the install (invalidated, or this
             # is a second pass over one feed): assign against what is there
             self._pass_keys, self._rows, self._rows_base = self._redo(
-                self._pass_keys)
+                self._pass_keys)[:3]
         rows = self._rows
         if self._slab is not None:
             with obs_span("promote_store_read"):

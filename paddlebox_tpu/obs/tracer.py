@@ -166,9 +166,12 @@ class _ThreadRing:
 
 
 class _NullSpan:
-    """Reusable no-op context manager for the disabled path."""
+    """Reusable no-op context manager for the disabled path. Its stamps
+    read 0.0: a caller that accounts a span's width (``with span(..) as
+    s: ...; s.t1 - s.t0``) accounts nothing while tracing is off."""
 
     __slots__ = ()
+    t0 = t1 = 0.0
 
     def __enter__(self):
         return self
@@ -181,7 +184,11 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "t0", "_ann")
+    """One live span. ``t0`` and ``t1`` are the perf_counter pair the ring
+    holds, handed back so that a counter taken from a span and the span
+    itself agree (``with span(..) as s`` then ``s.t0``, ``s.t1``)."""
+
+    __slots__ = ("_tr", "name", "t0", "t1", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str) -> None:
         self._tr = tracer
@@ -198,7 +205,7 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        self.t1 = t1 = time.perf_counter()
         self._tr._ring().record(self.name, self.t0, t1,
                                 getattr(_TRACE_CTX, "id", None))
         if self._ann is not None:

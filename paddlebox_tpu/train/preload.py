@@ -44,7 +44,7 @@ import numpy as np
 from paddlebox_tpu.obs import log as obs_log
 from paddlebox_tpu.obs.tracer import (pass_trace_id, span as obs_span,
                                       trace_ctx, with_current_trace)
-from paddlebox_tpu.utils.timer import Timer
+from paddlebox_tpu.utils.stats import gauge_set, stat_add
 
 
 class PromotePrefetcher:
@@ -145,7 +145,9 @@ class FeedAhead:
     under the current pass's training: joins the dataset's load (the
     final concat and the quality pass run on that join), then plans over
     the buffered keys on ``base`` (PassTable.plan_feed_pass, which writes
-    no field of the table). Its spans carry the pass it plans for."""
+    no field of the table). Its spans carry the pass it plans for, and
+    the plan carries their stamps (FeedPlan.stamps) to the boundary that
+    consumes it, where PassPreloader.wait accounts them."""
 
     def __init__(self, table, dataset, buffer: List[np.ndarray],
                  base) -> None:
@@ -159,10 +161,13 @@ class FeedAhead:
 
     def _run(self, table, dataset, buffer, base) -> None:
         try:
-            with obs_span("ingest_feed_ahead"):
-                with obs_span("ingest_load_join"):
+            with obs_span("ingest_feed_ahead") as ahead:
+                with obs_span("ingest_load_join") as join:
                     dataset.wait_preload_done()
-                self._plan = table.plan_feed_pass(buffer, base)
+                plan = table.plan_feed_pass(buffer, base)
+            plan.stamps["ingest_load_join"] = (join.t0, join.t1)
+            plan.stamps["ingest_feed_ahead"] = (ahead.t0, ahead.t1)
+            self._plan = plan
         except BaseException as e:  # surfaced at finish()
             self._err = e
 
@@ -173,6 +178,37 @@ class FeedAhead:
         if self._err is not None:
             raise self._err
         return self._plan
+
+
+# counter <- the span of the feed-ahead chain it is taken from
+FEED_PLAN_COUNTERS = (("feed_plan_us", "ingest_feed_ahead"),
+                      ("feed_plan_load_join_us", "ingest_load_join"),
+                      ("feed_plan_unique_us", "feed_unique"),
+                      ("feed_plan_diff_us", "promote_diff"),
+                      ("feed_plan_index_us", "feed_route_index"))
+
+
+def account_feed_plan(stamps: Dict[str, Tuple[float, float]],
+                      t_ask: float) -> None:
+    """Work done ahead for a pass, accounted to the pass that consumes it
+    at the moment it is consumed: the chain's length and its four stages
+    (whole microseconds of the spans' own perf_counter pairs; a plan with
+    no base has no diff: 0), and the slack, how long the finished plan lay
+    waiting before wait() asked for it at ``t_ask``. Slack and a
+    non-trivial ingest_wait_preload exclude each other; a plan redone on
+    the boundary still adds the chain that made it. The two gauges hold
+    the newest pass's values for the pass report, /metrics and the flight
+    recorder. With tracing off every stamp is 0.0 and so is every sum."""
+    def whole_us(t0: float, t1: float) -> int:
+        return int((t1 - t0) * 1e6)
+
+    t0, t_done = stamps["ingest_feed_ahead"]
+    slack = max(0, whole_us(t_done, t_ask))
+    for counter, name in FEED_PLAN_COUNTERS:
+        stat_add(counter, whole_us(*stamps.get(name, (0.0, 0.0))))
+    stat_add("feed_plan_slack_us", slack)
+    gauge_set("feed_plan_last_ms", whole_us(t0, t_done) / 1000.0)
+    gauge_set("feed_plan_slack_last_ms", slack / 1000.0)
 
 
 class PassPreloader:
@@ -189,7 +225,6 @@ class PassPreloader:
         self._dataset = None
         self._prefetch: Optional[PromotePrefetcher] = None
         self._ahead: Optional[FeedAhead] = None
-        self.timers = {"wait": Timer()}
 
     def preload(self, dataset) -> None:
         """Start the next pass's read threads; returns immediately. When
@@ -252,20 +287,20 @@ class PassPreloader:
         preloader resets: a retrying driver can preload again."""
         if dataset is not self._dataset:
             raise RuntimeError("wait() for a dataset that was not preloaded")
-        t = self.timers["wait"]
-        t.start()
         try:
             # the WaitFeedPassDone stall: whatever of the load and of the
             # feed-ahead plan the overlap did NOT hide shows up as this
             # span's width in the exported trace
             plan = None
-            with obs_span(self.WAIT_SPAN):
+            with obs_span(self.WAIT_SPAN) as asked:
                 if self._ahead is None:
                     dataset.wait_preload_done()
                 else:
                     plan = self._ahead.finish()
             if admit_fn is not None and not admit_fn(dataset):
                 return False
+            if plan is not None:
+                account_feed_plan(plan.stamps, asked.t0)
             pre, self._prefetch = self._prefetch, None
             if pre is not None:
                 with obs_span("promote_prefetch_finish"):
@@ -281,7 +316,6 @@ class PassPreloader:
         finally:
             # done, refused or failed: nothing of this preload is kept
             self._reset()
-            t.pause()
 
     def _feed_on_the_boundary(self, allgather) -> None:
         """A table without a plan (ShardedPassTable: its end_feed_pass

@@ -142,7 +142,11 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
     single-host path): stack_fn then runs concurrently with device
     compute instead of serially between dispatches. stack_fn must be
     safe to call off-thread (the table is read-only during a pass). Peak
-    extra memory = prefetch_depth staged chunks.
+    extra memory = prefetch_depth staged chunks. The bounded queue's two
+    edges are spans, one each a chunk: chunk_stage_wait on the caller's
+    thread (a sibling of scan_dispatch and chunk_drain: the consumer waits
+    for the stager) and stage_queue_full on the stager (it waits for the
+    consumer: its slack). Which of the two is wide says who sets the pace.
     Returns (carry, losses, n_consumed)."""
     losses_all: List[float] = []
     if n_items is None:
@@ -194,7 +198,10 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
         def produce():
             try:
                 for item in chunks():
-                    if not _put(item):
+                    # the stager is a chunk ahead and blocked: its slack
+                    with obs_span("stage_queue_full"):
+                        put = _put(item)
+                    if not put:
                         return
             except BaseException as e:   # surfaced at the consumer's get
                 _put(e)
@@ -207,7 +214,9 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
 
         def staged_chunks():
             for _ in range(0, n_full, chunk):
-                item = q.get()
+                # nothing to dispatch: a device that has drained idles here
+                with obs_span("chunk_stage_wait"):
+                    item = q.get()
                 if isinstance(item, BaseException):
                     raise item
                 yield item

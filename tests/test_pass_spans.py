@@ -79,13 +79,14 @@ def fresh_compiles():
         cc.reset_cache()
 
 
-def two_passes(files, feed):
+def two_passes(files, feed, scan_chunk=8):
     """A fresh trainer through two preloaded passes; (losses, ring spans).
     400 examples of 32 a batch: one scan chunk of 8, then 5 single steps."""
     get_tracer().clear()
     trainer = BoxTrainer(
         CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D), hidden=(16,)),
-        table_cfg(), feed, TrainerConfig(dense_lr=0.01), seed=0)
+        table_cfg(), feed,
+        TrainerConfig(dense_lr=0.01, scan_chunk=scan_chunk), seed=0)
     datasets = []
     for _ in range(2):
         ds = BoxDataset(feed, read_threads=1)
@@ -241,6 +242,80 @@ def test_a_pass_s_batches_are_packed_at_the_pull_under_its_steps(run):
     assert {s[5] for s in tail} == {step_trace_id(0, n)
                                     for n in (9, 10, 11, 12, 13,
                                               22, 23, 24, 25, 26)}
+
+
+def test_the_stager_s_queue_has_a_span_on_each_edge(run):
+    """ISSUE 39: the main thread's q.get() is chunk_stage_wait, a sibling
+    of scan_dispatch and chunk_drain inside train_pass; the stager's put
+    is stage_queue_full, after the chunk's host_stage; one each a chunk,
+    both under the pass's id."""
+    main = run["main"]
+    for k in (0, 1):
+        mine = by_pass(run, k)
+        train, = [s for s in mine if s[0] == "train_pass"]
+        wait, = [s for s in mine if s[0] == "chunk_stage_wait"]
+        full, = [s for s in mine if s[0] == "stage_queue_full"]
+        assert wait[1] == main
+        assert train[3] <= wait[3] and wait[4] <= train[4]
+        dispatch, = [s for s in mine if s[0] == "scan_dispatch"]
+        assert wait[4] <= dispatch[3]
+        stage, = [s for s in mine
+                  if s[0] == "host_stage" and s[2] == "chunk-stager"]
+        assert full[2] == "chunk-stager" and full[1] == stage[1] != main
+        assert stage[4] <= full[3] and full[4] <= train[4]
+        # the first chunk: the queue was empty, the consumer waited for
+        # the whole of its pack and stage
+        assert wait[4] >= stage[4]
+    edges = [s for s in run["spans"]
+             if s[0] in ("chunk_stage_wait", "stage_queue_full")]
+    assert len(edges) == 4
+
+
+@pytest.fixture(scope="module")
+def chunks_of_four(data):
+    """The same two passes as three scan chunks of 4 and one single step,
+    at prefetch depth 0, 1 and 2: {depth: (losses, spans)}."""
+    flags.set_flag("dataset_disable_shuffle", True)
+    was = flags.get_flag("chunk_prefetch_depth")
+    out = {}
+    try:
+        for depth in (0, 1, 2):
+            flags.set_flag("chunk_prefetch_depth", depth)
+            out[depth] = two_passes(*data, scan_chunk=4)
+    finally:
+        flags.set_flag("chunk_prefetch_depth", was)
+        flags.set_flag("dataset_disable_shuffle", False)
+    return out
+
+
+@pytest.mark.parametrize("depth", [0, 1, 2])
+def test_each_chunk_has_both_edges_and_no_stager_has_neither(
+        chunks_of_four, depth):
+    """One chunk_stage_wait on the main thread and one stage_queue_full
+    on the stager a chunk; with chunk_prefetch_depth 0 there is no queue
+    and neither span; the losses are the same at every depth."""
+    losses, spans = chunks_of_four[depth]
+    assert losses == chunks_of_four[0][0]
+    main = threading.get_ident()
+    for k in (0, 1):
+        mine = [s for s in spans if s[5] == pass_trace_id(0, k)]
+        train, = [s for s in mine if s[0] == "train_pass"]
+        waits = [s for s in mine if s[0] == "chunk_stage_wait"]
+        fulls = [s for s in mine if s[0] == "stage_queue_full"]
+        assert len(waits) == len(fulls) == (3 if depth else 0)
+        assert sum(s[0] == "scan_dispatch" for s in mine) == 3
+        assert all(s[1] == main for s in waits)
+        assert all(s[2] == "chunk-stager" and s[1] != main for s in fulls)
+        assert all(train[3] <= s[3] and s[4] <= train[4]
+                   for s in waits + fulls)
+        # siblings: a wait overlaps no dispatch and no drain
+        others = [s for s in mine if s[0] in ("scan_dispatch", "chunk_drain")]
+        assert all(w[4] <= o[3] or o[4] <= w[3]
+                   for w in waits for o in others)
+    named = [s for s in spans
+             if s[0] in ("chunk_stage_wait", "stage_queue_full")]
+    assert all(s[5] in (pass_trace_id(0, 0), pass_trace_id(0, 1))
+               for s in named)
 
 
 def test_a_step_id_does_not_outlive_the_step_loop(run):
@@ -417,6 +492,7 @@ def test_scope_map_reads_the_innermost_scope_through_autodiff_wrappers():
 def test_with_obs_trace_off_no_span_and_the_same_losses(run, data):
     flags.set_flag("obs_trace", False)
     flags.set_flag("dataset_disable_shuffle", True)
+    plan_us = stat_get("feed_plan_us"), stat_get("feed_plan_slack_us")
     try:
         losses, spans = two_passes(*data)
     finally:
@@ -425,6 +501,9 @@ def test_with_obs_trace_off_no_span_and_the_same_losses(run, data):
         obs_tracer.configure_from_flags()
     assert spans == []
     assert losses == run["losses"]
+    # ISSUE 39: the plan's clock is its spans' stamps; no span, no time
+    assert plan_us == (stat_get("feed_plan_us"),
+                       stat_get("feed_plan_slack_us"))
 
 
 def test_profiler_trace_marks_the_traced_stretch(monkeypatch, tmp_path):
@@ -471,3 +550,57 @@ def test_scope_times_sums_to_the_ops_total():
     assert acc["fwd_bwd"] == pytest.approx(
         sum(d for (op, _c), d in ops if op == "%fusion.8"), rel=1e-9)
     assert scope_times.main(["scope_times.py"]) == 2
+
+
+# ISSUE 39: the eight metric files over the plan's counters and the
+# stager's two spans, and what each reads from a slice of 2 passes, 16 steps
+FEED_PLAN_METRICS = {
+    "pass_lifecycle.feed_plan_us_per_pass": 1_900_000.0,
+    "pass_lifecycle.feed_plan_load_join_us_per_pass": 60_000.0,
+    "pass_lifecycle.feed_plan_unique_us_per_pass": 680_000.0,
+    "pass_lifecycle.feed_plan_diff_us_per_pass": 390_000.0,
+    "pass_lifecycle.feed_plan_index_us_per_pass": 750_000.0,
+    "pass_lifecycle.feed_plan_slack_us_per_pass": 0.0,
+    "dispatch.stage_wait_ms_per_step": 12.5,
+    "host_stage.queue_full_ms_per_step": 3.0,
+}
+
+
+@pytest.mark.parametrize("name", sorted(FEED_PLAN_METRICS))
+def test_the_metric_files_over_the_plan_s_clock_reduce(name):
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        from harness import reducers
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert spec["kind"] in reducers.KINDS and "workloads" not in entry
+    assert all(spec[k] == entry[k]
+               for k in ("layer", "unit", "moves", "better"))
+    counter = spec["kind"] == "counter_delta_per"
+    assert entry["source"] == ("program_counter" if counter
+                               else "program_span")
+    assert spec["what"].endswith(
+        "a program without the counter reads 0") == counter
+    ctx = {"passes": 2, "steps": 16, "examples": 16 * 32,
+           "counters": {"feed_plan_us": 3_800_000,
+                        "feed_plan_load_join_us": 120_000,
+                        "feed_plan_unique_us": 1_360_000,
+                        "feed_plan_diff_us": 780_000,
+                        "feed_plan_index_us": 1_500_000,
+                        "feed_plan_slack_us": 0},
+           "spans": [("chunk_stage_wait", 1.0, 1.1),
+                     ("chunk_stage_wait", 2.0, 2.1),
+                     ("stage_queue_full", 1.2, 1.224),
+                     ("stage_queue_full", 2.2, 2.224),
+                     ("host_stage", 1.0, 1.2)]}
+    assert reducers.reduce_metric(spec, ctx) == pytest.approx(
+        FEED_PLAN_METRICS[name], rel=1e-9)
+    # the parent's side of a pair: the counters read 0 (stat_get of a name
+    # nobody added), the spans are not there and the metric is left out
+    empty = dict(ctx, spans=[], counters={c: 0 for c in ctx["counters"]})
+    assert reducers.reduce_metric(spec, empty) == (0.0 if counter else None)

@@ -1,6 +1,9 @@
 """Preload overlap (BoxHelper PreLoadIntoMemory/WaitFeedPassDone cadence):
 pipelined passes must train identically to sequential passes."""
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -11,10 +14,12 @@ from paddlebox_tpu.models import CtrDnn
 from paddlebox_tpu.models.base import ModelSpec
 from paddlebox_tpu.parallel.mesh import device_mesh_1d
 from paddlebox_tpu.parallel.sharded_trainer import ShardedBoxTrainer
-from paddlebox_tpu.train.preload import PassPreloader, run_preloaded_passes
+from paddlebox_tpu.obs.tracer import get_tracer, pass_trace_id
+from paddlebox_tpu.train.preload import (FEED_PLAN_COUNTERS, PassPreloader,
+                                         run_preloaded_passes)
 from paddlebox_tpu.train.trainer import BoxTrainer
 from paddlebox_tpu.embedding.pass_table import PassTable
-from paddlebox_tpu.utils.stats import stat_get
+from paddlebox_tpu.utils.stats import gauge_get, stat_get
 
 D = 4
 NUM_SLOTS = 4
@@ -221,3 +226,215 @@ def test_a_refused_window_leaves_the_table_as_it_was():
     table.end_pass()
     assert pre.wait(third) is True
     assert table._rows_base is held[1]
+
+
+# ---- ISSUE 39: the plan carries its clock to the boundary that consumes it
+PLAN_COUNTERS = [c for c, _span in FEED_PLAN_COUNTERS] + ["feed_plan_slack_us"]
+
+
+def plan_counters():
+    return {c: stat_get(c) for c in PLAN_COUNTERS}
+
+
+def delta(after, before):
+    return {c: after[c] - before[c] for c in after}
+
+
+def whole_us(span):
+    return int((span[4] - span[3]) * 1e6)
+
+
+@pytest.fixture(scope="module")
+def three_passes(data):
+    """Three preloaded passes; the six counters as each pass ended, the
+    two gauges as the last one did, and the ring."""
+    from paddlebox_tpu.config import flags
+    files, feed = data
+    flags.set_flag("dataset_disable_shuffle", True)
+    get_tracer().clear()
+    trainer = BoxTrainer(
+        CtrDnn(ModelSpec(num_slots=NUM_SLOTS, slot_dim=3 + D), hidden=(16,)),
+        table_cfg(), feed, TrainerConfig(dense_lr=0.01), seed=0)
+    seen = [plan_counters()]
+    try:
+        run_preloaded_passes(trainer, datasets(files, feed, 3),
+                             after_pass=lambda _k, _s: seen.append(
+                                 plan_counters()))
+    finally:
+        trainer.close()
+        flags.set_flag("dataset_disable_shuffle", False)
+    return {"per_pass": [delta(b, a) for a, b in zip(seen, seen[1:])],
+            "gauges": (gauge_get("feed_plan_last_ms"),
+                       gauge_get("feed_plan_slack_last_ms")),
+            "spans": get_tracer().all_spans()}
+
+
+def span_of(spans, name, k):
+    """Pass k's one span of that name off the main thread's boundary: the
+    chain's stages lie on the feed-ahead thread."""
+    found = [s for s in spans
+             if s[0] == name and s[5] == pass_trace_id(0, k)]
+    assert len(found) == 1, (name, k, found)
+    return found[0]
+
+
+@pytest.mark.parametrize("counter,span", FEED_PLAN_COUNTERS)
+def test_every_installed_plan_adds_its_span_s_width_once(three_passes,
+                                                         counter, span):
+    """wait() adds, for the plan it installs, the whole microseconds of
+    the span the feed-ahead thread recorded under that pass's id: the same
+    perf_counter pair, so counter and span agree to the microsecond."""
+    for k, added in enumerate(three_passes["per_pass"]):
+        if span == "promote_diff" and k == 0:
+            # the first plan has no base and so no diff
+            assert added[counter] == 0
+            assert not [s for s in three_passes["spans"] if s[0] == span
+                        and s[5] == pass_trace_id(0, 0)]
+            continue
+        s = span_of(three_passes["spans"], span, k)
+        assert s[2] == "feed-ahead"
+        assert added[counter] == whole_us(s), (k, counter)
+    assert all(added["feed_plan_us"] > 0
+               for added in three_passes["per_pass"])
+
+
+def test_the_stages_sum_to_at_most_the_chain(three_passes):
+    stages = [c for c, _s in FEED_PLAN_COUNTERS if c != "feed_plan_us"]
+    for added in three_passes["per_pass"]:
+        assert 0 < sum(added[c] for c in stages) <= added["feed_plan_us"]
+
+
+def test_slack_is_the_ask_less_the_chain_s_end_and_excludes_a_wait(
+        three_passes):
+    """feed_plan_slack_us = max(0, open of ingest_wait_preload - close of
+    ingest_feed_ahead); where it is positive the wait joined a thread that
+    had finished."""
+    spans = three_passes["spans"]
+    for k, added in enumerate(three_passes["per_pass"]):
+        ask = span_of(spans, "ingest_wait_preload", k)
+        done = span_of(spans, "ingest_feed_ahead", k)
+        assert added["feed_plan_slack_us"] == max(
+            0, int((ask[3] - done[4]) * 1e6))
+        if added["feed_plan_slack_us"] > 0:
+            assert ask[4] - ask[3] < 1e-3
+        else:
+            assert ask[4] >= done[4]
+
+
+def test_the_gauges_hold_the_newest_pass_s_plan(three_passes):
+    last = three_passes["per_pass"][-1]
+    assert three_passes["gauges"] == (
+        last["feed_plan_us"] / 1000.0, last["feed_plan_slack_us"] / 1000.0)
+
+
+class HeldDataset(KeysDataset):
+    """A load that is done when the test says so."""
+
+    def __init__(self, keys):
+        super().__init__(keys)
+        self.loaded = threading.Event()
+
+    def wait_preload_done(self):
+        assert self.loaded.wait(30.0)
+
+
+@pytest.mark.parametrize("held", ["the_load", "the_train_pass"])
+def test_who_was_held_back_reads_as_wait_or_as_slack(held):
+    """A load that ends 50 ms after wait() asked: a wait, and no slack. A
+    main thread that asks 50 ms after the plan was done (a train_pass that
+    ran on): slack, and a wait of a finished thread's join."""
+    get_tracer().clear()
+    table = PassTable(TableConfig(embedx_dim=D, pass_capacity=64), seed=0)
+    pre = PassPreloader(table)
+    ds = HeldDataset(np.arange(1, 30))
+    before = plan_counters()
+    pre.preload(ds)
+    if held == "the_load":
+        threading.Timer(0.05, ds.loaded.set).start()
+    else:
+        ds.loaded.set()
+        pre._ahead._thread.join()
+        time.sleep(0.05)
+    assert pre.wait(ds) is True
+    added = delta(plan_counters(), before)
+    (wait,) = [s for s in get_tracer().all_spans()
+               if s[0] == "ingest_wait_preload"]
+    if held == "the_load":
+        assert wait[4] - wait[3] >= 0.04
+        assert added["feed_plan_slack_us"] == 0
+        assert added["feed_plan_load_join_us"] >= 40_000
+    else:
+        assert wait[4] - wait[3] < 1e-3
+        assert added["feed_plan_slack_us"] >= 50_000
+    assert added["feed_plan_us"] >= added["feed_plan_load_join_us"]
+
+
+class PlanlessTable:
+    """What PassPreloader asks of a table that offers no plan."""
+
+    def __init__(self):
+        self.keys, self.fed = [], None
+
+    def begin_feed_pass(self):
+        self.keys = []
+
+    def add_keys(self, keys):
+        self.keys.append(keys)
+
+    def end_feed_pass(self):
+        self.fed = np.unique(np.concatenate(self.keys))
+
+
+@pytest.mark.parametrize("case", ["refused", "load_error", "no_plan",
+                                  "redone"])
+def test_only_an_accepted_plan_is_accounted_and_a_redone_one_once(case):
+    """A refused admit_fn, a failed load and a table without a plan add
+    nothing. A plan whose base is gone at the boundary adds the chain that
+    made it, once: the redo's own spans, on the main thread under
+    ingest_feed_pass, are the boundary's."""
+    get_tracer().clear()
+    table = (PlanlessTable() if case == "no_plan" else
+             PassTable(TableConfig(embedx_dim=D, pass_capacity=64), seed=0))
+    pre = PassPreloader(table)
+    if case in ("refused", "load_error", "redone"):
+        first = KeysDataset(np.arange(1, 30))
+        pre.preload(first)
+        assert pre.wait(first) is True
+    before, redone = plan_counters(), stat_get("feed_plan_redone")
+    gauges = (gauge_get("feed_plan_last_ms"),
+              gauge_get("feed_plan_slack_last_ms"))
+    if case == "refused":
+        ds = KeysDataset(np.arange(15, 40))
+        pre.preload(ds)
+        assert pre.wait(ds, admit_fn=lambda _ds: False) is False
+    elif case == "load_error":
+        ds = KeysDataset(np.arange(15, 40), error=OSError("disk gone"))
+        pre.preload(ds)
+        with pytest.raises(OSError):
+            pre.wait(ds)
+    elif case == "no_plan":
+        ds = KeysDataset(np.arange(15, 40))
+        pre.preload(ds)
+        assert pre.wait(ds) is True
+        np.testing.assert_array_equal(table.fed, np.arange(15, 40))
+    else:
+        table.begin_pass()
+        ds = KeysDataset(np.arange(15, 40))
+        pre.preload(ds)                     # planned on the open pass
+        table.end_pass()
+        table.invalidate_residency()        # a save: the plan's base is gone
+        assert pre.wait(ds) is True
+    added = delta(plan_counters(), before)
+    if case != "redone":
+        assert not any(added.values()), added
+        assert gauges == (gauge_get("feed_plan_last_ms"),
+                          gauge_get("feed_plan_slack_last_ms"))
+        return
+    assert stat_get("feed_plan_redone") == redone + 1
+    spans = get_tracer().all_spans()
+    main = threading.get_ident()
+    for counter, name in FEED_PLAN_COUNTERS:
+        ahead = [s for s in spans[::-1] if s[0] == name and s[1] != main]
+        assert added[counter] == whole_us(ahead[0]), counter
+    # the boundary redid the index on the main thread, and no counter has it
+    assert [s for s in spans if s[0] == "feed_route_index" and s[1] == main]
