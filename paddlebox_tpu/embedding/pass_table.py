@@ -37,7 +37,8 @@ from paddlebox_tpu.embedding.accessor import (PushLayout, ValueLayout,
 from paddlebox_tpu.embedding.host_store import HostEmbeddingStore
 from paddlebox_tpu.embedding.native_store import make_host_store
 from paddlebox_tpu.embedding.optimizers import apply_push
-from paddlebox_tpu.embedding.row_map import RowMap, sorted_member
+from paddlebox_tpu.embedding.row_map import (KeyFold, RowMap,
+                                              sorted_member)
 from paddlebox_tpu.obs.device import account_d2h, account_h2d, instrument_jit
 from paddlebox_tpu.obs.tracer import span as obs_span
 from paddlebox_tpu.utils.stats import gauge_set, stat_add
@@ -317,18 +318,61 @@ _SPENT = object()
 
 class FeedPlan(NamedTuple):
     """What a feed pass derives, apart from the table that will run it:
-    the pass's sorted unique keys and the RowMap (native index built)
-    that succeeds ``base``, by rank where ``base`` is None. It holds
-    while ``base`` is the map the slab holds (PassTable.install_feed_plan).
+    the pass's sorted unique keys and the RowMap (with its index) that
+    succeeds ``base``, by rank where ``base`` is None. It holds while
+    ``base`` is the map the slab holds (PassTable.install_feed_plan).
     ``stamps`` is the plan's clock: {span name: (t0, t1)} of the live
-    spans it was derived under (feed_unique, promote_diff where there is
+    spans it was finished under (feed_unique, promote_diff where there is
     a base, feed_route_index; the preloader's feed-ahead thread adds its
-    own two), for the pass that consumes the plan to account
-    (PassPreloader.wait). All 0.0 while tracing is off."""
+    own two), and ``counts`` what deriving it took and found: {counter:
+    amount} (the fold's time and keys, the keys that arrived and left,
+    the index shared or rebuilt), both for the pass that consumes the
+    plan to account (preload.account_feed_plan). The stamps are 0.0
+    while tracing is off."""
     keys: np.ndarray
     rows: RowMap
     base: Optional[RowMap]
     stamps: Dict[str, Tuple[float, float]]
+    counts: Dict[str, int]
+
+
+class FeedFold:
+    """A feed pass being derived, one registered key chunk at a time
+    (PassTable.begin_feed_fold / finish_feed_fold). With a ``base`` each
+    chunk is folded against it as it comes (row_map.KeyFold) under a live
+    span feed_fold, on the thread that brings it; with none the chunks
+    wait for the one sort of the finish. One thread at a time."""
+
+    def __init__(self, base: Optional[RowMap]) -> None:
+        self.base = base
+        self.on_base = None if base is None else KeyFold(base)
+        self.chunks: list = []  # no base: every chunk, for np.unique
+        self.fold_us = 0  # the feed_fold spans' widths, whole us
+
+    @property
+    def folded(self) -> int:
+        """Keys probed against the base so far."""
+        return 0 if self.on_base is None else self.on_base.folded
+
+    def _timed(self, fn, *args):
+        with obs_span("feed_fold") as s:
+            out = fn(*args)
+        self.fold_us += int((s.t1 - s.t0) * 1e6)
+        return out
+
+    def add(self, chunk: np.ndarray) -> None:
+        if self.on_base is None:
+            self.chunks.append(np.asarray(chunk, np.uint64))
+        else:
+            self._timed(self.on_base.add, chunk)
+
+    def arrivals(self) -> np.ndarray:
+        """The keys found to arrive since the last call (sorted unique, in
+        no earlier return): what a promote prefetcher may read ahead.
+        Empty with no base: the whole slab is built then."""
+        if self.on_base is None or not self.on_base.unsettled:
+            return np.empty(0, np.uint64)
+        return self._timed(self.on_base.take_arrivals)
 
 
 class PassTable:
@@ -445,46 +489,78 @@ class PassTable:
 
     def plan_feed_pass(self, chunks: Sequence[np.ndarray],
                        base: Optional[RowMap]) -> FeedPlan:
-        """Derive a pass from its registered key chunks: the sorted
-        unique keys, the capacity check, the rows that succeed ``base``.
-        Reads the chunks and ``base`` and writes no field of the table,
-        so the preloader runs it on a thread of its own while the pass
-        before trains (the stager probes ``base``'s index beside it:
-        route.cc's index is probe-only once built)."""
-        with obs_span("feed_unique") as unique:
-            if len(chunks):
-                keys = np.unique(np.concatenate(
-                    [np.asarray(c, np.uint64) for c in chunks]))
-            else:
-                keys = np.empty(0, dtype=np.uint64)
-        if keys.size > self.capacity - 1:
-            raise RuntimeError(
-                f"pass working set {keys.size} exceeds table "
-                f"pass_capacity {self.capacity} (raise TableConfig.pass_capacity)")
-        return self._assign_rows(
-            keys, base, {"feed_unique": (unique.t0, unique.t1)})
+        """Derive a pass from its registered key chunks, all at once:
+        fold every chunk, then finish. The preloader makes the same two
+        calls a chunk at a time under the load (preload.FeedAhead)."""
+        fold = self.begin_feed_fold(base)
+        for c in chunks:
+            fold.add(c)
+        return self.finish_feed_fold(fold)
 
-    def _assign_rows(self, keys: np.ndarray, base: Optional[RowMap],
-                     stamps: Dict[str, Tuple[float, float]]) -> FeedPlan:
-        """Ask the row owner for this key set's rows. They succeed
-        ``base``'s: keys that stay keep their rows, rows of keys that left
-        are freed, keys that arrive take free rows. With no base (first
-        pass, after invalidate_residency or a test-mode pass), rows
-        0..n-1 by sorted rank. Its two spans' stamps join ``stamps``."""
+    def begin_feed_fold(self, base: Optional[RowMap]) -> FeedFold:
+        """Open the derivation of the pass that succeeds ``base``. It
+        reads ``base`` and writes no field of the table, so the preloader
+        runs it on threads of its own while the pass before trains (the
+        stager probes ``base``'s index beside it: route.cc's index is
+        probe-only once built)."""
+        return FeedFold(base)
+
+    def finish_feed_fold(self, fold: FeedFold) -> FeedPlan:
+        """The plan of the chunks ``fold`` took: the key set finished
+        (with a base, the arrivals settled; with none, np.unique over
+        every chunk), the capacity check, which raises and writes
+        nothing, then the rows that succeed the base and their index."""
+        base = fold.base
+        with obs_span("feed_unique") as unique:
+            if base is not None:
+                fold.on_base.take_arrivals()
+                keys, n = None, fold.on_base.size
+            else:
+                keys = (np.unique(np.concatenate(fold.chunks))
+                        if fold.chunks else np.empty(0, dtype=np.uint64))
+                n = int(keys.size)
+        if n > self.capacity - 1:
+            raise RuntimeError(
+                f"pass working set {n} exceeds table "
+                f"pass_capacity {self.capacity} (raise TableConfig.pass_capacity)")
+        plan = self._assign_rows(
+            keys, base, {"feed_unique": (unique.t0, unique.t1)},
+            fold.on_base)
+        plan.counts.update(feed_plan_fold_us=fold.fold_us,
+                           feed_keys_folded=fold.folded)
+        return plan
+
+    def _assign_rows(self, keys: Optional[np.ndarray],
+                     base: Optional[RowMap],
+                     stamps: Dict[str, Tuple[float, float]],
+                     fold: Optional[KeyFold] = None) -> FeedPlan:
+        """Ask the row owner for the map that succeeds ``base``: keys that
+        stay keep their rows, rows of keys that left are freed, keys that
+        arrive take free rows. From ``fold``, which took the chunks against
+        ``base``, as a delta on it; from the sorted unique ``keys`` where
+        there is none (a plan redone on the boundary). With no base (first
+        pass, after invalidate_residency or a test-mode pass), rows 0..n-1
+        by sorted rank. Then its index: the base's, shared, where nothing
+        arrived and nothing left. Its two spans' stamps join ``stamps``."""
         if base is None:
             # padding_id is never assigned
             rows = RowMap.by_rank(keys, self.capacity - 1)
         else:
             with obs_span("promote_diff") as diff:
-                rows = base.succeed(keys)
+                rows = (base.succeed(keys) if fold is None
+                        else fold.successor())
             stamps["promote_diff"] = (diff.t0, diff.t1)
         with obs_span("feed_route_index") as index:
-            # native key→row hash index, built once per pass and probed per
+            # native key→row hash index, one a key set and probed per
             # batch (~1 cache miss/key vs searchsorted's ~20): the host-side
             # DedupKeysAndFillIdx tier at line rate
-            rows.build_index()
+            shared = rows.index_like(base)
         stamps["feed_route_index"] = (index.t0, index.t1)
-        return FeedPlan(keys, rows, base, stamps)
+        return FeedPlan(rows.keys, rows, base, stamps, {
+            "feed_plan_arrived_keys": int(np.count_nonzero(rows.arrived)),
+            "feed_plan_departed_keys": rows.freed,
+            "feed_index_shared": int(shared),
+            "feed_index_rebuilt": int(not shared)})
 
     def install_feed_plan(self, plan: FeedPlan) -> None:
         """Make a plan the active pass: O(1) while its base is the object
@@ -753,11 +829,12 @@ class PassTable:
 
     # ------------------------------------------------- preload promote hooks
     def promote_prefetch_ctx(self):
-        """(known_fn, store, lock) for preload.PromotePrefetcher, or None
-        when the overlapped promote cannot run (flag off, test mode, store
-        without lookup_present, or no active pass to diff against). The
-        known_fn snapshots THIS pass's key set — exactly the set that will
-        be resident when the next begin_pass diffs."""
+        """(None, store, lock) for preload.PromotePrefetcher, or None when
+        the overlapped promote cannot run (flag off, test mode, store
+        without lookup_present, or no active pass to succeed). No
+        known_fn: the prefetcher of a table that plans is fed by the feed
+        fold (begin_feed_fold) with the keys found to arrive, and probes
+        nothing itself."""
         from paddlebox_tpu.config import flags
         if (not flags.get_flag("incremental_pass")
                 or not flags.get_flag("preload_promote")
@@ -767,18 +844,9 @@ class PassTable:
                 or not hasattr(self.store, "lookup_present")  # boxlint: disable=BX401
                 or self._pass_keys is None or self._pass_keys.size == 0):
             return None
-        # NOTE: the closure diffs against the numpy snapshot, NOT the
-        # native route index — the index handle can be destroyed by an
-        # interleaved eval pass's end_feed_pass while the prefetch thread
-        # is mid-probe; the snapshot array is kept alive by the closure
-        snapshot = self._pass_keys
-
-        def known(keys: np.ndarray) -> np.ndarray:
-            return sorted_member(snapshot, keys)[1]
-
         # handing the ref out, not touching contents: the prefetcher's
         # own accesses are the locked ones (preload.PromotePrefetcher)
-        return known, self.store, self.store_lock  # boxlint: disable=BX401
+        return None, self.store, self.store_lock  # boxlint: disable=BX401
 
     def accept_staged_rows(self, keys: np.ndarray, rows: np.ndarray) -> None:
         """Install the promote stager's prefetched (key, row) pairs for the

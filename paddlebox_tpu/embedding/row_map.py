@@ -6,7 +6,10 @@ next pass's keys, the free rows, and the (key, row) pairs of a touched
 bitmap for the write-back. A key that stays resident keeps its row from
 pass to pass (the BoxPS HBM table's contract); rows of keys that left go
 to the free list and keys that arrive take free rows, lowest first, so
-one seed gives the same rows run after run.
+one seed gives the same rows run after run. The map that succeeds
+another is derived either from the next pass's sorted key set
+(RowMap.succeed) or, a registered key chunk at a time and with no sort
+of what stays, as a delta on it (KeyFold): the same map, field for field.
 """
 
 from __future__ import annotations
@@ -30,6 +33,38 @@ def sorted_member(sorted_keys: np.ndarray, keys: np.ndarray):
     return pos, sorted_keys[pos] == keys
 
 
+def merge_sorted(a: np.ndarray, b: np.ndarray):
+    """(merged, from_b): the sorted union of two sorted unique arrays with
+    no element in common, and the mask of b's places in it. A merge, not a
+    sort: linear in both, plus one binary search an element of b."""
+    at = np.searchsorted(a, b) + np.arange(b.size)
+    from_b = np.zeros(a.size + b.size, bool)
+    from_b[at] = True
+    merged = np.empty(from_b.size, a.dtype)
+    merged[at] = b
+    merged[~from_b] = a
+    return merged, from_b
+
+
+class _NativeIndex:
+    """The one owner of a native key -> row hash index (route.cc). Maps
+    that hold the same keys at the same rows share one; it is destroyed
+    when the last of them, and the last fold probing it, lets go."""
+
+    __slots__ = ("handle",)
+
+    def __init__(self, handle) -> None:
+        self.handle = handle
+
+    def __del__(self):
+        try:
+            from paddlebox_tpu.native.build import destroy_route_index
+            destroy_route_index(self.handle)
+        except Exception:  # rationale: __del__ may run with a
+            # half-torn-down interpreter where even logging fails
+            pass
+
+
 class RowMap:
     """key -> slab row for ONE sorted unique key set, plus the free rows.
 
@@ -41,7 +76,8 @@ class RowMap:
     says rows is arange(n), so a slice does for an index. A map is not
     mutated after construction: succeed() returns the next pass's map and
     leaves this one valid, so a feed pass can be run again before
-    begin_pass. The map owns its native index, which goes when it does."""
+    begin_pass. Its native index is its own, or the one of the map it
+    succeeded where nothing arrived and nothing left (index_like)."""
 
     def __init__(self, keys: np.ndarray, rows: np.ndarray,
                  holes: np.ndarray, top: int, limit: int,
@@ -70,15 +106,22 @@ class RowMap:
         and probe take the searchsorted tier."""
         from paddlebox_tpu.native.build import create_route_index
         if self._index is None:
-            self._index = create_route_index([self.keys], [self.rows])
+            handle = create_route_index([self.keys], [self.rows])
+            if handle is not None:
+                self._index = _NativeIndex(handle)
 
-    def __del__(self):
-        try:
-            from paddlebox_tpu.native.build import destroy_route_index
-            destroy_route_index(self._index)
-        except Exception:  # rationale: __del__ may run with a
-            # half-torn-down interpreter where even logging fails
-            pass
+    def index_like(self, base: Optional["RowMap"]) -> bool:
+        """Give this map its index: ``base``'s own, shared, where this map
+        succeeded ``base`` and nothing arrived and nothing left (the same
+        keys at the same rows, so the same index, key for key); built
+        anew otherwise. True where it is shared."""
+        shared = (base is not None and not self.freed
+                  and not self.arrived.any())
+        if shared:
+            self._index = base._index
+        else:
+            self.build_index()
+        return shared
 
     @property
     def free_rows(self) -> int:
@@ -90,7 +133,7 @@ class RowMap:
         map to padding_id. KeyError for a valid key outside the set."""
         if self._index is not None:
             from paddlebox_tpu.native.build import route_lookup
-            return route_lookup(self._index, keys, valid, padding_id)
+            return route_lookup(self._index.handle, keys, valid, padding_id)
         pos, hit = sorted_member(self.keys, keys)
         ids = (self.rows[pos] if self.keys.size
                else np.zeros(keys.shape, np.int32))
@@ -106,7 +149,7 @@ class RowMap:
         """[K] int32 slab row per key, -1 for a key outside the set."""
         if self._index is not None:
             from paddlebox_tpu.native.build import route_lookup_serve
-            return route_lookup_serve(self._index, keys, -1)
+            return route_lookup_serve(self._index.handle, keys, -1)
         if not self.keys.size:
             return np.full(keys.size, -1, np.int32)
         pos, hit = sorted_member(self.keys, keys)
@@ -119,15 +162,26 @@ class RowMap:
         keys that arrive take free rows, lowest row first in key order."""
         rows = self.probe(keys)
         arrived = rows < 0
-        n_new = int(np.count_nonzero(arrived))
-        holes = self.holes
-        freed = self.keys.size - (keys.size - n_new)
-        if freed:
+        left = np.empty(0, np.int32)
+        if self.keys.size - (keys.size - np.count_nonzero(arrived)):
             # both key arrays are sorted, so the keys that stayed are a
             # sorted subsequence of this map's: rank them, free the rest
             stayed = np.zeros(self.keys.size, bool)
             stayed[np.searchsorted(self.keys, keys[~arrived])] = True
-            holes = np.sort(np.concatenate([holes, self.rows[~stayed]]))
+            left = self.rows[~stayed]
+        return self._followed_by(keys, rows, arrived, left)
+
+    def _followed_by(self, keys: np.ndarray, rows: np.ndarray,
+                     arrived: np.ndarray, left: np.ndarray) -> "RowMap":
+        """The successor over ``keys`` (sorted unique) once the delta is
+        known: rows[i] is the row keys[i] keeps, anything where
+        arrived[i], which is filled in here (``rows`` is the caller's to
+        give away); ``left`` holds the rows of this map's keys that are
+        not among ``keys``."""
+        n_new = int(np.count_nonzero(arrived))
+        holes = self.holes
+        if left.size:
+            holes = np.sort(np.concatenate([holes, left]))
         take = min(n_new, int(holes.size))
         # top grows only once the holes are used up, and then equals
         # keys.size, which the table holds to limit
@@ -136,7 +190,8 @@ class RowMap:
             rows[arrived] = np.concatenate(
                 [holes[:take], np.arange(self.top, top, dtype=np.int32)])
         return RowMap(keys, rows, holes[take:], top, self.limit, arrived,
-                      int(freed), self.dense and not n_new and not freed)
+                      int(left.size),
+                      self.dense and not n_new and not left.size)
 
     def touched(self, bitmap: np.ndarray):
         """(keys, rows) of the assigned rows a touched-row bitmap marks, in
@@ -145,3 +200,88 @@ class RowMap:
         marks = bitmap[:self.keys.size] if self.dense else bitmap[self.rows]
         sel = np.flatnonzero(marks)
         return self.keys[sel], self.rows[sel]
+
+
+class KeyFold:
+    """The key set of the pass that follows ``base`` on the same slab,
+    taken one registered chunk at a time, in any order, with repeats
+    inside and across chunks: each key is probed once against ``base``
+    (its native index, in native code with the GIL released; the
+    searchsorted tier without one). A key ``base`` holds marks its row
+    seen, and ``stayed`` counts the rows marked, so "every key of base
+    was seen" compares two integers; any other key joins ``arrived``, a
+    side set kept sorted unique across chunks, the one thing sorted.
+    successor() is then the map RowMap.succeed gives for the sorted
+    unique union of the chunks, field for field, at the cost of the
+    chunks and of the delta, not of a sort of the whole set. One thread
+    at a time; ``base`` is only read, and kept alive, by the fold."""
+
+    def __init__(self, base: RowMap) -> None:
+        self.base = base
+        # by slab row: every row base assigns lies below its top
+        self._seen = np.zeros(base.top, np.uint8)
+        self.stayed = 0
+        self.folded = 0  # keys probed, repeats included
+        self.arrived = np.empty(0, np.uint64)
+        self._loose: list = []  # misses not yet in `arrived`, as they came
+
+    def add(self, keys: np.ndarray) -> None:
+        keys = np.ascontiguousarray(keys, dtype=np.uint64)
+        base = self.base
+        if base._index is not None:
+            from paddlebox_tpu.native.build import route_fold
+            miss, marked = route_fold(base._index.handle, keys, self._seen)
+        else:
+            pos, hit = sorted_member(base.keys, keys)
+            rows = np.unique(base.rows[pos[hit]])
+            rows = rows[self._seen[rows] == 0]
+            self._seen[rows] = 1
+            miss, marked = keys[~hit], int(rows.size)
+        self.stayed += marked
+        self.folded += int(keys.size)
+        if miss.size:
+            self._loose.append(miss)
+
+    def take_arrivals(self) -> np.ndarray:
+        """Settle the misses of the chunks added since the last call into
+        ``arrived``; returns those no earlier call returned (sorted
+        unique): what a promote prefetcher may read ahead."""
+        if not self._loose:
+            return self.arrived[:0]
+        new = np.unique(np.concatenate(self._loose))
+        self._loose = []
+        new = new[~sorted_member(self.arrived, new)[1]]
+        if new.size:
+            self.arrived = merge_sorted(self.arrived, new)[0]
+        return new
+
+    @property
+    def unsettled(self) -> bool:
+        """Misses wait for take_arrivals."""
+        return bool(self._loose)
+
+    @property
+    def size(self) -> int:
+        """Keys in the set, once the arrivals are settled."""
+        return self.stayed + int(self.arrived.size)
+
+    def successor(self) -> RowMap:
+        """The map of the folded key set: stayed are base's seen keys,
+        which keep their rows; the rest of base's left and free theirs;
+        the misses arrived and take free rows, lowest first in key
+        order. With no delta it holds base's own arrays."""
+        self.take_arrivals()
+        base = self.base
+        n = base.keys.size
+        if self.stayed == n and not self.arrived.size:
+            return RowMap(base.keys, base.rows, base.holes, base.top,
+                          base.limit, np.zeros(n, bool), 0, base.dense)
+        keys, rows, left = base.keys, base.rows, np.empty(0, np.int32)
+        if self.stayed < n:
+            seen = self._seen[:n] if base.dense else self._seen[base.rows]
+            stays = seen.view(bool)
+            keys, rows, left = keys[stays], rows[stays], rows[~stays]
+        keys, arrived = merge_sorted(keys, self.arrived)
+        kept = np.full(keys.size, -1, np.int32)
+        kept[~arrived] = rows
+        return base._followed_by(keys, kept, arrived, left)

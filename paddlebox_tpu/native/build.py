@@ -158,6 +158,10 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.rt_lookup_serve.restype = c.c_int64
     lib.rt_lookup_serve.argtypes = [c.c_void_p, P(c.c_uint64), c.c_int64,
                                     c.c_int32, P(c.c_int32)]
+    lib.rt_fold.restype = c.c_int64
+    lib.rt_fold.argtypes = [c.c_void_p, P(c.c_uint64), c.c_int64,
+                            P(c.c_uint8), c.c_int64, P(c.c_uint64),
+                            P(c.c_int64)]
     lib.rt_dedup.restype = c.c_int64
     lib.rt_dedup.argtypes = [P(c.c_int32), c.c_int64, c.c_int32,
                              P(c.c_int32), P(c.c_int32), P(c.c_int32),
@@ -261,6 +265,52 @@ def route_lookup_serve(handle, keys, miss_id: int):
         keys.shape[0], miss_id,
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
     return out
+
+
+# keys a thread of route_fold takes at least: under it one thread's probe
+# is ~20 ms and a second thread's start buys nothing
+_FOLD_SLICE = 1 << 20
+
+
+def route_fold(handle, keys, seen):
+    """One key chunk of the next pass against the index of the map it
+    succeeds (rt_fold): marks, in ``seen`` (uint8, one byte a row of that
+    map's slab up to its top), the row of every key the index holds, and
+    returns (the keys it does not hold, as they came; how many rows this
+    call marked first). The call releases the GIL; a chunk of several
+    _FOLD_SLICE is folded a slice a thread, on up to half the host's
+    cores: the probes are cache misses one thread cannot keep enough of
+    in flight."""
+    import numpy as np
+    lib = get_lib()
+    keys = np.ascontiguousarray(keys, dtype=np.uint64)
+    if seen.dtype != np.uint8 or not seen.flags.c_contiguous:
+        raise ValueError("route_fold: seen must be contiguous uint8")
+    u8p, u64p = ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_uint64)
+
+    def fold(part):
+        miss = np.empty(part.shape[0], np.uint64)
+        marked = np.zeros(1, np.int64)
+        n_miss = lib.rt_fold(
+            handle, part.ctypes.data_as(u64p), part.shape[0],
+            seen.ctypes.data_as(u8p), seen.shape[0],
+            miss.ctypes.data_as(u64p),
+            marked.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        if n_miss < 0:
+            raise ValueError("route_fold: the index holds a row outside "
+                             "the %d marks" % seen.shape[0])
+        # a copy: the view would keep the chunk-sized buffer alive
+        return miss[:n_miss].copy(), int(marked[0])
+
+    threads = min(keys.shape[0] // _FOLD_SLICE, (os.cpu_count() or 2) // 2)
+    if threads < 2:
+        return fold(keys)
+    from concurrent.futures import ThreadPoolExecutor
+    # two slices may hold one key and both count its mark: count here
+    before = int(np.count_nonzero(seen))
+    with ThreadPoolExecutor(threads) as pool:
+        misses = [m for m, _ in pool.map(fold, np.array_split(keys, threads))]
+    return np.concatenate(misses), int(np.count_nonzero(seen)) - before
 
 
 def load_lib(path: str) -> ctypes.CDLL:

@@ -357,6 +357,56 @@ int64_t rt_lookup_serve(void* index, const uint64_t* keys, int64_t K,
   return 0;
 }
 
+// One key chunk of the NEXT pass folded against the index of the map that
+// pass succeeds (embedding/row_map.py, KeyFold): a key the index holds
+// marks its row in seen[n_rows] (one byte a row; *n_marked counts the
+// rows this call marked first), any other key is appended to miss_out[K]
+// as it came (repeats included: the caller sorts the misses, and nothing
+// else). Returns the number of misses, or -1 for a row outside
+// [0, n_rows): the marks are not this index's. Probe-only on the index,
+// so it runs beside the stager's lookups (THREAD CONTRACT above). Slices
+// of one chunk may be folded on several threads into ONE seen[]: a mark
+// is a relaxed byte store of 1, the same from whoever makes it; only
+// *n_marked can then count a row twice (a key both slices hold), so such
+// a caller counts the marks itself. A chunk in key order probes a slot
+// the cache has never seen for every key, so the slots of the keys
+// kFoldAhead further on are asked for before this one's is read.
+int64_t rt_fold(void* index, const uint64_t* keys, int64_t K,
+                uint8_t* seen, int64_t n_rows, uint64_t* miss_out,
+                int64_t* n_marked) {
+  constexpr int64_t kFoldAhead = 16;
+  RouteIndex* ix = static_cast<RouteIndex*>(index);
+  const uint64_t mask = ix->mask;
+  const uint64_t* const ikeys = ix->keys;
+  const int32_t* const ipos = ix->pos;
+  int64_t n_miss = 0, marked = 0;
+  for (int64_t i = 0; i < K; ++i) {
+    if (i + kFoldAhead < K) {
+      uint64_t ha = mix64(keys[i + kFoldAhead]) & mask;
+      __builtin_prefetch(ikeys + ha);
+      __builtin_prefetch(ipos + ha);
+    }
+    uint64_t k = keys[i];
+    int64_t row;
+    if (k == kEmpty) {  // sentinel-colliding key lives out-of-band
+      row = ix->has_max_key ? ix->max_key_pos : -1;
+    } else {
+      uint64_t h = mix64(k) & mask;
+      while (ikeys[h] != kEmpty && ikeys[h] != k) h = (h + 1) & mask;
+      row = (ikeys[h] == kEmpty) ? -1 : ipos[h];
+    }
+    if (row < 0) {
+      miss_out[n_miss++] = k;
+      continue;
+    }
+    if (row >= n_rows) return -1;
+    marked += !__atomic_load_n(seen + row, __ATOMIC_RELAXED);
+    __atomic_store_n(seen + row, static_cast<uint8_t>(1), __ATOMIC_RELAXED);
+  }
+  *n_marked = marked;
+  return n_miss;
+}
+
 // Per-batch id dedup for the single-shard push (host analog of
 // DedupKeysAndFillIdx, box_wrapper_impl.h:129): hash dedup + counting sort,
 // no comparison sort. Outputs feed push_sparse_hostdedup:

@@ -4,33 +4,44 @@ The BoxHelper cadence (PreLoadIntoMemory / WaitFeedPassDone,
 box_wrapper.h:1131-1172): the dataset's read/parse/merge threads for the
 NEXT pass run concurrently with the device steps of the CURRENT pass.
 
-Key registration buffers OUTSIDE the table (a plain list) so the active
-pass's routing state (_pass_keys / _rows) is untouched while the next
-pass streams in. The feed pass itself (np.unique over every registered
-and parsed key, the resident diff, the native index build) is the most
-expensive thing a pass does with the device idle, and nothing it reads is
-unknown while the previous pass trains: its keys are the buffer, and the
-map the slab will hold at the boundary is the installed pass's own
-(PassTable.next_base). So where the table can derive a pass apart from
-making it the active one (PassTable.plan_feed_pass / install_feed_plan), a
-feed-ahead thread joins the load and plans under pass N's steps, and the
-boundary installs the finished plan: O(1) while the plan's base is the
-object that is resident then, else the assignment is redone there from
-the plan's keys (after a save's invalidate_residency, an eval pass, a
-poisoned pass). A table that offers no plan (ShardedPassTable: its
-end_feed_pass writes the active pass's routing state in place and may run
-a host collective) keeps its feed pass on the boundary, the part the
-reference also leaves in EndFeedPass (box_wrapper.cc:153-168).
+Key registration stays OUTSIDE the table's active state, so the active
+pass's routing state (_pass_keys / _rows) is untouched while the next pass
+streams in. The feed pass itself was the most expensive thing a pass did
+with the device idle, and nothing it reads is unknown while the previous
+pass trains: its keys are the chunks as they are registered, and the map
+the slab will hold at the boundary is the installed pass's own
+(PassTable.next_base), which already has nearly every one of those keys,
+sorted, with its row, behind a native index. So where the table can derive
+a pass apart from making it the active one (PassTable.begin_feed_fold /
+finish_feed_fold / install_feed_plan), the plan is a delta on that base,
+folded a chunk at a time under the load: a feed-fold thread probes each
+chunk against the base as it is registered (the working set at preload(),
+then every parsed block; one probe a key in native code, nothing sorted
+but the keys the base does not hold), a feed-ahead thread joins the load
+and the fold and finishes the plan (the delta's row assignment; the base's
+own index, shared, where nothing arrived and nothing left), and the
+boundary installs it: O(1) while the plan's base is the object that is
+resident then, else the assignment is redone there from the plan's keys
+(after a save's invalidate_residency, an eval pass, a poisoned pass). With
+no base (the first pass, after any of those) the chunks wait for one
+np.unique and rows by rank, as a first pass always did. A table that
+offers no plan (ShardedPassTable: its end_feed_pass writes the active
+pass's routing state in place and may run a host collective) keeps its
+feed pass on the boundary, the part the reference also leaves in
+EndFeedPass (box_wrapper.cc:153-168).
 
 Incremental promote overlap (round-6): with the incremental pass
 lifecycle, most of begin_pass's remaining host cost is store reads for
 keys that are NOT in the currently-resident set but HAVE been seen in
-earlier passes. A PromotePrefetcher thread diffs each arriving key chunk
-against the resident set (hash probe over the live pass index) and reads
-those rows from the host store while the previous pass still trains —
-the same tail-hiding the reference gets from PreLoad/WaitFeedPassDone.
-Creation of genuinely-new keys stays at the pass boundary so init-rng
-draw order (and therefore every bit) matches the non-overlapped path.
+earlier passes. A PromotePrefetcher thread reads those rows from the host
+store while the previous pass still trains, the same tail-hiding the
+reference gets from PreLoad/WaitFeedPassDone. Which keys those are the
+fold already knows (the keys it found missing from the base, unique across
+chunks), and feeds it just them; for a table without a fold an
+UnknownUnseen screen picks them out of the raw chunks on the prefetcher's
+thread. Creation of genuinely-new keys stays at the pass boundary so
+init-rng draw order (and therefore every bit) matches the non-overlapped
+path.
 """
 
 from __future__ import annotations
@@ -42,33 +53,54 @@ from typing import Dict, Iterable, List, Optional, Tuple
 import numpy as np
 
 from paddlebox_tpu.obs import log as obs_log
-from paddlebox_tpu.obs.tracer import (pass_trace_id, span as obs_span,
-                                      trace_ctx, with_current_trace)
+from paddlebox_tpu.obs.tracer import (get_tracer, pass_trace_id,
+                                      span as obs_span, trace_ctx,
+                                      with_current_trace)
 from paddlebox_tpu.utils.stats import gauge_set, stat_add
 
 
-class PromotePrefetcher:
-    """Background diff + host-store read of the next pass's non-resident
-    keys (the overlapped half of the incremental begin_pass).
+class UnknownUnseen:
+    """Screen for the prefetcher of a table that folds nothing
+    (ShardedPassTable): of a batch of raw registered chunks, the keys its
+    known_fn(keys)->bool mask does not mark resident and no earlier batch
+    brought, sorted unique. The dedup stays in numpy (sorted_member probe
+    + union1d merge); a Python set at feed-key line rate would cost
+    hundreds of ms/pass on this thread."""
 
-    known_fn(keys)->bool mask marks keys already resident (the current
-    pass's set — exactly what the next begin_pass will diff against);
-    store.lookup_present(keys)->(rows, found) reads WITHOUT creating, so
-    rng parity with the boundary path holds; lock serializes store access
-    against the current pass's end_pass writeback."""
-
-    def __init__(self, known_fn, store, lock: threading.Lock) -> None:
+    def __init__(self, known_fn) -> None:
         self._known = known_fn
+        self._seen = np.empty(0, np.uint64)
+
+    def __call__(self, chunk: np.ndarray) -> np.ndarray:
+        from paddlebox_tpu.embedding.row_map import sorted_member
+        cand = np.unique(chunk)
+        cand = cand[~self._known(cand)]
+        if cand.size:
+            cand = cand[~sorted_member(self._seen, cand)[1]]
+        if cand.size:
+            self._seen = np.union1d(self._seen, cand)
+        return cand
+
+
+class PromotePrefetcher:
+    """Background host-store read of the next pass's non-resident keys
+    (the overlapped half of the incremental begin_pass).
+
+    It is fed the keys that arrive, each once (the feed fold's
+    arrivals), or, with a ``screen``, raw chunks the screen reduces to
+    those on this thread; store.lookup_present(keys)->(rows, found) reads
+    WITHOUT creating, so rng parity with the boundary path holds; lock
+    serializes store access against the current pass's end_pass
+    writeback."""
+
+    def __init__(self, store, lock: threading.Lock, screen=None) -> None:
+        self._screen = screen
         # the table's store_lock: every store touch from this worker must
         # hold it or race the current pass's end_pass writeback (round-6
         # serialization claim, machine-checked by boxlint BX401)
         self._store = store  # guarded-by: _lock
         self._lock = lock
         self._q: "queue.Queue" = queue.Queue()
-        # sorted accumulated candidate set — the dedup stays in numpy
-        # (sorted_member probe + union1d merge); a Python set at feed-key
-        # line rate would cost hundreds of ms/pass on this thread
-        self._seen = np.empty(0, np.uint64)
         self._keys: List[np.ndarray] = []
         self._rows: List[np.ndarray] = []
         self._err: Optional[BaseException] = None
@@ -82,7 +114,6 @@ class PromotePrefetcher:
         self._q.put(np.asarray(keys, np.uint64))
 
     def _run(self) -> None:
-        from paddlebox_tpu.embedding.pass_table import sorted_member
         try:
             done = False
             while not done:
@@ -90,8 +121,8 @@ class PromotePrefetcher:
                 if chunk is None:
                     return
                 # drain everything already queued: readers feed many small
-                # chunks, and one union over the batch beats one re-sort
-                # of the accumulated set per chunk
+                # chunks, and one store call (and one screen) over the
+                # batch beats one a chunk
                 parts = [chunk]
                 while True:
                     try:
@@ -102,16 +133,11 @@ class PromotePrefetcher:
                         done = True  # process this batch, then exit
                         break
                     parts.append(nxt)
-                chunk = np.concatenate(parts)
-                if not chunk.size:
-                    continue
-                cand = np.unique(chunk)
-                cand = cand[~self._known(cand)]
-                if cand.size:
-                    cand = cand[~sorted_member(self._seen, cand)[1]]
+                cand = np.concatenate(parts)
+                if self._screen is not None and cand.size:
+                    cand = self._screen(cand)
                 if not cand.size:
                     continue
-                self._seen = np.union1d(self._seen, cand)
                 with self._lock:
                     rows, found = self._store.lookup_present(cand)
                 if found.any():
@@ -141,39 +167,91 @@ class PromotePrefetcher:
 
 
 class FeedAhead:
-    """The feed pass of the next pass, planned on a thread of its own
-    under the current pass's training: joins the dataset's load (the
-    final concat and the quality pass run on that join), then plans over
-    the buffered keys on ``base`` (PassTable.plan_feed_pass, which writes
-    no field of the table). Its spans carry the pass it plans for, and
-    the plan carries their stamps (FeedPlan.stamps) to the boundary that
-    consumes it, where PassPreloader.wait accounts them."""
+    """The feed pass of the next pass, derived on two threads of its own
+    under the current pass's training. The feed-fold thread takes the key
+    chunks as they are registered (feed(), from the preloading thread and
+    the dataset's readers) and folds each against ``base``
+    (PassTable.begin_feed_fold: nothing to fold with no base), handing
+    the keys found to arrive to the promote prefetcher. The feed-ahead
+    thread, started once the readers are (start()), joins the dataset's
+    load (the final concat and the quality pass run on that join), then
+    what is left of the fold, then finishes the plan
+    (PassTable.finish_feed_fold, which writes no field of the table).
+    Their spans carry the pass they plan for, and the plan carries their
+    stamps and counts (FeedPlan) to the boundary that consumes it, where
+    PassPreloader.wait accounts them."""
 
-    def __init__(self, table, dataset, buffer: List[np.ndarray],
-                 base) -> None:
+    def __init__(self, table, base, prefetch) -> None:
+        self._table = table
+        self.fold = table.begin_feed_fold(base)
+        self._prefetch = prefetch
+        self._q: "queue.Queue" = queue.Queue()
         self._plan = None
         self._err: Optional[BaseException] = None
+        self._thread: Optional[threading.Thread] = None
+        self._folder = threading.Thread(
+            target=with_current_trace(self._fold_chunks), daemon=True,
+            name="feed-fold")
+        self._folder.start()
+
+    def feed(self, keys: np.ndarray) -> None:
+        self._q.put(keys)
+
+    def _fold_chunks(self) -> None:
+        try:
+            while True:
+                chunk = self._q.get()
+                if chunk is None:
+                    break
+                self.fold.add(chunk)
+                if self._q.empty():
+                    self._hand_arrivals()
+            self._hand_arrivals()
+        except BaseException as e:  # surfaced at finish()
+            self._err = e
+
+    def _hand_arrivals(self) -> None:
+        if self._prefetch is not None:
+            new = self.fold.arrivals()
+            if new.size:
+                self._prefetch.feed(new)
+
+    def start(self, dataset) -> None:
+        """Join the load, and plan, on the feed-ahead thread: called once
+        dataset.preload_into_memory has started the readers."""
         self._thread = threading.Thread(
-            target=with_current_trace(self._run),
-            args=(table, dataset, buffer, base), daemon=True,
-            name="feed-ahead")
+            target=with_current_trace(self._run), args=(dataset,),
+            daemon=True, name="feed-ahead")
         self._thread.start()
 
-    def _run(self, table, dataset, buffer, base) -> None:
+    def _run(self, dataset) -> None:
         try:
             with obs_span("ingest_feed_ahead") as ahead:
-                with obs_span("ingest_load_join") as join:
-                    dataset.wait_preload_done()
-                plan = table.plan_feed_pass(buffer, base)
+                try:
+                    with obs_span("ingest_load_join") as join:
+                        dataset.wait_preload_done()
+                finally:
+                    # what of the fold the load did not hide
+                    with obs_span("feed_fold_join"):
+                        self.stop()
+                if self._err is not None:
+                    raise self._err
+                plan = self._table.finish_feed_fold(self.fold)
             plan.stamps["ingest_load_join"] = (join.t0, join.t1)
             plan.stamps["ingest_feed_ahead"] = (ahead.t0, ahead.t1)
             self._plan = plan
         except BaseException as e:  # surfaced at finish()
             self._err = e
 
+    def stop(self) -> None:
+        """End the feed-fold thread once it has folded what was fed."""
+        self._q.put(None)
+        self._folder.join()
+
     def finish(self):
-        """Join the worker and return its FeedPlan, or raise what it
-        raised (the load's error, the plan's capacity check)."""
+        """Join the workers and return the FeedPlan, or raise what they
+        raised (the load's error, the fold's, the plan's capacity
+        check)."""
         self._thread.join()
         if self._err is not None:
             raise self._err
@@ -188,24 +266,31 @@ FEED_PLAN_COUNTERS = (("feed_plan_us", "ingest_feed_ahead"),
                       ("feed_plan_index_us", "feed_route_index"))
 
 
-def account_feed_plan(stamps: Dict[str, Tuple[float, float]],
-                      t_ask: float) -> None:
+def account_feed_plan(plan, t_ask: float) -> None:
     """Work done ahead for a pass, accounted to the pass that consumes it
-    at the moment it is consumed: the chain's length and its four stages
-    (whole microseconds of the spans' own perf_counter pairs; a plan with
-    no base has no diff: 0), and the slack, how long the finished plan lay
-    waiting before wait() asked for it at ``t_ask``. Slack and a
-    non-trivial ingest_wait_preload exclude each other; a plan redone on
-    the boundary still adds the chain that made it. The two gauges hold
-    the newest pass's values for the pass report, /metrics and the flight
-    recorder. With tracing off every stamp is 0.0 and so is every sum."""
+    at the moment it is consumed: the chain's length and its four serial
+    stages (whole microseconds of the spans' own perf_counter pairs; a
+    plan with no base has no diff: 0), what deriving the plan took and
+    found (FeedPlan.counts: the fold's time and keys, the keys that
+    arrived and left, the index shared or rebuilt), and the slack, how
+    long the finished plan lay waiting before wait() asked for it at
+    ``t_ask``. Slack and a non-trivial ingest_wait_preload exclude each
+    other; a plan redone on the boundary still adds the chain that made
+    it. The two gauges hold the newest pass's values for the pass report,
+    /metrics and the flight recorder. All of it is the tracing's: with
+    obs_trace off every stamp is 0.0, so is every sum, and the counts
+    are not added."""
     def whole_us(t0: float, t1: float) -> int:
         return int((t1 - t0) * 1e6)
 
+    stamps = plan.stamps
     t0, t_done = stamps["ingest_feed_ahead"]
     slack = max(0, whole_us(t_done, t_ask))
     for counter, name in FEED_PLAN_COUNTERS:
         stat_add(counter, whole_us(*stamps.get(name, (0.0, 0.0))))
+    if get_tracer().enabled:
+        for counter, amount in plan.counts.items():
+            stat_add(counter, amount)
     stat_add("feed_plan_slack_us", slack)
     gauge_set("feed_plan_last_ms", whole_us(t0, t_done) / 1000.0)
     gauge_set("feed_plan_slack_last_ms", slack / 1000.0)
@@ -227,39 +312,51 @@ class PassPreloader:
         self._ahead: Optional[FeedAhead] = None
 
     def preload(self, dataset) -> None:
-        """Start the next pass's read threads; returns immediately. When
-        the incremental lifecycle is active, a PromotePrefetcher also
-        starts pulling the next pass's non-resident rows from the host
-        store under the current pass's training. A table that can plan a
-        feed pass apart from installing it (PassTable) gets a feed-ahead
-        thread, which joins the load and plans under that training too."""
+        """Start the next pass's read threads; returns immediately. A
+        table that can plan a feed pass apart from installing it
+        (PassTable) gets a FeedAhead, which folds the key chunks against
+        the map the slab will hold as they are registered, joins the load
+        and plans, all under the current pass's training. When the
+        incremental lifecycle is active, a PromotePrefetcher also pulls
+        the next pass's non-resident rows from the host store under that
+        training: fed by the fold, or through a screen of its own for a
+        table without one."""
         if self._dataset is not None:
             raise RuntimeError("a preload is already in flight")
         self._buffer = []
         self._dataset = dataset
+        plans = hasattr(self.table, "plan_feed_pass")
+        # the base is read here, on the thread that installs and ends
+        # passes: the pass installed now has not begun
+        base = self.table.next_base() if plans else None
         ctx_fn = getattr(self.table, "promote_prefetch_ctx", None)
         ctx = ctx_fn() if ctx_fn is not None else None
         try:
-            if ctx is not None:
-                self._prefetch = PromotePrefetcher(*ctx)
+            # with a plan but no base the slab is built whole: no row
+            # read ahead would be used
+            if ctx is not None and (base is not None or not plans):
+                known, store, lock = ctx
+                self._prefetch = PromotePrefetcher(
+                    store, lock,
+                    screen=None if plans else UnknownUnseen(known))
+            if plans:
+                self._ahead = FeedAhead(self.table, base, self._prefetch)
+                add = self._ahead.feed
+            elif self._prefetch is not None:
                 buf = self._buffer
                 pre = self._prefetch
 
                 def add(keys):
                     buf.append(keys)
                     pre.feed(keys)
-
-                dataset.preload_into_memory(add_keys_fn=add)
             else:
-                dataset.preload_into_memory(add_keys_fn=self._buffer.append)
-            if hasattr(self.table, "plan_feed_pass"):
-                # the base is read here, on the thread that installs and
-                # ends passes: the pass installed now has not begun
-                self._ahead = FeedAhead(self.table, dataset, self._buffer,
-                                        self.table.next_base())
+                add = self._buffer.append
+            dataset.preload_into_memory(add_keys_fn=add)
+            if plans:
+                self._ahead.start(dataset)
         except BaseException:
-            # a failed launch must not wedge the preloader (or leave the
-            # prefetch worker parked on its queue forever)
+            # a failed launch must not wedge the preloader (or leave a
+            # worker parked on its queue forever)
             self._reset()
             raise
 
@@ -272,7 +369,11 @@ class PassPreloader:
                 self._prefetch.stop()
             finally:
                 self._prefetch = None
-        self._ahead = None
+        if self._ahead is not None:
+            try:
+                self._ahead.stop()
+            finally:
+                self._ahead = None
         self._buffer = None
         self._dataset = None
 
@@ -300,7 +401,7 @@ class PassPreloader:
             if admit_fn is not None and not admit_fn(dataset):
                 return False
             if plan is not None:
-                account_feed_plan(plan.stamps, asked.t0)
+                account_feed_plan(plan, asked.t0)
             pre, self._prefetch = self._prefetch, None
             if pre is not None:
                 with obs_span("promote_prefetch_finish"):

@@ -68,13 +68,24 @@ def _device_isolation():
     mon = device.monitor()
     with mon._lock:
         before = list(mon._entries.values())
+    # a module-level jit's entry counts its compiles over the process's
+    # life: whether the suites this worker ran before left one (say
+    # pass_table._writeback_gather, a shape a table) past its warmup is
+    # the scheduler's draw, not this test's doing
+    history = [(e, e.compiles, e.steady_recompiles, e.recompile_flagged)
+               for e in before]
+    for e in before:
+        e.compiles, e.steady_recompiles, e.recompile_flagged = 0, 0, False
     _reset_device_state()
     yield
     _reset_device_state()
     # a module-level jit (pass_table._delta_promote) registers its entry
     # ONCE, at import: put back what this worker's later suites read
-    for entry in before:
-        mon.register(entry)
+    for e, compiles, steady, flagged in history:
+        e.compiles += compiles
+        e.steady_recompiles += steady
+        e.recompile_flagged = e.recompile_flagged or flagged
+        mon.register(e)
 
 
 def _f(x, y):

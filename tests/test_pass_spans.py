@@ -36,8 +36,8 @@ D, NUM_SLOTS = 4, 4
 # feed pass is planned on the feed-ahead thread, under ingest_feed_ahead;
 # ingest_feed_pass, on the main thread, installs the plan
 CHILDREN = {
-    "ingest_feed_ahead": ["ingest_load_join", "feed_unique", "promote_diff",
-                          "feed_route_index"],
+    "ingest_feed_ahead": ["ingest_load_join", "feed_fold_join",
+                          "feed_unique", "promote_diff", "feed_route_index"],
     "pass_end": ["writeback_select", "writeback_d2h", "writeback_decode",
                  "writeback_store", "pass_mem_check"],
     "train_pass": ["pass_begin", "pass_split_batches", "pass_end",
@@ -212,6 +212,24 @@ def test_the_feed_pass_of_pass_1_is_planned_under_pass_0(run):
         assert not [s for s in run["spans"] if s[0] in derived
                     and s[1] == main and f[3] <= s[3] and s[4] <= f[4]]
     assert not [s for s in run["spans"] if s[0] in derived and s[1] == main]
+
+
+def test_pass_1_s_chunks_are_folded_on_a_thread_of_their_own(run):
+    """ISSUE 40: pass 1 succeeds pass 0's map, so each registered key
+    chunk (one a parsed file here) is folded against it on the feed-fold
+    thread under pass 1's id, before the feed-ahead thread, having joined
+    the load and then the fold, finishes the plan; pass 0 has no base and
+    folds nothing."""
+    folds = [s for s in run["spans"] if s[0] == "feed_fold"]
+    assert [s[5] for s in folds] == [pass_trace_id(0, 1)] * 2
+    assert {s[2] for s in folds} == {"feed-fold"}
+    ahead, = [s for s in by_pass(run, 1) if s[0] == "ingest_feed_ahead"]
+    joined, = [s for s in by_pass(run, 1) if s[0] == "feed_fold_join"]
+    unique, = [s for s in by_pass(run, 1) if s[0] == "feed_unique"]
+    assert ahead[2] == "feed-ahead" and ahead[1] not in {s[1] for s in folds}
+    assert all(s[4] <= joined[4] <= unique[3] for s in folds)
+    train0, = [s for s in by_pass(run, 0) if s[0] == "train_pass"]
+    assert all(s[4] <= train0[4] for s in folds), "folded under pass 0"
 
 
 def test_a_pass_s_batches_are_packed_at_the_pull_under_its_steps(run):
@@ -492,7 +510,11 @@ def test_scope_map_reads_the_innermost_scope_through_autodiff_wrappers():
 def test_with_obs_trace_off_no_span_and_the_same_losses(run, data):
     flags.set_flag("obs_trace", False)
     flags.set_flag("dataset_disable_shuffle", True)
-    plan_us = stat_get("feed_plan_us"), stat_get("feed_plan_slack_us")
+    clock = ("feed_plan_us", "feed_plan_slack_us", "feed_plan_fold_us",
+             "feed_keys_folded", "feed_plan_arrived_keys",
+             "feed_plan_departed_keys", "feed_index_shared",
+             "feed_index_rebuilt")
+    plan_us = [stat_get(c) for c in clock]
     try:
         losses, spans = two_passes(*data)
     finally:
@@ -501,9 +523,10 @@ def test_with_obs_trace_off_no_span_and_the_same_losses(run, data):
         obs_tracer.configure_from_flags()
     assert spans == []
     assert losses == run["losses"]
-    # ISSUE 39: the plan's clock is its spans' stamps; no span, no time
-    assert plan_us == (stat_get("feed_plan_us"),
-                       stat_get("feed_plan_slack_us"))
+    # ISSUE 39: the plan's clock is its spans' stamps; no span, no time.
+    # ISSUE 40: nor any of the counts the plan carries beside them
+    assert plan_us == [stat_get(c) for c in clock]
+    assert plan_us[2] > 0 and plan_us[3] > 0    # the traced run added them
 
 
 def test_profiler_trace_marks_the_traced_stretch(monkeypatch, tmp_path):
@@ -563,6 +586,10 @@ FEED_PLAN_METRICS = {
     "pass_lifecycle.feed_plan_slack_us_per_pass": 0.0,
     "dispatch.stage_wait_ms_per_step": 12.5,
     "host_stage.queue_full_ms_per_step": 3.0,
+    # ISSUE 40: the fold, the index shared, the delta's size
+    "pass_lifecycle.feed_plan_fold_us_per_pass": 450_000.0,
+    "pass_lifecycle.feed_index_shared_per_pass": 1.0,
+    "pass_lifecycle.feed_plan_delta_keys_per_pass": 1_250_000.0,
 }
 
 
@@ -592,7 +619,10 @@ def test_the_metric_files_over_the_plan_s_clock_reduce(name):
                         "feed_plan_unique_us": 1_360_000,
                         "feed_plan_diff_us": 780_000,
                         "feed_plan_index_us": 1_500_000,
-                        "feed_plan_slack_us": 0},
+                        "feed_plan_slack_us": 0,
+                        "feed_plan_fold_us": 900_000, "feed_index_shared": 2,
+                        "feed_plan_arrived_keys": 1_300_000,
+                        "feed_plan_departed_keys": 1_200_000},
            "spans": [("chunk_stage_wait", 1.0, 1.1),
                      ("chunk_stage_wait", 2.0, 2.1),
                      ("stage_queue_full", 1.2, 1.224),

@@ -176,25 +176,40 @@ class KeysDataset:
             raise self.error
 
 
-@pytest.mark.parametrize("fault", ["load_error", "capacity_overflow"])
+def plan_threads():
+    return [t.name for t in threading.enumerate()
+            if t.name in ("feed-fold", "feed-ahead", "promote-prefetch")]
+
+
+@pytest.mark.parametrize("fault", ["load_error", "capacity_overflow",
+                                   "fold_error"])
 def test_a_fault_on_the_feed_ahead_thread_surfaces_from_wait(fault):
-    """What the feed-ahead thread raises (the load's error, the plan's
-    capacity check) comes out of wait(); the table is as it was and the
-    preloader takes a fresh preload."""
+    """What the planning threads raise (the load's error, the plan's
+    capacity check, the fold's own) comes out of wait(); the table is as
+    it was, no thread of the preload is left, and the preloader takes a
+    fresh preload."""
     table = PassTable(TableConfig(embedx_dim=D, pass_capacity=64), seed=0)
     pre = PassPreloader(table)
     first = KeysDataset(np.arange(1, 30))
     pre.preload(first)
     assert pre.wait(first) is True
     held = (table._pass_keys, table._rows)
-    bad = (KeysDataset(np.arange(1, 30), error=OSError("disk gone"))
-           if fault == "load_error" else KeysDataset(np.arange(1, 200)))
+    bad, raised, match = {
+        "load_error": (KeysDataset(np.arange(1, 30),
+                                   error=OSError("disk gone")),
+                       OSError, "disk gone"),
+        "capacity_overflow": (KeysDataset(np.arange(1, 200)),
+                              RuntimeError, "pass_capacity"),
+        # ISSUE 40: a chunk the fold cannot take, on the feed-fold thread
+        "fold_error": (KeysDataset(np.arange(1, 30)), ValueError, "literal"),
+    }[fault]
+    if fault == "fold_error":
+        bad.keys = np.array(["not", "a key"])
     pre.preload(bad)
-    with pytest.raises(OSError if fault == "load_error" else RuntimeError,
-                       match="disk gone" if fault == "load_error"
-                       else "pass_capacity"):
+    with pytest.raises(raised, match=match):
         pre.wait(bad)
     assert (table._pass_keys, table._rows) == held
+    assert plan_threads() == []
     again = KeysDataset(np.arange(10, 50))
     pre.preload(again)                      # not "already in flight"
     assert pre.wait(again) is True
@@ -222,6 +237,7 @@ def test_a_refused_window_leaves_the_table_as_it_was():
     table.begin_pass()
     np.testing.assert_array_equal(
         table.lookup_ids(np.arange(1, 21, dtype=np.uint64)), np.arange(20))
+    assert plan_threads() == []             # the fold went with the plan
     pre.preload(third)                      # planned on the open pass
     table.end_pass()
     assert pre.wait(third) is True
@@ -438,3 +454,160 @@ def test_only_an_accepted_plan_is_accounted_and_a_redone_one_once(case):
         assert added[counter] == whole_us(ahead[0]), counter
     # the boundary redid the index on the main thread, and no counter has it
     assert [s for s in spans if s[0] == "feed_route_index" and s[1] == main]
+
+
+# ---- ISSUE 40: the plan is a delta on its base, folded under the load
+FOLD_COUNTERS = ["feed_plan_fold_us", "feed_keys_folded",
+                 "feed_plan_arrived_keys", "feed_plan_departed_keys",
+                 "feed_index_shared", "feed_index_rebuilt"]
+
+
+def fold_counters():
+    return {c: stat_get(c) for c in FOLD_COUNTERS}
+
+
+def wait_until(cond, seconds=30.0):
+    end = time.monotonic() + seconds
+    while not cond() and time.monotonic() < end:
+        time.sleep(0.002)
+    assert cond()
+
+
+def test_chunks_are_folded_while_the_load_is_still_held():
+    """A chunk registered before the load's join is folded before it: the
+    fold's count of probed keys advances while wait_preload_done blocks,
+    nothing is accounted until the install, and the install adds the
+    plan's counts once."""
+    get_tracer().clear()
+    table = PassTable(TableConfig(embedx_dim=D, pass_capacity=64), seed=0)
+    pre = PassPreloader(table)
+    first = KeysDataset(np.arange(1, 30))
+    pre.preload(first)
+    assert pre.wait(first) is True          # no base: nothing was folded
+    table.begin_pass()
+    before = fold_counters()
+    ds = HeldDataset(np.arange(1, 30))      # the same set: zero drift
+    pre.preload(ds)                         # planned on the open pass
+    fold = pre._ahead.fold
+    wait_until(lambda: fold.folded == 29)
+    pre._ahead.feed(np.arange(20, 29, dtype=np.uint64))   # a parsed block
+    wait_until(lambda: fold.folded == 38)
+    assert pre._ahead._thread.is_alive() and not ds.loaded.is_set()
+    assert fold_counters() == before
+    ds.loaded.set()
+    table.end_pass()
+    assert pre.wait(ds) is True
+    added = delta(fold_counters(), before)
+    folds = [s for s in get_tracer().all_spans() if s[0] == "feed_fold"]
+    assert [s[2] for s in folds] == ["feed-fold"] * 2
+    load_join, = [s for s in get_tracer().all_spans()[::-1]
+                  if s[0] == "ingest_load_join"][:1]
+    assert all(s[4] <= load_join[4] for s in folds)
+    assert added == {"feed_plan_fold_us": sum(whole_us(s) for s in folds),
+                     "feed_keys_folded": 38, "feed_plan_arrived_keys": 0,
+                     "feed_plan_departed_keys": 0, "feed_index_shared": 1,
+                     "feed_index_rebuilt": 0}
+    assert table._rows._index is table._rows_base._index
+    table.begin_pass()
+    np.testing.assert_array_equal(
+        table.lookup_ids(np.arange(1, 30, dtype=np.uint64)), np.arange(29))
+    table.end_pass()
+
+
+def test_the_prefetcher_is_fed_the_arrivals_alone_each_once(monkeypatch):
+    """The fold hands the promote prefetcher the keys it found missing
+    from the base, sorted unique and never twice, whatever repeats the
+    chunks bring; the rows it stages are the store's own for those of
+    them the store holds: what a diff of the whole key set would read."""
+    from paddlebox_tpu.train import preload as preload_mod
+    table = PassTable(TableConfig(embedx_dim=D, pass_capacity=256), seed=0)
+    pre = PassPreloader(table)
+    old, resident = np.arange(100, 140), np.arange(1, 60)
+    for keys in (old, resident):            # `old` ends up in the store only
+        ds = KeysDataset(keys)
+        pre.preload(ds)
+        assert pre.wait(ds) is True
+        table.begin_pass()
+        table.end_pass()
+    fed = []
+    real_feed = preload_mod.PromotePrefetcher.feed
+    monkeypatch.setattr(preload_mod.PromotePrefetcher, "feed",
+                        lambda self, keys: (fed.append(np.array(keys)),
+                                            real_feed(self, keys))[1])
+
+    class Blocks(KeysDataset):
+        def preload_into_memory(self, add_keys_fn=None):
+            for block in self.keys:
+                add_keys_fn(block)
+
+    back, new = np.arange(110, 130), np.arange(300, 320)
+    ds = Blocks(np.empty(0))
+    ds.keys = [np.concatenate([resident[:40], back[:12]]).astype(np.uint64),
+               np.concatenate([back[5:], new, back[:3]]).astype(np.uint64),
+               np.concatenate([new[::-1], resident[30:50]]).astype(np.uint64)]
+    pre.preload(ds)
+    assert pre.wait(ds) is True
+    arrivals = np.concatenate([back, new]).astype(np.uint64)
+    assert all((f[1:] > f[:-1]).all() for f in fed)
+    got = np.concatenate(fed)
+    assert got.size == np.unique(got).size
+    np.testing.assert_array_equal(np.sort(got), arrivals)
+    staged_keys, staged_rows = table._staged
+    np.testing.assert_array_equal(staged_keys, back.astype(np.uint64))
+    with table.store_lock:
+        np.testing.assert_array_equal(staged_rows,
+                                      table.store.lookup(staged_keys))
+    before = stat_get("pass_rows_promote_prefetched")
+    table.begin_pass()
+    assert stat_get("pass_rows_promote_prefetched") - before == back.size
+    table.end_pass()
+
+
+def test_readers_feed_the_fold_while_the_stager_probes_the_base():
+    """Eight threads register chunks at once while this one looks keys up
+    through the base's index (probe-only, shared with the fold): every
+    lookup answers and the plan is the one the sorted derivation gives."""
+    import sys
+    table = PassTable(TableConfig(embedx_dim=D, pass_capacity=1 << 15),
+                      seed=0)
+    pre = PassPreloader(table)
+    rng = np.random.RandomState(40)
+    resident = np.unique(rng.randint(1, 1 << 40, 20000).astype(np.uint64))
+    first = KeysDataset(resident)
+    pre.preload(first)
+    assert pre.wait(first) is True
+    table.begin_pass()
+    base = table._rows
+    nxt = np.concatenate([resident[::2], np.unique(
+        rng.randint(1 << 41, 1 << 42, 6000).astype(np.uint64))])
+    blocks = [rng.choice(nxt, 5000) for _ in range(63)] + [nxt]
+
+    class Readers(HeldDataset):
+        def preload_into_memory(self, add_keys_fn=None):
+            self.threads = [threading.Thread(
+                target=lambda part: [add_keys_fn(b) for b in part],
+                args=(blocks[i::8],)) for i in range(8)]
+            for t in self.threads:
+                t.start()
+
+    ds = Readers(np.empty(0))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pre.preload(ds)
+        while any(t.is_alive() for t in ds.threads):
+            np.testing.assert_array_equal(
+                table.lookup_ids(resident[::3]), base.rows[::3])
+        for t in ds.threads:
+            t.join(30.0)
+            assert not t.is_alive()
+        ds.loaded.set()
+        table.end_pass()
+        assert pre.wait(ds) is True
+    finally:
+        sys.setswitchinterval(interval)
+    want = base.succeed(np.unique(nxt))
+    for f in ("keys", "rows", "holes", "top", "arrived", "freed", "dense"):
+        np.testing.assert_array_equal(getattr(table._rows, f),
+                                      getattr(want, f), f)
+    assert table._rows_base is base and plan_threads() == []

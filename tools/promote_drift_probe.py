@@ -23,9 +23,10 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 import numpy as np
 
-SPANS = ("feed_unique", "promote_diff", "feed_route_index", "pass_begin",
-         "promote_store_read", "promote_stage", "promote_dispatch",
-         "pass_end", "writeback_select", "writeback_d2h", "writeback_store")
+SPANS = ("feed_fold", "feed_unique", "promote_diff", "feed_route_index",
+         "pass_begin", "promote_store_read", "promote_stage",
+         "promote_dispatch", "pass_end", "writeback_select", "writeback_d2h",
+         "writeback_store")
 COUNTERS = ("pass_rows_promote_hit", "pass_rows_promote_new",
             "pass_rows_freed", "pass_rows_written_back")
 
