@@ -12,6 +12,7 @@ from paddlebox_tpu.models.bst import BstSeqCtr
 from paddlebox_tpu.models.wide_tower import EpMMoE, TpDeepFM
 from paddlebox_tpu.models.afmoe import AfMoE
 from paddlebox_tpu.models.granite_hybrid import GraniteHybrid
+from paddlebox_tpu.models.nemotron_h import NemotronH
 
 MODEL_ZOO = {
     "ctr_dnn": CtrDnn,
@@ -28,9 +29,10 @@ MODEL_ZOO = {
     "ep_mmoe": EpMMoE,
     "afmoe": AfMoE,
     "granite_hybrid": GraniteHybrid,
+    "nemotron_h": NemotronH,
 }
 
 __all__ = ["mlp_init", "mlp_apply", "CtrDnn", "DeepFM", "WideDeep", "DLRM",
            "MMoE", "ESMM", "JoinPvDnn", "CtrDnnExpand",
            "CtrDnnAux", "BstSeqCtr", "TpDeepFM", "EpMMoE", "AfMoE",
-           "GraniteHybrid", "MODEL_ZOO"]
+           "GraniteHybrid", "NemotronH", "MODEL_ZOO"]

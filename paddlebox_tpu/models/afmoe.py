@@ -184,9 +184,10 @@ class AfMoE:
         with jax.named_scope("moe_route"):
             experts, weights = route(flat, p["router_w"], p["router_b"],
                                      self.top_k, self.route_scale)
-        y, pairs = routed_experts(
+        y, sizes = routed_experts(
             flat.astype(cdt), experts, weights, p["e_gate"], p["e_up"],
             p["e_down"], self.expert_offset, self.num_experts)
+        pairs = sizes.sum()
         with jax.named_scope("dense_mlp"):
             shared = swiglu(flat.astype(cdt), p["s_gate"], p["s_up"],
                             p["s_down"])

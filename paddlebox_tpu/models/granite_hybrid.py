@@ -89,8 +89,9 @@ class GraniteHybrid:
         if any(t not in (MAMBA, ATTENTION) for t in layer_types):
             raise ValueError(f"layer_types {layer_types!r}")
         if ssm_groups != 1:
-            raise ValueError("ops/ssd.py shares one group of B and C over "
-                             f"every head; {ssm_groups} groups asked")
+            raise ValueError("this tower's mixer splits one group of B and "
+                             f"C and norms all of d_inner; {ssm_groups} "
+                             "groups asked (models/nemotron_h.py has them)")
         self.spec = spec
         self.layer_types = tuple(layer_types)
         self.hidden, self.intermediate = hidden, intermediate

@@ -153,14 +153,16 @@ def analyze_compiled(compiled, examples: Optional[int] = None,
 #: step's (train/trainer.py, ops/, embedding/optimizers.py) and
 #: delta_promote's and writeback_gather's (embedding/pass_table.py);
 #: inside fwd_bwd a sequence tower's kernels (models/afmoe.py,
-#: models/granite_hybrid.py, ops/attention.py, ops/routed_experts.py,
-#: ops/ssd.py): the innermost name on an operation's path wins, and the
-#: backward pass's operations carry the forward's
+#: models/granite_hybrid.py, models/nemotron_h.py, ops/attention.py,
+#: ops/routed_experts.py, ops/ssd.py): the innermost name on an
+#: operation's path wins, and the backward pass's operations carry the
+#: forward's
 SCOPE_NAMES = frozenset((
     "pull", "pool", "fwd_bwd", "dense_opt", "push_grads", "push_merge",
     "push_opt", "push_write", "promote_scatter", "writeback_gather",
     "attn_window", "attn_full", "moe_route", "moe_experts", "dense_mlp",
-    "ssm_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm"))
+    "ssm_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm", "moe_latent",
+    "moe_shared"))
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")

@@ -311,7 +311,7 @@ def test_shares_add_up_to_the_uncut_layer(tower):
             full["e_up"][chip:chip + 1], full["e_down"][chip:chip + 1],
             chip, E)
         parts.append(y)
-        pairs += int(n)
+        pairs += int(n.sum())
     assert pairs == B * S * 2               # every pair is some chip's
     total = sum(parts) + ref.swiglu(x, p["s_gate"], p["s_up"], p["s_down"])
     np.testing.assert_allclose(total, whole, rtol=2e-5, atol=2e-5)
@@ -334,7 +334,7 @@ def test_routing_under_imbalance_drops_nothing(tower, bias_at, held_pairs,
     experts, weights = route(x, p["router_w"], p["router_b"], 2, 2.826)
     y, n = jax.jit(routed_experts, static_argnums=(6, 7))(
         x, experts, weights, p["e_gate"], p["e_up"], p["e_down"], 3, 8)
-    assert int(n) == held_pairs == ref.pairs_held(CFG, p, x)
+    assert int(n.sum()) == held_pairs == ref.pairs_held(CFG, p, x)
     # all pairs held is twice a chunk: the second chunk runs too
     assert chunk_rows(B * S, 2, 2, 8) == B * S
     np.testing.assert_allclose(y, ref.routed_part(CFG, p, x), rtol=2e-5,

@@ -515,7 +515,8 @@ def test_flag_off_returns_bare_jit():
 
 @pytest.mark.parametrize("name", ["attn_window", "attn_full", "moe_route",
                                   "moe_experts", "dense_mlp", "ssm_proj",
-                                  "ssm_conv", "ssd_scan", "ssm_gate_norm"])
+                                  "ssm_conv", "ssd_scan", "ssm_gate_norm",
+                                  "moe_latent", "moe_shared"])
 def test_tower_scope_resolves_through_autodiff_and_checkpoint(name):
     """A kernel's scope inside fwd_bwd, under jax.checkpoint and
     value_and_grad: the backward pass's operations, the recomputed

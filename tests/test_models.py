@@ -63,7 +63,7 @@ def test_zoo_registry():
                               "mmoe", "esmm", "join_pv_dnn",
                               "ctr_dnn_expand", "ctr_dnn_aux",
                               "bst_seq_ctr", "tp_deepfm", "ep_mmoe", "afmoe",
-                              "granite_hybrid"}
+                              "granite_hybrid", "nemotron_h"}
 
 
 def test_esmm_entire_space_loss():
