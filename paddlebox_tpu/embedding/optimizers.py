@@ -283,7 +283,6 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
                           layout: ValueLayout,
                           conf: SparseOptimizerConfig,
                           pulled_rows: Optional[jnp.ndarray] = None,
-                          first_idx: Optional[jnp.ndarray] = None,
                           write: str = "scatter") -> jnp.ndarray:
     """Push with HOST-precomputed dedup (PassTable.dedup_for_push): no
     on-device sort. jnp.unique in push_sparse_dedup lowers to an XLA sort of
@@ -299,7 +298,7 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
     perm:       [K] occurrence indices grouped by unique id
     inv_sorted: [K] nondecreasing merged-row index per permuted occurrence
     grads:      [K, push.width] per-occurrence push rows (padding all-zero)
-    pulled_rows/first_idx: optional pull-gather reuse (see _merged_new_rows)
+    pulled_rows: [U, width] optional pull-gather reuse (see _merged_new_rows)
     write: 'scatter' (the classic donated row scatter) or 'blocked'
            (round 11: bucketize the sorted uids into contiguous row
            blocks, place per block with dynamic_update_slice). 'blocked'
@@ -309,7 +308,7 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
            twin lives in push_sparse_rebuild.
     """
     new_rows = _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng,
-                                layout, conf, pulled_rows, first_idx)
+                                layout, conf, pulled_rows)
     if write not in ("scatter", "blocked"):
         raise ValueError(f"hostdedup write strategy {write!r} "
                          "(scatter or blocked)")
@@ -326,22 +325,21 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
 
 
 def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
-                     conf, pulled_rows=None, first_idx=None) -> jnp.ndarray:
+                     conf, pulled_rows=None) -> jnp.ndarray:
     """Shared push prologue: occurrence gather → sorted segment-sum merge →
     row gather → in-table optimizer. Both slab-write strategies (scatter /
     rebuild) consume these rows — keep them in one place so merge or
     lazy-init fixes can't diverge between the two.
 
-    pulled_rows [K, width] + first_idx [U]: the step's pull already
-    gathered every occurrence's full row (DECODED f32 under the bf16 slab
-    diet) from this same pre-update slab, so when given, each unique's row
-    comes from pulled_rows[first_idx[j]] (a [U]-domain gather; host stages
-    first_idx next to the dedup) instead of a second slab-wide gather.
-    The merge, the row take and the update all run over uids.shape[0]
-    slots, padding included: the staged domain is their whole cost.
-    first_idx[j] must be an occurrence index of uids[j] (padding tail
-    entries may point anywhere: their g_show == 0 rows pass through
-    untouched and are never written back)."""
+    pulled_rows [U, width]: the rows of ``uids`` as the step's pull
+    already gathered them (ops/sparse.pull_sparse_unique: DECODED f32
+    under the bf16 slab diet, from this same pre-update slab, an
+    out-of-slab padding uid clipped onto the trash row exactly as the
+    gather below clips it); when given they ARE the push's rows and the
+    slab is not read a second time.
+    The merge and the update run over uids.shape[0] slots, padding
+    included: the staged domain is their whole cost. A padding slot's
+    g_show == 0 row passes through untouched and is never written back."""
     with jax.named_scope("push_merge"):
         sorted_grads = jnp.take(grads, perm, axis=0,
                                 indices_are_sorted=False,
@@ -350,9 +348,8 @@ def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
                                      num_segments=uids.shape[0],
                                      indices_are_sorted=True)
     with jax.named_scope("push_opt"):
-        if pulled_rows is not None and first_idx is not None:
-            rows = jnp.take(pulled_rows, first_idx, axis=0)
-        else:
+        rows = pulled_rows
+        if rows is None:
             rows = decode_slab_rows(
                 jnp.take(slab, uids, axis=0, mode="clip"), layout)
         return _dispatch_apply_push(rows, merged, prng, layout, conf,
@@ -452,8 +449,9 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
       merge  segment scatter-add over inv — same per-unique ascending-
              occurrence addition order as push_sparse_hostdedup's sorted
              segment-sum, so the merged grads are bit-identical
-      first  scatter-min of occurrence indices (the pull-row-reuse index
-             first_occurrence_idx stages host-side for BoxTrainer)
+      first  scatter-min of occurrence indices (the index into the
+             occurrence-domain pulled_rows; BoxTrainer's pull hands the
+             push the rows of uids themselves and needs none)
       pos    (write='rebuild') one [capacity] int32 scatter — the map
              pos_for_rebuild stages host-side, at 4 bytes/slab-row H2D
 
@@ -510,8 +508,7 @@ def push_sparse_rebuild(slab: jnp.ndarray, uids: jnp.ndarray,
                         inv_sorted: jnp.ndarray, grads: jnp.ndarray,
                         prng: jax.Array, layout: ValueLayout,
                         conf: SparseOptimizerConfig,
-                        pulled_rows: Optional[jnp.ndarray] = None,
-                        first_idx: Optional[jnp.ndarray] = None
+                        pulled_rows: Optional[jnp.ndarray] = None
                         ) -> jnp.ndarray:
     """push_sparse_hostdedup with the final row SCATTER replaced by a
     full-slab gather-rebuild: out[r] = new_rows[pos[r]] if pos[r] >= 0 else
@@ -531,7 +528,7 @@ def push_sparse_rebuild(slab: jnp.ndarray, uids: jnp.ndarray,
         # an empty dedup touches nothing by definition
         return slab
     new_rows = _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng,
-                                layout, conf, pulled_rows, first_idx)
+                                layout, conf, pulled_rows)
     with jax.named_scope("push_write"):
         new_rows = encode_slab_rows(new_rows, layout)
         sel = jnp.take(new_rows, jnp.clip(pos, 0, new_rows.shape[0] - 1),

@@ -282,22 +282,16 @@ def dedup_uids_sorted(ids: np.ndarray, pad_base: int) -> np.ndarray:
     return out
 
 
-def first_occurrence_idx(perm: np.ndarray, inv: np.ndarray) -> np.ndarray:
-    """[K] int32 occurrence index of each dedup unique's FIRST occurrence:
-    first_idx[j] is a position into the batch's key vector whose id is
-    uids[j]. Lets the push reuse the pull's already-gathered rows
-    (pulled_rows[first_idx] == slab[uids], see _merged_new_rows) instead of
-    a second slab-wide gather. Padding tail entries point at occurrence 0;
-    their merged g_show is 0 so the row value is never used."""
-    K = perm.shape[0]
-    first = np.zeros(K, np.int32)
-    if K:
-        newseg = np.empty(K, bool)
-        newseg[0] = True
-        np.not_equal(inv[1:], inv[:-1], out=newseg[1:])
-        starts = perm[newseg]
-        first[:starts.shape[0]] = starts
-    return first
+def occurrence_uid_slots(perm: np.ndarray, inv: np.ndarray) -> np.ndarray:
+    """[K] int32 slot in the dedup's uids of each occurrence, in the batch's
+    own order: ids[k] == uids[occ_uid[k]], every value below n_u. The
+    inverse of (perm, inv), which name the same slots in PERMUTED order;
+    no second pass over the keys. The step pulls by it: the slab is
+    gathered once a uid and each occurrence takes its view from that block
+    (ops/sparse.pull_sparse_unique)."""
+    occ_uid = np.empty_like(inv)
+    occ_uid[perm] = inv
+    return occ_uid
 
 
 def pos_for_rebuild(uids: np.ndarray, capacity: int) -> np.ndarray:

@@ -30,7 +30,7 @@ from paddlebox_tpu.embedding.accessor import (PushLayout, ValueLayout,
 @jax.named_scope("pull")
 def pull_view_from_rows(rows: jnp.ndarray,
                         layout: ValueLayout) -> jnp.ndarray:
-    """Pull view [K, 3+D] (show, click, embed_w, embedx) from already
+    """Pull view [N, 3+D] (show, click, embed_w, embedx) from already
     gathered full rows — split out so a step can keep the full rows and
     hand them to the push (which needs the state columns too) without a
     second slab-wide gather."""
@@ -62,6 +62,25 @@ def pull_sparse(slab: jnp.ndarray, ids: jnp.ndarray,
     return pull_view_from_rows(gather_slab_rows(slab, ids, layout), layout)
 
 
+@jax.named_scope("pull")
+def pull_sparse_unique(slab: jnp.ndarray, uids: jnp.ndarray,
+                       occ_uid: jnp.ndarray, layout: ValueLayout):
+    """pull_sparse over the push's unique-row domain: (pull view [K, 3+D],
+    DECODED rows of uids [U, width]). The slab is gathered once a distinct
+    row of the batch (U slots; an out-of-slab padding uid clips onto the
+    trash row), the view is made from those U rows, and each occurrence
+    takes its view from that small block by its slot in uids
+    (occ_uid [K], every value below the dedup's real count). Same bits as
+    pull_sparse(slab, ids): a gather from the slab costs an index three
+    (8,128) tiles of a multi-GB array, and a batch repeats two rows in
+    three. The rows go on to the push as its rows of uids
+    (optimizers._merged_new_rows pulled_rows)."""
+    rows_u = decode_slab_rows(jnp.take(slab, uids, axis=0, mode="clip"),
+                              layout)
+    view_u = pull_view_from_rows(rows_u, layout)
+    return jnp.take(view_u, occ_uid, axis=0, mode="clip"), rows_u
+
+
 @jax.named_scope("push_grads")
 def build_push_grads(d_emb: jnp.ndarray, slots: jnp.ndarray,
                      clicks: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
@@ -80,14 +99,25 @@ def build_push_grads(d_emb: jnp.ndarray, slots: jnp.ndarray,
     show), so a pushed -dL/d(emb) is descent. PushCopy's further factor,
     the batch size, is left to the learning rate: d_emb is the gradient of
     the batch's MEAN loss and is pushed at that scale.
+
+    The rows are assembled K-minor, as one flat [(4+D) * K] vector, and
+    transposed once at the end. A flat vector has one layout, the
+    occurrence on the lanes, so the three per-key vectors enter as they
+    are; built as [K, 1] columns of a [K, 4+D] concatenate, the compiler
+    is free to lay every column out row-major, one value a 128-lane line
+    (40 MB and a relayout copy each at K = 79,872: +0.7 ms a step on a
+    v5e once no K-minor block, the occurrence pull's, anchored them;
+    PERF.md section 6, PR 42). Same values either way.
     """
-    v = valid.astype(d_emb.dtype)[:, None]
-    return jnp.concatenate([
-        slots.astype(d_emb.dtype)[:, None],
+    K, n = d_emb.shape[0], d_emb.shape[1] - 2
+    v = valid.astype(d_emb.dtype)
+    flat = jnp.concatenate([
+        slots.astype(d_emb.dtype),
         v,                                     # show = 1 per occurrence
-        clicks.astype(d_emb.dtype)[:, None] * v,
-        -d_emb[:, 2:] * v,                     # -(embed_g + embedx_g)
-    ], axis=1)
+        clicks.astype(d_emb.dtype) * v,
+        -d_emb[:, 2:].T.reshape(-1) * jnp.tile(v, n),   # -(embed_g, embedx_g)
+    ])
+    return flat.reshape(3 + n, K).T
 
 
 @jax.named_scope("pull")

@@ -35,7 +35,7 @@ from paddlebox_tpu.embedding.accessor import ValueLayout
 from paddlebox_tpu.embedding.optimizers import (push_sparse_hostdedup,
                                                 push_sparse_rebuild)
 from paddlebox_tpu.embedding.pass_table import (PassTable,
-                                                first_occurrence_idx,
+                                                occurrence_uid_slots,
                                                 push_domain)
 from paddlebox_tpu.metrics.auc import MetricRegistry
 from paddlebox_tpu.models.base import ModelSpec
@@ -51,9 +51,8 @@ from paddlebox_tpu.obs.tracer import (current_trace, set_trace,
 from paddlebox_tpu.ops.seqpool import fused_seqpool_cvm, seqpool_sum
 from paddlebox_tpu.ops.sparse import (build_push_grads,
                                       build_push_grads_extended,
-                                      gather_slab_rows,
                                       pull_sparse, pull_sparse_extended,
-                                      pull_view_from_rows)
+                                      pull_sparse_unique)
 from paddlebox_tpu.utils.stats import stat_add
 from paddlebox_tpu.utils.timer import Timer
 
@@ -623,13 +622,17 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
         return loss, (preds, counts)
 
     def _pull(state, batch):
-        """(emb_view, full_rows) — full_rows kept for the push's row reuse
-        (None on the expand path, which pulls a dual view)."""
-        ids = batch["ids"]
+        """(emb_view, rows_u): where the wire carries the push's dedup
+        (a train batch: uids, occ_uid) the slab is gathered once a
+        distinct row and rows_u, the rows of uids, go on to the push;
+        else (eval, predict) the occurrence gather and None, as on the
+        expand path, which pulls a dual view."""
         if use_expand:
-            return pull_sparse_extended(state, ids, layout), None
-        rows = gather_slab_rows(state, ids, layout)
-        return pull_view_from_rows(rows, layout), rows
+            return pull_sparse_extended(state, batch["ids"], layout), None
+        if "occ_uid" in batch:
+            return pull_sparse_unique(state, batch["uids"],
+                                      batch["occ_uid"], layout)
+        return pull_sparse(state, batch["ids"], layout), None
 
     def _sparse_push(slab, demb, batch, sub, pulled_rows=None):
         # per-key click = its instance's label (first task's label)
@@ -651,19 +654,17 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
             raise KeyError(
                 "train batch lacks host dedup (perm/inv) — host_batch must "
                 "run dedup_for_push for train batches")
-        # pull-gather reuse: the pull already gathered every occurrence's
-        # full row from this same pre-update slab
-        fi = batch.get("first_idx") if pulled_rows is not None else None
-        rows = pulled_rows if fi is not None else None
+        # pulled_rows: the rows of uids as the pull gathered them from
+        # this same pre-update slab (None: the push gathers its own)
         if "push_pos" in batch:
             return push_sparse_rebuild(slab, batch["uids"],
                                        batch["push_pos"], batch["perm"],
                                        batch["inv"], push_grads, sub,
-                                       layout, conf, pulled_rows=rows,
-                                       first_idx=fi)
+                                       layout, conf,
+                                       pulled_rows=pulled_rows)
         return push_sparse_hostdedup(slab, batch["uids"], batch["perm"],
                                      batch["inv"], push_grads, sub, layout,
-                                     conf, pulled_rows=rows, first_idx=fi,
+                                     conf, pulled_rows=pulled_rows,
                                      write=("blocked"
                                             if uid_write == "blocked"
                                             else "scatter"))
@@ -907,26 +908,32 @@ class BoxTrainer:
 
     def _trim_push_domain(self, hosts: List[Dict[str, np.ndarray]],
                           n_us: List[Optional[int]]) -> None:
-        """Cut the per-unique-row leaves (uids, first_idx) of staged train
-        dicts to ONE static domain U (pass_table.push_domain of the
-        largest real count among them): uids[:U] holds every real uid, so
-        the push merges, updates and writes the same rows to the same
-        bits over U index slots instead of one an occurrence. perm, inv
-        and ids are per occurrence and keep their [K]; the rebuild map
-        names real uids only and is the same either way. The mark makes
-        every dict of a trainer's life agree on U (tail batches too)
-        until a batch outgrows the bucket."""
-        if not hosts or n_us[0] is None:
-            return          # eval batches carry no push leaves
+        """Cut the per-unique-row leaf (uids) of staged train dicts to ONE
+        static domain U (pass_table.push_domain of the largest real count
+        among them): uids[:U] holds every real uid, so the pull gathers
+        and the push merges, updates and writes the same rows to the same
+        bits over U index slots instead of one an occurrence. perm, inv,
+        occ_uid and ids are per occurrence and keep their [K] (every
+        occ_uid value is below its dict's real count, so below U); the
+        rebuild map names real uids only and is the same either way. The
+        mark makes every dict of a trainer's life agree on U (tail
+        batches too) until a batch outgrows the bucket."""
+        if not hosts:
+            return
+        if n_us[0] is None:
+            # eval batches carry no push leaves: an occurrence gather
+            stat_add("pull_index_slots",
+                     hosts[0]["ids"].shape[0] * len(hosts))
+            return
         K = hosts[0]["uids"].shape[0]
         U = push_domain(max(n_us), K, self._push_domain_mark.get(K, 0))
         self._push_domain_mark[K] = U
         stat_add("push_index_slots", U * len(hosts))
         stat_add("push_unique_rows", sum(n_us))
+        stat_add("pull_index_slots",
+                 (U if "occ_uid" in hosts[0] else K) * len(hosts))
         for h in hosts:
-            for k in ("uids", "first_idx"):
-                if k in h:
-                    h[k] = h[k][:U]
+            h["uids"] = h["uids"][:U]
 
     def _stack_batches_host(self, group: List[PackedBatch]
                             ) -> Dict[str, np.ndarray]:
@@ -962,9 +969,11 @@ class BoxTrainer:
         dedup's real unique count; None for an eval batch, which carries
         no push leaves). A train batch ships ids, segments, ins_valid,
         labels [, dense, rank_offset, aux_offset, labels_<task>] plus the
-        host dedup that _sparse_push eats: uids, perm[K], inv[K]
-        [, first_idx][, push_pos[capacity]]. The per-unique-row leaves
-        are still [K] here: the caller cuts them (_trim_push_domain)."""
+        host dedup: uids, perm[K], inv[K] for _sparse_push
+        [, push_pos[capacity]] and, unless the model pulls the expand
+        view, occ_uid[K], each occurrence's slot in uids, by which _pull
+        gathers the slab once a uid. uids is still [K] here: the caller
+        cuts it (_trim_push_domain)."""
         # per-key slots/valid are derived on device (make_train_step).
         # Touched-row accounting for the incremental EndPass happens in
         # table.lookup_ids (the `ids` passed here already marked the pass
@@ -986,9 +995,9 @@ class BoxTrainer:
                 ids, sort=self._push_write == "blocked")
             out.update(perm=perm, inv=inv, uids=uids)
             if not getattr(self.model, "use_expand", False):
-                # pull-row reuse index — the expand path pulls a dual view
-                # and never consumes it, so don't compute/transfer it there
-                out["first_idx"] = first_occurrence_idx(perm, inv)
+                # the expand path pulls a dual view by occurrence and
+                # never consumes it, so don't compute/transfer it there
+                out["occ_uid"] = occurrence_uid_slots(perm, inv)
             if self._push_write == "rebuild":
                 # the largest transfer: it buys removing the slab scatter
                 # from the step

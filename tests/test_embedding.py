@@ -414,76 +414,3 @@ def test_save_load_roundtrip(tmp_path):
     store2.load(p)
     np.testing.assert_array_equal(
         store2.lookup(keys)[:, acc.EMBED_W], [1, 2, 3])
-
-
-def test_first_occurrence_idx_alignment():
-    """first_idx[j] must be an occurrence position whose id == uids[j], for
-    BOTH dedup backends (native rt_dedup counting sort and the numpy
-    stable-argsort fallback) — the pull-row reuse contract
-    (pulled_rows[first_idx] == slab[uids], _merged_new_rows)."""
-    from paddlebox_tpu.embedding.pass_table import (dedup_ids,
-                                                    first_occurrence_idx)
-    rng = np.random.RandomState(7)
-    for trial in range(4):
-        K = int(rng.randint(3, 200))
-        ids = rng.randint(0, 40, K).astype(np.int32)
-        uids, perm, inv, n_u = dedup_ids(ids, pad_base=1000)
-        first = first_occurrence_idx(perm, inv)
-        assert n_u == int((uids < 1000).sum())
-        np.testing.assert_array_equal(ids[first[:n_u]], uids[:n_u])
-        # numpy fallback path must satisfy the same contract
-        import paddlebox_tpu.native.build as nb
-        saved = nb.get_lib
-        nb.get_lib = lambda: None
-        try:
-            uids2, perm2, inv2, _ = dedup_ids(ids, pad_base=1000)
-        finally:
-            nb.get_lib = saved
-        first2 = first_occurrence_idx(perm2, inv2)
-        np.testing.assert_array_equal(ids[first2[:n_u]], uids2[:n_u])
-
-
-def test_push_pull_row_reuse_matches_slab_gather():
-    """push with pulled_rows/first_idx (the fused step's reuse) must be
-    bit-identical to the slab-gather path, scatter and rebuild both."""
-    init_range = 1e-3
-    from paddlebox_tpu.embedding.optimizers import (push_sparse_hostdedup,
-                                                    push_sparse_rebuild)
-    from paddlebox_tpu.embedding.pass_table import (first_occurrence_idx,
-                                                    pos_for_rebuild)
-    table = TableConfig(embedx_dim=D, pass_capacity=1 << 8,
-                        optimizer=SparseOptimizerConfig(
-                            mf_initial_range=init_range,
-                            mf_create_thresholds=0.0))
-    pt = PassTable(table, seed=6)
-    rng = np.random.RandomState(8)
-    keys = np.unique(rng.randint(1, 10**9, 50).astype(np.uint64))
-    pt.begin_feed_pass(); pt.add_keys(keys); pt.end_feed_pass()
-    pt.begin_pass()
-    K = 96
-    occ = rng.choice(keys, K).astype(np.uint64)
-    valid = rng.rand(K) > 0.2
-    ids = pt.lookup_ids(occ, valid)
-    push = PushLayout(D)
-    grads = rng.randn(K, push.width).astype(np.float32)
-    grads[:, push.SHOW] = 1.0
-    grads[~valid] = 0.0
-    prng = jax.random.PRNGKey(3)
-    slab0 = pt.slab
-    uids, perm, inv, _ = pt.dedup_for_push(ids)
-    first = first_occurrence_idx(perm, inv)
-    pulled = slab0[jnp.asarray(ids)]
-    args = (jnp.asarray(uids), jnp.asarray(perm), jnp.asarray(inv),
-            jnp.asarray(grads), prng, pt.layout, table.optimizer)
-    ref = push_sparse_hostdedup(slab0, *args)
-    got = push_sparse_hostdedup(slab0, *args, pulled_rows=pulled,
-                                first_idx=jnp.asarray(first))
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
-    pos = jnp.asarray(pos_for_rebuild(uids, table.pass_capacity))
-    ref_r = push_sparse_rebuild(slab0, args[0], pos, *args[1:])
-    got_r = push_sparse_rebuild(slab0, args[0], pos, *args[1:],
-                                pulled_rows=pulled,
-                                first_idx=jnp.asarray(first))
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(ref_r))
-    np.testing.assert_array_equal(np.asarray(ref_r), np.asarray(got_r))
-    pt.end_pass()

@@ -1,6 +1,7 @@
-"""The push's unique-row domain U (ISSUE 31): the stager cuts uids and
-first_idx from one slot an occurrence (K) to the power-of-two bucket of
-the dedup's own count, a high-water mark on the trainer.
+"""The push's unique-row domain U (ISSUE 31): the stager cuts uids from
+one slot an occurrence (K) to the power-of-two bucket of the dedup's own
+count, a high-water mark on the trainer; occ_uid, the pull's per-occurrence
+slot in uids (ISSUE 42), keeps its [K].
 
 Contracts under test:
 
@@ -28,9 +29,8 @@ from paddlebox_tpu.config.configs import (SparseOptimizerConfig, TableConfig,
                                           TrainerConfig)
 from paddlebox_tpu.data import BoxDataset, write_synthetic_ctr_files
 from paddlebox_tpu.embedding import accessor as acc
-from paddlebox_tpu.embedding.pass_table import (dedup_ids,
-                                                first_occurrence_idx,
-                                                pos_for_rebuild, push_domain)
+from paddlebox_tpu.embedding.pass_table import (dedup_ids, pos_for_rebuild,
+                                                push_domain)
 from paddlebox_tpu.models import CtrDnn
 from paddlebox_tpu.models.base import ModelSpec
 from paddlebox_tpu.utils.stats import stat_get
@@ -74,15 +74,16 @@ def test_trimmed_domain_push_writes_the_padded_pushs_bits(write, reuse,
     prng = jax.random.PRNGKey(11)
 
     uids, perm, inv, n_u = dedup_ids(ids, cap, sort=write == "blocked")
-    first = first_occurrence_idx(perm, inv)
     U = push_domain(n_u, K)
     assert n_u <= U < K
-    pulled = (acc.decode_slab_rows(slab[jnp.asarray(ids)], layout)
-              if reuse else None)
     flags.set_flag("push_block_rows", 64)
 
     def run(u):
-        fi = jnp.asarray(first[:u]) if reuse else None
+        # the pull's block: the rows of uids[:u], padding clipped onto
+        # the trash row (ops/sparse.pull_sparse_unique)
+        pulled = acc.decode_slab_rows(
+            jnp.take(slab, jnp.asarray(uids[:u]), axis=0, mode="clip"),
+            layout) if reuse else None
         common = (jnp.asarray(perm), jnp.asarray(inv), jnp.asarray(grads),
                   prng, layout, conf)
         if write == "rebuild":
@@ -91,10 +92,9 @@ def test_trimmed_domain_push_writes_the_padded_pushs_bits(write, reuse,
             return push_sparse_rebuild(
                 slab, jnp.asarray(uids[:u]),
                 jnp.asarray(pos_for_rebuild(uids, cap)), *common,
-                pulled_rows=pulled, first_idx=fi)
+                pulled_rows=pulled)
         return push_sparse_hostdedup(slab, jnp.asarray(uids[:u]), *common,
-                                     pulled_rows=pulled, first_idx=fi,
-                                     write=write)
+                                     pulled_rows=pulled, write=write)
 
     padded, trimmed = np.asarray(run(K)), np.asarray(run(U))
     np.testing.assert_array_equal(padded, trimmed)
@@ -105,23 +105,29 @@ def test_trimmed_domain_push_writes_the_padded_pushs_bits(write, reuse,
     assert created.size and (dec[created, acc.MF_SIZE] == D).all()
 
 
-def _ids_case(case: str, K: int = 64) -> np.ndarray:
+def _ids_case(case: str, K: int = 64, trash: int = 999) -> np.ndarray:
     if case == "none":
         return np.zeros(0, np.int32)
     if case == "one":
         return np.full(K, 7, np.int32)
     if case == "all_distinct":
         return np.random.RandomState(2).permutation(K).astype(np.int32)
-    return np.random.RandomState(3).randint(0, K // 4, K).astype(np.int32)
+    rng = np.random.RandomState(3)
+    ids = rng.randint(0, K // 4, K).astype(np.int32)
+    if case == "trash":
+        ids[rng.rand(K) < 0.3] = trash        # padding occurrences
+    return ids
 
 
 @pytest.mark.parametrize("tier", ["native", "numpy", "numpy_sorted"])
-@pytest.mark.parametrize("case", ["none", "one", "all_distinct", "repeats"])
+@pytest.mark.parametrize("case", ["none", "one", "all_distinct", "repeats",
+                                  "trash"])
 def test_dedup_ids_counts_its_real_uids(case, tier):
     from paddlebox_tpu.native.build import available
     if tier == "native" and not available():
         pytest.skip("native library unavailable")
-    ids, pad_base = _ids_case(case), 1000
+    pad_base = 1000
+    ids = _ids_case(case, trash=pad_base - 1)
     if tier == "native":
         uids, perm, inv, n_u = dedup_ids(ids, pad_base)
     else:
@@ -179,14 +185,14 @@ def _trainer(feed, scan_chunk=2, seed=0):
 
 def _fake_hosts(K, n_us):
     return [{"uids": np.arange(K, dtype=np.int32),
-             "first_idx": np.arange(K, dtype=np.int32),
+             "occ_uid": np.zeros(K, dtype=np.int32),
              "perm": np.arange(K, dtype=np.int32)} for _ in n_us]
 
 
 def test_domain_is_a_high_water_mark_on_the_trainer(data):
     """U over a trainer's life: the bucket of the largest count seen for
-    this K, never smaller again; perm stays [K]; another K keeps a mark
-    of its own."""
+    this K, never smaller again; perm and occ_uid stay [K]; another K
+    keeps a mark of its own."""
     _files, feed = data
     tr = _trainer(feed)
     try:
@@ -195,8 +201,8 @@ def test_domain_is_a_high_water_mark_on_the_trainer(data):
             hosts = _fake_hosts(K, n_us)
             tr._trim_push_domain(hosts, n_us)
             assert len({h["uids"].shape for h in hosts}) == 1
-            assert all(h["first_idx"].shape == h["uids"].shape
-                       and h["perm"].shape == (K,) for h in hosts)
+            assert all(h["occ_uid"].shape == h["perm"].shape == (K,)
+                       for h in hosts)
             seen.append(hosts[0]["uids"].shape[0])
         assert seen == [32, 32, 64, 64, 96, 96]
         other = _fake_hosts(4 * K, [50])
@@ -222,7 +228,7 @@ def _run_pass(files, feed, padded: bool):
 
     def spy(group):
         out = stack(group)
-        staged_shapes.append((out["uids"].shape, out["first_idx"].shape,
+        staged_shapes.append((out["uids"].shape, out["occ_uid"].shape,
                               out["perm"].shape))
         return out
 
@@ -252,7 +258,7 @@ def test_one_bucket_one_program_and_the_padded_stagings_store(data):
     assert len(shapes_t) == 4 and shapes_p == [((2, K),) * 3] * 4
     U = shapes_t[0][0][1]
     assert U < K and U == push_domain(U, K)
-    assert shapes_t == [((2, U), (2, U), (2, K))] * 4
+    assert shapes_t == [((2, U), (2, K), (2, K))] * 4
     assert compiles_t == 1 and compiles_p == 1
     assert loss_t == loss_p
     np.testing.assert_array_equal(keys_t, keys_p)

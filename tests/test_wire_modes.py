@@ -3,11 +3,13 @@
 A BoxTrainer train batch has ONE wire. BoxTrainer._host_batch makes it on
 the stager thread: ids, segments, ins_valid, labels [, dense, rank_offset,
 aux_offset, labels_<task>] plus the host dedup of the batch's ids, uids[U]
-and first_idx[U] over the push's unique-row domain (pass_table.push_domain),
-perm[K] and inv[K] per occurrence, push_pos[capacity] under
-push_write=rebuild; an eval batch carries no push leaf. _sparse_push inside
-make_train_step is the one consumer: push_sparse_rebuild where push_pos is
-there, push_sparse_hostdedup(write=scatter|blocked) otherwise.
+over the push's unique-row domain (pass_table.push_domain), perm[K], inv[K]
+and occ_uid[K] (the occurrence's slot in uids) per occurrence,
+push_pos[capacity] under push_write=rebuild; an eval batch carries no push
+leaf. Inside make_train_step _pull gathers the slab once a uid where the
+wire has occ_uid (pull_sparse_unique) and by occurrence where it has not;
+_sparse_push is the one consumer of the rest: push_sparse_rebuild where
+push_pos is there, push_sparse_hostdedup(write=scatter|blocked) otherwise.
 
 Contracts under test:
 
@@ -73,26 +75,35 @@ def push_write(mode):
 
 @contextlib.contextmanager
 def reference_push(on=True):
-    """Test-only: while a step is traced under this, the two push calls
-    of _sparse_push hand the batch's ids (as the pull saw them) and the
-    same grads to push_sparse_dedup: device jnp.unique, none of the
-    host's dedup products read. Yields a dict whose "pushes" counts the
-    substituted calls, so that a run can show it took this road; with
-    on=False nothing is substituted and None is yielded."""
+    """Test-only: while a step is staged and traced under this, the wire
+    loses occ_uid, so _pull gathers by occurrence (pull_sparse over the
+    batch's ids), and the two push calls of _sparse_push hand those ids
+    (as the pull saw them) and the same grads to push_sparse_dedup: device
+    jnp.unique, none of the host's dedup products read. Yields a dict
+    whose "pushes" counts the substituted calls, so that a run can show it
+    took this road; with on=False nothing is substituted and None is
+    yielded."""
     if not on:
         yield None
         return
     import paddlebox_tpu.train.trainer as trainer_mod
     from paddlebox_tpu.embedding.optimizers import push_sparse_dedup
     seen = {"pushes": 0}
-    gather = trainer_mod.gather_slab_rows
+    pull = trainer_mod.pull_sparse
+    host_batch = trainer_mod.BoxTrainer._host_batch
 
-    def spy_gather(slab, ids, layout):
+    def no_occ_uid(self, b, ids):
+        out, n_u = host_batch(self, b, ids)
+        out.pop("occ_uid", None)
+        return out, n_u
+
+    def spy_pull(slab, ids, layout):
         seen["ids"] = ids
-        return gather(slab, ids, layout)
+        return pull(slab, ids, layout)
 
     def ref_hostdedup(slab, uids, perm, inv, grads, prng, layout, conf,
-                      **_kw):
+                      pulled_rows=None, **_kw):
+        assert pulled_rows is None
         seen["pushes"] += 1
         return push_sparse_dedup(slab, seen["ids"], grads, prng, layout,
                                  conf)
@@ -100,7 +111,9 @@ def reference_push(on=True):
     def ref_rebuild(slab, uids, pos, *rest, **kw):
         return ref_hostdedup(slab, uids, *rest, **kw)
 
-    with mock.patch.object(trainer_mod, "gather_slab_rows", spy_gather), \
+    with mock.patch.object(trainer_mod.BoxTrainer, "_host_batch",
+                           no_occ_uid), \
+            mock.patch.object(trainer_mod, "pull_sparse", spy_pull), \
             mock.patch.object(trainer_mod, "push_sparse_hostdedup",
                               ref_hostdedup), \
             mock.patch.object(trainer_mod, "push_sparse_rebuild",
@@ -194,9 +207,10 @@ def _leaf_specs(tree):
 @pytest.mark.parametrize("write", WRITES)
 def test_wire_contract(data, write, mode):
     """The exact leaves of host_batch's dict and of _stack_batches_host's
-    (a dict with a leading chunk axis, never a tuple): uids/first_idx of
-    length U = push_domain(...), perm/inv of K, push_pos only under
-    rebuild, uids ascending under blocked, NO push leaf in test mode."""
+    (a dict with a leading chunk axis, never a tuple): uids of length
+    U = push_domain(...), perm/inv/occ_uid of K with ids == uids[occ_uid],
+    push_pos only under rebuild, uids ascending under blocked, NO push
+    leaf in test mode."""
     files, feed = data
     K, B = feed.key_capacity(), feed.batch_size
     i32 = np.dtype(np.int32)
@@ -220,7 +234,7 @@ def test_wire_contract(data, write, mode):
             if mode == "train":
                 U1 = push_domain(n_us[0], K)
                 U2 = push_domain(max(n_us[1:]), K, U1)
-                want.update(uids=((U1,), i32), first_idx=((U1,), i32),
+                want.update(uids=((U1,), i32), occ_uid=((K,), i32),
                             perm=((K,), i32), inv=((K,), i32))
                 if write == "rebuild":
                     want["push_pos"] = ((CAPACITY,), i32)
@@ -229,9 +243,8 @@ def test_wire_contract(data, write, mode):
             np.testing.assert_array_equal(one["ids"], ids[0])
             staged = tr._stack_batches_host(batches[1:])
             assert isinstance(staged, dict)
-            for k in ("uids", "first_idx"):
-                if k in want:
-                    want[k] = ((U2,), i32)
+            if "uids" in want:
+                want["uids"] = ((U2,), i32)
             assert _leaf_specs(staged) == {
                 k: ((2,) + shp, dt) for k, (shp, dt) in want.items()}
             if mode == "train":
@@ -239,6 +252,11 @@ def test_wire_contract(data, write, mode):
                 real = np.sort(one["uids"][:n_us[0]])
                 np.testing.assert_array_equal(real, np.unique(ids[0]))
                 assert (one["uids"][n_us[0]:] >= CAPACITY).all()
+                np.testing.assert_array_equal(
+                    one["uids"][one["occ_uid"]], ids[0])
+                np.testing.assert_array_equal(
+                    np.take_along_axis(staged["uids"], staged["occ_uid"],
+                                       axis=1), np.stack(ids[1:]))
                 if write == "blocked":
                     assert (np.diff(one["uids"].astype(np.int64)) > 0).all()
                     assert (np.diff(staged["uids"].astype(np.int64),
@@ -318,7 +336,7 @@ def test_train_batch_without_perm_raises(data):
         tr.table.begin_pass()
         b = ds.split_batches(num_workers=1)[0][0]
         batch = tr.device_batch(b, tr.table.lookup_ids(b.keys, b.valid))
-        for k in ("perm", "inv", "uids", "first_idx"):
+        for k in ("perm", "inv", "uids", "occ_uid"):
             del batch[k]
         with pytest.raises(KeyError, match="host dedup"):
             tr.fns.step(tr.table.slab, tr.params, tr.opt_state, batch,
@@ -352,7 +370,6 @@ def test_push_sparse_uidwire_unit():
                                                     push_sparse_uidwire)
     from paddlebox_tpu.embedding.pass_table import (dedup_ids,
                                                     dedup_uids_sorted,
-                                                    first_occurrence_idx,
                                                     pos_for_rebuild)
 
     rng = np.random.RandomState(3)
@@ -370,13 +387,12 @@ def test_push_sparse_uidwire_unit():
     prng = jax.random.PRNGKey(7)
 
     uids, perm, inv, _ = dedup_ids(ids, cap)
-    first = first_occurrence_idx(perm, inv)
     pulled = jnp.asarray(slab[ids])
     host = push_sparse_hostdedup(jnp.asarray(slab), jnp.asarray(uids),
                                  jnp.asarray(perm), jnp.asarray(inv),
                                  jnp.asarray(grads), prng, layout, conf,
-                                 pulled_rows=pulled,
-                                 first_idx=jnp.asarray(first))
+                                 pulled_rows=jnp.asarray(
+                                     slab[np.minimum(uids, cap - 1)]))
     suids = dedup_uids_sorted(ids, cap)
     for pr in (pulled, None):
         wire = push_sparse_uidwire(jnp.asarray(slab), jnp.asarray(suids),
@@ -448,8 +464,7 @@ def test_two_virtual_process_uid_staging():
     from paddlebox_tpu.embedding.accessor import PushLayout, ValueLayout
     from paddlebox_tpu.embedding.optimizers import (push_sparse_hostdedup,
                                                     push_sparse_uidwire)
-    from paddlebox_tpu.embedding.pass_table import (dedup_ids,
-                                                    first_occurrence_idx)
+    from paddlebox_tpu.embedding.pass_table import dedup_ids
     from paddlebox_tpu.parallel.sharded_table import stage_push_dedup
 
     P, KB, shard_cap = 8, 16, 128

@@ -158,7 +158,8 @@ CEILINGS = {
     # ceiling is tight: ~1.5x recorded catches any fat field sneaking
     # into the staged batch. Recorded 2026-08-04 (394,496 B/step: six
     # [K] int32 leaves ids+segments+perm+inv+uids+first_idx at U = K,
-    # + labels + ins_valid; less since the push's domain is cut to U);
+    # + labels + ins_valid; less since the push's domain is cut to U,
+    # and occ_uid[K] rides where first_idx[U] did: five [K] and one [U]);
     # ceiling = ~1.5x
     "device_h2d_bytes_per_step": (394.5e3, 600e3),
     # round-19 streaming plane: drop-to-journal-poll freshness — the
