@@ -4,8 +4,8 @@ The reference keeps per-instance SlotRecord objects pooled in a slab
 allocator (SlotObjPool, data_feed.h:305) to dodge allocation churn. The
 TPU-native pipeline goes further: the native parser emits whole files as
 flat columnar arrays (keys + per-key slot/record ids, labels, dense), and
-batches are packed by pure numpy slicing — no per-record Python objects
-anywhere on the hot path.
+a batch's keys are packed by one native call (numpy where the library is
+missing) — no per-record Python objects anywhere on the hot path.
 """
 
 from __future__ import annotations
@@ -17,6 +17,7 @@ import numpy as np
 
 from paddlebox_tpu.config.configs import DataFeedConfig
 from paddlebox_tpu.data.packer import PackedBatch
+from paddlebox_tpu.native.build import get_lib
 from paddlebox_tpu.utils.stats import stat_add
 
 
@@ -106,19 +107,17 @@ class ColumnarBlock:
 def pack_columnar(block: ColumnarBlock, rec_idx: np.ndarray,
                   feed: DataFeedConfig, kcap: int, num_slots: int,
                   max_lens: np.ndarray) -> PackedBatch:
-    """Pack selected records into one static-shaped batch, fully vectorized.
+    """Pack selected records into one static-shaped batch.
 
     rec_idx: record indices for this batch (≤ batch_size).
     Truncates each (record, slot) run to the slot's max_len and the batch to
-    kcap keys, counting drops (packer contract parity).
+    kcap keys, counting drops (packer contract parity). The [kcap] key
+    arrays are one native call with the GIL released (_pack_keys_native);
+    where it cannot take the batch, _pack_keys_numpy packs the same bits.
     """
     B = feed.batch_size
     n = min(rec_idx.shape[0], B)
     rec_idx = rec_idx[:n]
-    starts = block.rec_offsets[rec_idx]
-    ends = block.rec_offsets[rec_idx + 1]
-    counts = (ends - starts).astype(np.int64)
-    total = int(counts.sum())
 
     labels = np.zeros(B, dtype=np.int32)
     labels[:n] = block.labels[rec_idx]
@@ -144,6 +143,68 @@ def pack_columnar(block: ColumnarBlock, rec_idx: np.ndarray,
             task_labels[t] = arr
 
     stat_add("ingest_ins_packed", n)
+    packed = _pack_keys_native(block, rec_idx, B, kcap, num_slots, max_lens)
+    if packed is None:
+        packed = _pack_keys_numpy(block, rec_idx, B, kcap, num_slots,
+                                  max_lens)
+    keys, slots, segments, valid = packed
+    return PackedBatch(keys=keys, slots=slots, segments=segments, valid=valid,
+                       labels=labels, ins_valid=ins_valid, dense=dense,
+                       n_ins=n, qvalues=qvalues,
+                       cmatch_rank=np.zeros(B, dtype=np.uint64),
+                       task_labels=task_labels)
+
+
+# the parser's columns (keys, key_slot, rec_offsets): the kernel's types
+_BLOCK_DTYPES = (np.dtype(np.uint64), np.dtype(np.int32), np.dtype(np.int64))
+
+
+def _pack_keys_native(block: ColumnarBlock, rec_idx: np.ndarray, B: int,
+                      kcap: int, num_slots: int, max_lens: np.ndarray):
+    """(keys, slots, segments, valid) of pack_columnar in one call of
+    psr_pack_batch (native/slot_parser.cc), which holds no GIL and no
+    shared scratch, so stager and pool threads pack side by side. None
+    where the library is missing, the block's columns are not the
+    parser's, or the kernel declines a kept segment below the one before
+    it (a plugin's slot order: the numpy pack's stable sort repairs it).
+    Counter ingest_batches_packed_native: +1 a batch packed here."""
+    lib = get_lib()
+    cols = (block.keys, block.key_slot, block.rec_offsets)
+    if (lib is None or max_lens.shape != (num_slots,)
+            or max_lens.dtype.kind not in "iu"
+            or block.key_slot.shape != block.keys.shape
+            or tuple(c.dtype for c in cols) != _BLOCK_DTYPES
+            or not all(c.flags.c_contiguous for c in cols)):
+        return None
+    rec_idx = np.ascontiguousarray(rec_idx, np.int64)
+    max_lens = np.ascontiguousarray(max_lens, np.int64)
+    keys = np.zeros(kcap, dtype=np.uint64)
+    slots = np.zeros(kcap, dtype=np.int32)
+    segments = np.full(kcap, B * num_slots - 1, dtype=np.int32)
+    valid = np.zeros(kcap, dtype=bool)
+    dropped = lib.psr_pack_batch(
+        block.keys.ctypes.data, block.key_slot.ctypes.data,
+        block.rec_offsets.ctypes.data, block.rec_offsets.shape[0] - 1,
+        block.keys.shape[0], rec_idx.ctypes.data, rec_idx.shape[0],
+        max_lens.ctypes.data, int(num_slots), int(kcap), keys.ctypes.data,
+        slots.ctypes.data, segments.ctypes.data, valid.ctypes.data)
+    if dropped < 0:
+        return None
+    if dropped:
+        stat_add("packer_keys_dropped", dropped)
+    stat_add("ingest_batches_packed_native", 1)
+    return keys, slots, segments, valid
+
+
+def _pack_keys_numpy(block: ColumnarBlock, rec_idx: np.ndarray, B: int,
+                     kcap: int, num_slots: int, max_lens: np.ndarray):
+    """(keys, slots, segments, valid) of pack_columnar, vectorized numpy:
+    the fallback of _pack_keys_native and its oracle."""
+    n = rec_idx.shape[0]
+    starts = block.rec_offsets[rec_idx]
+    ends = block.rec_offsets[rec_idx + 1]
+    counts = (ends - starts).astype(np.int64)
+    total = int(counts.sum())
     keys = np.zeros(kcap, dtype=np.uint64)
     slots = np.zeros(kcap, dtype=np.int32)
     # padding tail pinned to the last segment id: the native parser emits
@@ -184,11 +245,7 @@ def pack_columnar(block: ColumnarBlock, rec_idx: np.ndarray,
         segments[:w] = seg
         valid[:w] = True
 
-    return PackedBatch(keys=keys, slots=slots, segments=segments, valid=valid,
-                       labels=labels, ins_valid=ins_valid, dense=dense,
-                       n_ins=n, qvalues=qvalues,
-                       cmatch_rank=np.zeros(B, dtype=np.uint64),
-                       task_labels=task_labels)
+    return keys, slots, segments, valid
 
 
 def _run_aranges(counts: np.ndarray) -> np.ndarray:

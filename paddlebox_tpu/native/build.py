@@ -91,6 +91,13 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     c = ctypes
     P = c.POINTER
     _bind_parser(lib)
+    # a batch's columnar pack (data/columnar.pack_columnar): pointers as
+    # plain addresses, so a call costs no pointer objects; the GIL is
+    # released for the call, as for every function of a CDLL
+    vp, i64 = c.c_void_p, c.c_int64
+    lib.psr_pack_batch.restype = i64
+    lib.psr_pack_batch.argtypes = [vp, vp, vp, i64, i64, vp, i64, vp,
+                                   c.c_int32, i64, vp, vp, vp, vp]
     # host store
     lib.hs_create.restype = c.c_void_p
     lib.hs_create.argtypes = [c.c_int32, c.c_double]

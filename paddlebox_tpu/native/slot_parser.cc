@@ -241,4 +241,52 @@ void psr_free(ParsedFile* p) {
   delete p;
 }
 
+// Pack records rec_idx[0..n) of a columnar block (keys, key_slot and
+// rec_offsets as above: n_recs records, n_keys keys) into one batch's key
+// arrays, in one pass: a key's ordinal counts within its CONTIGUOUS run of
+// one slot inside its record; a key at or past max_lens[slot] is dropped,
+// and so is every kept key past kcap; kept key w gets segment
+// i * num_slots + slot, i its record's place in the batch. The out_* arrays
+// come zeroed / padded; [0, w) is written. Returns the dropped count, or -1
+// (declined: the outputs are not to be read) where a kept segment falls
+// below the one before it (a plugin parser's slot order, which the
+// caller's stable-sort repair takes) or an index lies outside the block.
+// No state: any number of threads may call it at once.
+int64_t psr_pack_batch(const uint64_t* keys, const int32_t* key_slot,
+                       const int64_t* rec_offsets, int64_t n_recs,
+                       int64_t n_keys, const int64_t* rec_idx, int64_t n,
+                       const int64_t* max_lens, int32_t num_slots,
+                       int64_t kcap, uint64_t* out_keys, int32_t* out_slots,
+                       int32_t* out_segments, uint8_t* out_valid) {
+  int64_t total = 0;
+  int64_t w = 0;
+  int32_t prev_seg = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t r = rec_idx[i];
+    if (r < 0 || r >= n_recs) return -1;
+    const int64_t lo = rec_offsets[r];
+    const int64_t hi = rec_offsets[r + 1];
+    if (lo < 0 || hi < lo || hi > n_keys) return -1;
+    total += hi - lo;
+    int32_t run_slot = -1;
+    int64_t ordinal = 0;
+    for (int64_t k = lo; k < hi; ++k) {
+      const int32_t s = key_slot[k];
+      if (s < 0 || s >= num_slots) return -1;
+      ordinal = s == run_slot ? ordinal + 1 : 0;
+      run_slot = s;
+      if (ordinal >= max_lens[s] || w >= kcap) continue;
+      const int32_t seg = static_cast<int32_t>(i * num_slots + s);
+      if (w && seg < prev_seg) return -1;
+      out_keys[w] = keys[k];
+      out_slots[w] = s;
+      out_segments[w] = seg;
+      out_valid[w] = 1;
+      prev_seg = seg;
+      ++w;
+    }
+  }
+  return total - w;
+}
+
 }  // extern "C"
