@@ -898,10 +898,28 @@ class PassTable:
             raise RuntimeError("no active pass key set")
         ids = self._rows.lookup(keys, valid, self.padding_id)
         # every staged train batch flows through here, so this is the
-        # ONE accumulation point for the touched-row bitmap (uids are
-        # a subset of these ids)
+        # accumulation point for the touched-row bitmap (uids are a
+        # subset of these ids); a chunk looked up in a plan ahead of its
+        # pass (lookup_in) is marked when that pass takes it
         self.note_touched(ids)
         return ids
+
+    def lookup_in(self, plan: FeedPlan, keys: np.ndarray,
+                  valid: Optional[np.ndarray] = None) -> np.ndarray:
+        """lookup_ids against a plan that is not installed yet (the next
+        pass's first chunk, staged while this pass trains): the keys' rows
+        in ``plan.rows``. It marks nothing, since the touched rows are the
+        open pass's; the ids are the next pass's while it runs that map
+        (runs)."""
+        return plan.rows.lookup(np.asarray(keys, dtype=np.uint64), valid,
+                                self.padding_id)
+
+    def runs(self, rows: RowMap) -> bool:
+        """Whether the open pass runs ``rows``, a plan's map, as it was
+        made: False where the boundary redid the assignment (the plan's
+        base was not resident: invalidate_residency, an eval pass, a
+        poisoned pass)."""
+        return self._in_pass and self._rows is rows
 
     def dedup_for_push(self, ids: np.ndarray, sort: bool = False):
         """Host-side per-batch dedup for push_sparse_hostdedup (see

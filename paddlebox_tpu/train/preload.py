@@ -42,6 +42,14 @@ UnknownUnseen screen picks them out of the raw chunks on the prefetcher's
 thread. Creation of genuinely-new keys stays at the pass boundary so
 init-rng draw order (and therefore every bit) matches the non-overlapped
 path.
+
+Stage ahead: the plan holds the exact key -> row map the boundary
+installs, and staging a batch reads no slab value, so once the plan is
+done the feed-ahead thread goes on to the pass's shuffle, split and first
+scan chunk (the trainer's StagedAhead: pack, lookup in the plan, dedup,
+stack), all while the pass before still trains. The pass dispatches that
+chunk first, while the boundary installed the plan as it was made; where
+the assignment was redone there, the chunk is dropped and staged again.
 """
 
 from __future__ import annotations
@@ -179,15 +187,20 @@ class FeedAhead:
     (PassTable.finish_feed_fold, which writes no field of the table).
     Their spans carry the pass they plan for, and the plan carries their
     stamps and counts (FeedPlan) to the boundary that consumes it, where
-    PassPreloader.wait accounts them."""
+    PassPreloader.wait accounts them. With a ``stage`` (the trainer's
+    StagedAhead), the feed-ahead thread goes on, once the plan is handed
+    out, to stage the pass's first chunk against it under a span
+    stage_ahead of its own; a plan that failed skips it."""
 
-    def __init__(self, table, base, prefetch) -> None:
+    def __init__(self, table, base, prefetch, stage=None) -> None:
         self._table = table
         self.fold = table.begin_feed_fold(base)
         self._prefetch = prefetch
+        self._stage = stage
         self._q: "queue.Queue" = queue.Queue()
         self._plan = None
         self._err: Optional[BaseException] = None
+        self._planned = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._folder = threading.Thread(
             target=with_current_trace(self._fold_chunks), daemon=True,
@@ -242,6 +255,16 @@ class FeedAhead:
             self._plan = plan
         except BaseException as e:  # surfaced at finish()
             self._err = e
+        finally:
+            self._planned.set()
+        if self._stage is None:
+            return
+        if self._plan is None:
+            self._stage.skip()
+            return
+        # outside ingest_feed_ahead: the chain's clock stops at the plan
+        with obs_span("stage_ahead"):
+            self._stage.run(self._plan)
 
     def stop(self) -> None:
         """End the feed-fold thread once it has folded what was fed."""
@@ -249,10 +272,13 @@ class FeedAhead:
         self._folder.join()
 
     def finish(self):
-        """Join the workers and return the FeedPlan, or raise what they
-        raised (the load's error, the fold's, the plan's capacity
-        check)."""
-        self._thread.join()
+        """Wait for the plan and return it, or raise what the workers
+        raised (the load's error, the fold's, the plan's capacity check).
+        A stage ahead goes on after it, on the feed-ahead thread, which is
+        joined where there is none."""
+        self._planned.wait()
+        if self._stage is None or self._err is not None:
+            self._thread.join()
         if self._err is not None:
             raise self._err
         return self._plan
@@ -311,21 +337,26 @@ class PassPreloader:
         self._prefetch: Optional[PromotePrefetcher] = None
         self._ahead: Optional[FeedAhead] = None
 
-    def preload(self, dataset) -> None:
+    def preload(self, dataset, stage=None) -> None:
         """Start the next pass's read threads; returns immediately. A
         table that can plan a feed pass apart from installing it
         (PassTable) gets a FeedAhead, which folds the key chunks against
         the map the slab will hold as they are registered, joins the load
-        and plans, all under the current pass's training. When the
+        and plans, all under the current pass's training, and then runs
+        ``stage`` (the trainer's StagedAhead) against the plan. When the
         incremental lifecycle is active, a PromotePrefetcher also pulls
         the next pass's non-resident rows from the host store under that
         training: fed by the fold, or through a screen of its own for a
-        table without one."""
+        table without one. A ``stage`` that no plan will come for (a table
+        without one, a failed launch) is skipped."""
         if self._dataset is not None:
             raise RuntimeError("a preload is already in flight")
         self._buffer = []
         self._dataset = dataset
         plans = hasattr(self.table, "plan_feed_pass")
+        if stage is not None and not plans:
+            stage.skip()
+            stage = None
         # the base is read here, on the thread that installs and ends
         # passes: the pass installed now has not begun
         base = self.table.next_base() if plans else None
@@ -340,7 +371,8 @@ class PassPreloader:
                     store, lock,
                     screen=None if plans else UnknownUnseen(known))
             if plans:
-                self._ahead = FeedAhead(self.table, base, self._prefetch)
+                self._ahead = FeedAhead(self.table, base, self._prefetch,
+                                        stage)
                 add = self._ahead.feed
             elif self._prefetch is not None:
                 buf = self._buffer
@@ -357,6 +389,8 @@ class PassPreloader:
         except BaseException:
             # a failed launch must not wedge the preloader (or leave a
             # worker parked on its queue forever)
+            if stage is not None:
+                stage.skip()
             self._reset()
             raise
 
@@ -448,34 +482,47 @@ def run_preloaded_passes(trainer, datasets: Iterable,
     its preload (so the reader threads parsing pass N+1 under pass N's
     training, and the feed-ahead thread planning it, carry N+1's id), its
     wait, its train_pass and its release.
+
+    A trainer that stages ahead (BoxTrainer.stage_ahead) gets, with each
+    preload over a table that plans, the pass's shuffle, split and first
+    scan chunk made on the feed-ahead thread once the plan is done, under
+    the pass before; train_pass takes them. Its shuffle seed is drawn at
+    the preload, so in pass order.
     """
     allgather = None
     if getattr(trainer, "multiprocess", False):
         allgather = trainer.fleet.all_gather
     pre = PassPreloader(trainer.table)
+    stage_fn = getattr(trainer, "stage_ahead", None)
     rank = obs_log.get_rank()
     results: List[Dict[str, float]] = []
+
+    def preload(ds):
+        stage = None if stage_fn is None else stage_fn(ds)
+        pre.preload(ds, stage=stage)
+        return {} if stage is None else {"ahead": stage}
+
     it = iter(datasets)
     cur = next(it, None)
     if cur is None:
         return results
     with trace_ctx(pass_trace_id(rank, 0)):
-        pre.preload(cur)
+        ahead = preload(cur)
     while cur is not None:
         k = len(results)  # cur is pass k of this call
         with trace_ctx(pass_trace_id(rank, k)):
             pre.wait(cur, allgather=allgather)
-            nxt = next(it, None)
+            nxt, nxt_ahead = next(it, None), {}
             if nxt is not None:
                 # start pass N+1's read threads and its feed-ahead
                 # plan BEFORE training pass N
                 with trace_ctx(pass_trace_id(rank, k + 1)):
-                    pre.preload(nxt)
-            results.append(trainer.train_pass(cur, preloaded=True))
+                    nxt_ahead = preload(nxt)
+            results.append(trainer.train_pass(cur, preloaded=True, **ahead))
             if after_pass is not None:
                 after_pass(k, results[-1])
             if release:
                 with obs_span("pass_release"):
                     cur.release_memory()
-        cur = nxt
+        cur, ahead = nxt, nxt_ahead
     return results

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -114,7 +115,8 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
                     stack_fn: Callable, carry: Tuple,
                     on_chunk: Callable, timer=None,
                     n_items: Optional[int] = None,
-                    prefetch_depth: int = 0):
+                    prefetch_depth: int = 0,
+                    first: Optional[Callable] = None):
     """Drive the megastep over full chunks of `items`, double-buffered:
     chunk i+1 is host-stacked and dispatched BEFORE chunk i's results are
     pulled to host, so H2D staging and metric extraction overlap device
@@ -146,13 +148,21 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
     thread (a sibling of scan_dispatch and chunk_drain: the consumer waits
     for the stager) and stage_queue_full on the stager (it waits for the
     consumer: its slack). Which of the two is wide says who sets the pace.
+
+    first(), when given, hands back the first full chunk as (group,
+    stacked), staged elsewhere (BoxTrainer's, under the pass before): it
+    is taken under chunk_stage_wait on the caller's thread, at any
+    prefetch depth, and dispatched first; the chunks staged here start at
+    items[chunk], so ``items`` is then a sequence, and n_consumed counts
+    the first chunk's items too.
     Returns (carry, losses, n_consumed)."""
     losses_all: List[float] = []
     if n_items is None:
         n_items = len(items)
-    it = iter(items)
     # chunk=1 means "megastep off": everything falls to the per-step path
     n_full = (n_items // chunk) * chunk if chunk > 1 else 0
+    lo0 = chunk if first is not None and n_full else 0
+    it = iter(items[lo0:] if lo0 else items)
     pending = None  # (lo, group, losses_dev, preds_dev)
 
     def drain(p):
@@ -165,7 +175,7 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
         # the ONE definition of chunk grouping + staging, shared by both
         # paths (a grouping change applied to only one would silently
         # diverge prefetch-on and prefetch-off runs)
-        for lo in range(0, n_full, chunk):
+        for lo in range(lo0, n_full, chunk):
             if stop is not None and stop.is_set():
                 # consumer already exited — bail BEFORE the next stack_fn,
                 # not just between queue puts (a long native dedup here
@@ -179,7 +189,7 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
 
     stop = None
     producer = None
-    if prefetch_depth > 0 and n_full:
+    if prefetch_depth > 0 and n_full > lo0:
         import queue as _queue
         import threading as _threading
         q: "_queue.Queue" = _queue.Queue(maxsize=prefetch_depth)
@@ -212,7 +222,7 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
         producer.start()
 
         def staged_chunks():
-            for _ in range(0, n_full, chunk):
+            for _ in range(lo0, n_full, chunk):
                 # nothing to dispatch: a device that has drained idles here
                 with obs_span("chunk_stage_wait"):
                     item = q.get()
@@ -222,6 +232,13 @@ def run_scan_chunks(scan_call: Callable, items, chunk: int,
         source = staged_chunks()
     else:
         source = chunks()
+    if lo0:
+        def with_first(rest):
+            with obs_span("chunk_stage_wait"):
+                group, stacked = first()
+            yield 0, group, stacked
+            yield from rest
+        source = with_first(source)
 
     try:
         for lo, group, stacked in source:
@@ -751,6 +768,70 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                         uid_write=uid_write)
 
 
+class StagedAhead:
+    """A pass's shuffle, split and first scan chunk, made under the pass
+    before it (BoxTrainer.stage_ahead). ``seed`` is the pass's shuffle
+    seed, drawn when this is made, on the thread that drives the passes
+    and in their order. Once the pass's feed plan is finished, run(plan)
+    (preload.FeedAhead, on the feed-ahead thread) shuffles and splits the
+    dataset, then packs the first ``scan_chunk`` batches and stages them
+    as any chunk is staged, but looked up in the plan's map
+    (PassTable.lookup_in, which marks nothing) and kept on the host: the
+    consuming pass makes the device copy, so that no chunk of the next
+    pass lives on the device under this one's steps and boundary. skip()
+    settles one that no plan came for. The consuming train_pass waits on
+    ``split`` for the batches and on ``done`` for the chunk; an error
+    raised here is raised there."""
+
+    def __init__(self, trainer: "BoxTrainer", dataset: BoxDataset,
+                 seed: int) -> None:
+        self._trainer = trainer
+        self.dataset = dataset
+        self.seed = seed
+        self.rows = None         # the plan's RowMap, where a plan came
+        self.push_write: Optional[str] = None
+        self.batches = None      # the pass's BatchPlan
+        self.group: Optional[List[PackedBatch]] = None
+        self.host: Optional[Dict[str, np.ndarray]] = None
+        self.err: Optional[BaseException] = None
+        self.split = threading.Event()
+        self.done = threading.Event()
+
+    def run(self, plan) -> None:
+        tr = self._trainer
+        try:
+            try:
+                self.rows, self.push_write = plan.rows, tr._push_write
+                self.dataset.local_shuffle(self.seed)
+                self.batches = self.dataset.split_batches(num_workers=1)[0]
+            finally:
+                self.split.set()
+            chunk = tr._scan_chunk()
+            if chunk and len(self.batches) >= chunk:
+                pool = tr._host_pool()
+                with obs_span("ingest_pack"):
+                    # the native pack releases the GIL: this chunk's
+                    # batches pack on the staging pool, as they are then
+                    # looked up, since its stage is on the critical path
+                    # from the plan to the pass's first dispatch
+                    pull = self.batches.__getitem__
+                    group = (list(pool.map(pull, range(chunk)))
+                             if pool is not None
+                             else [pull(i) for i in range(chunk)])
+                with obs_span("host_stage"):
+                    self.host = tr._stack_batches_host(
+                        group, functools.partial(tr.table.lookup_in, plan))
+                self.group = group
+        except BaseException as e:  # raised by the consuming train_pass
+            self.err = e
+        finally:
+            self.done.set()
+
+    def skip(self) -> None:
+        self.split.set()
+        self.done.set()
+
+
 class BoxTrainer:
     """Single-host trainer over one PassTable + model. The sharded multi-chip
     variant lives in parallel/ (same pass cadence, pjit-compiled step)."""
@@ -829,11 +910,14 @@ class BoxTrainer:
             getattr(_w(), "table", None), "_slab", None))
         register_owner("dense_params", lambda: getattr(_w(), "params", None))
         register_owner("opt_state", lambda: getattr(_w(), "opt_state", None))
-        self._stage_pool = None  # lazy host-staging thread pool
+        # two stagers may run at once (a pass's chunk-stager and the next
+        # pass's StagedAhead): the pool and the mark are theirs in turn
+        self._stage_lock = threading.RLock()
+        self._stage_pool = None  # guarded-by: _stage_lock
         # largest unique-row domain staged so far, by a batch's occurrence
         # count K: never shrinks, so the step compiles once a bucket
         # (_trim_push_domain)
-        self._push_domain_mark: Dict[int, int] = {}
+        self._push_domain_mark: Dict[int, int] = {}  # guarded-by: _stage_lock
         self._step_count = 0
         self._shuffle_rng = np.random.RandomState(seed + 1)
         self.multi_task = len(getattr(model, "task_names", ("ctr",))) > 1
@@ -865,9 +949,10 @@ class BoxTrainer:
         if self.dump_writer is not None:
             self.dump_writer.close()
             self.dump_writer = None
-        if self._stage_pool and self._stage_pool[1] is not None:
-            self._stage_pool[1].shutdown(wait=False)
-        self._stage_pool = None
+        with self._stage_lock:
+            if self._stage_pool and self._stage_pool[1] is not None:
+                self._stage_pool[1].shutdown(wait=False)
+            self._stage_pool = None
         if getattr(self, "reporter", None) is not None:
             self.reporter.close()
 
@@ -889,22 +974,24 @@ class BoxTrainer:
         serial."""
         from paddlebox_tpu.config import flags
         n = int(flags.get_flag("stack_threads"))
-        cur_n, pool = self._stage_pool or (0, None)
-        if n != cur_n:
-            if pool is not None:
-                pool.shutdown(wait=False)
-            if n > 1:
-                from concurrent.futures import ThreadPoolExecutor
-                pool = ThreadPoolExecutor(n,
-                                          thread_name_prefix="pbtpu-stage")
-            else:
-                pool = None
-            self._stage_pool = (n, pool)
+        with self._stage_lock:
+            cur_n, pool = self._stage_pool or (0, None)
+            if n != cur_n:
+                if pool is not None:
+                    pool.shutdown(wait=False)
+                if n > 1:
+                    from concurrent.futures import ThreadPoolExecutor
+                    pool = ThreadPoolExecutor(
+                        n, thread_name_prefix="pbtpu-stage")
+                else:
+                    pool = None
+                self._stage_pool = (n, pool)
         return pool
 
-    def _stage_one(self, b: PackedBatch
+    def _stage_one(self, b: PackedBatch, lookup: Optional[Callable] = None
                    ) -> Tuple[Dict[str, np.ndarray], Optional[int]]:
-        return self._host_batch(b, self.table.lookup_ids(b.keys, b.valid))
+        lookup = self.table.lookup_ids if lookup is None else lookup
+        return self._host_batch(b, lookup(b.keys, b.valid))
 
     def _trim_push_domain(self, hosts: List[Dict[str, np.ndarray]],
                           n_us: List[Optional[int]]) -> None:
@@ -926,8 +1013,9 @@ class BoxTrainer:
                      hosts[0]["ids"].shape[0] * len(hosts))
             return
         K = hosts[0]["uids"].shape[0]
-        U = push_domain(max(n_us), K, self._push_domain_mark.get(K, 0))
-        self._push_domain_mark[K] = U
+        with self._stage_lock:
+            U = push_domain(max(n_us), K, self._push_domain_mark.get(K, 0))
+            self._push_domain_mark[K] = U
         stat_add("push_index_slots", U * len(hosts))
         stat_add("push_unique_rows", sum(n_us))
         stat_add("pull_index_slots",
@@ -935,25 +1023,32 @@ class BoxTrainer:
         for h in hosts:
             h["uids"] = h["uids"][:U]
 
-    def _stack_batches_host(self, group: List[PackedBatch]
+    def _stack_batches_host(self, group: List[PackedBatch],
+                            lookup: Optional[Callable] = None
                             ) -> Dict[str, np.ndarray]:
         """Stack a chunk of packed batches on a leading scan axis as HOST
-        arrays (the device conversion is _stack_batches)."""
+        arrays (the device conversion is _stack_batches). ``lookup``
+        replaces table.lookup_ids (StagedAhead: the next pass's plan)."""
         pool = self._host_pool()
+        stage = (self._stage_one if lookup is None
+                 else functools.partial(self._stage_one, lookup=lookup))
         if pool is not None and len(group) > 1:
-            staged = list(pool.map(self._stage_one, group))
+            staged = list(pool.map(stage, group))
         else:
-            staged = [self._stage_one(b) for b in group]
+            staged = [stage(b) for b in group]
         hosts = [h for h, _ in staged]
         self._trim_push_domain(hosts, [n for _, n in staged])
         return {k: np.stack([h[k] for h in hosts]) for k in hosts[0]}
 
+    @staticmethod
+    def _to_device(staged: Dict[str, np.ndarray]) -> Dict[str, jnp.ndarray]:
+        account_h2d(tree_nbytes(staged))  # device transfer ledger
+        return {k: jnp.asarray(v) for k, v in staged.items()}
+
     def _stack_batches(self, group: List[PackedBatch]
                        ) -> Dict[str, jnp.ndarray]:
         """Host-stack + one H2D per leaf."""
-        staged = self._stack_batches_host(group)
-        account_h2d(tree_nbytes(staged))  # device transfer ledger
-        return {k: jnp.asarray(v) for k, v in staged.items()}
+        return self._to_device(self._stack_batches_host(group))
 
     def host_batch(self, b: PackedBatch,
                    ids: np.ndarray) -> Dict[str, np.ndarray]:
@@ -977,7 +1072,8 @@ class BoxTrainer:
         # per-key slots/valid are derived on device (make_train_step).
         # Touched-row accounting for the incremental EndPass happens in
         # table.lookup_ids (the `ids` passed here already marked the pass
-        # bitmap) — ONE accumulation point that covers every write path.
+        # bitmap), or, for a chunk staged ahead against a plan, where the
+        # pass that runs the plan takes it (_first_ahead).
         out = {
             "ids": ids,
             "segments": b.segments,
@@ -1018,9 +1114,7 @@ class BoxTrainer:
 
     def device_batch(self, b: PackedBatch,
                      ids: np.ndarray) -> Dict[str, jnp.ndarray]:
-        host = self.host_batch(b, ids)
-        account_h2d(tree_nbytes(host))  # device transfer ledger
-        return {k: jnp.asarray(v) for k, v in host.items()}
+        return self._to_device(self.host_batch(b, ids))
 
     def _refresh_aux(self) -> None:
         """ToHBM cadence (box_wrapper.h:83): freeze the side table's
@@ -1032,9 +1126,65 @@ class BoxTrainer:
                                .to_device(self.model.aux_capacity))
 
     # ---------------------------------------------------------- pass cadence
-    def train_pass(self, dataset: BoxDataset,
-                   preloaded: bool = False) -> Dict[str, float]:
-        """One full pass: feed → build → train → metrics → end."""
+    def _scan_chunk(self) -> int:
+        """Batches a scan dispatch takes; 0 where the megastep is off."""
+        chunk = max(1, self.cfg.scan_chunk)
+        return chunk if self.fns.scan_steps is not None and chunk > 1 else 0
+
+    def stage_ahead(self, dataset: BoxDataset) -> StagedAhead:
+        """The StagedAhead of a pass about to be preloaded: its shuffle
+        seed is drawn now (run_preloaded_passes asks at each preload, so in
+        pass order); the preloader runs it once the pass's plan is done,
+        and train_pass(dataset, ahead=...) consumes it."""
+        return StagedAhead(self, dataset, self._shuffle_rng.randint(1 << 31))
+
+    def _split_ahead(self, dataset: BoxDataset, ahead: StagedAhead):
+        """The pass's batches from its StagedAhead: shuffled and split
+        there, or here with its seed where no plan came for it."""
+        if ahead.dataset is not dataset:
+            raise ValueError("train_pass given another dataset's StagedAhead")
+        ahead.split.wait()
+        if ahead.batches is not None:
+            return ahead.batches
+        if ahead.err is not None:
+            raise ahead.err
+        dataset.local_shuffle(ahead.seed)
+        return dataset.split_batches(num_workers=1)[0]
+
+    def _first_ahead(self, ahead: StagedAhead) -> Optional[Callable]:
+        """What run_scan_chunks takes the pass's first chunk from, where it
+        was staged ahead against the map this pass runs (not one redone on
+        the boundary), with this pass's push write, out of test mode; else
+        None, and the chunk is staged as any other (a staged one dropped:
+        counter stage_ahead_dropped)."""
+        if ahead.rows is None:
+            return None
+        if not (self.table.runs(ahead.rows) and not self.table.test_mode
+                and ahead.push_write == self._push_write):
+            ahead.done.wait()    # nothing of it runs beside the pass
+            if ahead.host is not None:
+                stat_add("stage_ahead_dropped")
+            return None
+
+        def take():
+            ahead.done.wait()
+            if ahead.err is not None:
+                raise ahead.err
+            host, group = ahead.host, ahead.group
+            ahead.host = ahead.group = None
+            # looked up in the plan before this pass began: its rows are
+            # marked now, so end_pass writes back what it always did
+            self.table.note_touched(host["ids"])
+            # work done ahead is the consuming pass's
+            stat_add("stage_ahead_chunks")
+            return group, self._to_device(host)
+        return take
+
+    def train_pass(self, dataset: BoxDataset, preloaded: bool = False,
+                   ahead: Optional[StagedAhead] = None) -> Dict[str, float]:
+        """One full pass: feed → build → train → metrics → end. ``ahead``:
+        the pass's StagedAhead (run_preloaded_passes), whose seed, split
+        and first chunk it takes."""
         # live set_flag takes effect at pass boundaries only (mid-pass flips
         # would mix rebuild/scatter host dicts inside one scan chunk)
         self._push_write = resolve_push_write(
@@ -1056,10 +1206,10 @@ class BoxTrainer:
                 "write strategy"
                 % (self._push_write, self.fns.uid_write))
         with obs_span("train_pass"):
-            return self._train_pass(dataset, preloaded)
+            return self._train_pass(dataset, preloaded, ahead)
 
-    def _train_pass(self, dataset: BoxDataset,
-                    preloaded: bool) -> Dict[str, float]:
+    def _train_pass(self, dataset: BoxDataset, preloaded: bool,
+                    ahead: Optional[StagedAhead]) -> Dict[str, float]:
         from paddlebox_tpu.config import flags
         t_pass = self.timers["pass"]
         t_pass.start()
@@ -1074,16 +1224,20 @@ class BoxTrainer:
         self.table.begin_pass()
         with obs_span("pass_split_batches"):
             # the shuffle and the plan of the split: a batch is packed when
-            # the stager (or the tail loop below) takes it
-            dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
-            worker_batches = dataset.split_batches(num_workers=1)
+            # the stager (or the tail loop below) takes it. Staged ahead,
+            # both are done and this waits for them
+            if ahead is None:
+                dataset.local_shuffle(self._shuffle_rng.randint(1 << 31))
+                pending = dataset.split_batches(num_workers=1)[0]
+            else:
+                pending = self._split_ahead(dataset, ahead)
+                ahead.batches = None
+        n_batches = len(pending)
         losses = []
         prng = self.table.next_prng()
-        chunk = max(1, self.cfg.scan_chunk)
-        pending = worker_batches[0]
+        chunk = self._scan_chunk()
         state = self.table.slab
-        use_scan = self.fns.scan_steps is not None and chunk > 1
-        if use_scan and len(pending) >= chunk:
+        if chunk and len(pending) >= chunk:
             # megastep path: scan whole chunks in one dispatch each; the
             # remainder falls through to the per-step loop below
 
@@ -1123,7 +1277,8 @@ class BoxTrainer:
                 scan_call, pending, chunk, self._stack_batches,
                 carry, on_chunk, timer=self.timers["step"],
                 prefetch_depth=max(0, int(
-                    flags.get_flag("chunk_prefetch_depth"))))
+                    flags.get_flag("chunk_prefetch_depth"))),
+                first=None if ahead is None else self._first_ahead(ahead))
             state, self.params, self.opt_state, prng = carry
             self.table.set_slab(state)
             losses.extend(chunk_losses)
@@ -1202,7 +1357,7 @@ class BoxTrainer:
             from paddlebox_tpu.utils.profiler import timer_report
             obs_log.info(timer_report(self.timers, prefix="trainer."))
         return {"loss": mean_loss,
-                "batches": len(worker_batches[0]),
+                "batches": n_batches,
                 "instances": len(dataset)}
 
     @property

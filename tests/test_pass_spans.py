@@ -234,9 +234,12 @@ def test_pass_1_s_chunks_are_folded_on_a_thread_of_their_own(run):
 
 def test_a_pass_s_batches_are_packed_at_the_pull_under_its_steps(run):
     """ISSUE 35: pass_split_batches, in train_pass on the main thread,
-    plans; the chunk's eight batches are packed by the stager's pull,
-    ingest_pack, under the pass's id and before (not inside) its
-    host_stage; the five tail batches by the step loop, a step an id."""
+    takes the plan of the split; the chunk's eight batches are packed by
+    a pull, ingest_pack, under the pass's id and before (not inside) its
+    host_stage; the five tail batches by the step loop, a step an id.
+    That chunk is the pass's first, so the pull and the stage run inside
+    stage_ahead on the feed-ahead thread, once the pass's plan is done
+    and before the main thread takes the chunk."""
     main = run["main"]
     assert run["packed"] == 2 * 13
     for k in (0, 1):
@@ -245,12 +248,18 @@ def test_a_pass_s_batches_are_packed_at_the_pull_under_its_steps(run):
         split, = [s for s in mine if s[0] == "pass_split_batches"]
         assert split[1] == main
         assert train[3] <= split[3] and split[4] <= train[4]
+        ahead, = [s for s in mine if s[0] == "stage_ahead"]
+        plan, = [s for s in mine if s[0] == "ingest_feed_ahead"]
+        assert ahead[2] == "feed-ahead" and ahead[1] == plan[1] != main
+        assert plan[4] <= ahead[3]
         pack, = [s for s in mine if s[0] == "ingest_pack"]
-        assert pack[1] != main and pack[2] == "chunk-stager"
+        assert pack[1] == ahead[1]
         stage, = [s for s in mine
                   if s[0] == "host_stage" and s[1] == pack[1]]
-        assert split[4] <= pack[3] and pack[4] <= stage[3]
-        assert stage[4] <= train[4]
+        assert ahead[3] <= pack[3] and pack[4] <= stage[3]
+        assert stage[4] <= ahead[4]
+        taken, = [s for s in mine if s[0] == "chunk_stage_wait"]
+        assert stage[4] <= taken[4] <= train[4]
     tail = [s for s in run["spans"]
             if s[0] == "ingest_pack" and s[1] == main]
     staged = [s for s in run["spans"]
@@ -262,31 +271,39 @@ def test_a_pass_s_batches_are_packed_at_the_pull_under_its_steps(run):
                                               22, 23, 24, 25, 26)}
 
 
-def test_the_stager_s_queue_has_a_span_on_each_edge(run):
+def test_the_stager_s_queue_has_a_span_on_each_edge(chunks_of_four):
     """ISSUE 39: the main thread's q.get() is chunk_stage_wait, a sibling
     of scan_dispatch and chunk_drain inside train_pass; the stager's put
     is stage_queue_full, after the chunk's host_stage; one each a chunk,
-    both under the pass's id."""
-    main = run["main"]
+    both under the pass's id. The first chunk was staged ahead and is
+    taken under a chunk_stage_wait of its own, so the queue holds the
+    second and third of the three chunks of four."""
+    _losses, spans = chunks_of_four[1]
+    main = threading.get_ident()
     for k in (0, 1):
-        mine = by_pass(run, k)
+        mine = [s for s in spans if s[5] == pass_trace_id(0, k)]
         train, = [s for s in mine if s[0] == "train_pass"]
-        wait, = [s for s in mine if s[0] == "chunk_stage_wait"]
-        full, = [s for s in mine if s[0] == "stage_queue_full"]
-        assert wait[1] == main
-        assert train[3] <= wait[3] and wait[4] <= train[4]
-        dispatch, = [s for s in mine if s[0] == "scan_dispatch"]
-        assert wait[4] <= dispatch[3]
-        stage, = [s for s in mine
+        first, *waits = [s for s in mine if s[0] == "chunk_stage_wait"]
+        fulls = [s for s in mine if s[0] == "stage_queue_full"]
+        stages = [s for s in mine
                   if s[0] == "host_stage" and s[2] == "chunk-stager"]
-        assert full[2] == "chunk-stager" and full[1] == stage[1] != main
-        assert stage[4] <= full[3] and full[4] <= train[4]
-        # the first chunk: the queue was empty, the consumer waited for
-        # the whole of its pack and stage
-        assert wait[4] >= stage[4]
-    edges = [s for s in run["spans"]
+        dispatches = [s for s in mine if s[0] == "scan_dispatch"]
+        assert len(waits) == len(fulls) == len(stages) == 2
+        assert len(dispatches) == 3
+        assert first[1] == main and first[4] <= dispatches[0][3]
+        for wait, full, stage, dispatch in zip(waits, fulls, stages,
+                                               dispatches[1:]):
+            assert wait[1] == main
+            assert train[3] <= wait[3] and wait[4] <= train[4]
+            assert wait[4] <= dispatch[3]
+            assert full[2] == "chunk-stager" and full[1] == stage[1] != main
+            assert stage[4] <= full[3] and full[4] <= train[4]
+        # the queue's first chunk: the stager began it as the main thread
+        # took the staged-ahead one, so its wait ends after its stage
+        assert waits[0][4] >= stages[0][4]
+    edges = [s for s in spans
              if s[0] in ("chunk_stage_wait", "stage_queue_full")]
-    assert len(edges) == 4
+    assert len(edges) == 10
 
 
 @pytest.fixture(scope="module")
@@ -310,8 +327,10 @@ def chunks_of_four(data):
 def test_each_chunk_has_both_edges_and_no_stager_has_neither(
         chunks_of_four, depth):
     """One chunk_stage_wait on the main thread and one stage_queue_full
-    on the stager a chunk; with chunk_prefetch_depth 0 there is no queue
-    and neither span; the losses are the same at every depth."""
+    on the stager a chunk the queue carries; with chunk_prefetch_depth 0
+    there is no queue and neither span, but for the wait that takes the
+    first chunk, staged ahead at every depth; the losses are the same at
+    every depth."""
     losses, spans = chunks_of_four[depth]
     assert losses == chunks_of_four[0][0]
     main = threading.get_ident()
@@ -320,7 +339,7 @@ def test_each_chunk_has_both_edges_and_no_stager_has_neither(
         train, = [s for s in mine if s[0] == "train_pass"]
         waits = [s for s in mine if s[0] == "chunk_stage_wait"]
         fulls = [s for s in mine if s[0] == "stage_queue_full"]
-        assert len(waits) == len(fulls) == (3 if depth else 0)
+        assert len(waits) == len(fulls) + 1 == (3 if depth else 1)
         assert sum(s[0] == "scan_dispatch" for s in mine) == 3
         assert all(s[1] == main for s in waits)
         assert all(s[2] == "chunk-stager" and s[1] != main for s in fulls)
