@@ -6,7 +6,7 @@ embedx 8, hidden 512/256/128, batch 1024, 1M-row pass slab, bf16 dense
 tower, every flag at its default), and checks what comes out by the repo's
 own means. ONE process; nothing it starts touches JAX.
 
-    python chip_smoke.py                # leg A + kernel leg, one chip
+    python chip_smoke.py                # leg A, one chip
     python chip_smoke.py --chips 4      # ... plus leg B on a 4-chip mesh
     python chip_smoke.py --dry-run-cpu  # tiny shapes on the CPU, to debug
                                         # the command before chip time
@@ -15,8 +15,6 @@ own means. ONE process; nothing it starts touches JAX.
            BoxTrainer.train_pass calls: incremental begin_pass,
            PromotePrefetcher, touched-row end_pass write-back) with a
            streaming AUC registered -> predict_batches on one file.
-  kernels  pallas_apply_push and pallas_blocked_write COMPILED (never
-           interpreted) at production shapes against their XLA oracles.
   leg B    ShardedBoxTrainer on device_mesh_1d(N), N x 1M-row slab, two
            train_passes, twice: once as leg A is configured (bf16, flags
            at their defaults), once in f32 against a one-chip BoxTrainer
@@ -61,17 +59,13 @@ class Sizes:
     a_batches: int          # leg A batches per pass (> scan_chunk, so the
                             # scan megastep AND the per-step tail compile)
     b_steps: int            # leg B global steps per pass
-    kernel_rows: int        # rows in one kernel-leg update
-    block_rows: int         # pallas_blocked_write block
     dense_lr: float
 
 
 FULL = Sizes(slots=32, hidden=(512, 256, 128), batch=1024, capacity=1 << 20,
-             vocab=4000, lines=1024, a_batches=12, b_steps=9,
-             kernel_rows=4096, block_rows=512, dense_lr=1e-3)
+             vocab=4000, lines=1024, a_batches=12, b_steps=9, dense_lr=1e-3)
 TINY = Sizes(slots=4, hidden=(16, 8), batch=32, capacity=1 << 11, vocab=60,
-             lines=32, a_batches=10, b_steps=9, kernel_rows=64,
-             block_rows=16, dense_lr=1e-2)
+             lines=32, a_batches=10, b_steps=9, dense_lr=1e-2)
 
 
 class Failed(Exception):
@@ -333,98 +327,6 @@ def leg_a(sz: Sizes, tmp: str, dry: bool) -> dict:
     return rec
 
 
-# ------------------------------------------------------------- kernel leg
-
-def leg_kernels(sz: Sizes, dry: bool) -> dict:
-    """Both Pallas kernels at production shapes against their XLA oracles.
-    On the chip they are COMPILED by Mosaic (pallas_interpret() is False
-    there); the CPU dry run interprets them, which is all a CPU can do."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from paddlebox_tpu.config import flags
-    from paddlebox_tpu.embedding import accessor as acc
-    from paddlebox_tpu.embedding.accessor import PushLayout, ValueLayout
-    from paddlebox_tpu.embedding.optimizers import (apply_push,
-                                                    push_blocked_write)
-    from paddlebox_tpu.embedding.pallas_push import (pallas_apply_push,
-                                                     pallas_interpret)
-
-    check(pallas_interpret() == dry, "kernel leg would %s the kernels"
-          % ("compile" if dry else "interpret"))
-    n, cap, B = sz.kernel_rows, sz.capacity, sz.block_rows
-    conf = table_config(cap, 0.1, 1e-3).optimizer
-    # creation off, mf present everywhere: the creation randoms (hash-keyed
-    # in the kernel, jax.random in the oracle) never enter the comparison
-    conf = dataclasses.replace(conf, mf_create_thresholds=1e9)
-    layout, push = ValueLayout(D, "adagrad"), PushLayout(D)
-    rng = np.random.RandomState(3)
-    rows = layout.new_rows(n, rng, conf)
-    rows[:, acc.SLOT] = rng.randint(0, 5, n)
-    rows[:, acc.SHOW] = rng.randint(1, 30, n)
-    rows[:, acc.CLICK] = rng.randint(0, 5, n)
-    rows[:, acc.MF_SIZE] = D
-    rows[:, layout.embedx_w:layout.embedx_w + D] = rng.randn(n, D) * 0.01
-    rows[:, layout.embedx_state] = rng.rand(n)
-    grads = np.zeros((n, push.width), np.float32)
-    grads[:, push.SLOT] = rows[:, acc.SLOT]
-    grads[:, push.SHOW] = rng.randint(0, 4, n)      # zero-show rows too
-    grads[:, push.CLICK] = np.minimum(grads[:, push.SHOW],
-                                      rng.randint(0, 2, n))
-    grads[:, push.EMBED_G:] = rng.randn(n, 1 + D) * 0.2
-    rows_d, grads_d = jnp.asarray(rows, jnp.float32), jnp.asarray(grads)
-
-    t0 = time.perf_counter()
-    want = jax.jit(lambda v, g: apply_push(
-        v, g, jax.random.PRNGKey(0), layout, conf))(rows_d, grads_d)
-    got = jax.jit(lambda v, g: pallas_apply_push(
-        v, g, jnp.int32(7), layout, conf))(rows_d, grads_d)
-    apply_err = float(jnp.max(jnp.abs(got - want)))
-    # tests/test_pallas_push.py's tolerance
-    apply_ok = bool(np.allclose(np.asarray(got), np.asarray(want),
-                                rtol=1e-5, atol=1e-6))
-
-    # placement: n sorted unique rows of the slab + an out-of-slab padding
-    # tail, bitwise against the fori_loop of dynamic_update_slices
-    slab = jnp.asarray(rng.rand(cap, layout.width).astype(np.float32))
-    pad = n // 8
-    uids = np.concatenate([
-        np.sort(rng.choice(cap, n - pad, replace=False)),
-        cap + np.arange(pad)]).astype(np.int32)
-    new_rows = jnp.asarray(rng.rand(n, layout.width).astype(np.float32))
-    outs = {}
-    for use_pallas in (False, True):
-        flags.set_flag("push_blocked_pallas", use_pallas)
-        try:
-            outs[use_pallas] = jax.jit(
-                lambda s, u, r: push_blocked_write(s, u, r, B))(
-                    slab, jnp.asarray(uids), new_rows)
-        finally:
-            flags.set_flag("push_blocked_pallas", False)
-    blocked_ok = bool(jnp.array_equal(outs[False], outs[True]))
-    placed = np.asarray(outs[True][jnp.asarray(uids[:n - pad])])
-    placed_ok = bool(np.array_equal(placed, np.asarray(new_rows[:n - pad])))
-
-    rec = {"leg": "kernels", "interpreted": dry,
-           "rows": n, "width": layout.width, "slab_rows": cap,
-           "block_rows": B,
-           "pallas_apply_push": {"matches_apply_push": apply_ok,
-                                 "max_abs_err": apply_err},
-           "pallas_blocked_write": {"bitwise_equals_fori": blocked_ok,
-                                    "rows_placed": placed_ok}}
-    if not dry:
-        rec["seconds_info"] = {
-            "device_kind": jax.devices()[0].device_kind,
-            "incl_compile": round(time.perf_counter() - t0, 2)}
-    print(json.dumps(rec), flush=True)
-    check(apply_ok, "pallas_apply_push != apply_push (max err %g)"
-          % apply_err)
-    check(blocked_ok and placed_ok,
-          "pallas_blocked_write != the fori_loop placement")
-    return rec
-
-
 # ------------------------------------------------------------------- leg B
 
 def sharded_two_passes(sz: Sizes, P: int, tcfg, table_cfg, feed_w,
@@ -624,8 +526,8 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1,
                     help="4 = also run leg B on a 4-chip mesh")
     ap.add_argument("--dry-run-cpu", action="store_true",
-                    help="tiny shapes on the CPU backend, Pallas "
-                         "interpreted; prints no seconds")
+                    help="tiny shapes on the CPU backend; prints no "
+                         "seconds")
     args = ap.parse_args(argv)
     dry = args.dry_run_cpu
     if dry and args.chips > 1 and "xla_force_host_platform_device_count" \
@@ -649,9 +551,8 @@ def main(argv=None) -> int:
     head = {"device": device_info(), "versions": versions(),
             "compile_cache": cache.path, "dry_run": dry}
     tmp = tempfile.mkdtemp(prefix="pbtpu_chip_smoke_")
-    # every leg of the plan runs: A and the kernels always, B on a mesh
-    plan = [("A", lambda: leg_a(sz, tmp, dry)),
-            ("kernels", lambda: leg_kernels(sz, dry))]
+    # every leg of the plan runs: A always, B on a mesh
+    plan = [("A", lambda: leg_a(sz, tmp, dry))]
     if args.chips > 1:
         plan.append(("B", lambda: leg_b(sz, tmp, args.chips, dry)))
     failures = []

@@ -163,33 +163,17 @@ define_flag("stream_depth", 2,
             "device_reader_->Next double-buffer role)")
 define_flag("push_write", "auto",
             "how the push writes updated rows back into the pass slab: "
-            "'scatter' (row scatter, cost ~ touched rows — right for CPU "
-            "and small batches), 'rebuild' (pos map + full slab "
-            "gather/select, flat cost ~ slab bytes; pos host-staged by "
-            "BoxTrainer, device-derived on the sharded uid wire), or "
-            "'auto' "
-            "(rebuild below a capacity/batch-keys crossover on the TPU — "
-            "a crossover not yet measured on the chip; "
-            "scatter on CPU). The round-5 'log' mode was deleted in "
-            "round 8 — no measured regime ever selected it")
-define_flag("push_block_rows", 1024,
-            "blocked-scatter tile height for push_write=blocked (round "
-            "11): the sorted uid vector is bucketized into contiguous "
-            "row blocks of this many slab rows and each touched block is "
-            "applied with ONE dynamic_update_slice of a gathered tile "
-            "instead of a giant row scatter (push_blocked_write). Must "
-            "divide the table's pass_capacity (resolve_push_write "
-            "validates). Cost class ~ min(touched_blocks) * block bytes: "
-            "small blocks approach scatter's touched-rows cost, large "
-            "blocks approach rebuild's slab-bytes cost (the crossover is "
-            "not measured on the chip: ROADMAP S1)")
-define_flag("push_blocked_pallas", False,
-            "route push_write=blocked's per-block tile placement through "
-            "the hand-written Mosaic kernel (pallas_blocked_write: grid "
-            "over touched blocks, block ids scalar-prefetched, slab "
-            "aliased in place) instead of the XLA fori_loop of "
-            "dynamic_update_slices. Compiled on tpu; on cpu (tests) it "
-            "runs interpreted; any other backend is an error")
+            "'auto' | 'scatter' | 'rebuild'. 'scatter' = donated row "
+            "scatter, cost ~ touched rows; 'rebuild' = pos map + full slab "
+            "gather/select, flat cost ~ slab bytes (pos host-staged by "
+            "BoxTrainer, device-derived on the sharded uid wire). 'auto' "
+            "= rebuild on the TPU where pass_capacity <= 16 x the batch's "
+            "key budget, scatter otherwise and on CPU: deepfm-criteo "
+            "(67.1M rows against 79,872 keys) scatters, the sequence "
+            "towers (16k-32k rows against 8k-16k keys) rebuild. The 16x "
+            "crossover was never measured against the other write at "
+            "either shape. The 'log' and 'blocked' writes were deleted: "
+            "no cell selected them")
 define_flag("slab_embed_dtype", "float32",
             "DEVICE slab storage precision for the embedding weight "
             "columns (round-11 dtype diet): 'float32' = the classic "
@@ -202,16 +186,11 @@ define_flag("slab_embed_dtype", "float32",
             "Host stores, checkpoints and the push/pull math stay f32; "
             "rows decode at gather and encode at write. Weight updates "
             "round to bf16 at the slab write (AUC-parity gated, "
-            "tests/test_push_blocked.py), stats round-trip bit-exactly")
+            "tests/test_slab_bf16.py), stats round-trip bit-exactly")
 define_flag("flatten_dense_opt", True,
             "wrap the dense optimizer in optax.flatten so the whole dense "
             "update runs as one fused vector op instead of per-parameter "
             "op chains (elementwise optimizers only; exact same numbers)")
-define_flag("use_pallas_push", False,
-            "route the in-table adagrad row update through the hand-written "
-            "Pallas kernel (embedding/pallas_push.py) instead of XLA. "
-            "Compiled on tpu, interpreted on cpu (tests), an error "
-            "elsewhere; its effect on the chip's step is not measured")
 define_flag("strict_bucket_overflow", False,
             "raise on sharded bucket overflow instead of dropping the "
             "overflowed keys' gradients with a warning (the "

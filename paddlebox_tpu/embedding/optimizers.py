@@ -225,22 +225,6 @@ def apply_push(values: jnp.ndarray, grads: jnp.ndarray, prng: jax.Array,
     return jnp.where(active, out, values)
 
 
-def _dispatch_apply_push(rows: jnp.ndarray, merged: jnp.ndarray,
-                         prng: jax.Array, layout: ValueLayout,
-                         conf: SparseOptimizerConfig,
-                         row_ids=None) -> jnp.ndarray:
-    """One place that picks the in-table update kernel (Pallas adagrad when
-    flagged and applicable, XLA apply_push otherwise) for both push paths."""
-    from paddlebox_tpu.config import flags
-    if (flags.get_flag("use_pallas_push")
-            and layout.optimizer == "adagrad" and not layout.expand_dim):
-        from paddlebox_tpu.embedding.pallas_push import pallas_apply_push
-        seed = jax.random.randint(prng, (), 0, jnp.int32(2**31 - 1))
-        return pallas_apply_push(rows, merged, seed, layout, conf,
-                                 row_ids=row_ids)
-    return apply_push(rows, merged, prng, layout, conf, row_ids=row_ids)
-
-
 def push_sparse_dedup(slab: jnp.ndarray, ids: jnp.ndarray,
                       grads: jnp.ndarray, prng: jax.Array,
                       layout: ValueLayout,
@@ -261,8 +245,8 @@ def push_sparse_dedup(slab: jnp.ndarray, ids: jnp.ndarray,
                            grads.dtype).at[inv].add(grads)
     with jax.named_scope("push_opt"):
         rows = decode_slab_rows(slab[uids], layout)
-        new_rows = _dispatch_apply_push(rows, merged, prng, layout, conf,
-                                        row_ids=uids)
+        new_rows = apply_push(rows, merged, prng, layout, conf,
+                              row_ids=uids)
     with jax.named_scope("push_write"):
         return slab.at[uids].set(encode_slab_rows(new_rows, layout))
 
@@ -282,8 +266,8 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
                           grads: jnp.ndarray, prng: jax.Array,
                           layout: ValueLayout,
                           conf: SparseOptimizerConfig,
-                          pulled_rows: Optional[jnp.ndarray] = None,
-                          write: str = "scatter") -> jnp.ndarray:
+                          pulled_rows: Optional[jnp.ndarray] = None
+                          ) -> jnp.ndarray:
     """Push with HOST-precomputed dedup (PassTable.dedup_for_push): no
     on-device sort. jnp.unique in push_sparse_dedup lowers to an XLA sort of
     the whole key vector per step — measured as the dominant cost of the
@@ -299,25 +283,12 @@ def push_sparse_hostdedup(slab: jnp.ndarray, uids: jnp.ndarray,
     inv_sorted: [K] nondecreasing merged-row index per permuted occurrence
     grads:      [K, push.width] per-occurrence push rows (padding all-zero)
     pulled_rows: [U, width] optional pull-gather reuse (see _merged_new_rows)
-    write: 'scatter' (the classic donated row scatter) or 'blocked'
-           (round 11: bucketize the sorted uids into contiguous row
-           blocks, place per block with dynamic_update_slice). 'blocked'
-           REQUIRES sorted uids: the staging side pins the sorted dedup
-           tier (dedup_ids sort=True — the native rt_dedup tier is
-           hash-ordered and would silently drop rows here). The rebuild
-           twin lives in push_sparse_rebuild.
+    The write is the donated row scatter; the rebuild twin lives in
+    push_sparse_rebuild.
     """
     new_rows = _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng,
                                 layout, conf, pulled_rows)
-    if write not in ("scatter", "blocked"):
-        raise ValueError(f"hostdedup write strategy {write!r} "
-                         "(scatter or blocked)")
     with jax.named_scope("push_write"):
-        if write == "blocked":
-            from paddlebox_tpu.config import flags
-            return push_blocked_write(slab, uids,
-                                      encode_slab_rows(new_rows, layout),
-                                      int(flags.get_flag("push_block_rows")))
         # out-of-range padding ids drop; in-range ids are unique by
         # construction
         return slab.at[uids].set(encode_slab_rows(new_rows, layout),
@@ -352,85 +323,7 @@ def _merged_new_rows(slab, uids, perm, inv_sorted, grads, prng, layout,
         if rows is None:
             rows = decode_slab_rows(
                 jnp.take(slab, uids, axis=0, mode="clip"), layout)
-        return _dispatch_apply_push(rows, merged, prng, layout, conf,
-                                    row_ids=uids)
-
-
-def push_blocked_write(slab: jnp.ndarray, uids: jnp.ndarray,
-                       new_rows: jnp.ndarray,
-                       block_rows: int) -> jnp.ndarray:
-    """Blocked slab write (round 11, ``push_write=blocked``): the sorted
-    uid vector is bucketized into contiguous row blocks of ``block_rows``
-    (a prefix-scan over the already-sorted uids — no sort) and each
-    touched block is applied with ONE ``lax.dynamic_update_slice`` of a
-    gather-assembled [B, W] tile, instead of one giant row scatter. Cost
-    class ~ min(U, C/B) * B rows of sequential tile traffic: between
-    scatter (~U rows + per-index plumbing) and rebuild (always C rows) —
-    the middle regime of the write ladder, with DMA-friendly contiguous
-    tiles instead of scattered row writes.
-
-    uids must be STRICTLY ASCENDING with an out-of-slab padding tail
-    (dedup_uids_sorted); new_rows are the ENCODED device rows to place.
-    block_rows must divide the slab's row count (resolve_push_write
-    enforces; keeps every tile aligned — a clamped partial tail block
-    would silently shift its rows' local offsets).
-    """
-    C, W = slab.shape
-    U = uids.shape[0]
-    if U == 0:
-        # an empty dedup touches nothing (same guard as the rebuild
-        # twin); the run-length machinery below assumes U >= 1
-        return slab
-    B = int(block_rows)
-    if B <= 0 or C % B:
-        raise ValueError(
-            "push_blocked_write: block_rows=%d must be positive and divide "
-            "the slab capacity %d" % (B, C))
-    n_blocks = C // B
-    NB = min(U, n_blocks)  # static bound on touched blocks
-    blk = uids // B        # nondecreasing (uids sorted)
-    in_range = uids < C
-    is_first = jnp.concatenate(
-        [jnp.ones((1,), bool), blk[1:] != blk[:-1]])
-    slot = jnp.cumsum(is_first.astype(jnp.int32)) - 1       # [U]
-    # block id per touched-block slot; slots fed only by padding uids keep
-    # the sentinel (their tile clamps to the last block and writes its own
-    # current contents back — a no-op by construction)
-    blk_of_slot = jnp.full((NB,), n_blocks, jnp.int32).at[slot].set(
-        jnp.where(in_range, blk, n_blocks).astype(jnp.int32), mode="drop")
-    # flattened (slot, local offset) -> source row in new_rows; -1 = keep
-    tgt = jnp.where(in_range, slot * B + (uids - blk * B), NB * B)
-    row_map = jnp.full((NB * B,), -1, jnp.int32).at[tgt].set(
-        jnp.arange(U, dtype=jnp.int32), mode="drop").reshape(NB, B)
-    starts = jnp.minimum(blk_of_slot * B, C - B)
-
-    def write_block(i, slab):
-        start = starts[i]
-        cur = jax.lax.dynamic_slice(slab, (start, 0), (B, W))
-        rm = row_map[i]
-        src = jnp.take(new_rows, jnp.clip(rm, 0, U - 1), axis=0)
-        tile = jnp.where((rm >= 0)[:, None], src, cur)
-        return jax.lax.dynamic_update_slice(slab, tile, (start, 0))
-
-    from paddlebox_tpu.config import flags
-    if flags.get_flag("push_blocked_pallas"):
-        from paddlebox_tpu.embedding.pallas_push import pallas_blocked_write
-        tiles = jnp.take(new_rows,
-                         jnp.clip(row_map, 0, U - 1).reshape(NB * B),
-                         axis=0).reshape(NB, B, W)
-        # REVERSED slot order — the grid's block-revisit safety invariant
-        # (pallas_blocked_write docstring): sentinel slots (padding tail,
-        # clamped onto the LAST block) must run BEFORE that block's real
-        # write. A revisit before the update writes the block's original
-        # bits (identity, prefetch-safe); a revisit after it could land
-        # stale prefetched bits over the real update under Mosaic's grid
-        # pipelining. Real slots address distinct blocks, so reversing
-        # puts all sentinels first and leaves the rest hazard-free.
-        rev = jnp.arange(NB - 1, -1, -1)
-        return pallas_blocked_write(
-            slab, tiles[rev], row_map[rev],
-            jnp.minimum(blk_of_slot, n_blocks - 1)[rev])
-    return jax.lax.fori_loop(0, NB, write_block, slab)
+        return apply_push(rows, merged, prng, layout, conf, row_ids=uids)
 
 
 def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
@@ -469,9 +362,9 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
     """
     K = ids.shape[0]
     U = uids.shape[0]
-    if write not in ("scatter", "rebuild", "blocked"):
+    if write not in ("scatter", "rebuild"):
         raise ValueError(f"uid-wire write strategy {write!r} "
-                         "(scatter, rebuild or blocked)")
+                         "(scatter or rebuild)")
     with jax.named_scope("push_merge"):
         inv = jnp.searchsorted(uids, ids).astype(jnp.int32)
         merged = jax.ops.segment_sum(grads, inv, num_segments=U)
@@ -484,8 +377,8 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
             rows = decode_slab_rows(
                 jnp.take(slab, uids, axis=0, mode="clip"), layout)
         new_rows = encode_slab_rows(
-            _dispatch_apply_push(rows, merged, prng, layout, conf,
-                                 row_ids=uids), layout)
+            apply_push(rows, merged, prng, layout, conf, row_ids=uids),
+            layout)
     with jax.named_scope("push_write"):
         if write == "rebuild":
             pos = jnp.full((slab.shape[0],), -1, jnp.int32).at[uids].set(
@@ -493,13 +386,6 @@ def push_sparse_uidwire(slab: jnp.ndarray, uids: jnp.ndarray,
                 unique_indices=True)
             sel = jnp.take(new_rows, jnp.clip(pos, 0, U - 1), axis=0)
             return jnp.where((pos >= 0)[:, None], sel, slab)
-        if write == "blocked":
-            # blocked scatter (round 11): bucketize the sorted uids into
-            # contiguous row blocks, apply per block with
-            # dynamic_update_slice
-            from paddlebox_tpu.config import flags
-            return push_blocked_write(slab, uids, new_rows,
-                                      int(flags.get_flag("push_block_rows")))
         return slab.at[uids].set(new_rows, mode="drop", unique_indices=True)
 
 
@@ -519,7 +405,7 @@ def push_sparse_rebuild(slab: jnp.ndarray, uids: jnp.ndarray,
     cost scales with index count while this rebuild is one gather + one
     select at flat cost ~ slab bytes / copy bandwidth — the better trade
     whenever touched-row count is large relative to the slab (big batches,
-    merged chunks). Neither cost is measured on the chip (ROADMAP S2).
+    merged chunks). resolve_push_write picks between the two.
     Reference work shape: PushSparseGradCaseGPU merge + update
     (box_wrapper_impl.h:373-522); the write strategy is ours.
     """

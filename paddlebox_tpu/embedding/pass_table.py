@@ -151,7 +151,7 @@ def push_domain(n_u: int, K: int, floor: int = 0) -> int:
     return max(min(_pow2_pad(n_u), K), floor)
 
 
-def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
+def dedup_ids(ids: np.ndarray, pad_base: int):
     """Host-side per-batch id dedup for push_sparse_hostdedup: the device
     analog (jnp.unique) is an XLA sort of the whole key vector inside every
     train step; here it rides the already-overlapped host batch stage
@@ -168,15 +168,9 @@ def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
       and keep their [K]).
 
     Fast path: native rt_dedup (hash dedup + counting sort, no comparison
-    sort); numpy argsort fallback.
-
-    sort=True guarantees uids come back STRICTLY ASCENDING (with
-    perm/inv consistent): required whenever the products feed
-    push_write='blocked', whose device-side bucketize trusts sortedness
-    (unsorted uids make its run-length slots overflow and DROP rows, with
-    no error). The native tier returns hash-probe order, so sort=True
-    pins the numpy argsort tier — sorted by construction, same cost
-    class as a post-sort remap without the extra pass."""
+    sort); numpy argsort fallback. The native tier returns uids in
+    hash-probe order, the numpy tier ascending: no consumer relies on
+    the order (dedup_uids_sorted is the sorted product)."""
     raw = np.asarray(ids)
     ids = np.ascontiguousarray(raw, dtype=np.int32)
     K = ids.shape[0]
@@ -190,7 +184,7 @@ def dedup_ids(ids: np.ndarray, pad_base: int, sort: bool = False):
                          % (raw.min(), raw.max(), raw.dtype))
     from paddlebox_tpu.native.build import get_lib
     lib = get_lib()
-    if lib is not None and K and not sort:
+    if lib is not None and K:
         import ctypes
         uids = np.empty(K, np.int32)
         perm = np.empty(K, np.int32)
@@ -921,11 +915,10 @@ class PassTable:
         poisoned pass)."""
         return self._in_pass and self._rows is rows
 
-    def dedup_for_push(self, ids: np.ndarray, sort: bool = False):
+    def dedup_for_push(self, ids: np.ndarray):
         """Host-side per-batch dedup for push_sparse_hostdedup (see
-        dedup_ids): padding ids start at this table's capacity. sort=True
-        = sorted-uids contract (push_write='blocked' staging)."""
-        return dedup_ids(ids, self.capacity, sort=sort)
+        dedup_ids): padding ids start at this table's capacity."""
+        return dedup_ids(ids, self.capacity)
 
     def pos_for_rebuild(self, uids: np.ndarray) -> np.ndarray:
         """[capacity] int32 inverse of the dedup's uids for the

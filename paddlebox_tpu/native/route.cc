@@ -23,8 +23,8 @@
 // step). The scratch used to live in RouteIndex; concurrent callers could
 // then draw the same generation and read each other's seen-marks, silently
 // mis-routing an occurrence of a key both batches carried — the PR-6
-// 6/780-elements show-off-by-one flake (reproduced + pinned by
-// tools/sharded_stress_probe.py's concurrent-parity leg). Cost of the fix: one scratch table per ROUTING THREAD
+// 6/780-elements show-off-by-one flake (pinned by
+// tests/test_native.py::test_concurrent_bucketize_parity). Cost of the fix: one scratch table per ROUTING THREAD
 // (~20 B per next_pow2(2K) slots, e.g. ~5 MB/thread at K=128k) instead of
 // one per index. rt_index_create itself must still finish before the
 // first concurrent consumer — the pass-cadence callers already guarantee

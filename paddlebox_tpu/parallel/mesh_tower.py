@@ -173,9 +173,7 @@ class MeshTowerTrainer:
             else:
                 slab = push_sparse_hostdedup(
                     slab, uids, batch["perm"], batch["inv"], pg, sub,
-                    layout, conf,
-                    write=("blocked" if self._push_write == "blocked"
-                           else "scatter"))
+                    layout, conf)
             params = {k: (v[None] if sharded[k] else v)
                       for k, v in local.items()}
             opt_state = jax.tree.map(
@@ -223,8 +221,7 @@ class MeshTowerTrainer:
             # eval never pushes — skip the dedup + transfers; uids ride the
             # host stage (device reconstruction is a scatter), and rebuild
             # mode stages the pos map for the scatter-free slab write
-            uids, perm, inv, _n_u = self.table.dedup_for_push(
-                ids, sort=self._push_write == "blocked")
+            uids, perm, inv, _n_u = self.table.dedup_for_push(ids)
             host.update(perm=perm, inv=inv, uids=uids)
             if self._push_write == "rebuild":
                 host["push_pos"] = self.table.pos_for_rebuild(uids)

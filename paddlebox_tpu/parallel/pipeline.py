@@ -1372,9 +1372,7 @@ class ShardedCtrPipelineRunner:
                 slab = push_sparse_hostdedup(
                     slab, batch["push_uids"], batch["push_perm"],
                     batch["push_inv"], recv_g.reshape(Pn * KB, -1), sub,
-                    layout, conf,
-                    write=("blocked" if push_write == "blocked"
-                           else "scatter"))
+                    layout, conf)
             elif "push_uids" in batch:
                 # uid wire (h2d_uid_wire, round 8): only the sorted uid
                 # vector staged — the incoming ids are the a2a'd buckets
@@ -1537,7 +1535,6 @@ class ShardedCtrPipelineRunner:
                 note_touched=self.table.note_touched,
                 uid_only=bool(flags.get_flag("h2d_uid_wire")),
                 mesh=self.host_mesh,
-                sort_uids=self._push_write == "blocked",
                 policy=self.policy))
         return {k: self._put_flat(np.stack(v)) for k, v in leaves.items()}
 

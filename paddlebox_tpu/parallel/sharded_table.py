@@ -321,8 +321,7 @@ def exchange_push_uids_p2p(buckets_local: np.ndarray,
 def stage_push_dedup(buckets, local_positions, num_devices: int,
                      shard_cap: int, multiprocess: bool, all_gather,
                      rebuild: bool, pool, note_touched=None,
-                     uid_only: bool = False, mesh=None,
-                     sort_uids: bool = False, policy=None):
+                     uid_only: bool = False, mesh=None, policy=None):
     """Per-destination push-dedup staging shared by BOTH sharded runners
     (trainer's _step_host_arrays + pipeline's device_batch): makes each
     shard's incoming a2a ids host-known (exchange_outgoing_buckets when
@@ -389,10 +388,7 @@ def stage_push_dedup(buckets, local_positions, num_devices: int,
             uids = dedup_uids_sorted(incoming_of(d), shard_cap)
             perm = inv = None
         else:
-            # sort_uids: push_write='blocked' consumes these products and
-            # its device bucketize trusts sorted uids (see dedup_ids)
-            uids, perm, inv, _n_u = dedup_ids(incoming_of(d), shard_cap,
-                                              sort=sort_uids)
+            uids, perm, inv, _n_u = dedup_ids(incoming_of(d), shard_cap)
         if note_touched is not None:
             # every id this destination shard will push rides these uids —
             # the per-pass touched-row accumulation point (incremental

@@ -617,9 +617,7 @@ class ShardedBoxTrainer:
                 slab = push_sparse_hostdedup(
                     slab, batch["push_uids"], batch["push_perm"],
                     batch["push_inv"], recv_g.reshape(Pn * KB, -1), prng,
-                    layout, conf,
-                    write=("blocked" if push_write == "blocked"
-                           else "scatter"))
+                    layout, conf)
             elif "push_uids" in batch:
                 # uid wire (h2d_uid_wire, round 8): the shard's incoming
                 # ids ARE the a2a'd buckets already on device (req), so
@@ -798,7 +796,6 @@ class ShardedBoxTrainer:
                 note_touched=self.table.note_touched,
                 uid_only=bool(flags.get_flag("h2d_uid_wire")),
                 mesh=self.host_mesh,
-                sort_uids=self._push_write == "blocked",
                 policy=self.policy))
         return {k: np.stack(v) for k, v in stacked.items()}
 

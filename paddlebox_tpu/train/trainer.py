@@ -71,11 +71,6 @@ class TrainStepFns:
     # axis), amortizing dispatch overhead (its size on the chip is not
     # measured)
     scan_steps: Optional[Callable] = None
-    # the slab-write strategy the step was built with: blocked-vs-scatter
-    # is BAKED into the jitted push, so a live push_write flip on or off
-    # 'blocked' cannot retarget it (train_pass guards); scatter<->rebuild
-    # follows the batch's push_pos leaf
-    uid_write: str = "scatter"
 
 
 def make_scan(step_fn: Callable, extra_carry: int = 0) -> Callable:
@@ -319,24 +314,19 @@ def check_expand_config(model, layout: ValueLayout, use_expand: bool) -> None:
 
 def resolve_push_write(capacity: Optional[int] = None,
                        batch_keys: Optional[int] = None) -> str:
-    """'scatter' | 'rebuild' | 'blocked' from the push_write flag.
+    """'scatter' | 'rebuild' from the push_write flag.
 
     * rebuild — full slab gather/select driven by a pos map; cost ~ slab
-      bytes, so it should win SMALL slabs (<= ~16x the per-batch key
-      budget) where the gather is cheaper than a scatter's index
-      plumbing. 'auto' selects it in exactly that regime on the TPU.
-    * scatter — donated in-step row scatter; ~capacity-flat. 'auto'
-      selects it beyond the rebuild regime, and ALWAYS on CPU.
-    * blocked — bucketize the sorted uid vector into contiguous row
-      blocks of push_block_rows and place each touched block with ONE
-      dynamic_update_slice (optionally the Mosaic kernel,
-      push_blocked_pallas). Cost ~ touched_blocks x block bytes of
-      sequential tile traffic — between scatter and rebuild. Never an
-      'auto' candidate so far.
+      bytes. 'auto' selects it on the TPU where capacity <= 16 x
+      batch_keys: the sequence towers' cells (16k-32k slab rows against
+      8k-16k keys a batch) run it on the chip.
+    * scatter — donated in-step row scatter; cost ~ touched rows. 'auto'
+      selects it beyond that regime, and ALWAYS off the TPU:
+      deepfm-criteo's cells (67.1M rows against 79,872 keys) run it.
 
-    The 16x crossover and the ranking of the three writes are NOT
-    measured on the chip (ROADMAP S2 runs the ladder there and re-derives
-    or deletes the rule).
+    The 16x crossover itself was never measured against the other write
+    at either shape. 'log' and 'blocked' were deleted (no cell selected
+    either); naming one is an error.
     """
     from paddlebox_tpu.config import flags
     mode = flags.get_flag("push_write")
@@ -346,20 +336,9 @@ def resolve_push_write(capacity: Optional[int] = None,
         if capacity and batch_keys and capacity > 16 * batch_keys:
             return "scatter"
         return "rebuild"
-    if mode == "blocked":
-        block = int(flags.get_flag("push_block_rows"))
-        if block <= 0:
-            raise ValueError(
-                f"push_write=blocked needs push_block_rows > 0, got {block}")
-        if capacity and capacity % block:
-            # a clamped partial tail block would silently shift its rows'
-            # local offsets — refuse at resolve time, not deep in the jit
-            raise ValueError(
-                f"push_write=blocked: push_block_rows={block} must divide "
-                f"the table's pass capacity {capacity}")
-        return mode
     if mode not in ("scatter", "rebuild"):
-        hint = " — 'log' was deleted in round 8" if mode == "log" else ""
+        hint = (f" — '{mode}' was deleted" if mode in ("log", "blocked")
+                else "")
         raise ValueError(f"push_write flag: unknown mode {mode!r}{hint}")
     return mode
 
@@ -548,6 +527,9 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                     async_dense: bool = False,
                     compute_dtype: str = "float32",
                     uid_write: str = "scatter") -> TrainStepFns:
+    # uid_write: accepted and ignored; the push's write follows the batch
+    # (a push_pos leaf selects rebuild, its absence the scatter)
+    del uid_write
     conf = table.optimizer
     multi_task = len(getattr(model, "task_names", ("ctr",))) > 1
     wants_rank_offset = model_accepts_rank_offset(model)
@@ -681,10 +663,7 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
                                        pulled_rows=pulled_rows)
         return push_sparse_hostdedup(slab, batch["uids"], batch["perm"],
                                      batch["inv"], push_grads, sub, layout,
-                                     conf, pulled_rows=pulled_rows,
-                                     write=("blocked"
-                                            if uid_write == "blocked"
-                                            else "scatter"))
+                                     conf, pulled_rows=pulled_rows)
 
     # The slab is DONATED into the step: at production pass capacities the
     # slab is hundreds of MB and the pass holds exactly one live copy, so
@@ -764,8 +743,7 @@ def make_train_step(model, layout: ValueLayout, table: TableConfig,
     return TrainStepFns(step=step_async if async_dense else step,
                         eval_step=eval_step,
                         batch_size=batch_size, num_slots=num_slots,
-                        scan_steps=None if async_dense else scan_steps,
-                        uid_write=uid_write)
+                        scan_steps=None if async_dense else scan_steps)
 
 
 class StagedAhead:
@@ -883,8 +861,7 @@ class BoxTrainer:
             model, self.table.layout, table_cfg, self.dense_opt,
             feed.batch_size, self.num_slots, use_cvm,
             async_dense=self.async_mode,
-            compute_dtype=self.cfg.compute_dtype,
-            uid_write=self._push_write)
+            compute_dtype=self.cfg.compute_dtype)
         self.async_table = None
         self._unravel = None
         if self.async_mode:
@@ -1085,10 +1062,7 @@ class BoxTrainer:
             # train batches carry the host-precomputed push dedup (uids
             # included: rebuilding them on device is a scatter); eval
             # batches never push, so skip the dedup + extra transfers
-            # blocked write: the device bucketize trusts SORTED uids, so
-            # the staging pins the sorted dedup tier (see dedup_ids)
-            uids, perm, inv, n_u = self.table.dedup_for_push(
-                ids, sort=self._push_write == "blocked")
+            uids, perm, inv, n_u = self.table.dedup_for_push(ids)
             out.update(perm=perm, inv=inv, uids=uids)
             if not getattr(self.model, "use_expand", False):
                 # the expand path pulls a dual view by occurrence and
@@ -1190,21 +1164,6 @@ class BoxTrainer:
         self._push_write = resolve_push_write(
             capacity=self.table.capacity,
             batch_keys=self.feed.key_capacity())
-        if self._push_write != self.fns.uid_write and "blocked" in (
-                self._push_write, self.fns.uid_write):
-            # blocked-vs-scatter is baked into the jitted step (round 11):
-            # a live push_write flip cannot retarget it silently. Worse
-            # than silent: a flip OFF 'blocked' stops the staging sort
-            # (dedup_ids sort=False → native hash order) while the baked
-            # step still runs the blocked bucketize, which silently drops
-            # rows (the round-11 sortedness hazard). scatter<->rebuild
-            # stays live-retargetable: the push_pos dict structure
-            # retraces the step.
-            raise ValueError(
-                "push_write resolved to %r but the jitted step was "
-                "built with %r — construct a fresh trainer to change the "
-                "write strategy"
-                % (self._push_write, self.fns.uid_write))
         with obs_span("train_pass"):
             return self._train_pass(dataset, preloaded, ahead)
 

@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import afmoe_reference as ref  # noqa: E402
 from test_afmoe import build  # noqa: E402
 
+from paddlebox_tpu.config import flags  # noqa: E402
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig,  # noqa: E402
                                           TableConfig, TrainerConfig)
 from paddlebox_tpu.data import (BoxDataset,  # noqa: E402
@@ -84,7 +85,11 @@ def one_pass(model, data):
         tr.close()
 
 
-def test_one_pass_matches_the_references_steps(data):
+# both slab writes: 'rebuild' is the one 'auto' picks on the chip at the
+# towers' shapes, 'scatter' the one it picks on a CPU
+@pytest.mark.parametrize("write", ["scatter", "rebuild"])
+def test_one_pass_matches_the_references_steps(data, write):
+    flags.set_flag("push_write", write)
     model = build(CFG)
     pairs0 = stat_get("moe_pairs_held")
     losses, params, rows = one_pass(model, data)

@@ -37,11 +37,11 @@ def test_dry_run_cpu_end_to_end():
     final = lines[-1]
     assert final == {"ok": True, "dry_run": True,
                      "device": {"platform": "cpu", "kind": "cpu", "count": 4}}
-    assert [l["leg"] for l in lines if "leg" in l] == ["A", "kernels", "B"]
+    assert [l["leg"] for l in lines if "leg" in l] == ["A", "B"]
     summary = lines[-2]
     assert summary["dry_run"] is True and summary["failures"] == []
-    assert summary["legs"] == ["A", "kernels", "B"]
-    leg_b = lines[2]
+    assert summary["legs"] == ["A", "B"]
+    leg_b = lines[1]
     assert leg_b["default"]["compute_dtype"] == "bfloat16"
     assert leg_b["parity"]["compute_dtype"] == "float32"
     # a dry run's times are CPU times: none may be printed

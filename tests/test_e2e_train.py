@@ -3,6 +3,10 @@ pass-table → fused train step → streaming AUC lift → checkpoint/resume.
 The Python analog of running the reference's full BoxPS cadence without the
 closed binary (SURVEY.md §4's missing tier)."""
 
+import glob
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -17,6 +21,9 @@ from paddlebox_tpu.train import BoxTrainer, CheckpointManager
 
 D = 8
 NUM_SLOTS = 4
+BENCH_CONFIGS = sorted(glob.glob(os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmarks", "configs", "*.json")))
 
 
 @pytest.fixture(scope="module")
@@ -172,6 +179,30 @@ def test_push_write_auto_heuristic(monkeypatch):
     assert resolve_push_write(1 << 20, 131072) == "rebuild"
     assert resolve_push_write(1 << 22, 131072) == "scatter"  # 32x keys
     assert resolve_push_write(None, None) == "rebuild"       # no hints
+
+
+@pytest.mark.parametrize("path", BENCH_CONFIGS, ids=lambda p: os.path.basename(
+    p)[:-len(".json")])
+def test_push_write_auto_picks_each_benchmark_configs_write(path,
+                                                            monkeypatch):
+    """The write 'auto' picks on the chip for each benchmark configuration,
+    from the feed the harness builds (one key a slot) and the
+    configuration's pass_capacity: deepfm-criteo's 67.1M rows against
+    79,872 keys a batch and dlrm-mlperf's 6.25M against 53,248 scatter;
+    the sequence towers' 16k-32k rows against 8k-16k keys rebuild."""
+    import jax as _jax
+
+    from benchmarks.harness.traffic import feed_config
+    from paddlebox_tpu.train.trainer import resolve_push_write
+    with open(path) as f:
+        cfg = json.load(f)
+    name = os.path.basename(path)[:-len(".json")]
+    monkeypatch.setattr(_jax, "default_backend", lambda: "tpu")
+    got = resolve_push_write(capacity=int(cfg["pass_capacity"]),
+                             batch_keys=feed_config(cfg).key_capacity())
+    want = ("scatter" if name in ("deepfm-criteo", "dlrm-mlperf")
+            else "rebuild")
+    assert got == want
 
 
 def test_chunk_prefetch_matches_inline(data):

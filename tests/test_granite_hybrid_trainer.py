@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import granite_hybrid_reference as ref  # noqa: E402
 from test_granite_hybrid import build  # noqa: E402
 
+from paddlebox_tpu.config import flags  # noqa: E402
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig,  # noqa: E402
                                           TableConfig, TrainerConfig)
 from paddlebox_tpu.data import (BoxDataset,  # noqa: E402
@@ -83,7 +84,11 @@ def passes(model, data, lr=1e-3):
         tr.close()
 
 
-def test_passes_match_the_references_steps_and_the_loss_falls(data):
+# both slab writes: 'rebuild' is the one 'auto' picks on the chip at the
+# towers' shapes, 'scatter' the one it picks on a CPU
+@pytest.mark.parametrize("write", ["scatter", "rebuild"])
+def test_passes_match_the_references_steps_and_the_loss_falls(data, write):
+    flags.set_flag("push_write", write)
     model = build(CFG)
     device.monitor().reset()
     chunks0 = stat_get("ssd_chunks_scanned")

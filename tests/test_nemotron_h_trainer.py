@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import nemotron_h_reference as ref  # noqa: E402
 from test_nemotron_h import WHOLE, build  # noqa: E402
 
+from paddlebox_tpu.config import flags  # noqa: E402
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig,  # noqa: E402
                                           TableConfig, TrainerConfig)
 from paddlebox_tpu.data import (BoxDataset,  # noqa: E402
@@ -84,8 +85,12 @@ def passes(model, data, lr=1e-3):
         tr.close()
 
 
+# both slab writes: 'rebuild' is the one 'auto' picks on the chip at the
+# towers' shapes, 'scatter' the one it picks on a CPU
+@pytest.mark.parametrize("write", ["scatter", "rebuild"])
 def test_passes_match_the_references_steps_and_the_loss_falls(
-        data, monkeypatch):
+        data, monkeypatch, write):
+    flags.set_flag("push_write", write)
     from paddlebox_tpu.ops import routed_experts as module
     monkeypatch.setattr(module, "TILING", (32, 64, 32))
     assert MODEL_ZOO["nemotron_h"] is type(build(CFG))

@@ -9,11 +9,13 @@ import sys
 
 import jax
 import numpy as np
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import olmo_hybrid_reference as ref  # noqa: E402
 from test_olmo_hybrid import CFG as SHARE, build  # noqa: E402
 
+from paddlebox_tpu.config import flags  # noqa: E402
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig,  # noqa: E402
                                           TableConfig, TrainerConfig)
 from paddlebox_tpu.data import (BoxDataset,  # noqa: E402
@@ -67,7 +69,12 @@ def passes(model, data, lr=1e-3):
         tr.close()
 
 
-def test_passes_match_the_references_steps_and_the_loss_falls(tmp_path):
+# both slab writes: 'rebuild' is the one 'auto' picks on the chip at the
+# towers' shapes, 'scatter' the one it picks on a CPU
+@pytest.mark.parametrize("write", ["scatter", "rebuild"])
+def test_passes_match_the_references_steps_and_the_loss_falls(tmp_path,
+                                                             write):
+    flags.set_flag("push_write", write)
     files, feed = write_synthetic_ctr_files(
         str(tmp_path), num_files=1, lines_per_file=STEPS * BATCH,
         num_slots=CFG["num_sparse_slots"], vocab_per_slot=12, max_len=1,

@@ -6,12 +6,13 @@ by occ_uid[K], the occurrence's slot in uids; the same U rows are the push's.
 Contracts under test:
 
   * occurrence_uid_slots inverts the dedup of BOTH tiers (native rt_dedup,
-    numpy argsort, sorted or not): ids == uids[occ_uid], every value below
+    numpy argsort, also where the native one declines): ids ==
+    uids[occ_uid], every value below
     n_u, whatever repeats, the trash row among the ids;
   * pull_sparse_unique returns pull_sparse's bits and slab[uids]'s rows,
     out-of-slab padding uids clipped onto the trash row, f32 and bf16 slab;
   * the push fed the pulled block writes the bits of the push that gathers
-    its own rows (scatter, blocked, rebuild);
+    its own rows (scatter, rebuild);
   * a step through the unique pull equals, bit for bit (slab, params, loss,
     predictions), the step whose wire lacks occ_uid and so pulls by
     occurrence: the choice is read from the wire's leaves;
@@ -24,14 +25,14 @@ Contracts under test:
 import contextlib
 import os
 import sys
-import unittest.mock as mock
 
 import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from test_push_domain import (D, _ids_case, _trainer,  # noqa: E402
-                              data)  # noqa: F401  (the fixture)
+from test_push_domain import (DEDUP_TIERS, D, _ids_case,  # noqa: E402
+                              _trainer, data,  # noqa: F401  (the fixture)
+                              dedup_in_tier)
 
 from paddlebox_tpu.config import flags  # noqa: E402
 from paddlebox_tpu.config.configs import SparseOptimizerConfig  # noqa: E402
@@ -43,28 +44,19 @@ from paddlebox_tpu.embedding.pass_table import (dedup_ids,  # noqa: E402
 from paddlebox_tpu.utils.stats import stat_get  # noqa: E402
 
 CAPACITY = 2048             # test_push_domain._trainer's pass_capacity
-WRITES = ("scatter", "blocked", "rebuild")
+WRITES = ("scatter", "rebuild")
 DTYPES = ("float32", "bfloat16")
 
 
 # ------------------------------------------------------------- unit tier
 
-@pytest.mark.parametrize("tier", ["native", "numpy", "numpy_sorted"])
+@pytest.mark.parametrize("tier", DEDUP_TIERS)
 @pytest.mark.parametrize("case", ["none", "one", "all_distinct", "repeats",
                                   "trash"])
 def test_occ_uid_inverts_the_dedup_of_both_tiers(case, tier):
-    from paddlebox_tpu.native.build import available
-    if tier == "native" and not available():
-        pytest.skip("native library unavailable")
     pad_base = 1000
     ids = _ids_case(case, trash=pad_base - 1)
-    if tier == "native":
-        uids, perm, inv, n_u = dedup_ids(ids, pad_base)
-    else:
-        with mock.patch("paddlebox_tpu.native.build.get_lib",
-                        return_value=None):
-            uids, perm, inv, n_u = dedup_ids(ids, pad_base,
-                                             sort=tier == "numpy_sorted")
+    uids, perm, inv, n_u = dedup_in_tier(ids, pad_base, tier)
     occ_uid = occurrence_uid_slots(perm, inv)
     assert occ_uid.shape == ids.shape and occ_uid.dtype == np.int32
     np.testing.assert_array_equal(uids[occ_uid], ids)
@@ -75,7 +67,7 @@ def test_occ_uid_inverts_the_dedup_of_both_tiers(case, tier):
         np.testing.assert_array_equal(np.unique(occ_uid), np.arange(n_u))
 
 
-def _slab_and_dedup(embed_dtype, sort=False, cap=512, K=96):
+def _slab_and_dedup(embed_dtype, cap=512, K=96):
     import jax.numpy as jnp
     rng = np.random.RandomState(5)
     layout = acc.ValueLayout(D, "adagrad", embed_dtype=embed_dtype)
@@ -86,7 +78,7 @@ def _slab_and_dedup(embed_dtype, sort=False, cap=512, K=96):
     slab = jnp.asarray(acc.encode_slab_rows_np(rows, layout))
     ids = rng.randint(0, 40, K).astype(np.int32)
     ids[rng.rand(K) < 0.2] = cap - 1              # padding occurrences
-    uids, perm, inv, n_u = dedup_ids(ids, cap, sort=sort)
+    uids, perm, inv, n_u = dedup_ids(ids, cap)
     return layout, slab, ids, uids, perm, inv, n_u, rng
 
 
@@ -123,7 +115,7 @@ def test_push_fed_the_pulled_block_matches_its_own_gather(write):
                                                     push_sparse_rebuild)
     from paddlebox_tpu.ops.sparse import pull_sparse_unique
     layout, slab, ids, uids, perm, inv, n_u, rng = _slab_and_dedup(
-        "float32", sort=write == "blocked")
+        "float32")
     cap, K = slab.shape[0], ids.shape[0]
     conf = SparseOptimizerConfig(mf_create_thresholds=0.0,
                                  mf_initial_range=1e-3)
@@ -138,15 +130,13 @@ def test_push_fed_the_pulled_block_matches_its_own_gather(write):
         slab, u, jnp.asarray(occurrence_uid_slots(perm, inv)), layout)
     common = (jnp.asarray(perm), jnp.asarray(inv), jnp.asarray(grads),
               jax.random.PRNGKey(3), layout, conf)
-    flags.set_flag("push_block_rows", 64)
 
     def run(pulled):
         if write == "rebuild":
             return push_sparse_rebuild(
                 slab, u, jnp.asarray(pos_for_rebuild(uids, cap)), *common,
                 pulled_rows=pulled)
-        return push_sparse_hostdedup(slab, u, *common, pulled_rows=pulled,
-                                     write=write)
+        return push_sparse_hostdedup(slab, u, *common, pulled_rows=pulled)
 
     own, fed = np.asarray(run(None)), np.asarray(run(rows_u))
     np.testing.assert_array_equal(own, fed)
@@ -186,13 +176,11 @@ def test_push_rows_assembled_k_minor_hold_the_columns_values(width):
 @contextlib.contextmanager
 def _modes(write="auto", embed_dtype="float32"):
     flags.set_flag("push_write", write)
-    flags.set_flag("push_block_rows", 256)
     flags.set_flag("slab_embed_dtype", embed_dtype)
     try:
         yield
     finally:
         flags.set_flag("push_write", "auto")
-        flags.set_flag("push_block_rows", 1024)
         flags.set_flag("slab_embed_dtype", "float32")
 
 

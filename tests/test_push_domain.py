@@ -6,11 +6,12 @@ slot in uids (ISSUE 42), keeps its [K].
 Contracts under test:
 
   * the trimmed-domain push writes the SAME BITS as the K-padded push,
-    for every full-wire write (scatter, blocked, rebuild), with and
+    for every full-wire write (scatter, rebuild), with and
     without the pull's rows, on the f32 and the bf16 slab, created
     embeddings included (mf_initial_range > 0);
-  * dedup_ids returns n_u from both tiers, the real ids in uids[:n_u],
-    every inv below n_u;
+  * dedup_ids returns n_u from both tiers (and from the numpy tier where
+    the native one declines), the real ids in uids[:n_u], every inv below
+    n_u;
   * push_domain: pow2, capped at K, never under the mark; U = K when
     nothing repeats;
   * chunks of different n_u inside one bucket share ONE compiled
@@ -19,12 +20,12 @@ Contracts under test:
   * push_index_slots / push_unique_rows add U / n_u a staged step.
 """
 
+import types
 import unittest.mock as mock
 
 import numpy as np
 import pytest
 
-from paddlebox_tpu.config import flags
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig, TableConfig,
                                           TrainerConfig)
 from paddlebox_tpu.data import BoxDataset, write_synthetic_ctr_files
@@ -44,7 +45,7 @@ NUM_SLOTS = 4
 @pytest.mark.parametrize("embed_dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("reuse", [False, True],
                          ids=["slab_gather", "pulled_rows"])
-@pytest.mark.parametrize("write", ["scatter", "blocked", "rebuild"])
+@pytest.mark.parametrize("write", ["scatter", "rebuild"])
 def test_trimmed_domain_push_writes_the_padded_pushs_bits(write, reuse,
                                                           embed_dtype):
     import jax
@@ -73,10 +74,9 @@ def test_trimmed_domain_push_writes_the_padded_pushs_bits(write, reuse,
     grads[ids == cap - 1] = 0.0
     prng = jax.random.PRNGKey(11)
 
-    uids, perm, inv, n_u = dedup_ids(ids, cap, sort=write == "blocked")
+    uids, perm, inv, n_u = dedup_ids(ids, cap)
     U = push_domain(n_u, K)
     assert n_u <= U < K
-    flags.set_flag("push_block_rows", 64)
 
     def run(u):
         # the pull's block: the rows of uids[:u], padding clipped onto
@@ -94,7 +94,7 @@ def test_trimmed_domain_push_writes_the_padded_pushs_bits(write, reuse,
                 jnp.asarray(pos_for_rebuild(uids, cap)), *common,
                 pulled_rows=pulled)
         return push_sparse_hostdedup(slab, jnp.asarray(uids[:u]), *common,
-                                     pulled_rows=pulled, write=write)
+                                     pulled_rows=pulled)
 
     padded, trimmed = np.asarray(run(K)), np.asarray(run(U))
     np.testing.assert_array_equal(padded, trimmed)
@@ -119,22 +119,34 @@ def _ids_case(case: str, K: int = 64, trash: int = 999) -> np.ndarray:
     return ids
 
 
-@pytest.mark.parametrize("tier", ["native", "numpy", "numpy_sorted"])
+DEDUP_TIERS = ["native", "numpy", "native_declined"]
+
+
+def dedup_in_tier(ids: np.ndarray, pad_base: int, tier: str):
+    """dedup_ids through one tier: the native rt_dedup, the numpy argsort
+    with no native library, or the numpy argsort after rt_dedup declines
+    (returns -1)."""
+    from paddlebox_tpu.native.build import available
+    if tier == "native":
+        if not available():
+            pytest.skip("native library unavailable")
+        return dedup_ids(ids, pad_base)
+    lib = (None if tier == "numpy"
+           else types.SimpleNamespace(rt_dedup=lambda *_a: -1))
+    with mock.patch("paddlebox_tpu.native.build.get_lib", return_value=lib):
+        return dedup_ids(ids, pad_base)
+
+
+@pytest.mark.parametrize("tier", DEDUP_TIERS)
 @pytest.mark.parametrize("case", ["none", "one", "all_distinct", "repeats",
                                   "trash"])
 def test_dedup_ids_counts_its_real_uids(case, tier):
-    from paddlebox_tpu.native.build import available
-    if tier == "native" and not available():
-        pytest.skip("native library unavailable")
     pad_base = 1000
     ids = _ids_case(case, trash=pad_base - 1)
-    if tier == "native":
-        uids, perm, inv, n_u = dedup_ids(ids, pad_base)
-    else:
-        with mock.patch("paddlebox_tpu.native.build.get_lib",
-                        return_value=None):
-            uids, perm, inv, n_u = dedup_ids(ids, pad_base,
-                                             sort=tier == "numpy_sorted")
+    uids, perm, inv, n_u = dedup_in_tier(ids, pad_base, tier)
+    if tier != "native":
+        # the numpy tier's uids come out ascending
+        assert (np.diff(uids.astype(np.int64)) > 0).all()
     real = np.unique(ids)
     assert isinstance(n_u, int) and n_u == real.size
     np.testing.assert_array_equal(np.sort(uids[:n_u]), real)

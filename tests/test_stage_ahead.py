@@ -9,6 +9,7 @@ import threading
 import numpy as np
 import pytest
 
+from paddlebox_tpu.config import flags
 from paddlebox_tpu.config.configs import (SparseOptimizerConfig, TableConfig,
                                           TrainerConfig)
 from paddlebox_tpu.data import BoxDataset, write_synthetic_ctr_files
@@ -25,6 +26,9 @@ from paddlebox_tpu.utils.stats import stat_get
 D, NUM_SLOTS = 4, 4
 PASSES = 4
 SECONDS = 120.0     # each test's own limit
+# the two slab writes: a staged chunk carries push_pos under 'rebuild',
+# the write 'auto' picks on the chip at the towers' shapes
+WRITES = pytest.mark.parametrize("write", ["scatter", "rebuild"])
 
 
 def within(fn, seconds=SECONDS):
@@ -153,11 +157,13 @@ def assert_same(got, want):
         np.testing.assert_array_equal(g, w)
 
 
-def test_preloaded_passes_train_as_the_same_passes_one_by_one(data):
+@WRITES
+def test_preloaded_passes_train_as_the_same_passes_one_by_one(data, write):
     """Four passes, shuffled, through run_preloaded_passes (every first
     chunk staged ahead, against the plan, on the feed-ahead thread) leave
     the losses, the slab and the host store of four train_pass calls to
     the last bit: the seeds are drawn in pass order."""
+    flags.set_flag("push_write", write)
     files, feed = data
     want_losses, want = within(lambda: one_by_one(files, feed))
     losses, got, counts, _ = within(lambda: preloaded(files, feed))
@@ -171,12 +177,14 @@ def invalidate_after_pass_1(tr, k):
         tr.table.invalidate_residency()
 
 
+@WRITES
 def test_a_save_between_passes_drops_the_staged_chunk_and_stages_it_again(
-        data):
+        data, write):
     """invalidate_residency (what a save does) after pass 1 takes away the
     base pass 2's plan was made on: the boundary redoes the assignment, the
     chunk staged against the plan is dropped and staged again as any
     chunk, and the passes still train as one by one."""
+    flags.set_flag("push_write", write)
     files, feed = data
     want_losses, want = within(
         lambda: one_by_one(files, feed, after=invalidate_after_pass_1))
@@ -188,11 +196,14 @@ def test_a_save_between_passes_drops_the_staged_chunk_and_stages_it_again(
                       "stage_ahead_dropped": 1}
 
 
-def test_the_rows_written_back_include_the_first_chunk_s(data, monkeypatch):
+@WRITES
+def test_the_rows_written_back_include_the_first_chunk_s(data, monkeypatch,
+                                                          write):
     """The staged chunk was looked up before its pass began, marking
     nothing; the pass marks its rows when it takes it, so end_pass writes
     back the rows it wrote back one by one, the first chunk's among
     them."""
+    flags.set_flag("push_write", write)
     files, feed = data
     looked_up = []
     lookup_in = PassTable.lookup_in
@@ -260,12 +271,14 @@ def test_an_error_staging_ahead_surfaces_at_the_consuming_pass(data,
         tr.close()
 
 
-def test_no_second_scan_steps_compile_across_the_passes(data):
+@WRITES
+def test_no_second_scan_steps_compile_across_the_passes(data, write):
     """The chunk staged ahead has the wire, the shapes and the push
     domain of any chunk: scan_steps compiles in the first pass alone (once
     a push-domain bucket the pass's chunks reach), as one by one, and
     never after it, though the next pass's first chunk is staged (and its
     domain marked) while the chunks of the pass before still are."""
+    flags.set_flag("push_write", write)
     files, feed = data
 
     def counted(into):
@@ -275,6 +288,49 @@ def test_no_second_scan_steps_compile_across_the_passes(data):
     *_, compiles = within(lambda: preloaded(files, feed, after=counted(seen)))
     within(lambda: one_by_one(files, feed, after=counted(want)))
     assert seen == [compiles] * PASSES == want
+
+
+def test_a_push_write_change_between_passes_drops_the_staged_chunk(data):
+    """A chunk staged under a scatter pass carries no push_pos: where the
+    push_write flag turns to 'rebuild' before the pass that takes it, that
+    pass drops it and stages its first chunk as any other, and trains as
+    the same pass with nothing staged ahead."""
+    files, feed = data
+    flags.set_flag("push_write", "scatter")
+    tr, plain = trainer(feed), trainer(feed)
+    ds1, ds2 = datasets(files, feed, 2)
+    fresh1, fresh2 = datasets(files, feed, 2)
+    pre = PassPreloader(tr.table)
+    names = ("stage_ahead_chunks", "stage_ahead_dropped")
+
+    def staged_then_flipped():
+        tr.train_pass(ds1)
+        ahead = tr.stage_ahead(ds2)
+        pre.preload(ds2, stage=ahead)
+        assert pre.wait(ds2) is True
+        ahead.done.wait()
+        assert ahead.push_write == "scatter" and ahead.host is not None
+        assert "push_pos" not in ahead.host
+        flags.set_flag("push_write", "rebuild")
+        before = {n: stat_get(n) for n in names}
+        loss = tr.train_pass(ds2, preloaded=True, ahead=ahead)["loss"]
+        return loss, {n: stat_get(n) - before[n] for n in names}
+
+    def unstaged():
+        flags.set_flag("push_write", "scatter")
+        plain.train_pass(fresh1)
+        flags.set_flag("push_write", "rebuild")
+        return plain.train_pass(fresh2)["loss"]
+
+    try:
+        loss, counts = within(staged_then_flipped)
+        assert tr._push_write == "rebuild"
+        assert counts == {"stage_ahead_chunks": 0, "stage_ahead_dropped": 1}
+        assert loss == within(unstaged)
+        assert_same(state(tr), state(plain))
+    finally:
+        tr.close()
+        plain.close()
 
 
 def test_a_table_without_a_plan_stages_nothing_ahead(data):

@@ -9,7 +9,7 @@ push_pos[capacity] under push_write=rebuild; an eval batch carries no push
 leaf. Inside make_train_step _pull gathers the slab once a uid where the
 wire has occ_uid (pull_sparse_unique) and by occurrence where it has not;
 _sparse_push is the one consumer of the rest: push_sparse_rebuild where
-push_pos is there, push_sparse_hostdedup(write=scatter|blocked) otherwise.
+push_pos is there, push_sparse_hostdedup (the row scatter) otherwise.
 
 Contracts under test:
 
@@ -27,7 +27,8 @@ Contracts under test:
     stage_push_dedup uid_only); the step derives the maps from the a2a'd
     bucket ids (push_sparse_uidwire) and composes with the 2-process
     host-plane bucket exchange; the sorted-uid contract holds on every
-    sharded staging path."""
+    sharded staging path; the full-product staging's rebuild pos maps
+    survive the 2-process exchange."""
 
 import contextlib
 import unittest.mock as mock
@@ -47,7 +48,7 @@ from paddlebox_tpu.train import BoxTrainer
 D = 4
 NUM_SLOTS = 4
 CAPACITY = 2048
-WRITES = ("scatter", "rebuild", "blocked")
+WRITES = ("scatter", "rebuild")
 
 
 @pytest.fixture(scope="module")
@@ -65,12 +66,10 @@ def data(tmp_path_factory):
 @contextlib.contextmanager
 def push_write(mode):
     flags.set_flag("push_write", mode)
-    flags.set_flag("push_block_rows", 256)
     try:
         yield
     finally:
         flags.set_flag("push_write", "auto")
-        flags.set_flag("push_block_rows", 1024)
 
 
 @contextlib.contextmanager
@@ -172,7 +171,7 @@ def assert_identical(a, b):
 # ------------------------------------------- the wire against the reference
 @pytest.fixture(scope="module")
 def reference_runs(data):
-    """One reference run a chunking, shared by the three writes."""
+    """One reference run a chunking, shared by the two writes."""
     files, feed = data
     runs = {}
 
@@ -209,8 +208,7 @@ def test_wire_contract(data, write, mode):
     """The exact leaves of host_batch's dict and of _stack_batches_host's
     (a dict with a leading chunk axis, never a tuple): uids of length
     U = push_domain(...), perm/inv/occ_uid of K with ids == uids[occ_uid],
-    push_pos only under rebuild, uids ascending under blocked, NO push
-    leaf in test mode."""
+    push_pos only under rebuild, NO push leaf in test mode."""
     files, feed = data
     K, B = feed.key_capacity(), feed.batch_size
     i32 = np.dtype(np.int32)
@@ -257,10 +255,6 @@ def test_wire_contract(data, write, mode):
                 np.testing.assert_array_equal(
                     np.take_along_axis(staged["uids"], staged["occ_uid"],
                                        axis=1), np.stack(ids[1:]))
-                if write == "blocked":
-                    assert (np.diff(one["uids"].astype(np.int64)) > 0).all()
-                    assert (np.diff(staged["uids"].astype(np.int64),
-                                    axis=1) > 0).all()
             tr.table.end_pass()
             tr.table.set_test_mode(False)
             ds.release_memory()
@@ -304,16 +298,18 @@ def test_eval_after_train_bit_equal(data, reference_preds, scan_chunk):
 # ------------------------------------------------------- what went, loudly
 REMOVED_SWITCHES = [      # (name, is a flag; else a TrainerConfig field)
     ("h2d_lean", True), ("wire_delta_ids", True), ("h2d_stack_chunks", True),
-    ("push_onehot_rows", True), ("sparse_chunk_sync", False)]
+    ("push_onehot_rows", True), ("sparse_chunk_sync", False),
+    ("push_block_rows", True), ("push_blocked_pallas", True),
+    ("use_pallas_push", True)]
 
 
 @pytest.mark.parametrize("name,is_flag", REMOVED_SWITCHES,
                          ids=[n for n, _ in REMOVED_SWITCHES])
 def test_removed_switches_fail_loud(name, is_flag):
-    """The four flags and the TrainerConfig field that selected the lean
-    wires, the grouped transfer, the one-hot merge and the
-    chunk-synchronous step are gone: setting one is an error, not a
-    silent no-op."""
+    """The flags and the TrainerConfig field that selected the lean
+    wires, the grouped transfer, the one-hot merge, the chunk-synchronous
+    step, the blocked slab write and the Pallas push kernels are gone:
+    setting one is an error, not a silent no-op."""
     if is_flag:
         with pytest.raises(KeyError, match=name):
             flags.set_flag(name, 1)
@@ -347,12 +343,13 @@ def test_train_batch_without_perm_raises(data):
         tr.close()
 
 
-def test_push_write_log_deleted(data):
-    """The round-5 'log' mode is gone (verdict item 8): the flag value
-    fails loud with a pointer to the retained findings."""
+@pytest.mark.parametrize("mode", ["log", "blocked"])
+def test_push_write_log_deleted(data, mode):
+    """The 'log' and 'blocked' writes are gone (no cell selected either):
+    the flag value fails loud, naming the deleted write."""
     files, feed = data
-    with pytest.raises(ValueError, match="round 8"):
-        run_mode(files, feed, "log", passes=1)
+    with pytest.raises(ValueError, match=f"'{mode}' was deleted"):
+        run_mode(files, feed, mode, passes=1)
 
 
 # ------------------------------------------------------------ unit tier
@@ -531,6 +528,76 @@ def test_two_virtual_process_uid_staging():
         jnp.asarray(grads), prng, layout, conf)
     np.testing.assert_array_equal(np.asarray(host), np.asarray(wire))
     pool.shutdown(wait=False)
+
+
+def test_two_virtual_process_rebuild_staging():
+    """The full-product staging under push_write=rebuild composed with the
+    host-plane bucket exchange: two VIRTUAL processes (mesh positions 0-3
+    / 4-7) stage their owned destinations' uids, perm, inv and pos maps
+    and must reproduce the single-process staging exactly; push_sparse_
+    rebuild over them writes the scatter oracle's rows bit for bit."""
+    import concurrent.futures
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddlebox_tpu.embedding.accessor import PushLayout, ValueLayout
+    from paddlebox_tpu.embedding.optimizers import (push_sparse_hostdedup,
+                                                    push_sparse_rebuild)
+    from paddlebox_tpu.parallel.sharded_table import stage_push_dedup
+
+    P, KB, shard_cap = 8, 16, 128
+    rng = np.random.RandomState(8)
+    buckets = np.full((P, P, KB), shard_cap - 1, np.int32)
+    for s in range(P):
+        for d in range(P):
+            n = rng.randint(2, KB)
+            buckets[s, d, :n] = rng.randint(0, shard_cap - 1, n)
+    leaves = ("push_uids", "push_perm", "push_inv", "push_pos")
+
+    def payload_of(bl, positions):
+        bl = np.ascontiguousarray(bl, np.int32)
+        header = np.array([len(positions), P, KB] + list(positions),
+                          np.int32)
+        return np.concatenate([header, bl.ravel()])
+
+    parts = [payload_of(buckets[0:4], [0, 1, 2, 3]),
+             payload_of(buckets[4:8], [4, 5, 6, 7])]
+    with concurrent.futures.ThreadPoolExecutor(2) as pool:
+        single = stage_push_dedup(list(buckets), list(range(P)), P,
+                                  shard_cap, multiprocess=False,
+                                  all_gather=None, rebuild=True, pool=pool)
+        assert set(single) == set(leaves)
+        out = {}
+        for lo, positions in ((0, [0, 1, 2, 3]), (4, [4, 5, 6, 7])):
+            staged = stage_push_dedup(
+                list(buckets[lo:lo + 4]), positions, P, shard_cap,
+                multiprocess=True, all_gather=lambda payload: parts,
+                rebuild=True, pool=pool)
+            for i, d in enumerate(positions):
+                out[d] = tuple(staged[k][i] for k in leaves)
+    layout = ValueLayout(D, "adagrad")
+    conf = SparseOptimizerConfig(mf_create_thresholds=0.0,
+                                 mf_initial_range=1e-3)
+    push = PushLayout(D)
+    for d in range(P):
+        for k, got in zip(leaves, out[d]):
+            np.testing.assert_array_equal(got, single[k][d],
+                                          err_msg=f"{k} dest {d}")
+        uids, perm, inv, pos = (jnp.asarray(x) for x in out[d])
+        incoming = np.concatenate([buckets[s][d] for s in range(P)])
+        grads = rng.randn(incoming.size, push.width).astype(np.float32)
+        grads[:, push.SHOW] = 1.0
+        grads[incoming == shard_cap - 1] = 0.0
+        slab = jnp.asarray(rng.rand(shard_cap, layout.width)
+                           .astype(np.float32))
+        grads, prng = jnp.asarray(grads), jax.random.PRNGKey(d)
+        oracle = push_sparse_hostdedup(slab, uids, perm, inv, grads, prng,
+                                       layout, conf)
+        got = push_sparse_rebuild(slab, uids, pos, perm, inv, grads, prng,
+                                  layout, conf)
+        np.testing.assert_array_equal(np.asarray(oracle), np.asarray(got),
+                                      err_msg=f"dest {d}")
 
 
 # ---------------------------------------------- uid sortedness contract

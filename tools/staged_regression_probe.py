@@ -68,13 +68,10 @@ FLOORS = {
     # store=229.6ms vs p2p=36.4ms at the same shape this round
     "p2p_exchange_keys_per_sec": (30.1e6, 12e6),
     # round-11: the uid-wire push kernel (merge + in-table optimize +
-    # slab write) at both write strategies, donated 1M-row slab, dup~8
-    # batch — guards the blocked-scatter path on a CPU.
-    # Recorded under the round-10 load guard on 2026-08-03 (CPU tier;
-    # scatter leads blocked HERE; the chip's ranking is unmeasured);
-    # floors = ~40% of recorded
+    # row scatter), donated 1M-row slab, dup~8 batch.
+    # Recorded under the round-10 load guard on 2026-08-03 (CPU tier);
+    # floor = ~40% of recorded
     "push_scatter_keys_per_sec": (983e3, 390e3),
-    "push_blocked_keys_per_sec": (845e3, 340e3),
     # round-12: the serving plane's in-process lookup path (mmap view
     # stack + native key index, uniform mix incl. 10% misses over a 2M
     # base at batch 8192 — cache off: the algorithmic floor is the
@@ -488,11 +485,10 @@ def section_ingest(rng, K):
 
 
 def section_push(rng, K):
-    # --- device push-write kernels (round 11) ------------------------
-    # the uid-wire push at both write strategies, donated slab threaded
+    # --- device push-write kernel (round 11) -------------------------
+    # the uid-wire push with the row scatter, donated slab threaded
     # through like the train step: keys/s of the merge+optimize+write
-    # kernel alone. Guards the blocked-scatter path; floors recorded on
-    # a container's CPU tier.
+    # kernel alone; floor recorded on a container's CPU tier.
     import functools
 
     import jax
@@ -515,21 +511,18 @@ def section_push(rng, K):
     prng = jax.random.PRNGKey(0)
     uids_j, ids_j, grads_j = (jnp.asarray(uids), jnp.asarray(ids),
                               jnp.asarray(grads))
-    for write, stage in (("scatter", "push_scatter_keys_per_sec"),
-                         ("blocked", "push_blocked_keys_per_sec")):
-        step = jax.jit(functools.partial(push_sparse_uidwire,
-                                         layout=layout, conf=conf,
-                                         write=write),
-                       donate_argnums=(0,))
-        state = [jnp.zeros((cap, layout.width), jnp.float32)]
+    step = jax.jit(functools.partial(push_sparse_uidwire,
+                                     layout=layout, conf=conf),
+                   donate_argnums=(0,))
+    state = [jnp.zeros((cap, layout.width), jnp.float32)]
 
-        def one():
-            state[0] = jax.block_until_ready(
-                step(state[0], uids_j, ids_j, grads_j, prng))
+    def one():
+        state[0] = jax.block_until_ready(
+            step(state[0], uids_j, ids_j, grads_j, prng))
 
-        measure = lambda: timed_rate(one, K, secs=3.0)  # noqa: E731
-        report(stage, measure(), remeasure=measure)
-        state[0] = None
+    measure = lambda: timed_rate(one, K, secs=3.0)  # noqa: E731
+    report("push_scatter_keys_per_sec", measure(), remeasure=measure)
+    state[0] = None
 
 
 def section_serving(rng, K):
