@@ -23,6 +23,9 @@ with x the [n, C] streams of a token and x_hat = vec(x) / rms(vec(x)),
     M(x; G) = H_res x + H_post^T G(H_pre x)
 Sinkhorn: exp of the logits clamped to ``hc_clamp``, then
 ``sinkhorn_iters`` rounds of rows then columns, each over its sum + hc_eps.
+The streams are carried as [B, S, n hidden], stream j the column band
+[j hidden, (j + 1) hidden): a lane-aligned band where hidden is a multiple
+of 128, which is where ops/mhc.py's kernels take the hyper-connection.
 
 Attn (DeepSeek-V2/V3's MLA, held whole: data-parallel attention):
     c_q = N(u W_qa)                          q_lora_rank
@@ -68,7 +71,7 @@ import numpy as np
 from paddlebox_tpu.models.afmoe import rms_norm, swiglu
 from paddlebox_tpu.models.base import ModelSpec
 from paddlebox_tpu.ops.attention import blocked_attention
-from paddlebox_tpu.ops.mhc import pre_combine, res_mix_post_add, stream_maps
+from paddlebox_tpu.ops.mhc import fused_block, hyper_connection
 from paddlebox_tpu.ops.routed_experts import route, routed_experts
 
 F32 = jnp.float32
@@ -109,12 +112,14 @@ class Xing4:
     apply(params, pooled [B, S, 3 + hidden], dense) -> logits [B]. A
     caller that hands ``counters`` (a dict of its own trace) gets
     ``step_counters`` put into it: "moe_pairs_held", the pairs routed to
-    held experts over all routed layers; the trainer adds it to
+    held experts over all routed layers, and "mhc_fused_tokens", tokens x
+    sublayers whose hyper-connection took ops/mhc.py's kernels (a witness
+    that they ran, decided by the shapes); the trainer adds them to
     utils/stats at a chunk's drain."""
 
     name = "xing4_0"
     task_names = ("ctr",)
-    step_counters = ("moe_pairs_held",)
+    step_counters = ("moe_pairs_held", "mhc_fused_tokens")
 
     def __init__(self, spec: ModelSpec, *, num_layers: int,
                  num_dense_layers: int, hidden: int, heads: int,
@@ -280,21 +285,19 @@ class Xing4:
     def _connected(self, p, pre: str, x, sublayer):
         """(M(x; sublayer), what the sublayer hands back beside its
         output): the hyper-connection around one sublayer."""
-        h_pre, h_post, h_res = stream_maps(
-            x, p[pre + "phi"], p[pre + "alpha"], p[pre + "bias"], **self.hc)
-        f, aux = sublayer(pre_combine(x, h_pre))
-        return res_mix_post_add(x, h_res, h_post, f), aux
+        return hyper_connection(x, p[pre + "phi"], p[pre + "alpha"],
+                                p[pre + "bias"], sublayer, n=self.n,
+                                **self.hc)
 
     def _layer(self, i: int, p, x, cdt):
-        """(x' [B, S, n, H], pairs routed to each held expert). The first
+        """(x' [B, S, n H], pairs routed to each held expert). The first
         layer gets the embedding [B, S, H] and copies it into the n
         streams here, inside its checkpoint: what it keeps for the
         backward pass is the one copy."""
         p = {k: (v.astype(cdt) if k in _MATRICES else v)
              for k, v in p.items()}
-        if x.ndim == 3:
-            x = jnp.broadcast_to(x[:, :, None, :], x.shape[:2] + (self.n,)
-                                 + x.shape[2:])
+        if i == 0:
+            x = jnp.tile(x, (1, 1, self.n))
         x, _ = self._connected(p, "a_", x, lambda u: (self._mla(
             p, rms_norm(u, p["attn_norm"], self.eps), cdt), None))
         return self._connected(p, "f_", x,
@@ -312,10 +315,15 @@ class Xing4:
             x, sizes = jax.checkpoint(
                 lambda p, x, i=i: self._layer(i, p, x, cdt))(p, x)
             held = held + sizes.sum()
-        pooled_h = rms_norm(x.sum(axis=2), params["norm_f"],
-                            self.eps).mean(axis=1)
+        H = self.hidden
+        out = sum(x[..., j * H:(j + 1) * H] for j in range(self.n))
+        pooled_h = rms_norm(out, params["norm_f"], self.eps).mean(axis=1)
         logits = (self.head_scale * (pooled_h @ params["w_out"].astype(F32))
                   + params["b_out"].astype(F32))
         if counters is not None:
+            B, S = pooled.shape[:2]
+            fused = 2 * self.num_layers if fused_block(B * S, self.n, H) else 0
             counters["moe_pairs_held"] = held
+            counters["mhc_fused_tokens"] = jnp.asarray(B * S * fused,
+                                                       jnp.int32)
         return logits

@@ -125,7 +125,32 @@ def test_float32_matches_reference_tightly(tower, monkeypatch):
     alone: its gradient is nought on both sides. The pairs counted are
     the reference router's over each routed layer's own input."""
     small_tiles(monkeypatch)
-    model, params, pooled, labels, (want_loss, (want_gp, want_gx)) = tower
+    model, params, pooled, labels, want = tower
+    counts = matches_reference(CFG, model, params, pooled, labels, want)
+    assert int(counts["moe_pairs_held"]) == reference_pairs(params, pooled)
+    assert int(counts["mhc_fused_tokens"]) == 0     # hidden 32: XLA form
+
+
+def test_fused_hyper_connections_match_reference(monkeypatch):
+    """Hidden 128, a lane-aligned stream: every hyper-connection takes
+    ops/mhc.py's four kernels (interpreted here) and the tower reads as
+    the plain reference does, to the tolerances of (a); the counter holds
+    tokens x sublayers."""
+    small_tiles(monkeypatch)
+    cfg = dict(CFG, hidden_size=128, embedx_dim=128)
+    model, params, pooled, labels = seeded(cfg, seed=2)
+    want = jax.jit(jax.value_and_grad(
+        lambda p, x: bce(ref.forward(cfg, p, x), labels), argnums=(0, 1)))(
+            params, pooled)
+    counts = matches_reference(cfg, model, params, pooled, labels, want)
+    assert int(counts["mhc_fused_tokens"]) == (
+        B * S * 2 * cfg["num_hidden_layers"])
+
+
+def matches_reference(cfg, model, params, pooled, labels, want):
+    """Logits, loss and every leaf's gradient against the reference's
+    (want: its loss and gradients); the step counters handed back."""
+    want_loss, (want_gp, want_gx) = want
 
     def loss_fn(p, x):
         counts = {}
@@ -134,7 +159,7 @@ def test_float32_matches_reference_tightly(tower, monkeypatch):
     (loss, (logits, counts)), (gp, gx) = jax.jit(jax.value_and_grad(
         loss_fn, argnums=(0, 1), has_aux=True))(params, pooled)
     np.testing.assert_allclose(
-        logits, jax.jit(lambda p, x: ref.forward(CFG, p, x))(params, pooled),
+        logits, jax.jit(lambda p, x: ref.forward(cfg, p, x))(params, pooled),
         rtol=2e-5, atol=2e-6)
     assert abs(float(loss) - float(want_loss)) < 1e-6
     assert rel(gx, want_gx) < 1e-4
@@ -144,7 +169,7 @@ def test_float32_matches_reference_tightly(tower, monkeypatch):
             continue
         assert np.any(want_gp[name]), name
         assert rel(gp[name], want_gp[name]) < 1e-4, name
-    assert int(counts["moe_pairs_held"]) == reference_pairs(params, pooled)
+    return counts
 
 
 def reference_pairs(params, pooled):
@@ -244,7 +269,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(monkeypatch):
     model = build(cfg)
     mid = jax.jit(lambda p, x: model._connected(p, "a_", x, lambda u: (
         model._mla(p, rms_norm(u, p["attn_norm"], cfg["rms_norm_eps"]),
-                   jnp.float32), None))[0])(p, x)
+                   jnp.float32), None))[0])(p, x.reshape(B, S, -1))
     total = None
     for chip in range(8):
         scfg = dict(cfg, n_routed_experts=1, expert_offset=chip)
@@ -253,9 +278,10 @@ def test_expert_shares_add_up_to_the_uncut_layer(monkeypatch):
         share = build(scfg)
         ffn = jax.jit(lambda sp, mid: share._connected(
             sp, "f_", mid, lambda u: share._ffn(1, sp, u, jnp.float32))[0])
-        got = ffn(sp, mid)
+        got = ffn(sp, mid).reshape(x.shape)
         assert rel(got, ref.layer(scfg, 1, sp, x)) < 2e-5, chip
-        silent = ffn(dict(sp, e_down=jnp.zeros_like(sp["e_down"])), mid)
+        silent = ffn(dict(sp, e_down=jnp.zeros_like(sp["e_down"])),
+                     mid).reshape(x.shape)
         total = silent if total is None else total
         total = total + (got - silent)
     assert rel(total, want) < 2e-5

@@ -99,11 +99,14 @@ def test_passes_match_the_references_steps_and_the_loss_falls(
     monkeypatch.setattr(module, "TILING", (32, 32, 32))
     assert MODEL_ZOO["xing4_0"] is type(build(CFG))
     model = build(CFG)
-    assert model.step_counters == ("moe_pairs_held",)
+    assert model.step_counters == ("moe_pairs_held", "mhc_fused_tokens")
     device.monitor().reset()
     before = stat_get("moe_pairs_held")
+    fused = stat_get("mhc_fused_tokens")
     losses, params, rows = passes(model, data)
     pairs = stat_get("moe_pairs_held") - before
+    # hidden 32 is no lane-aligned stream: the XLA form, nothing counted
+    assert stat_get("mhc_fused_tokens") == fused
     compiles = device.snapshot()["entries"]["scan_steps"]["compiles"]
     want_losses, want_params, want_rows = plain
     np.testing.assert_allclose(losses, want_losses, rtol=1e-5)
