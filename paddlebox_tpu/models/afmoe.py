@@ -82,12 +82,13 @@ class AfMoE:
     apply(params, pooled [B, S, 3 + hidden], dense) -> logits [B]. A
     caller that hands ``counters`` (a dict of its own trace) gets
     ``step_counters`` put into it: "moe_pairs_held", the pairs routed to
-    held experts over all layers; the trainer adds it to utils/stats at a
-    chunk's drain."""
+    held experts over all layers, and "moe_combine_rows", the rows the
+    layers' forward combines fetched (ops/routed_experts.routed_experts);
+    the trainer adds them to utils/stats at a chunk's drain."""
 
     name = "afmoe"
     task_names = ("ctr",)
-    step_counters = ("moe_pairs_held",)
+    step_counters = ("moe_pairs_held", "moe_combine_rows")
 
     def __init__(self, spec: ModelSpec, *, layer_types: Sequence[str],
                  num_dense_layers: int, hidden: int, heads: int,
@@ -184,14 +185,14 @@ class AfMoE:
         with jax.named_scope("moe_route"):
             experts, weights = route(flat, p["router_w"], p["router_b"],
                                      self.top_k, self.route_scale)
-        y, sizes = routed_experts(
+        y, sizes, fetched = routed_experts(
             flat.astype(cdt), experts, weights, p["e_gate"], p["e_up"],
             p["e_down"], self.expert_offset, self.num_experts)
-        pairs = sizes.sum()
         with jax.named_scope("dense_mlp"):
             shared = swiglu(flat.astype(cdt), p["s_gate"], p["s_up"],
                             p["s_down"])
-        return (y + shared.astype(F32)).reshape(B, S, H), pairs
+        return ((y + shared.astype(F32)).reshape(B, S, H),
+                jnp.stack([sizes.sum(), fetched]))
 
     def _layer(self, i: int, p, h, cdt):
         sliding = self.layer_types[i] == SLIDING
@@ -203,27 +204,28 @@ class AfMoE:
             with jax.named_scope("dense_mlp"):
                 f = swiglu(x.astype(cdt), p["w_gate"], p["w_up"],
                            p["w_down"])
-            pairs = jnp.zeros((), jnp.int32)
+            counts = jnp.zeros((2,), jnp.int32)
         else:
-            f, pairs = self._routed(p, x, cdt)
-        return a + rms_norm(f, p["norm4"], self.eps), pairs
+            f, counts = self._routed(p, x, cdt)
+        return a + rms_norm(f, p["norm4"], self.eps), counts
 
     # -------------------------------------------------------------- apply
     def apply(self, params, pooled, dense=None, counters=None):
         cdt = pooled.dtype
         h = pooled[..., 3:].astype(F32) * np.sqrt(self.hidden)
-        pairs = jnp.zeros((), jnp.int32)
+        counts = jnp.zeros((2,), jnp.int32)   # pairs held, rows fetched
         for i in range(len(self.layer_types)):
             prefix = "l%d." % i
             p = {k[len(prefix):]: v for k, v in params.items()
                  if k.startswith(prefix)}
             h, n = jax.checkpoint(
                 lambda p, h, i=i: self._layer(i, p, h, cdt))(p, h)
-            pairs = pairs + n
+            counts = counts + n
         pooled_h = rms_norm(h, params["norm_f"], self.eps).mean(axis=1)
         logits = (self.head_scale * (pooled_h @ params["w_out"].astype(F32))
                   + params["b_out"].astype(F32))
         if counters is not None:
-            counters["moe_pairs_held"] = pairs
+            counters["moe_pairs_held"] = counts[0]
+            counters["moe_combine_rows"] = counts[1]
         return logits
 

@@ -98,13 +98,14 @@ class NemotronH:
     x state-space layers), "moe_pairs_held" (the pairs routed to held
     experts, all LatentMoE layers) and "moe_pairs_max_expert" (the fullest
     held expert's pairs, summed over those layers: the imbalance the
-    grouped products pay for); the trainer adds them to utils/stats at a
-    chunk's drain."""
+    grouped products pay for) and "moe_combine_rows" (the rows those
+    layers' forward combines fetched, ops/routed_experts.routed_experts);
+    the trainer adds them to utils/stats at a chunk's drain."""
 
     name = "nemotron_h"
     task_names = ("ctr",)
     step_counters = ("ssd_chunks_scanned", "moe_pairs_held",
-                     "moe_pairs_max_expert")
+                     "moe_pairs_max_expert", "moe_combine_rows")
 
     def __init__(self, spec: ModelSpec, *, pattern: Sequence[str],
                  hidden: int, ssm_heads: int, ssm_head_dim: int,
@@ -255,41 +256,45 @@ class NemotronH:
         xc = flat.astype(cdt)
         with jax.named_scope("moe_latent"):
             z = xc @ p["fc1"]
-        y, sizes = routed_experts(z, experts, weights, None, p["e_up"],
-                                  p["e_down"], self.expert_offset,
-                                  self.num_experts)
+        y, sizes, fetched = routed_experts(
+            z, experts, weights, None, p["e_up"], p["e_down"],
+            self.expert_offset, self.num_experts)
         with jax.named_scope("moe_latent"):
             out = y.astype(cdt) @ p["fc2"]
         with jax.named_scope("moe_shared"):
             shared = relu2(xc @ p["s_up"]) @ p["s_down"]
-        return (out.astype(F32) + shared.astype(F32)).reshape(B, S, H), sizes
+        return ((out.astype(F32) + shared.astype(F32)).reshape(B, S, H),
+                sizes, fetched)
 
     def _layer(self, i: int, p, h, cdt):
-        """(h', pairs routed to each held expert: nought off an E layer)."""
+        """(h', pairs routed to each held expert, rows the combine
+        fetched: nought off an E layer)."""
         p = {k: (v.astype(cdt) if k in _MATRICES else v)
              for k, v in p.items()}
         u = rms_norm(h, p["norm"], self.eps)
         sizes = jnp.zeros((self.experts_held,), jnp.int32)
+        fetched = jnp.zeros((), jnp.int32)
         if self.pattern[i] == MOE:
-            mixed, sizes = self._latent_moe(p, u, cdt)
+            mixed, sizes, fetched = self._latent_moe(p, u, cdt)
         elif self.pattern[i] == MAMBA:
             mixed = self._mamba(p, u, cdt)
         else:
             mixed = self._attention(p, u, cdt)
-        return h + mixed.astype(F32), sizes
+        return h + mixed.astype(F32), sizes, fetched
 
     # -------------------------------------------------------------- apply
     def apply(self, params, pooled, dense=None, counters=None):
         cdt = pooled.dtype
         h = pooled[..., 3:].astype(F32)
-        held = fullest = jnp.zeros((), jnp.int32)
+        held = fullest = rows = jnp.zeros((), jnp.int32)
         for i in range(len(self.pattern)):
             prefix = "l%d." % i
             p = {k[len(prefix):]: v for k, v in params.items()
                  if k.startswith(prefix)}
-            h, sizes = jax.checkpoint(
+            h, sizes, fetched = jax.checkpoint(
                 lambda p, h, i=i: self._layer(i, p, h, cdt))(p, h)
             held, fullest = held + sizes.sum(), fullest + sizes.max()
+            rows = rows + fetched
         pooled_h = rms_norm(h, params["norm_f"], self.eps).mean(axis=1)
         logits = (self.head_scale * (pooled_h @ params["w_out"].astype(F32))
                   + params["b_out"].astype(F32))
@@ -300,4 +305,5 @@ class NemotronH:
                 * self.pattern.count(MAMBA), jnp.int32)
             counters["moe_pairs_held"] = held
             counters["moe_pairs_max_expert"] = fullest
+            counters["moe_combine_rows"] = rows
         return logits

@@ -112,14 +112,17 @@ class Xing4:
     apply(params, pooled [B, S, 3 + hidden], dense) -> logits [B]. A
     caller that hands ``counters`` (a dict of its own trace) gets
     ``step_counters`` put into it: "moe_pairs_held", the pairs routed to
-    held experts over all routed layers, and "mhc_fused_tokens", tokens x
+    held experts over all routed layers, "moe_combine_rows", the rows
+    their forward combines fetched (ops/routed_experts.routed_experts),
+    and "mhc_fused_tokens", tokens x
     sublayers whose hyper-connection took ops/mhc.py's kernels (a witness
     that they ran, decided by the shapes); the trainer adds them to
     utils/stats at a chunk's drain."""
 
     name = "xing4_0"
     task_names = ("ctr",)
-    step_counters = ("moe_pairs_held", "mhc_fused_tokens")
+    step_counters = ("moe_pairs_held", "moe_combine_rows",
+                     "mhc_fused_tokens")
 
     def __init__(self, spec: ModelSpec, *, num_layers: int,
                  num_dense_layers: int, hidden: int, heads: int,
@@ -258,29 +261,31 @@ class Xing4:
             return o.transpose(0, 2, 1, 3).reshape(B, S, -1) @ p["o"]
 
     def _routed(self, p, u, cdt):
-        """(F(u) [B, S, H] float32, pairs routed to each held expert)."""
+        """(F(u) [B, S, H] float32, (pairs held, rows the combine
+        fetched))."""
         B, S, H = u.shape
         flat = u.reshape(B * S, H)
         with jax.named_scope("moe_route"):
             experts, weights = route(flat, p["router_w"], p["router_b"],
                                      self.top_k, self.route_scale)
         xc = flat.astype(cdt)
-        y, sizes = routed_experts(xc, experts, weights, p["e_gate"],
-                                  p["e_up"], p["e_down"], self.expert_offset,
-                                  self.num_experts)
+        y, sizes, fetched = routed_experts(
+            xc, experts, weights, p["e_gate"], p["e_up"], p["e_down"],
+            self.expert_offset, self.num_experts)
         with jax.named_scope("moe_shared"):
             shared = swiglu(xc, p["s_gate"], p["s_up"], p["s_down"])
-        return (y + shared.astype(F32)).reshape(B, S, H), sizes
+        return (y + shared.astype(F32)).reshape(B, S, H), jnp.stack(
+            [sizes.sum(), fetched])
 
     def _ffn(self, i: int, p, u, cdt):
-        """(F(N_f(u)), pairs routed to each held expert: nought on a dense
-        layer)."""
+        """(F(N_f(u)), (pairs held, rows the combine fetched): nought on a
+        dense layer)."""
         u = rms_norm(u, p["ffn_norm"], self.eps)
         if i >= self.num_dense_layers:
             return self._routed(p, u, cdt)
         with jax.named_scope("dense_mlp"):
             f = swiglu(u.astype(cdt), p["w_gate"], p["w_up"], p["w_down"])
-        return f, jnp.zeros((self.experts_held,), jnp.int32)
+        return f, jnp.zeros((2,), jnp.int32)
 
     def _connected(self, p, pre: str, x, sublayer):
         """(M(x; sublayer), what the sublayer hands back beside its
@@ -290,8 +295,8 @@ class Xing4:
                                 **self.hc)
 
     def _layer(self, i: int, p, x, cdt):
-        """(x' [B, S, n H], pairs routed to each held expert). The first
-        layer gets the embedding [B, S, H] and copies it into the n
+        """(x' [B, S, n H], (pairs held, rows the combine fetched)). The
+        first layer gets the embedding [B, S, H] and copies it into the n
         streams here, inside its checkpoint: what it keeps for the
         backward pass is the one copy."""
         p = {k: (v.astype(cdt) if k in _MATRICES else v)
@@ -307,14 +312,14 @@ class Xing4:
     def apply(self, params, pooled, dense=None, counters=None):
         cdt = pooled.dtype
         x = pooled[..., 3:].astype(F32)
-        held = jnp.zeros((), jnp.int32)
+        counts = jnp.zeros((2,), jnp.int32)   # pairs held, rows fetched
         for i in range(self.num_layers):
             prefix = "l%d." % i
             p = {k[len(prefix):]: v for k, v in params.items()
                  if k.startswith(prefix)}
-            x, sizes = jax.checkpoint(
+            x, n = jax.checkpoint(
                 lambda p, x, i=i: self._layer(i, p, x, cdt))(p, x)
-            held = held + sizes.sum()
+            counts = counts + n
         H = self.hidden
         out = sum(x[..., j * H:(j + 1) * H] for j in range(self.n))
         pooled_h = rms_norm(out, params["norm_f"], self.eps).mean(axis=1)
@@ -323,7 +328,8 @@ class Xing4:
         if counters is not None:
             B, S = pooled.shape[:2]
             fused = 2 * self.num_layers if fused_block(B * S, self.n, H) else 0
-            counters["moe_pairs_held"] = held
+            counters["moe_pairs_held"] = counts[0]
+            counters["moe_combine_rows"] = counts[1]
             counters["mhc_fused_tokens"] = jnp.asarray(B * S * fused,
                                                        jnp.int32)
         return logits

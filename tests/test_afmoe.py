@@ -306,7 +306,7 @@ def test_shares_add_up_to_the_uncut_layer(tower):
     experts, weights = route(x, p["router_w"], p["router_b"], 2, 2.826)
     parts, pairs = [], 0
     for chip in range(E):
-        y, n = jax.jit(routed_experts, static_argnums=(6, 7))(
+        y, n, _ = jax.jit(routed_experts, static_argnums=(6, 7))(
             x, experts, weights, full["e_gate"][chip:chip + 1],
             full["e_up"][chip:chip + 1], full["e_down"][chip:chip + 1],
             chip, E)
@@ -332,7 +332,7 @@ def test_routing_under_imbalance_drops_nothing(tower, bias_at, held_pairs,
     p["router_b"] = jnp.zeros(8).at[jnp.asarray(bias_at)].set(10.0)
     x = jax.random.normal(jax.random.PRNGKey(11), (B * S, 64))
     experts, weights = route(x, p["router_w"], p["router_b"], 2, 2.826)
-    y, n = jax.jit(routed_experts, static_argnums=(6, 7))(
+    y, n, _ = jax.jit(routed_experts, static_argnums=(6, 7))(
         x, experts, weights, p["e_gate"], p["e_up"], p["e_down"], 3, 8)
     assert int(n.sum()) == held_pairs == ref.pairs_held(CFG, p, x)
     # all pairs held is twice a chunk: the second chunk runs too

@@ -92,8 +92,10 @@ def test_one_pass_matches_the_references_steps(data, write):
     flags.set_flag("push_write", write)
     model = build(CFG)
     pairs0 = stat_get("moe_pairs_held")
+    fetched0 = stat_get("moe_combine_rows")
     losses, params, rows = one_pass(model, data)
     pairs = stat_get("moe_pairs_held") - pairs0
+    fetched = stat_get("moe_combine_rows") - fetched0
     want_losses, want_params, want_rows = one_pass(PlainTower(model), data)
     np.testing.assert_allclose(losses, want_losses, rtol=1e-5)
     for name in params:
@@ -104,6 +106,9 @@ def test_one_pass_matches_the_references_steps(data, write):
     # at an even routing; the plain tower's passes add nothing
     assert 0 < pairs <= 8 * 64 * 2
     assert stat_get("moe_pairs_held") - pairs0 == pairs
+    # the combines fetch every held pair's row, in whole row groups
+    assert pairs <= fetched
+    assert stat_get("moe_combine_rows") - fetched0 == fetched
 
 
 # -------------------------------------------------------------- donation

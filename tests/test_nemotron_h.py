@@ -363,7 +363,7 @@ def test_relu2_experts_match_a_loop_over_experts(bias_at, held_pairs, dtype,
     experts, weights = route(x, p["router_w"], p["router_b"], 3, 5.0)
     w_all, _ = ref.router(cfg, p, x)
     mats = (p["e_up"].astype(dtype), p["e_down"].astype(dtype))
-    y, sizes = jax.jit(routed_experts, static_argnums=(6, 7))(
+    y, sizes, _ = jax.jit(routed_experts, static_argnums=(6, 7))(
         z.astype(dtype), experts, weights, None, *mats, 3, 8)
     np.testing.assert_array_equal(sizes, ref.pairs_by_expert(cfg, p, x))
     assert int(sizes.sum()) == held_pairs
@@ -404,8 +404,8 @@ def test_a_gate_makes_the_expert_a_swiglu(bias_at, held_pairs, monkeypatch):
     _cfg, p, x, z = routed_setup(bias_at)
     gate = jax.random.normal(jax.random.PRNGKey(13), p["e_up"].shape) / 6.0
     experts, weights = route(x, p["router_w"], p["router_b"], 3, 5.0)
-    y, sizes = routed_experts(z, experts, weights, gate, p["e_up"],
-                              p["e_down"], 3, 8)
+    y, sizes, _ = routed_experts(z, experts, weights, gate, p["e_up"],
+                                 p["e_down"], 3, 8)
     def plain(z, mats):
         gate, up, down = mats
         return sum(weights[:, c:c + 1] * jnp.where(

@@ -95,7 +95,8 @@ def test_passes_match_the_references_steps_and_the_loss_falls(
     monkeypatch.setattr(module, "TILING", (32, 64, 32))
     assert MODEL_ZOO["nemotron_h"] is type(build(CFG))
     model = build(CFG)
-    names = ("ssd_chunks_scanned", "moe_pairs_held", "moe_pairs_max_expert")
+    names = ("ssd_chunks_scanned", "moe_pairs_held", "moe_pairs_max_expert",
+             "moe_combine_rows")
     assert model.step_counters == names
     device.monitor().reset()
     before = {n: stat_get(n) for n in names}
@@ -124,5 +125,7 @@ def test_passes_match_the_references_steps_and_the_loss_falls(
     pairs, fullest = got["moe_pairs_held"], got["moe_pairs_max_expert"]
     assert 0 < pairs < steps * 2 * BATCH * 24 * 3
     assert pairs / 4 <= fullest < pairs
+    # the combines fetch every held pair's row, in whole row groups
+    assert pairs <= got["moe_combine_rows"]
     # no compile after the first pass
     assert compiles == 1

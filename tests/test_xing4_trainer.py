@@ -99,12 +99,16 @@ def test_passes_match_the_references_steps_and_the_loss_falls(
     monkeypatch.setattr(module, "TILING", (32, 32, 32))
     assert MODEL_ZOO["xing4_0"] is type(build(CFG))
     model = build(CFG)
-    assert model.step_counters == ("moe_pairs_held", "mhc_fused_tokens")
+    assert model.step_counters == ("moe_pairs_held", "moe_combine_rows",
+                                   "mhc_fused_tokens")
     device.monitor().reset()
     before = stat_get("moe_pairs_held")
+    fetched = stat_get("moe_combine_rows")
     fused = stat_get("mhc_fused_tokens")
     losses, params, rows = passes(model, data)
     pairs = stat_get("moe_pairs_held") - before
+    # the combines fetch every held pair's row, in whole row groups
+    assert pairs <= stat_get("moe_combine_rows") - fetched
     # hidden 32 is no lane-aligned stream: the XLA form, nothing counted
     assert stat_get("mhc_fused_tokens") == fused
     compiles = device.snapshot()["entries"]["scan_steps"]["compiles"]

@@ -66,8 +66,8 @@ def main() -> int:
         w = jax.random.uniform(ks[7], (T, K), jnp.float32, 0.1, 0.4)
 
         def looped(x, w, wu, wd):
-            y, sizes = module.routed_experts(x, experts, w, None, wu, wd,
-                                             off, E)
+            y, sizes, _ = module.routed_experts(x, experts, w, None, wu,
+                                                wd, off, E)
             return (y * t).sum(), (y, sizes)
 
         def dense(x, w, wu, wd):
